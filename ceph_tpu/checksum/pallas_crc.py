@@ -45,23 +45,27 @@ BLOCK_TILE = 512
 
 
 def plane_fold_kb(block_bytes: int) -> np.ndarray:
-    """[8, block_bytes, 32] int8 per-plane fold matrices for ONE
-    zero-init csum block: kb[b][p, :] = the 32 crc-register bits
+    """[8, 32, block_bytes] int8 per-plane fold matrices for ONE
+    zero-init csum block: kb[b][:, p] = the 32 crc-register bits
     contributed by bit b of byte p of the block.
 
     This is the fold machinery the fused encode+checksum epilogue
     (ops/pallas_encode.gf_encode_csum_bitplane_pallas) keeps stationary
     in VMEM: the encode kernel already holds each tile's bit planes in
-    registers, so per-block CRCs are 8 extra [rows, block] @ kb[b]
-    dots — no second unpack, no second HBM pass."""
+    registers, so per-block CRCs are 8 extra [rows, block] x kb[b]^T
+    dots — no second unpack, no second HBM pass. The block axis is
+    MINOR: a 32-wide minor dimension pads to 128 lanes in VMEM, which
+    made the cb=4096 table 4 MiB per buffer (8 MiB double-buffered,
+    half of v5e's 16 MiB scoped VMEM); this layout is the exact int8
+    (32, 128) tile — 1 MiB per buffer."""
     from .crc32c import _pick_chunk, fold_tensor
 
     c = _pick_chunk(block_bytes)
     kf = fold_tensor(block_bytes, c)  # [S, 32, c*8]
     flat = np.transpose(kf, (1, 0, 2)).reshape(32, block_bytes * 8)
-    out = np.empty((8, block_bytes, 32), dtype=np.int8)
+    out = np.empty((8, 32, block_bytes), dtype=np.int8)
     for b in range(8):
-        out[b] = flat[:, b::8].T
+        out[b] = flat[:, b::8]
     return out
 
 
@@ -183,9 +187,9 @@ def crc32c_fold_pallas(
     from .crc32c import _pick_chunk, zero_gap_matrix
 
     if interpret is None:
-        from ceph_tpu.ops.pallas_encode import on_tpu
+        from ceph_tpu.utils import platform
 
-        interpret = not on_tpu()
+        interpret = platform.pallas_interpret()
     nblocks, block_bytes = data.shape
     c = _pick_chunk(block_bytes)
     kt = _kt_cached(block_bytes, c)

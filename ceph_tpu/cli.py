@@ -865,6 +865,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    from ceph_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     cl = Cluster(args.dir)
     try:
         return args.fn(cl, args)

@@ -43,6 +43,7 @@ from .matrix_codec import (
     BitplaneDispatchMixin,
     DecodeTableCache,
     _dispatch_counters,
+    count_route,
     dev_bmat,
 )
 
@@ -319,7 +320,7 @@ class BitMatrixCodec(BitplaneDispatchMixin, ErasureCodeBase):
         if not multi and self._host_sized(packets):
             from ceph_tpu.gf import gf_apply_bytes_host
 
-            _dispatch_counters().inc(f"host_{op}")
+            count_route(f"host_{op}", packets)
             out = gf_apply_bytes_host(mat01, np.asarray(packets))
             return self._to_chunks(out)
         out = None
@@ -340,7 +341,7 @@ class BitMatrixCodec(BitplaneDispatchMixin, ErasureCodeBase):
             ):
                 _dispatch_counters().inc("sched_rejected_shape")
             else:
-                _dispatch_counters().inc(f"sched_{op}")
+                count_route(f"sched_{op}", packets)
                 out = xor_schedule.xor_schedule_apply(rows, packets)
         if out is None:
             if tables:

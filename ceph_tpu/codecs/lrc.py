@@ -49,7 +49,7 @@ from ceph_tpu import PLUGIN_ABI_VERSION
 
 from .base import ErasureCodeBase, to_int
 from .interface import ErasureCodeProfile, Flag, SubChunkPlan
-from .matrix_codec import BitplaneDispatchMixin, _dispatch_counters
+from .matrix_codec import BitplaneDispatchMixin, count_route
 from .registry import registry
 
 
@@ -318,7 +318,7 @@ class LrcCodec(BitplaneDispatchMixin, ErasureCodeBase):
         if self._shards_host_route(shards, xp is np):
             from ceph_tpu.gf import gf_apply_bytes_host
 
-            _dispatch_counters().inc("host_encode")
+            count_route("host_encode", *shards)
             out = gf_apply_bytes_host(
                 self._composite, np.stack(shards, axis=-2)
             )

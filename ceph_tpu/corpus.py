@@ -215,10 +215,6 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--size", type=int, default=PAYLOAD_SIZE)
     args = p.parse_args(argv)
 
-    from ceph_tpu.utils import honor_platform_env
-
-    honor_platform_env()
-
     if args.action == "create":
         version = os.path.basename(os.path.normpath(args.base))
         suite = SUITES.get(version)

@@ -116,8 +116,8 @@ def gf_apply_bytes_host(mat: np.ndarray, stacked: np.ndarray) -> np.ndarray:
     XOR_c mat[r, c] * stacked[..., c, :].
 
     The small-op fast path (the reference's ec_encode_data on CPU):
-    device dispatch costs more than the math below ~1 MiB, especially
-    through a remote-device tunnel. Uses the native SIMD region kernel
+    device dispatch costs more than the math below a threshold
+    (``ec_host_dispatch_bytes``). Uses the native SIMD region kernel
     when built, the log/exp tables otherwise — both bit-identical to
     the device bit-plane path (verified in tests).
     """

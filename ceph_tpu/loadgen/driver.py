@@ -130,6 +130,23 @@ class LoadGenerator:
         )
         return b.create_perf_counters()
 
+    def adopt_objects(self, loader: "LoadGenerator") -> None:
+        """Continue on the working set a finished generator built —
+        a load phase followed by a mixed phase, each with its own
+        report. Both specs must derive the same oids and contents
+        (seed, object size, patch cap), or verification would compare
+        against bytes that were never written."""
+        a, b = self.spec, loader.spec
+        if (a.seed, a.object_size, a.rmw_max_len) != (
+            b.seed, b.object_size, b.rmw_max_len
+        ):
+            raise ValueError(
+                "adopt_objects needs equal seed/object_size/rmw_max_len"
+            )
+        with self._obj_lock:
+            self._objects = loader._objects
+            self._seq_next = loader._seq_next
+
     # -- op bookkeeping -------------------------------------------------
     def _next_op(self) -> int | None:
         """Claim the next global op number, or None when done."""

@@ -14,7 +14,9 @@ against the Python oracles in tests/test_native.py).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -23,35 +25,69 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "ceph_tpu_native.cc")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libceph_tpu_native.so")
+_CXXFLAGS = (
+    "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC", "-pthread",
+)
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _build() -> bool:
+def _host_cpu_flags() -> str:
+    """This host's CPU feature list — what ``-march=native`` compiles
+    against. A copied checkout (the chip tool ships the tree as it
+    stands, ``_build/`` included) must not load a library built for
+    another machine's instruction set."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return platform.machine() + " " + platform.processor()
+
+
+def _lib_path() -> str:
+    """The artefact is named by a hash of source, compiler flags and
+    the host's CPU flags: a stale or foreign build has another name,
+    so it is rebuilt here, never loaded."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_host_cpu_flags().encode())
+    return os.path.join(
+        _BUILD_DIR, f"libceph_tpu_native-{h.hexdigest()[:16]}.so"
+    )
+
+
+def _build(lib_path: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = [
-        "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-        _SRC, "-o", _LIB_PATH, "-pthread",
-    ]
+    # build beside the target and rename: a concurrent process never
+    # dlopens a half-written file
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXXFLAGS, _SRC, "-o", tmp]
     try:
         proc = subprocess.run(
             cmd, capture_output=True, text=True, timeout=120
         )
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    if proc.returncode != 0:
-        # -march=native can fail in exotic environments; retry plain.
-        cmd.remove("-march=native")
-        try:
+        if proc.returncode != 0:
+            # -march=native can fail in exotic environments; retry plain.
+            cmd.remove("-march=native")
             proc = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=120
             )
-        except (OSError, subprocess.TimeoutExpired):
+        if proc.returncode != 0:
             return False
-    return proc.returncode == 0
+        os.replace(tmp, lib_path)
+        return True
+    except (OSError, subprocess.TimeoutExpired):
+        return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _bind(lib: ctypes.CDLL) -> None:
@@ -122,15 +158,11 @@ def _load() -> ctypes.CDLL | None:
         _tried = True
         if os.environ.get("CEPH_TPU_NO_NATIVE"):
             return None
-        src_mtime = os.path.getmtime(_SRC)
-        stale = (
-            not os.path.exists(_LIB_PATH)
-            or os.path.getmtime(_LIB_PATH) < src_mtime
-        )
-        if stale and not _build():
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _build(lib_path):
             return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(lib_path)
             _bind(lib)
         except OSError:
             return None

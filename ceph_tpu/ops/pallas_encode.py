@@ -49,6 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ceph_tpu.utils import platform
+
 LANE_TILE = 2048       # minimum chunk-axis granularity the kernel accepts
 MAX_LANE_TILE = 65536  # sweep-best tile (grid-step overhead flat above)
 #: target combined lane width (stripes-per-step x tile) of one matmul:
@@ -348,9 +350,11 @@ def supported(data_shape: tuple[int, ...]) -> bool:
 #: block rows per grid step (sublane granularity: a 2D block's
 #: second-minor dim must be a multiple of 8 or the whole axis)
 SHARDS_SB = 8
-#: shards-form lane-tile cap: 64 KiB tiles crashed the remote Mosaic
-#: compiler at c=8 and measured no better than 32 KiB where they
-#: compiled (experiments/exp_r5_byteshards2.py)
+#: shards-form lane-tile cap, set in round 5 when 64 KiB tiles crashed
+#: that libtpu's Mosaic compiler at c=8 and measured no better than
+#: 32 KiB where they compiled (experiments/exp_r5_byteshards2.py).
+#: libtpu 0.0.34 compiles 64 KiB (AOT, round 21); whether it is faster
+#: is not measured, so the cap stays
 SHARDS_MAX_TILE = 32768
 #: widest contraction the shards form serves (F <= 16, one clean MXU
 #: pass); wider codes take the stacked kernel, which tiles the
@@ -463,7 +467,7 @@ def gf_encode_bitplane_pallas_shards(
     ``gf_encode_bitplane_pallas`` with neither side ever stacked.
     Callers gate with ``shards_supported``."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = platform.pallas_interpret()
     mat = np.ascontiguousarray(np.asarray(bitmatrix, dtype=np.uint8))
     r8, c8 = mat.shape
     lead = shards[0].shape[:-1]
@@ -485,13 +489,6 @@ def gf_encode_bitplane_pallas_shards(
     return [o.reshape(lead + (n,)) for o in outs]
 
 
-def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 # ------------------------------------------------- fused encode+checksum
 # One device pass for the whole write path: while each stripe's data
 # tiles are resident for the encode matmul, fold per-csum-block CRC32C
@@ -503,13 +500,22 @@ def on_tpu() -> bool:
 # path is bandwidth-bound, so that second HBM pass was the bill.
 #
 # The fold reuses checksum/pallas_crc's table machinery
-# (plane_fold_kb): per plane b a stationary [cb, 32] matrix whose row
-# p holds the crc-register contribution of bit b of byte p — the CRC
-# of one block is 8 extra [rows, cb] @ kb[b] MXU dots over bits the
-# kernel already holds. Csums come out ZERO-INIT; any seed is a
+# (plane_fold_kb): per plane b a stationary [32, cb] matrix whose
+# column p holds the crc-register contribution of bit b of byte p —
+# the CRC of one block is 8 extra [rows, cb] x kb[b]^T MXU dots over
+# bits the kernel already holds. Csums come out ZERO-INIT; any seed is a
 # constant XOR on the host (checksum.crc32c.crc32c_seed_shift), so
 # one kernel output serves BlueStore blob csums (seed -1), HashInfo
 # chaining, and wire csums alike.
+
+
+#: fused-kernel lane-tile caps. Stacked form: 32 KiB compiles at every
+#: geometry the write path produces. Shards form: its 8-stripe blocks
+#: and two-stripe [32, 64 Ki] int32 accumulator put a 32 KiB tile at
+#: 18.05 MB of v5e's 16 MiB scoped VMEM ("exceeded scoped vmem limit
+#: by 2.05M", libtpu 0.0.34); 16 KiB compiles with ~2 MB to spare
+FUSED_MAX_TILE = 32768
+FUSED_SHARDS_MAX_TILE = 16384
 
 
 @functools.lru_cache(maxsize=8)
@@ -530,7 +536,7 @@ def _crc_fold_tile(
     ([R, T]) — unpacked once more in registers (rows padded to the
     int32 sublane granularity), never via HBM. Returns [C+R, nb*32]
     int32 fold counts: per csum block q and plane b one
-    [C+R, cb] @ kb[b] dot, summed over the 8 planes — contraction cb,
+    [C+R, cb] x kb[b]^T dot, summed over the 8 planes — contraction cb,
     exactly the pallas_crc discipline, minus its unpack (already
     paid) and minus its HBM read (the data never left VMEM)."""
     t = parity8.shape[1]
@@ -554,22 +560,33 @@ def _crc_fold_tile(
             )  # [C+R, cb] bits of plane b
             part = jax.lax.dot_general(
                 rows, kb_ref[b],
-                (((1,), (0,)), ((), ())),
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32,
-            )  # [C+R, 32]
+            )  # [C+R, cb] x [32, cb]^T -> [C+R, 32]
             acc = part if acc is None else acc + part
         accs.append(acc)
     return accs[0] if nb == 1 else jnp.concatenate(accs, axis=1)
 
 
-def _csum_pack(acc, c, r, cb):
-    """[B, C+R, (N/cb)*32] int32 fold counts -> [B, C+R, N/cb] uint32
-    zero-init csums (mod 2 + LSB-first bit pack) — the tiny epilogue
-    outside the kernel, same as pallas_crc's."""
-    batch = acc.shape[0]
-    bits = (acc.reshape(batch, c + r, -1, 32) & 1).astype(jnp.uint32)
+def _csum_pack(acc, c, r):
+    """[B, N/tile, C+R, (tile/cb)*32] int32 fold counts ->
+    [B, C+R, N/cb] uint32 zero-init csums (mod 2 + LSB-first bit pack,
+    lane tiles back in shard order) — the tiny epilogue outside the
+    kernel, same as pallas_crc's.
+
+    The kernel emits one [C+R, nb*32] slab per (stripe, lane tile): a
+    block whose last two dims ARE the array's last two dims is legal
+    for any nb, where a [.., C+R, nb*32] block of a [.., C+R,
+    (N/cb)*32] array is rejected by the TPU lowering unless nb*32 is
+    a multiple of 128 or the whole axis (a 12 KiB tile at cb=4096 is
+    96 lanes)."""
+    batch, n_tiles = acc.shape[:2]
+    bits = (
+        acc.reshape(batch, n_tiles, c + r, -1, 32) & 1
+    ).astype(jnp.uint32)
     weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
+    packed = jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
+    return packed.transpose(0, 2, 1, 3).reshape(batch, c + r, -1)
 
 
 def _make_fused_kernel(c, r, s, pad, cb, interpret: bool):
@@ -597,10 +614,10 @@ def _make_fused_kernel(c, r, s, pad, cb, interpret: bool):
             )
             if s == 1:
                 out_ref[:] = tile.reshape(1, r, t)
-                csum_ref[:] = fold.reshape(1, c + r, nb * 32)
+                csum_ref[:] = fold.reshape(1, 1, c + r, nb * 32)
             else:
                 out_ref[si] = tile
-                csum_ref[si] = fold
+                csum_ref[si, 0] = fold
 
     return kernel
 
@@ -624,17 +641,19 @@ def _apply_tiled_csum(
         ],
         out_specs=[
             pl.BlockSpec((s, r, lane_tile), lambda b, ch: (b, 0, ch)),
-            pl.BlockSpec((s, c + r, nb * 32), lambda b, ch: (b, 0, ch)),
+            pl.BlockSpec(
+                (s, 1, c + r, nb * 32), lambda b, ch: (b, ch, 0, 0)
+            ),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((batch, r, n), jnp.uint8),
             jax.ShapeDtypeStruct(
-                (batch, c + r, (n // cb) * 32), jnp.int32
+                (batch, n // lane_tile, c + r, nb * 32), jnp.int32
             ),
         ],
         interpret=interpret,
     )(bmat_big, kb, data)
-    return parity, _csum_pack(acc, c, r, cb)
+    return parity, _csum_pack(acc, c, r)
 
 
 def fused_csum_supported(data_shape: tuple[int, ...], csum_block: int) -> bool:
@@ -672,7 +691,7 @@ def gf_encode_csum_bitplane_pallas(
     shards in input order, C..C+R-1 = the parity rows), all from one
     pallas_call. Callers gate with ``fused_csum_supported``."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = platform.pallas_interpret()
     mat = np.ascontiguousarray(np.asarray(bitmatrix, dtype=np.uint8))
     r8, c8 = mat.shape
     batch, c, n = data.shape
@@ -688,12 +707,11 @@ def gf_encode_csum_bitplane_pallas(
     kb = _kb_cached(csum_block)
     r = r8 // 8
     f = c + pad
-    # the fused epilogue adds the kb fold table (8*cb*32 int8) and the
-    # parity bit planes to the plain kernel's VMEM budget, and traced
-    # callers cannot retry a failed compile — cap the tile at the
-    # shards-form 32 KiB (measured no slower than 64 KiB where both
-    # compiled), with the plain kernel's wide-contraction shrink on top
-    cap = SHARDS_MAX_TILE if f <= 32 else max(
+    # the fused epilogue adds the kb fold table (8*32*cb int8) and the
+    # parity bit planes to the plain kernel's VMEM budget — cap the
+    # tile at 32 KiB, with the plain kernel's wide-contraction shrink
+    # on top
+    cap = FUSED_MAX_TILE if f <= 32 else max(
         max(csum_block, LANE_TILE), (65536 * 32) // f
     )
     tile = _pick_fused_tile(n, csum_block, cap)
@@ -701,25 +719,10 @@ def gf_encode_csum_bitplane_pallas(
     if not isinstance(data, jax.core.Tracer):
         big = _dev_cached(key, big)
         kb = _dev_cached(("kb", csum_block), kb)
-    else:
-        return _apply_tiled_csum(
-            big, kb, data, c, r, s, pad, tile, csum_block,
-            interpret=interpret,
-        )
-    step = max(csum_block, LANE_TILE)
-    while True:  # the eager compile-failure retry of the plain kernel
-        try:
-            return _apply_tiled_csum(
-                big, kb, data, c, r, s, pad, tile, csum_block,
-                interpret=interpret,
-            )
-        except Exception:
-            if s > 1:
-                s //= 2
-            elif tile > step:
-                tile = _pick_fused_tile(n, csum_block, tile - step)
-            else:
-                raise
+    return _apply_tiled_csum(
+        big, kb, data, c, r, s, pad, tile, csum_block,
+        interpret=interpret,
+    )
 
 
 # -- shards form --------------------------------------------------------
@@ -728,7 +731,7 @@ def fused_csum_shards_supported(
 ) -> bool:
     return (
         shards_supported(c, shape)
-        and 256 <= csum_block <= SHARDS_MAX_TILE
+        and 256 <= csum_block <= FUSED_SHARDS_MAX_TILE
         and csum_block & (csum_block - 1) == 0
         and shape[-1] % csum_block == 0
     )
@@ -741,8 +744,8 @@ def _shards_csum_fn(
 ):
     """Fused shards-form apply: the zero-waste shards kernel
     (_shards_fn) with the CRC fold epilogue per stripe — parity lands
-    in R per-shard refs, csums in one [B, C+R, (N/cb)*32] accumulator,
-    neither inputs nor outputs ever stacked in HBM."""
+    in R per-shard refs, csums in one [B, N/tile, C+R, nb*32]
+    accumulator (see ``_csum_pack``), neither inputs nor outputs ever stacked in HBM."""
     bitmatrix = np.frombuffer(mat_bytes, np.uint8).reshape(r8, c8)
     c, r = c8 // 8, r8 // 8
     pad = (-c) % 4
@@ -780,7 +783,7 @@ def _shards_csum_fn(
                 tile_o = out8[:, si * t : (si + 1) * t]
                 for j in range(r):
                     outs[j][q : q + 1, :] = tile_o[j : j + 1, :]
-                csum_ref[q] = _crc_fold_tile(
+                csum_ref[q, 0] = _crc_fold_tile(
                     planes[si], tile_o, kb_ref, c, f, r, rp, cb,
                     interpret,
                 )
@@ -805,7 +808,8 @@ def _shards_csum_fn(
             ]
             + [
                 pl.BlockSpec(
-                    (SHARDS_SB, c + r, nb * 32), lambda i, ch: (i, 0, ch)
+                    (SHARDS_SB, 1, c + r, nb * 32),
+                    lambda i, ch: (i, ch, 0, 0),
                 )
             ],
             out_shape=[
@@ -814,12 +818,12 @@ def _shards_csum_fn(
             ]
             + [
                 jax.ShapeDtypeStruct(
-                    (b, c + r, (n // cb) * 32), jnp.int32
+                    (b, n // tile, c + r, nb * 32), jnp.int32
                 )
             ],
             interpret=interpret,
         )(bmat, kb, *shards)
-        return list(outs[:r]) + [_csum_pack(outs[r], c, r, cb)]
+        return list(outs[:r]) + [_csum_pack(outs[r], c, r)]
 
     return apply, big, kb_np
 
@@ -835,7 +839,7 @@ def gf_encode_csum_bitplane_pallas_shards(
     zero-init csums) out. Callers gate with
     ``fused_csum_shards_supported``."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = platform.pallas_interpret()
     mat = np.ascontiguousarray(np.asarray(bitmatrix, dtype=np.uint8))
     r8, c8 = mat.shape
     lead = shards[0].shape[:-1]
@@ -844,7 +848,7 @@ def gf_encode_csum_bitplane_pallas_shards(
         raise ValueError(
             f"bitmatrix cols {c8} != shards*8 {len(shards) * 8}"
         )
-    tile = _pick_fused_tile(n, csum_block, SHARDS_MAX_TILE)
+    tile = _pick_fused_tile(n, csum_block, FUSED_SHARDS_MAX_TILE)
     s = _shards_lane_batch(tile)
     key = (mat.tobytes(), r8, c8, s, tile, csum_block, interpret)
     fn, big, kb = _shards_csum_fn(*key)
@@ -874,7 +878,7 @@ def gf_encode_bitplane_pallas(
     the zero-waste lane batching supersedes it."""
     del fold
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = platform.pallas_interpret()
     mat = np.ascontiguousarray(np.asarray(bitmatrix, dtype=np.uint8))
     r8, c8 = mat.shape
     batch, c, n = data.shape
@@ -901,24 +905,6 @@ def gf_encode_bitplane_pallas(
         while tile > LANE_TILE and tile > (65536 * 32) // f:
             tile //= 2
     s = _pick_lane_batch(batch, tile)
-    if isinstance(data, jax.core.Tracer):
-        # Under an outer trace the compile happens later, outside any
-        # try here — no retry is possible, so go with the sized tile.
-        return _apply_tiled(
-            big, data, c, r, s, pad, tile, interpret=interpret
-        )
-    # Eager call: retry on compile failure rather than refusing
-    # large k outright — shrink the combined lane width (stripes
-    # first, then the tile) until it compiles.
-    while True:
-        try:
-            return _apply_tiled(
-                big, data, c, r, s, pad, tile, interpret=interpret
-            )
-        except Exception:
-            if s > 1:
-                s //= 2
-            elif tile > LANE_TILE:
-                tile //= 2
-            else:
-                raise
+    return _apply_tiled(
+        big, data, c, r, s, pad, tile, interpret=interpret
+    )

@@ -66,6 +66,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ceph_tpu.utils import platform
+
 #: lane-tile granularity; multiples of 2048 keep uint8 blocks on the
 #: native (32, 128) tiling, and 8192 measured at/above every larger
 #: tile on v5e (grid-step overhead is already amortized there)
@@ -507,24 +509,19 @@ def _xla_apply(sel_rows, packets: jax.Array) -> jax.Array:
     return jnp.stack(outs, axis=-2)
 
 
-def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 def _n_rows(sel) -> int:
     """Output-row count of either schedule form."""
     return len(sel.outputs) if isinstance(sel, Schedule) else len(sel)
 
 
 # ---------------------------------------------------------- shards form
-#: scoped VMEM is 16 MiB on v5e; Mosaic's own scratch for this kernel
-#: measured ~3.8 MiB (a 12.58 MB block set OOMs by 396 KiB, an
-#: 11.0 MB set compiles), so gate the whole-chunk form at 12 MB of
-#: block bytes and leave the rest as headroom
-VMEM_BUDGET = 12_000_000
+#: scoped VMEM is 16 MiB on v5e. The pipeline double-buffers every
+#: operand and result block and the optimizer's scratch sits beside
+#: them; the compiler's own figure for a rejected shape is exactly
+#: 2 * block bytes + scratch bytes (libtpu 0.0.34: liberation 2-lost
+#: decode at 187264-byte chunks, "Scoped allocation with size 20.00M
+#: and limit 16.00M"). Gate that sum at 15 MiB.
+VMEM_BUDGET = 15 << 20
 SUBLANE = 8
 
 
@@ -539,9 +536,9 @@ def shards_supported(
 
     Requirements: 2D after lead-flatten, packet size lane-aligned,
     batch a sublane multiple (or small enough to be one block), and
-    (n_in + n_out) * sb * chunk — plus the optimizer's scratch,
-    ``n_slots`` live intermediate packets of sb * (chunk/w) bytes —
-    within the VMEM budget.
+    the double-buffered blocks 2 * (n_in + n_out) * sb * chunk — plus
+    the optimizer's scratch, ``n_slots`` live intermediate packets of
+    sb * (chunk/w) bytes — within the VMEM budget.
     """
     if len(shape) < 1:
         return False
@@ -550,8 +547,10 @@ def shards_supported(
     if chunk % w or (chunk // w) % 128:
         return False
     sb = SUBLANE if b % SUBLANE == 0 else b
-    blocks = (n_in + n_out) * sb * chunk + n_slots * sb * (chunk // w)
-    return blocks <= VMEM_BUDGET
+    rows = -(-sb // SUBLANE) * SUBLANE  # blocks pad to whole sublane tiles
+    blocks = 2 * (n_in + n_out) * rows * chunk
+    scratch = n_slots * rows * (chunk // w)
+    return blocks + scratch <= VMEM_BUDGET
 
 
 @functools.lru_cache(maxsize=256)
@@ -682,7 +681,7 @@ def xor_schedule_apply_shards(
     chunk = shards[0].shape[-1]
     n_out = _n_rows(sel_rows) // w
     if interpret is None:
-        if not on_tpu():
+        if not platform.on_tpu():
             stacked = jnp.stack(
                 [jnp.asarray(s) for s in shards], axis=-2
             )
@@ -690,7 +689,7 @@ def xor_schedule_apply_shards(
             out = _xla_apply(sel_rows, pk)
             ch = out.reshape(lead + (n_out, chunk))
             return [ch[..., j, :] for j in range(n_out)]
-        interpret = False
+        interpret = platform.pallas_interpret()
     b = int(np.prod(lead, initial=1))
     sb = SUBLANE if b % SUBLANE == 0 else b
     fn = _sched_shards_fn(sel_rows, n_in, w, chunk, sb, interpret)
@@ -712,9 +711,9 @@ def xor_schedule_apply(
     device array (callers on the host path use their own GF engine).
     """
     if interpret is None:
-        interpret = False
-        if not on_tpu():
+        if not platform.on_tpu():
             return _xla_apply(sel_rows, jnp.asarray(packets))
+        interpret = platform.pallas_interpret()
     lead = packets.shape[:-2]
     kw, p = packets.shape[-2:]
     if p % LANE_TILE:

@@ -34,6 +34,8 @@ objects too large for one chip's HBM.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,7 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ceph_tpu.ops.bitplane import pack_bits
 
-from .mesh import partial_parity_counts
+from .mesh import mesh_program, partial_parity_counts
 
 #: fixed fold granularity for the sequence-parallel CRC scan: keeps
 #: the fold-tensor constant bounded (<= 16 MiB) no matter how long
@@ -62,6 +64,14 @@ def ring_parity(
         from .mesh import sharded_encode
 
         return sharded_encode(mesh, bitmatrix, data)
+    return _ring_parity_fn(mesh, n)(bitmatrix, data)
+
+
+@functools.lru_cache(maxsize=64)
+def _ring_parity_fn(mesh: Mesh, n: int):
+    """The ring program for lane width ``n`` (the slice width is
+    static in it), built once per (mesh, n)."""
+    sp = mesh.shape["sp"]
     w = n // sp
     fwd = [(d, (d + 1) % sp) for d in range(sp)]
 
@@ -111,15 +121,12 @@ def ring_parity(
         out, _ = jax.lax.fori_loop(0, sp - 1, ag_step, (out, mine))
         return out
 
-    from .mesh import shard_map_compat
-
-    fn = shard_map_compat(
+    return mesh_program(
         local,
         mesh,
         in_specs=(P(None, "sp"), P("dp", "sp", None)),
         out_specs=P("dp", None, None),
     )
-    return fn(bitmatrix, data)
 
 
 def _suffix_transforms(n_shards: int, local_bytes: int) -> np.ndarray:
@@ -205,7 +212,6 @@ def sharded_crc32c(
     Returns [B] uint32."""
     from ceph_tpu.checksum.crc32c import (
         acc_to_crc32,
-        fold_blocks_bits,
         init_bits32,
         zero_gap_matrix,
     )
@@ -222,6 +228,22 @@ def sharded_crc32c(
     k_fb, a_fb = _fold_consts(fb)
     local_bytes = padded // n_dev
     suffix = _suffix_consts(n_dev, local_bytes)
+    acc = _sharded_crc_fn(mesh, axes, npieces, fb)(
+        k_fb, a_fb, suffix, data
+    )
+    a_true = jnp.asarray(
+        np.frombuffer(
+            zero_gap_matrix(total), dtype=np.uint8
+        ).reshape(32, 32),
+        jnp.int32,
+    )
+    acc = acc + (a_true @ init_bits32(init).astype(jnp.int32))
+    return acc_to_crc32(acc)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_crc_fn(mesh: Mesh, axes: tuple, npieces: int, fb: int):
+    from ceph_tpu.checksum.crc32c import fold_blocks_bits
 
     def local(kf, afb, sfx, blocks):
         pieces = blocks.reshape(blocks.shape[0], npieces, fb)
@@ -242,20 +264,9 @@ def sharded_crc32c(
         carried = local_bits @ a_sfx.T  # [B, 32] suffix-shifted
         return jax.lax.psum(carried, axes)  # one 32-int all-reduce
 
-    from .mesh import shard_map_compat
-
-    fn = shard_map_compat(
+    return mesh_program(
         local,
         mesh,
         in_specs=(P(), P(), P(), P(None, axes)),
         out_specs=P(),
     )
-    acc = fn(k_fb, a_fb, suffix, data)
-    a_true = jnp.asarray(
-        np.frombuffer(
-            zero_gap_matrix(total), dtype=np.uint8
-        ).reshape(32, 32),
-        jnp.int32,
-    )
-    acc = acc + (a_true @ init_bits32(init).astype(jnp.int32))
-    return acc_to_crc32(acc)

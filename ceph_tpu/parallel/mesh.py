@@ -20,7 +20,7 @@ the cross-device combine is a single standard all-reduce.
 
 from __future__ import annotations
 
-import math
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,24 +30,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ceph_tpu.ops.bitplane import pack_bits, unpack_bits
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the jax API window this repo spans:
-    new jax exports it top-level (replication check kwarg
-    ``check_vma``), 0.4.x keeps it in ``jax.experimental.shard_map``
-    (kwarg ``check_rep``). One seam so every collective call site
-    works on both — without it the whole mesh/DCN tier dies with
-    AttributeError on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
+def mesh_program(f, mesh: Mesh, in_specs, out_specs):
+    """``f`` as ONE jitted SPMD program over ``mesh``. Builders cache
+    the result per geometry (``functools.lru_cache``): an un-jitted
+    ``shard_map`` executes primitive by primitive, and a closure
+    rebuilt per call never hits jit's cache — the live path paid ~56
+    backend compilations for every dispatch that way."""
+    return jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
+        check_vma=False,
+    ))
 
 
 def make_ec_mesh(n_devices: int | None = None, k: int = 8) -> Mesh:
@@ -95,19 +87,23 @@ def sharded_encode(
     ``bitmatrix`` is the [m*8, k*8] GF(2) coding matrix; its column
     blocks are sharded over ``sp`` alongside the data shards.
     """
+    return _sharded_encode_fn(mesh)(bitmatrix, data)
+
+
+@functools.lru_cache(maxsize=16)
+def _sharded_encode_fn(mesh: Mesh):
     def local(bmat_cols: jax.Array, shards: jax.Array) -> jax.Array:
         acc = partial_parity_counts(bmat_cols, shards)
         acc = jax.lax.psum(acc, "sp")  # XOR-allreduce (mod 2 below)
         return pack_bits((acc & 1).astype(jnp.uint8))
 
     # bitmatrix columns follow the shard axis: [m*8, k*8] -> sp-sharded.
-    fn = shard_map_compat(
+    return mesh_program(
         local,
         mesh,
         in_specs=(P(None, "sp"), P("dp", "sp", None)),
         out_specs=P("dp", None, None),
     )
-    return fn(bitmatrix, data)
 
 
 def sharded_decode(

@@ -6,8 +6,8 @@ The role it fills is the reference's sharded op queues
 (osd/OSD.cc:9874-9933): many client ops across many PGs land on a
 shared queue and drain in batches. Here the batching axis IS the TPU
 win: one [B, k, L] device encode amortizes the per-dispatch launch
-(and, through a remote-device tunnel, the round trip) over every
-small op in the batch — the per-op path pays it per 4-64 KiB write.
+and transfer over every small op in the batch — the per-op path pays
+it per 4-64 KiB write.
 
 Shape of the machinery:
 
@@ -364,13 +364,21 @@ class StreamingDispatcher:
 
         try:
             counts = [nc for _, nc, _ in members]
-            stacked = np.concatenate(
-                [
+            total = sum(counts)
+            # The fused kernel is jitted on shape: every new sum(nc)
+            # would be a fresh Mosaic compile in the middle of a tick.
+            # Pad the stripe batch to the next power of two — a zero
+            # stripe encodes to zero parity (ZERO_INPUT_ZERO_OUTPUT)
+            # and its rows are never delivered — so a tick's batch
+            # sizes hit at most log2(max) compiled programs.
+            padded = 1 << (total - 1).bit_length()
+            stacked = np.zeros((padded, k, cs), np.uint8)
+            pos = 0
+            for _, nc, p in members:
+                stacked[pos : pos + nc] = (
                     p.reshape(k, nc, cs).transpose(1, 0, 2)
-                    for _, nc, p in members
-                ],
-                axis=0,
-            )  # [sum(nc), k, cs]
+                )
+                pos += nc
             pm, csums = self.codec.encode_chunks_with_csums(
                 {i: stacked[:, i, :] for i in range(k)}, cb
             )
@@ -379,7 +387,7 @@ class StreamingDispatcher:
             m = len(pm)
             out = np.stack(
                 [np.asarray(pm[k + j]) for j in range(m)], axis=1
-            )  # [sum(nc), m, cs]
+            )  # [padded, m, cs]
             csums = np.asarray(csums)
             results: list = []
             pos = 0

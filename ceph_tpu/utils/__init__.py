@@ -3,8 +3,9 @@ tracing — the ``src/common/`` analog layer."""
 
 from .platform import (
     apply_debug_modes,
-    honor_platform_env,
+    enable_compile_cache,
     install_debug_observer,
+    require_tpu,
 )
 from .perf_counters import (
     PerfCounters,
@@ -20,8 +21,9 @@ from .admin_socket import AdminSocket, admin_socket
 
 __all__ = [
     "apply_debug_modes",
-    "honor_platform_env",
+    "enable_compile_cache",
     "install_debug_observer",
+    "require_tpu",
     "PerfCounters",
     "PerfCountersBuilder",
     "PerfCountersCollection",

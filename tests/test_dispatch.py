@@ -13,6 +13,7 @@ from ceph_tpu.codecs.matrix_codec import _dispatch_counters
 from ceph_tpu.codecs.registry import registry
 from ceph_tpu.ops import pallas_encode as pe
 from ceph_tpu.ops.pallas_encode import LANE_TILE
+from ceph_tpu.utils import platform
 
 
 def _snap():
@@ -69,7 +70,7 @@ def test_host_paths_counted(rng, isa_codec):
 def test_pallas_fallback_counted(rng, isa_codec, monkeypatch):
     """Pallas enabled + on TPU + untileable shape -> fallback counter
     ticks and the einsum engine serves the op (no silent drop)."""
-    monkeypatch.setattr(pe, "on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     before = _snap()
     data = _device_chunks(rng, isa_codec, LANE_TILE + 256)
     isa_codec.encode_chunks(data)
@@ -82,7 +83,7 @@ def test_pallas_decode_path(rng, isa_codec, monkeypatch):
     """With the TPU predicate forced on (kernel in interpreter mode so
     CPU CI runs it), decode routes through the Pallas kernel and is
     bit-exact vs the original data."""
-    monkeypatch.setattr(pe, "on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     monkeypatch.setattr(
         pe,
         "gf_encode_bitplane_pallas",
@@ -103,7 +104,7 @@ def test_pallas_decode_path(rng, isa_codec, monkeypatch):
 def test_pallas_delta_path(rng, isa_codec, monkeypatch):
     import jax.numpy as jnp
 
-    monkeypatch.setattr(pe, "on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     monkeypatch.setattr(
         pe,
         "gf_encode_bitplane_pallas",
@@ -111,16 +112,18 @@ def test_pallas_delta_path(rng, isa_codec, monkeypatch):
     )
     data = _device_chunks(rng, isa_codec, LANE_TILE)
     parity = isa_codec.encode_chunks(data)
-    new0 = jnp.asarray(
+    new1 = jnp.asarray(
         rng.integers(0, 256, (LANE_TILE,), np.uint8)
     )
     before = _snap()
-    delta = {0: isa_codec.encode_delta(data[0], new0)}
+    # column 1, not 0: the ISA generator's column 0 is all ones, and a
+    # 0/1 delta column rides the XOR-schedule route on a TPU
+    delta = {1: isa_codec.encode_delta(data[1], new1)}
     updated = isa_codec.apply_delta(delta, parity)
     d = _delta(before, _snap())
     assert d.get("pallas_delta", 0) >= 1
     # parity after delta == parity of the updated data
-    data2 = dict(data) | {0: new0}
+    data2 = dict(data) | {1: new1}
     fresh = isa_codec.encode_chunks(data2)
     for pid in parity:
         np.testing.assert_array_equal(

@@ -358,9 +358,9 @@ def test_pallas_fallback_is_visible(monkeypatch, rng):
 
     from ceph_tpu.checksum import backends
     from ceph_tpu.checksum.crc32c import crc32c_device
-    from ceph_tpu.ops import pallas_encode as pe_mod
+    from ceph_tpu.utils import platform
 
-    monkeypatch.setattr(pe_mod, "on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     before = backends.counts().get("pallas_fallback", 0)
     data = rng.integers(0, 256, (3, 1000), np.uint8)  # untileable
     out = np.asarray(crc32c_device(jnp.asarray(data), SEED32))

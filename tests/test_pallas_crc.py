@@ -70,9 +70,9 @@ def test_device_dispatch_gates_on_tpu(rng, monkeypatch):
 
     from ceph_tpu.checksum import crc32c as crc_mod
     from ceph_tpu.checksum import pallas_crc
-    from ceph_tpu.ops import pallas_encode as pe
+    from ceph_tpu.utils import platform
 
-    monkeypatch.setattr(pe, "on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     called = []
     orig = pallas_crc.crc32c_fold_pallas
 

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from ceph_tpu.ops import xor_schedule
+from ceph_tpu.utils import platform
 
 
 @pytest.fixture
@@ -143,7 +144,7 @@ def test_codec_shards_route(rng, monkeypatch):
     from ceph_tpu.codecs import registry
     from ceph_tpu.codecs.matrix_codec import _dispatch_counters
 
-    monkeypatch.setattr(xor_schedule, "on_tpu", lambda: True)
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     orig = xor_schedule.xor_schedule_apply_shards
     monkeypatch.setattr(
         xor_schedule,

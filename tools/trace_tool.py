@@ -109,6 +109,9 @@ def main(argv: "list[str] | None" = None) -> int:
                         "traces in the report")
     args = p.parse_args(argv)
 
+    from ceph_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     spans: list[dict] = []
     ops: list[dict] = []
     for path in args.spans:
