@@ -43,7 +43,7 @@ from ceph_tpu.msg.messages import (
     OSDOpReply,
     WatchNotify,
 )
-from ceph_tpu.msg.messenger import Connection, Messenger
+from ceph_tpu.msg.messenger import Connection, Messenger, make_net_perf
 from ceph_tpu.utils import tracer
 from ceph_tpu.utils.optracker import NULL_OP, op_tracker
 
@@ -72,6 +72,11 @@ def _client_perf(name: str):
         PerfCountersBuilder(perf_collection, name)
         .add_u64_gauge("op_inflight", "ops currently in flight")
         .add_u64_counter("op_completed", "terminally successful ops")
+        .add_u64_counter(
+            "bytes_completed",
+            "payload bytes of terminally successful ops (written or "
+            "returned)",
+        )
         .add_u64_counter("op_resend", "attempts resent (retry loop)")
         .add_u64_counter("op_error", "terminally failed ops")
         .add_u64_counter(
@@ -184,6 +189,8 @@ class Objecter:
         self._inflight = 0
         # cluster PSK (keyring role): all client connections sealed
         self.messenger = Messenger("client", secret=secret)
+        if perf_name is not None:
+            self.messenger.net_pc = make_net_perf(f"{perf_name}.net")
         self.messenger.set_dispatcher(self._dispatch)
         self._conns: dict[tuple[str, int], Connection] = {}
         self._tids = itertools.count(1)
@@ -558,12 +565,15 @@ class Objecter:
         pc = self._pool_perf_for(aop.pool)
         if aop.op in self._WRITE_OPS:
             pc.inc("pool_op_w")
-            if aop.data:
-                pc.inc("pool_bytes_w", len(aop.data))
+            nbytes = len(aop.data)
+            if nbytes:
+                pc.inc("pool_bytes_w", nbytes)
         else:
             pc.inc("pool_op_r")
-            if reply is not None and reply.data:
-                pc.inc("pool_bytes_r", len(reply.data))
+            nbytes = len(reply.data) if reply is not None else 0
+            if nbytes:
+                pc.inc("pool_bytes_r", nbytes)
+        self.perf.inc("bytes_completed", nbytes)
 
     def _resolve(self, aop: _AsyncOp, reply, error) -> None:
         if self.perf is not None:

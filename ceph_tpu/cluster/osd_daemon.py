@@ -68,7 +68,7 @@ from ceph_tpu.msg.messages import (
     WatchNotify,
 )
 from ceph_tpu.msg.messages import serve_get_attrs
-from ceph_tpu.msg.messenger import Connection, Messenger
+from ceph_tpu.msg.messenger import Connection, Messenger, make_net_perf
 from ceph_tpu.msg.shard_server import NetShardBackend
 from ceph_tpu.codecs import registry
 from ceph_tpu.pipeline.extents import ExtentSet
@@ -87,6 +87,7 @@ from ceph_tpu.pipeline.rmw import (
 )
 from ceph_tpu.pipeline.stripe import StripeInfo
 from ceph_tpu.store import MemStore, Transaction
+from ceph_tpu.store.memstore import make_store_perf
 from ceph_tpu.utils import tracer
 from ceph_tpu.utils.lockdep import DebugLock
 from ceph_tpu.utils.mclock import MClockScheduler
@@ -114,19 +115,24 @@ class _ClientOpItem:
     worker can recognize a RUN of coalescable writes and execute
     them as one tick batch."""
 
-    __slots__ = ("daemon", "conn", "msg", "shard")
+    __slots__ = ("daemon", "conn", "msg", "shard", "t_enqueue")
 
     def __init__(self, daemon: "OSDDaemon", conn, msg) -> None:
         self.daemon = daemon
         self.conn = conn
         self.msg = msg
+        #: perf_counter when the reader thread queued it: ``opq_wait``
+        #: runs from here to the start of service
+        self.t_enqueue = time.perf_counter()
         #: op-shard this item was routed to at dispatch; execution
         #: serializes under that shard's lock (shard 0 == the classic
         #: single _op_lock path)
         self.shard = 0
 
     def __call__(self) -> None:
-        self.daemon._run_client_op(self.conn, self.msg, self.shard)
+        self.daemon._run_client_op(
+            self.conn, self.msg, self.shard, self.t_enqueue
+        )
 
     def coalescable(self) -> bool:
         return self.msg.op in _COALESCE_OPS
@@ -197,36 +203,24 @@ def _coalesce_perf(name: str):
     )
 
 
-def make_net_perf(name: str):
-    """The per-daemon ``net`` counter set (``perf dump`` section
-    ``osd.<id>.net``, Prometheus via the exporter): what the seeded
-    fault plane did to this daemon's links, and what the dedup tiers
-    absorbed — the observability half of the chaos contract (injected
-    faults MUST show up here, absorbed duplicates MUST show up there,
-    and the ledger still balances exactly-once)."""
+def make_opq_perf(name: str):
+    """The op queue's counter set (``perf dump`` section
+    ``osd.<id>.opq``), over client ops that went through the mClock
+    queue: how long they sat in it, how long the worker then served
+    them (the ``osd_op`` span's wall; for a coalesced tick batch the
+    batch's wall, once), and how much of that the worker thread was on
+    the CPU (``time.thread_time``): the rest of the service time it
+    waited — for the GIL, a socket, sub-op acks."""
     from ceph_tpu.utils import PerfCountersBuilder, perf_collection
 
     return (
         PerfCountersBuilder(perf_collection, name)
-        .add_u64_counter(
-            "frames_dropped", "frames dropped by fault injection"
-        )
-        .add_u64_counter(
-            "frames_delayed", "frames delayed by fault injection"
-        )
-        .add_u64_counter(
-            "frames_duped", "frames duplicated by fault injection"
-        )
-        .add_u64_counter(
-            "frames_reordered", "frames reordered by fault injection"
-        )
-        .add_u64_counter(
-            "resends_absorbed",
-            "duplicate/straggler sub-write acks with no pending op",
-        )
-        .add_u64_counter(
-            "dedup_hits",
-            "resent client mutations replayed from the reqid cache",
+        .add_u64_counter("ops", "client ops taken off the queue")
+        .add_time("wait_seconds", "opq_wait: enqueue to start of service")
+        .add_time("service_seconds", "osd_op: the worker serving them")
+        .add_time(
+            "service_cpu_seconds",
+            "CPU seconds of the serving thread over the same intervals",
         )
         .create_perf_counters()
     )
@@ -617,6 +611,10 @@ class OSDDaemon:
         #: faulted endpoint
         self.net_pc = make_net_perf(f"osd.{osd_id}.net")
         self.peers.messenger.net_pc = self.net_pc
+        #: op-queue wait and service time of queued client ops
+        self.opq_pc = make_opq_perf(f"osd.{osd_id}.opq")
+        if isinstance(self.store, MemStore):
+            self.store.perf = make_store_perf(f"osd.{osd_id}.store")
         #: crash-replay observability (rollbacks/rollforwards)
         self.rmw_crash_pc = make_rmw_crash_perf(f"osd.{osd_id}.rmw_crash")
         self.peers.on_subwrite_batch = self._on_subwrite_batch
@@ -2320,9 +2318,27 @@ class OSDDaemon:
         )
         self._schedule(cls, _ClientOpItem(self, conn, msg), cost)
 
+    def _note_queue_wait(self, msg: OSDOp, t_enqueue: float) -> None:
+        """One client op leaves the mClock queue for service: count it
+        and record ``opq_wait``, a child of the client's span."""
+        self.opq_pc.inc("ops")
+        tracer.record(
+            "opq_wait", t_enqueue, time.perf_counter(),
+            trace_id=msg.trace_id, parent_id=msg.parent_span,
+            perf=self.opq_pc, key="wait_seconds",
+            osd=self.osd_id, tid=msg.tid,
+        )
+
     def _run_client_op(
-        self, conn: Connection, msg: OSDOp, shard: int = 0
+        self, conn: Connection, msg: OSDOp, shard: int = 0,
+        t_enqueue: "float | None" = None,
     ) -> None:
+        # ops that skip the queue (watch/unwatch/notify) are no part of
+        # its accounting
+        queued = t_enqueue is not None
+        if queued:
+            self._note_queue_wait(msg, t_enqueue)
+        cpu0 = time.thread_time()
         try:
             # adopt the client's trace context (the wire hop of the
             # ZTracer-through-the-pipeline pattern): this daemon's
@@ -2330,7 +2346,10 @@ class OSDDaemon:
             # client op's trace id
             with tracer.continue_trace(msg.trace_id, msg.parent_span):
                 with tracer.span(
-                    "osd_op", op=msg.op, oid=msg.oid,
+                    "osd_op",
+                    perf=self.opq_pc if queued else None,
+                    key="service_seconds",
+                    op=msg.op, oid=msg.oid,
                     osd=self.osd_id, tid=msg.tid,
                 ):
                     reply = self._execute_client_op(msg, conn, shard)
@@ -2341,6 +2360,10 @@ class OSDDaemon:
             )
             reply = OSDOpReply(
                 msg.tid, self.osdmap.epoch, error="eio", data=str(e).encode()
+            )
+        if queued:
+            self.opq_pc.tinc(
+                "service_cpu_seconds", time.thread_time() - cpu0
             )
         if msg.op in _MUTATING_OPS and not reply.error:
             # crash point: the mutation is committed cluster-wide, the
@@ -2475,6 +2498,30 @@ class OSDDaemon:
         self, items: "list[_ClientOpItem]", shard: int = 0
     ) -> None:
         to_send: list[tuple] = []
+        # the batch is served as one: every op's queue wait ends here,
+        # and the batch's wall and this thread's CPU go to the service
+        # counters once (the per-op osd_op spans below cover submit only)
+        for it in items:
+            self._note_queue_wait(it.msg, it.t_enqueue)
+        t_service, cpu0 = time.perf_counter(), time.thread_time()
+        try:
+            self._serve_coalesced_batch(items, shard, to_send)
+        finally:
+            self.opq_pc.tinc(
+                "service_seconds", time.perf_counter() - t_service
+            )
+            self.opq_pc.tinc(
+                "service_cpu_seconds", time.thread_time() - cpu0
+            )
+        for conn, reply in to_send:
+            try:
+                conn.send(reply)
+            except (ConnectionError, OSError):
+                pass  # client gone; its resend finds the answer cached
+
+    def _serve_coalesced_batch(
+        self, items: "list[_ClientOpItem]", shard: int, to_send: list,
+    ) -> None:
         pre: list[_CoalCtx] = []
         for it in items:
             msg = it.msg
@@ -2536,11 +2583,6 @@ class OSDDaemon:
         if len(items) > 1:
             self.coalesce_pc.inc("op_coalesced", executed)
             self.coalesce_pc.hinc("batch_size", len(items))
-        for conn, reply in to_send:
-            try:
-                conn.send(reply)
-            except (ConnectionError, OSError):
-                pass  # client gone; its resend finds the answer cached
 
     def _coalesce_prelude(
         self, ctx: _CoalCtx, to_send: list

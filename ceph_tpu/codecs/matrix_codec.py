@@ -65,6 +65,23 @@ def _dispatch_counters():
                 f"{route}_{op}_bytes", f"input bytes of the {op}s {how}"
             )
     b.add_u64_counter(
+        "dispatches", "dispatches served, over every route and op"
+    )
+    # the host's side of a dispatch, step by step: each is the wall of
+    # the ``codec.<step>`` span (codec_stage), summed over dispatches
+    b.add_time("prep_seconds", "codec.prep: stack / reshape on the host")
+    b.add_time("h2d_seconds", "codec.h2d: jnp.asarray of host data")
+    b.add_time(
+        "launch_seconds",
+        "codec.launch: the jitted / Pallas call returning (on the mesh "
+        "route the sharded upload rides inside it)",
+    )
+    b.add_time(
+        "fetch_seconds",
+        "codec.fetch: np.asarray of the results (waits for the kernel "
+        "and copies back)",
+    )
+    b.add_u64_counter(
         "fused_encode",
         "encodes served by the fused encode+checksum kernel (parity "
         "AND per-block crc32c in one device pass)",
@@ -116,11 +133,34 @@ def count_route(name: str, *arrays) -> None:
     carried under ``name_bytes`` — the host/device byte split is what
     says how much of the traffic reached the chip."""
     pc = _dispatch_counters()
+    pc.inc("dispatches")
     pc.inc(name)
     pc.inc(
         name + "_bytes",
         sum(int(a.size) * a.dtype.itemsize for a in arrays),
     )
+
+
+def codec_stage(step: str):
+    """Time one host-side step of a dispatch (``prep``, ``h2d``,
+    ``launch``, ``fetch``) once: a ``codec.<step>`` span under the
+    caller's encode / reconstruct stage, and the same seconds into
+    ``ec_dispatch:<step>_seconds``. It reads the host's clock around
+    code that runs anyway: no sync, no copy of its own."""
+    from ceph_tpu.utils.trace import tracer
+
+    return tracer.span(
+        "codec." + step, perf=_dispatch_counters(), key=step + "_seconds"
+    )
+
+
+def _upload(x):
+    """``codec.h2d``: host data onto the device, where the jitted call
+    would have put it anyway; device arrays and tracers pass through."""
+    if not isinstance(x, np.ndarray):
+        return x
+    with codec_stage("h2d"):
+        return jnp.asarray(x)
 
 
 def dev_bmat(
@@ -319,20 +359,30 @@ class BitplaneDispatchMixin:
                 mesh, bmat_np.shape, flat.shape
             ):
                 count_route(f"mesh_{op}", flat)
-                out = mesh_dispatch.mesh_apply_bitmatrix(
-                    mesh, bmat_dev, flat
-                )
-                return out.reshape(stacked.shape[:-2] + out.shape[-2:])
+                with codec_stage("launch"):
+                    out = mesh_dispatch.mesh_apply_bitmatrix(
+                        mesh, bmat_dev, flat
+                    )
+                    return out.reshape(
+                        stacked.shape[:-2] + out.shape[-2:]
+                    )
             _dispatch_counters().inc("mesh_fallback")
         if config.get("ec_use_pallas") and platform.on_tpu():
             if pe.supported((1,) + stacked.shape[-2:]):
                 count_route(f"pallas_{op}", stacked)
-                flat = stacked.reshape((-1,) + stacked.shape[-2:])
-                out = pe.gf_encode_bitplane_pallas(bmat_np, flat)
-                return out.reshape(stacked.shape[:-2] + out.shape[-2:])
+                flat = _upload(
+                    stacked.reshape((-1,) + stacked.shape[-2:])
+                )
+                with codec_stage("launch"):
+                    out = pe.gf_encode_bitplane_pallas(bmat_np, flat)
+                    return out.reshape(
+                        stacked.shape[:-2] + out.shape[-2:]
+                    )
             _dispatch_counters().inc("pallas_fallback")
         count_route(f"einsum_{op}", stacked)
-        return _apply_bitmatrix(bmat_dev, stacked)
+        stacked = _upload(stacked)
+        with codec_stage("launch"):
+            return _apply_bitmatrix(bmat_dev, stacked)
 
     def _sched_shards_route(
         self,
@@ -467,8 +517,10 @@ class BitplaneDispatchMixin:
             and not self._dcn_routable_shape(shape, host_staged)
         ):
             count_route(f"pallas_{op}", *shards)
-            return pe.gf_encode_bitplane_pallas_shards(bmat_np, shards)
-        stacked = self._stack(list(shards))
+            with codec_stage("launch"):
+                return pe.gf_encode_bitplane_pallas_shards(bmat_np, shards)
+        with codec_stage("prep"):
+            stacked = self._stack(list(shards))
         out = self._dispatch_bitmatrix(bmat_np, bmat_dev, stacked, op)
         return [out[..., j, :] for j in range(out.shape[-2])]
 
@@ -545,10 +597,11 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         ) and not all(isinstance(v, np.ndarray) for v in shards):
             # device-resident per-shard inputs skip the stack relayout
             count_route("fused_encode", *shards)
-            parity, csums = pe.gf_encode_csum_bitplane_pallas_shards(
-                self._encode_bmat_np, shards, csum_block,
-                interpret=interpret,
-            )
+            with codec_stage("launch"):
+                parity, csums = pe.gf_encode_csum_bitplane_pallas_shards(
+                    self._encode_bmat_np, shards, csum_block,
+                    interpret=interpret,
+                )
             return (
                 {self.k + j: parity[j] for j in range(self.m)},
                 csums,
@@ -561,16 +614,19 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
             _dispatch_counters().inc("fused_fallback")
             return None, None
         count_route("fused_encode", *shards)
-        stacked = self._stack(list(shards))
-        lead = stacked.shape[:-2]
-        flat = stacked.reshape(stacked_shape)
-        parity, csums = pe.gf_encode_csum_bitplane_pallas(
-            self._encode_bmat_np, jnp.asarray(flat), csum_block,
-            interpret=interpret,
-        )
-        n = shards[0].shape[-1]
-        parity = parity.reshape(lead + (self.m, n))
-        csums = csums.reshape(lead + (c + self.m, n // csum_block))
+        with codec_stage("prep"):
+            stacked = self._stack(list(shards))
+            lead = stacked.shape[:-2]
+            flat = stacked.reshape(stacked_shape)
+        flat = _upload(flat)
+        with codec_stage("launch"):
+            parity, csums = pe.gf_encode_csum_bitplane_pallas(
+                self._encode_bmat_np, flat, csum_block,
+                interpret=interpret,
+            )
+            n = shards[0].shape[-1]
+            parity = parity.reshape(lead + (self.m, n))
+            csums = csums.reshape(lead + (c + self.m, n // csum_block))
         return (
             {self.k + j: parity[..., j, :] for j in range(self.m)},
             csums,

@@ -402,6 +402,56 @@ class NetFaultPlane:
 #: the process-global fault plane (tests and loadgen arm it)
 net_faults = NetFaultPlane()
 
+def make_net_perf(name: str):
+    """A messenger's ``net`` counter set (``perf dump`` section
+    ``osd.<id>.net`` / ``<client>.net``, Prometheus via the exporter).
+    Traffic first: frames and bytes each way, with the seconds spent
+    framing + writing (``send_seconds``: encode and ``sendall`` under
+    the send lock) and reading + parsing (``recv_seconds``: from a
+    frame's header in hand to its message decoded, so an idle link
+    adds nothing). Then what the seeded fault plane did to this
+    daemon's links, and what the dedup tiers absorbed — the
+    observability half of the chaos contract (injected faults MUST
+    show up here, absorbed duplicates MUST show up there, and the
+    ledger still balances exactly-once)."""
+    from ceph_tpu.utils import PerfCountersBuilder, perf_collection
+
+    return (
+        PerfCountersBuilder(perf_collection, name)
+        .add_u64_counter("frames_sent", "frames written to a socket")
+        .add_u64_counter("bytes_sent", "framed bytes written")
+        .add_time("send_seconds", "frame encode + sendall")
+        .add_u64_counter("frames_recv", "frames read and decoded")
+        .add_u64_counter("bytes_recv", "framed bytes read")
+        .add_time(
+            "recv_seconds",
+            "frame header in hand to message decoded (body read, "
+            "CRC check, decode)",
+        )
+        .add_u64_counter(
+            "frames_dropped", "frames dropped by fault injection"
+        )
+        .add_u64_counter(
+            "frames_delayed", "frames delayed by fault injection"
+        )
+        .add_u64_counter(
+            "frames_duped", "frames duplicated by fault injection"
+        )
+        .add_u64_counter(
+            "frames_reordered", "frames reordered by fault injection"
+        )
+        .add_u64_counter(
+            "resends_absorbed",
+            "duplicate/straggler sub-write acks with no pending op",
+        )
+        .add_u64_counter(
+            "dedup_hits",
+            "resent client mutations replayed from the reqid cache",
+        )
+        .create_perf_counters()
+    )
+
+
 # In-the-clear handshake frame type for secure-mode nonce exchange
 # (outside the normal message-type space; auth_none + CephX roles).
 HANDSHAKE_TYPE = 0x7FFF
@@ -507,7 +557,9 @@ class Connection:
         self._send_now(msg)
 
     def _send_now(self, msg) -> None:
+        pc = self.messenger.net_pc
         with self._send_lock:
+            t0 = time.perf_counter()
             self._seq += 1
             # Sealing must happen under the send lock: the AEAD tx
             # counter and the socket write have to agree on order.
@@ -523,6 +575,10 @@ class Connection:
             except OSError as e:
                 self.alive = False
                 raise ConnectionError(str(e)) from e
+            if pc is not None:
+                pc.inc("frames_sent")
+                pc.inc("bytes_sent", len(frame))
+                pc.tinc("send_seconds", time.perf_counter() - t0)
 
     def _read_exact(self, n: int) -> bytes:
         buf = b""
@@ -534,12 +590,31 @@ class Connection:
         return buf
 
     def _read_loop(self) -> None:
+        # per frame: bytes read, and the clock at the header's arrival
+        # (the wait for it is an idle link, not receive work)
+        got = [0, 0.0]
+
+        def read_counted(n: int) -> bytes:
+            buf = self._read_exact(n)
+            if not got[0]:
+                got[1] = time.perf_counter()
+            got[0] += n
+            return buf
+
         try:
             while True:
+                got[0] = 0
                 msg_type, _seq, segments = decode_frame(
-                    self._read_exact, secure=self._rx
+                    read_counted, secure=self._rx
                 )
                 msg = decode_message(msg_type, segments)
+                pc = self.messenger.net_pc
+                if pc is not None:
+                    pc.inc("frames_recv")
+                    pc.inc("bytes_recv", got[0])
+                    pc.tinc(
+                        "recv_seconds", time.perf_counter() - got[1]
+                    )
                 if net_faults.active and self.peer_name is not None:
                     # inbound half of the link (peer → me): replies on
                     # a client-initiated conn are faulted HERE, after

@@ -67,6 +67,13 @@ def ring_parity(
     return _ring_parity_fn(mesh, n)(bitmatrix, data)
 
 
+#: the ring encode's program name on the device is ``jit_local``: the
+#: benchmark's mesh cell finds its codec program in a trace by that
+#: text (``benchmark/workloads/rs84-4m-mesh4.write.json``); pinned so a
+#: rename of the inner function cannot empty ``codec_roofline``
+RING_PROGRAM_NAME = "local"
+
+
 @functools.lru_cache(maxsize=64)
 def _ring_parity_fn(mesh: Mesh, n: int):
     """The ring program for lane width ``n`` (the slice width is
@@ -126,6 +133,7 @@ def _ring_parity_fn(mesh: Mesh, n: int):
         mesh,
         in_specs=(P(None, "sp"), P("dp", "sp", None)),
         out_specs=P("dp", None, None),
+        name=RING_PROGRAM_NAME,
     )
 
 

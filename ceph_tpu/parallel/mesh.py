@@ -30,16 +30,23 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ceph_tpu.ops.bitplane import pack_bits, unpack_bits
 
 
-def mesh_program(f, mesh: Mesh, in_specs, out_specs):
+def mesh_program(f, mesh: Mesh, in_specs, out_specs, name=None):
     """``f`` as ONE jitted SPMD program over ``mesh``. Builders cache
     the result per geometry (``functools.lru_cache``): an un-jitted
     ``shard_map`` executes primitive by primitive, and a closure
     rebuilt per call never hits jit's cache — the live path paid ~56
-    backend compilations for every dispatch that way."""
-    return jax.jit(jax.shard_map(
+    backend compilations for every dispatch that way.
+
+    ``name`` pins the program's name on the device (``jit_<name>``,
+    what a profiler trace's module line shows) whatever ``f`` is
+    called in the source."""
+    mapped = jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
-    ))
+    )
+    if name is not None:
+        mapped.__name__ = mapped.__qualname__ = name
+    return jax.jit(mapped)
 
 
 def make_ec_mesh(n_devices: int | None = None, k: int = 8) -> Mesh:

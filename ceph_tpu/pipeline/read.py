@@ -31,6 +31,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ceph_tpu.utils.trace import tracer
+
 from .extents import ExtentSet
 from .shard_map import ShardExtentMap
 from .stripe import StripeInfo
@@ -288,7 +290,11 @@ class ClientReadOp:
         self.done = False
         self.data: bytes | None = None
         self.error: Exception | None = None
-        self.t_submit: float | None = None
+        #: (trace_id, span_id) open at submit (the daemon's osd_op):
+        #: parent of the recorded ``sub_read_wait``
+        self.trace_ctx: tuple = (None, None)
+        #: perf_counter when the first round of sub-reads had gone out
+        self.t_issued: float | None = None
 
 
 class ReadPipeline:
@@ -317,15 +323,25 @@ class ReadPipeline:
             .add_u64_counter("read_ops", "client reads submitted")
             .add_u64_counter("read_bytes", "client bytes returned")
             .add_u64_counter("reconstruct_ops", "reads that decoded")
-            .add_u64_counter(
-                "helper_read_bytes",
-                "bytes requested from shard stores by sub-reads (the "
-                "MSR observable: CLAY fractional repair keeps this "
-                "below the k-full-chunk bytes a naive decode reads)",
-            )
             .add_u64_counter("retries", "sub-read retries after errors")
             .add_u64_counter("errors", "reads failed after retry")
-            .add_avg("read_lat", "submit-to-complete seconds")
+            # stage timers (utils/trace.py spans of the same names,
+            # summed over the reads whose sub-reads came back)
+            .add_u64_counter(
+                "gather_ops", "reads whose sub-reads were gathered"
+            )
+            .add_time(
+                "issue_seconds", "ec_read.issue: plan + sub-read fan-out"
+            )
+            .add_time(
+                "gather_seconds",
+                "sub_read_wait: sub-reads issued to the last reply needed",
+            )
+            .add_time("reconstruct_seconds", "ec_reconstruct: the decode")
+            .add_time(
+                "finish_seconds",
+                "ec_read.finish: assemble the byte range, complete in order",
+            )
             .create_perf_counters()
         )
 
@@ -338,7 +354,6 @@ class ReadPipeline:
         on_complete: Callable[[ClientReadOp], None] | None = None,
     ) -> int:
         op = ClientReadOp(self._next_rid, oid, ro_offset, length, on_complete)
-        op.t_submit = time.perf_counter()
         self._next_rid += 1
         self._inflight[op.rid] = op
         self.perf.inc("read_ops")
@@ -354,19 +369,27 @@ class ReadPipeline:
             self._finish(op)
             return op.rid
 
-        op.want = self.sinfo.ro_range_to_shard_extent_set(
-            op.ro_offset, op.length
-        )
-        op.result = ShardExtentMap(self.sinfo)
-        try:
-            op.shard_reads, op.need_decode = get_min_avail_to_read_shards(
-                self.sinfo, self.codec, op.want, self._avail()
+        op.trace_ctx = tracer.current()
+        with tracer.span(
+            "ec_read.issue", perf=self.perf, key="issue_seconds",
+            oid=oid, rid=op.rid,
+        ):
+            op.want = self.sinfo.ro_range_to_shard_extent_set(
+                op.ro_offset, op.length
             )
-        except ValueError as e:
-            op.error = e
-            self._finish(op)
-            return op.rid
-        self._issue(op, op.shard_reads)
+            op.result = ShardExtentMap(self.sinfo)
+            try:
+                op.shard_reads, op.need_decode = (
+                    get_min_avail_to_read_shards(
+                        self.sinfo, self.codec, op.want, self._avail()
+                    )
+                )
+            except ValueError as e:
+                op.error = e
+                self._finish(op)
+                return op.rid
+            self._issue(op, op.shard_reads)
+        op.t_issued = time.perf_counter()
         return op.rid
 
     def read_sync(self, oid: str, ro_offset: int, length: int) -> bytes:
@@ -390,14 +413,6 @@ class ReadPipeline:
     def _issue(self, op: ClientReadOp, reads: dict[int, ShardRead]) -> None:
         for shard in reads:
             op.pending[shard] = op.pending.get(shard, 0) + 1
-        self.perf.inc(
-            "helper_read_bytes",
-            sum(
-                end - start
-                for sr in reads.values()
-                for start, end in sr.extents
-            ),
-        )
         for sr in list(reads.values()):
             self.backend.read_shard_async(
                 sr.shard,
@@ -472,23 +487,36 @@ class ReadPipeline:
             self._complete(op)
 
     def _complete(self, op: ClientReadOp) -> None:
+        # a backend that answers inside _issue (local stores) gets here
+        # before t_issued is set: it waited for nothing
+        now = time.perf_counter()
+        self.perf.inc("gather_ops")
+        tracer.record(
+            "sub_read_wait", op.t_issued or now, now,
+            trace_id=op.trace_ctx[0], parent_id=op.trace_ctx[1],
+            perf=self.perf, key="gather_seconds", oid=op.oid, rid=op.rid,
+        )
         if op.error is None and op.need_decode:
-            from ceph_tpu.utils import tracer
-
             self.perf.inc("reconstruct_ops")
             try:
-                with tracer.span("ec_reconstruct", oid=op.oid, rid=op.rid):
+                with tracer.span(
+                    "ec_reconstruct", perf=self.perf,
+                    key="reconstruct_seconds", oid=op.oid, rid=op.rid,
+                ):
                     self._reconstruct(op)
             except ValueError as e:
                 op.error = e
-        if op.error is None:
-            op.data = gather_ro_range(
-                self.sinfo, op.result, op.ro_offset, op.length
-            )
-            self.perf.inc("read_bytes", len(op.data))
-        else:
-            self.perf.inc("errors")
-        self._finish(op)
+        with tracer.span(
+            "ec_read.finish", perf=self.perf, key="finish_seconds"
+        ):
+            if op.error is None:
+                op.data = gather_ro_range(
+                    self.sinfo, op.result, op.ro_offset, op.length
+                )
+                self.perf.inc("read_bytes", len(op.data))
+            else:
+                self.perf.inc("errors")
+            self._finish(op)
 
     def _reconstruct(self, op: ClientReadOp) -> None:
         """Decode missing wanted shards from the survivors in
@@ -511,9 +539,5 @@ class ReadPipeline:
             if not front.done:
                 return
             self._inflight.pop(rid)
-            if front.t_submit is not None:
-                self.perf.ainc(
-                    "read_lat", time.perf_counter() - front.t_submit
-                )
             if front.on_complete is not None:
                 front.on_complete(front)

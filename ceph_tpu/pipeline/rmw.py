@@ -39,6 +39,7 @@ from ceph_tpu.codecs.interface import Flag
 from ceph_tpu.store import Transaction
 from ceph_tpu.utils.crash_points import crash_points
 from ceph_tpu.utils.optracker import NULL_OP, op_tracker
+from ceph_tpu.utils.trace import tracer
 
 from .extent_cache import CacheOp, ECExtentCache
 from .extents import ExtentSet
@@ -222,6 +223,20 @@ class ClientOp:
         #: live-op handle (dump_ops_in_flight): queued -> dispatched
         #: -> waiting_for_subops -> committed -> done
         self.tracked = NULL_OP
+        #: (trace_id, span_id) open when the op was submitted (the
+        #: daemon's osd_op): parent of the recorded ``subop_wait``
+        self.trace_ctx: tuple = (None, None)
+        #: (trace_id, ec_write span id): a ``_cache_ready`` that runs
+        #: after ec_write closed (queued behind another op on the
+        #: object) adopts it, so its stage spans stay in the op's tree
+        self.write_ctx: tuple = (None, None)
+        #: perf_counter at the end of the sub-write fan-out and at the
+        #: ack that committed the op; ``subop_wait`` is the interval
+        #: between them, recorded once both are known
+        self.t_fanout_end: float | None = None
+        self.t_last_ack: float | None = None
+        #: TIME counter that wait goes to (set with the fan-out's kind)
+        self.wait_key: str | None = None
 
 
 class ShardBackend:
@@ -425,6 +440,42 @@ class RMWPipeline:
             .add_u64_counter("full_stripe_ops", "writes via full re-encode")
             .add_u64_counter("aborts", "writes failed before dispatch")
             .add_avg("commit_lat", "submit-to-commit seconds")
+            # stage timers of the write path: each is the wall of the
+            # span of the same name under ec_write (utils/trace.py),
+            # summed over ops; divide by encode_ops for ms per op
+            .add_u64_counter(
+                "encode_ops",
+                "writes that reached the encode: what the stage "
+                "seconds below are summed over",
+            )
+            .add_time("write_seconds", "ec_write: the whole of submit")
+            .add_time("plan_seconds", "ec_write.plan: plan + cache prepare")
+            .add_time(
+                "assemble_seconds",
+                "ec_write.assemble: chunk loop and old-data merge",
+            )
+            .add_time("encode_seconds", "ec_write.encode: the codec call")
+            .add_time(
+                "txn_build_seconds",
+                "ec_write.txn_build: k+m transactions, pg-log append",
+            )
+            .add_time(
+                "fanout_seconds",
+                "ec_write.fanout: the submit_shard_txn loop",
+            )
+            .add_time(
+                "subop_wait_seconds",
+                "subop_wait of writes: end of fan-out to the last ack",
+            )
+            .add_u64_counter("truncate_ops", "truncates dispatched")
+            .add_time(
+                "truncate_seconds",
+                "ec_truncate: a truncate's transactions and fan-out",
+            )
+            .add_time(
+                "truncate_wait_seconds",
+                "subop_wait of truncates",
+            )
             .create_perf_counters()
         )
 
@@ -532,34 +583,41 @@ class RMWPipeline:
             self._check_commit_order()
             return op.tid
 
-        from ceph_tpu.utils import tracer
-
         self._projected_sizes[oid] = max(
             self._projected_sizes.get(
                 oid, self._object_sizes.get(oid, 0)
             ),
             ro_offset + len(data),
         )
-        with tracer.span("ec_write", oid=oid, tid=op.tid, bytes=len(data)):
-            object_size = self._object_sizes.get(oid, 0)
-            op.plan = plan_write(
-                self.sinfo,
-                self.codec.get_flags(),
-                ro_offset,
-                len(data),
-                object_size,
-            )
-            self.perf.inc(
-                "parity_delta_ops" if op.plan.do_parity_delta
-                else "full_stripe_ops"
-            )
-            op.cache_op = self.cache.prepare(
-                oid,
-                op.plan.to_read,
-                op.plan.to_write,
-                object_size,
-                lambda cop, _op=op: self._cache_ready(_op),
-            )
+        op.trace_ctx = tracer.current()
+        op.wait_key = "subop_wait_seconds"
+        with tracer.span(
+            "ec_write", perf=self.perf, key="write_seconds",
+            oid=oid, tid=op.tid, bytes=len(data),
+        ):
+            op.write_ctx = tracer.current()
+            with tracer.span(
+                "ec_write.plan", perf=self.perf, key="plan_seconds"
+            ):
+                object_size = self._object_sizes.get(oid, 0)
+                op.plan = plan_write(
+                    self.sinfo,
+                    self.codec.get_flags(),
+                    ro_offset,
+                    len(data),
+                    object_size,
+                )
+                self.perf.inc(
+                    "parity_delta_ops" if op.plan.do_parity_delta
+                    else "full_stripe_ops"
+                )
+                op.cache_op = self.cache.prepare(
+                    oid,
+                    op.plan.to_read,
+                    op.plan.to_write,
+                    object_size,
+                    lambda cop, _op=op: self._cache_ready(_op),
+                )
             self.cache.execute([op.cache_op])
         return op.tid
 
@@ -649,8 +707,20 @@ class RMWPipeline:
         self._inflight[op.tid] = op
         self._track(op, "rmw_truncate")
         sinfo = self.sinfo
+        op.trace_ctx = tracer.current()
+        op.wait_key = "truncate_wait_seconds"
 
         def dispatch(cop, _op=op) -> None:
+            # may run later, from the write_done of the op it queued
+            # behind: adopt the submitter's context either way
+            with tracer.continue_trace(*_op.trace_ctx), tracer.span(
+                "ec_truncate", perf=self.perf, key="truncate_seconds",
+                oid=oid, tid=_op.tid,
+            ):
+                self.perf.inc("truncate_ops")
+                dispatch_inner(_op)
+
+        def dispatch_inner(_op) -> None:
             try:
                 live = set(self.backend.avail_shards())
                 if len(live) < sinfo.k:
@@ -712,6 +782,7 @@ class RMWPipeline:
                         shard, txn,
                         lambda s=shard, o=_op: self._shard_ack(o, s),
                     )
+                self._fanout_done(_op)
             except Exception as e:
                 self._abort_op(_op, e)
 
@@ -870,7 +941,11 @@ class RMWPipeline:
             return
         op.tracked.mark_event("cache_ready")
         try:
-            self._cache_ready_inner(op)
+            # usually still inside ec_write; an op that queued behind
+            # another on its object gets here from that op's ack, after
+            # its own ec_write closed: either way the stages are its
+            with tracer.continue_trace(*op.write_ctx):
+                self._cache_ready_inner(op)
         except Exception as e:
             self._abort_op(op, e)
 
@@ -880,45 +955,56 @@ class RMWPipeline:
         old_size = self._object_sizes.get(op.oid, 0)
         new_size = max(old_size, op.ro_offset + len(op.data))
 
-        new_map = ShardExtentMap(sinfo)
-        pos = op.ro_offset
-        data = np.frombuffer(op.data, dtype=np.uint8)
-        taken = 0
-        while taken < len(op.data):
-            chunk_index = pos // sinfo.chunk_size
-            raw = chunk_index % sinfo.k
-            in_chunk = pos % sinfo.chunk_size
-            take = min(sinfo.chunk_size - in_chunk, len(op.data) - taken)
-            shard_off = (chunk_index // sinfo.k) * sinfo.chunk_size + in_chunk
-            new_map.insert(
-                sinfo.get_shard(raw), shard_off, data[taken : taken + take]
-            )
-            pos += take
-            taken += take
+        with tracer.span(
+            "ec_write.assemble", perf=self.perf, key="assemble_seconds"
+        ):
+            new_map = ShardExtentMap(sinfo)
+            pos = op.ro_offset
+            data = np.frombuffer(op.data, dtype=np.uint8)
+            taken = 0
+            while taken < len(op.data):
+                chunk_index = pos // sinfo.chunk_size
+                raw = chunk_index % sinfo.k
+                in_chunk = pos % sinfo.chunk_size
+                take = min(
+                    sinfo.chunk_size - in_chunk, len(op.data) - taken
+                )
+                shard_off = (
+                    (chunk_index // sinfo.k) * sinfo.chunk_size + in_chunk
+                )
+                new_map.insert(
+                    sinfo.get_shard(raw), shard_off,
+                    data[taken : taken + take],
+                )
+                pos += take
+                taken += take
 
-        hinfo = self._get_hinfo(op.oid)
-        hashed = hinfo.get_total_chunk_size()
-        append_base = None
-        if op.plan.do_parity_delta:
-            new_map.encode_parity_delta(self.codec, old_map)
-            hinfo.clear()  # overwrite invalidates cumulative shard crcs
-        else:
-            # merge old data under the new so parity encodes full stripes
-            for shard in old_map.shards():
-                if not sinfo.is_data_shard(shard):
-                    continue
-                for start, end in old_map.get_extent_set(shard):
-                    gap = ExtentSet([(start, end)]).difference(
-                        new_map.get_extent_set(shard)
-                    )
-                    for s, e in gap:
-                        new_map.insert(shard, s, old_map.get(shard, s, e - s))
-            lo, _hi = new_map.ro_range()
-            if lo == hashed:
-                append_base = hashed
-            if append_base is not None:
+            hinfo = self._get_hinfo(op.oid)
+            hashed = hinfo.get_total_chunk_size()
+            if not op.plan.do_parity_delta:
+                # merge old data under the new so parity encodes full
+                # stripes
+                for shard in old_map.shards():
+                    if not sinfo.is_data_shard(shard):
+                        continue
+                    for start, end in old_map.get_extent_set(shard):
+                        gap = ExtentSet([(start, end)]).difference(
+                            new_map.get_extent_set(shard)
+                        )
+                        for s, e in gap:
+                            new_map.insert(
+                                shard, s, old_map.get(shard, s, e - s)
+                            )
+        self.perf.inc("encode_ops")
+        with tracer.span(
+            "ec_write.encode", perf=self.perf, key="encode_seconds"
+        ):
+            if op.plan.do_parity_delta:
+                new_map.encode_parity_delta(self.codec, old_map)
+                hinfo.clear()  # overwrite invalidates cumulative crcs
+            elif new_map.ro_range()[0] == hashed:
                 new_map.encode(
-                    self.codec, hinfo, old_size=append_base,
+                    self.codec, hinfo, old_size=hashed,
                     csum_block=self.csum_block,
                 )
             else:
@@ -958,6 +1044,29 @@ class RMWPipeline:
         """Emit one Transaction per shard (ECTransaction.cc:916): the
         shard's written extents, a truncate to the new shard size, and
         the refreshed hinfo attr (ECTransaction.cc:497,902)."""
+        with tracer.span(
+            "ec_write.txn_build", perf=self.perf, key="txn_build_seconds"
+        ):
+            live, txns = self._build_transactions(op, result, new_size)
+        # build every txn before the first dispatch: a synchronous ack
+        # (local stores) must see the complete written map
+        with tracer.span(
+            "ec_write.fanout", perf=self.perf, key="fanout_seconds"
+        ):
+            for shard, txn in txns:
+                if shard not in live:
+                    continue  # hole: journaled above, recovered later
+                self.backend.submit_shard_txn(
+                    shard, txn,
+                    lambda s=shard, o=op: self._shard_ack(o, s),
+                )
+        self._fanout_done(op)
+
+    def _build_transactions(
+        self, op: ClientOp, result: ShardExtentMap, new_size: int
+    ) -> "tuple[set[int], list[tuple[int, Transaction]]]":
+        """(live shards, one Transaction per shard), with the pg-log
+        entry appended: everything short of the first dispatch."""
         sinfo = self.sinfo
         hinfo_bytes = self._get_hinfo(op.oid).to_bytes()
         # Dispatch to LIVE shards only: an acting-set hole (down OSD)
@@ -1029,14 +1138,29 @@ class RMWPipeline:
             tid=op.tid,
         )
         op.tracked.mark_event("waiting_for_subops", n=len(live))
-        # build every txn before the first dispatch: a synchronous ack
-        # (local stores) must see the complete written map
-        for shard, txn in txns:
-            if shard not in live:
-                continue  # hole: journaled above, recovered later
-            self.backend.submit_shard_txn(
-                shard, txn, lambda s=shard, o=op: self._shard_ack(o, s)
-            )
+        return live, txns
+
+    # -- the recorded wait for sub-op acks ------------------------------
+    def _fanout_done(self, op: ClientOp) -> None:
+        with self._ack_lock:
+            op.t_fanout_end = time.perf_counter()
+            self._note_subop_wait(op)
+
+    def _note_subop_wait(self, op: ClientOp) -> None:
+        """``subop_wait``: end of the fan-out to the ack that committed
+        the op. They happen on different threads in either order (a
+        local store acks inside the fan-out loop), so whichever comes
+        second records the interval; caller holds ``_ack_lock``."""
+        if op.t_fanout_end is None or op.t_last_ack is None:
+            return
+        tracer.record(
+            "subop_wait", op.t_fanout_end,
+            max(op.t_last_ack, op.t_fanout_end),
+            trace_id=op.trace_ctx[0], parent_id=op.trace_ctx[1],
+            perf=self.perf, key=op.wait_key,
+            oid=op.oid, tid=op.tid,
+        )
+        op.t_last_ack = None  # once
 
 
     # -- shared identity plumbing (write + truncate txns) --------------
@@ -1106,6 +1230,8 @@ class RMWPipeline:
                 )
                 op.committed = True
                 op.tracked.mark_event("committed")
+                op.t_last_ack = time.perf_counter()
+                self._note_subop_wait(op)
                 finish = True
         # cache release OUTSIDE the ack lock: write_done may dispatch
         # the next queued op for this object, whose RMW backend read

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ceph_tpu.codecs.matrix_codec import codec_stage
+
 from .extents import ExtentSet
 from .hashinfo import HashInfo
 from .stripe import PAGE_SIZE, StripeInfo, align_page_next, align_page_prev
@@ -201,14 +203,15 @@ class ShardExtentMap:
         lo = (lo0 // cs) * cs
         hi = -(-hi0 // cs) * cs
         n_chunks = (hi - lo) // cs
-        data = np.stack(
-            [
-                self.get(self.sinfo.get_shard(r), lo, hi - lo).reshape(
-                    n_chunks, cs
-                )
-                for r in range(k)
-            ]
-        )
+        with codec_stage("prep"):
+            data = np.stack(
+                [
+                    self.get(self.sinfo.get_shard(r), lo, hi - lo).reshape(
+                        n_chunks, cs
+                    )
+                    for r in range(k)
+                ]
+            )
         parity = csums = None
         cb = csum_block
         if (
@@ -232,9 +235,14 @@ class ShardExtentMap:
                     {i: data[i] for i in range(k)}, cb
                 )
                 if parity_map is not None:
-                    parity = np.stack(
-                        [np.asarray(parity_map[k + j]) for j in range(m)]
-                    )
+                    # the first np.asarray waits for the kernel; the
+                    # csum words come back with the parity
+                    with codec_stage("fetch"):
+                        parity = np.stack(
+                            [np.asarray(parity_map[k + j])
+                             for j in range(m)]
+                        )
+                        csums = np.asarray(csums)
         if parity is None:
             parity = self._dispatch_encode(codec, data)
         for j in range(m):
@@ -346,9 +354,10 @@ class ShardExtentMap:
         parity = codec.encode_chunks(
             {i: np.asarray(data[i]) for i in range(k)}
         )
-        return np.stack(
-            [np.asarray(parity[k + j]) for j in range(len(parity))]
-        )
+        with codec_stage("fetch"):
+            return np.stack(
+                [np.asarray(parity[k + j]) for j in range(len(parity))]
+            )
 
     def encode_parity_delta(self, codec, old_map: "ShardExtentMap") -> None:
         """Parity-delta RMW (ECUtil.cc:542-588): for each data shard
@@ -403,10 +412,11 @@ class ShardExtentMap:
             )
             parity_in[k + j] = p.reshape(shape) if chunk_gran else p
         parity_out = codec.apply_delta(deltas, parity_in)
+        with codec_stage("fetch"):
+            fetched = [np.asarray(parity_out[k + j]) for j in range(m)]
         for j in range(m):
             self.insert(
-                self.sinfo.get_shard(k + j), lo,
-                np.asarray(parity_out[k + j]).reshape(-1),
+                self.sinfo.get_shard(k + j), lo, fetched[j].reshape(-1)
             )
 
     def decode(self, codec, want: set[int], object_size: int) -> None:
@@ -481,18 +491,24 @@ class ShardExtentMap:
                 present_raw.append(raw)
         present_raw.sort()
         n_chunks = (hi - lo) // cs
-        chunks = {
-            raw: np.asarray(
-                self.get(sinfo.get_shard(raw), lo, hi - lo).reshape(
-                    n_chunks, cs
+        with codec_stage("prep"):
+            chunks = {
+                raw: np.asarray(
+                    self.get(sinfo.get_shard(raw), lo, hi - lo).reshape(
+                        n_chunks, cs
+                    )
                 )
-            )
-            for raw in present_raw
-        }
+                for raw in present_raw
+            }
         out = codec.decode_chunks(set(missing_raw), chunks)
+        with codec_stage("fetch"):
+            fetched = {
+                raw: np.asarray(out[raw]).reshape(-1)
+                for raw in missing_raw
+            }
         for raw in missing_raw:
             shard = sinfo.get_shard(raw)
-            buf = np.asarray(out[raw]).reshape(-1)
+            buf = fetched[raw]
             shard_size = sinfo.object_size_to_shard_size(object_size, shard)
             end = min(hi, shard_size)
             if end > lo:

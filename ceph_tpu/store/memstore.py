@@ -11,10 +11,29 @@ a functional-style store suits a replayable TPU pipeline).
 
 from __future__ import annotations
 
-import threading
+import time
 
 from .transaction import Op, OpKind, Transaction
 from ceph_tpu.utils.lockdep import DebugLock
+
+
+def make_store_perf(name: str):
+    """A store's counter set (``perf dump`` section ``osd.<id>.store``):
+    transactions applied and reads served, their bytes, and the
+    seconds each took inside the store (lock wait included: it is
+    what the caller waited)."""
+    from ceph_tpu.utils import PerfCountersBuilder, perf_collection
+
+    return (
+        PerfCountersBuilder(perf_collection, name)
+        .add_u64_counter("txns", "queue_transactions calls applied")
+        .add_u64_counter("txn_bytes", "data bytes of their WRITE ops")
+        .add_time("apply_seconds", "seconds inside queue_transactions")
+        .add_u64_counter("reads", "read calls served")
+        .add_u64_counter("read_bytes", "bytes they returned")
+        .add_time("read_seconds", "seconds inside read")
+        .create_perf_counters()
+    )
 
 
 class _Object:
@@ -39,6 +58,9 @@ class MemStore:
         self._objects: dict[str, _Object] = {}
         self._lock = DebugLock("store.mem", rank=60)
         self.committed_seq = 0  # count of applied transactions
+        #: the owning daemon attaches ``make_store_perf(...)``; a bare
+        #: store (tests, tools) counts nothing
+        self.perf = None
 
     # -- write path ----------------------------------------------------
     def queue_transactions(self, txns: list[Transaction] | Transaction) -> int:
@@ -46,6 +68,18 @@ class MemStore:
         sequence (the on_commit callback's context in the reference)."""
         if isinstance(txns, Transaction):
             txns = [txns]
+        t0 = time.perf_counter()
+        seq = self._apply_all(txns)
+        if self.perf is not None:
+            self.perf.inc("txns")
+            self.perf.inc("txn_bytes", sum(
+                len(op.data) for t in txns for op in t.ops
+                if op.kind is OpKind.WRITE
+            ))
+            self.perf.tinc("apply_seconds", time.perf_counter() - t0)
+        return seq
+
+    def _apply_all(self, txns: list[Transaction]) -> int:
         with self._lock:
             staged: dict[str, _Object | None] = {}
 
@@ -131,13 +165,19 @@ class MemStore:
     def read(self, oid: str, offset: int = 0, length: int | None = None) -> bytes:
         """Read a range; short if it extends past EOF (POSIX-style, as
         MemStore::read). FileNotFoundError if the object is absent."""
+        t0 = time.perf_counter()
         with self._lock:
             obj = self._objects.get(oid)
             if obj is None:
                 raise FileNotFoundError(oid)
             if length is None:
                 length = len(obj.data) - offset
-            return bytes(obj.data[offset:offset + length])
+            out = bytes(obj.data[offset:offset + length])
+        if self.perf is not None:
+            self.perf.inc("reads")
+            self.perf.inc("read_bytes", len(out))
+            self.perf.tinc("read_seconds", time.perf_counter() - t0)
+        return out
 
     def getattr(self, oid: str, name: str) -> bytes:
         with self._lock:

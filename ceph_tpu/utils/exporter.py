@@ -10,6 +10,7 @@ pipeline/daemon counter set registers there) the same way:
 - U64 counters      -> ``counter``
 - gauges            -> ``gauge``
 - time accumulators -> ``counter`` (seconds, ``_seconds`` suffix)
+- sampled readings  -> ``counter`` (process clocks, read at scrape)
 - averages          -> ``_sum`` + ``_count`` (an untyped summary)
 - histograms        -> ``_bucket{le=...}`` cumulative + ``_count``
                        + ``_sum``
@@ -85,7 +86,13 @@ def render_exposition(
             elif t is CounterType.GAUGE:
                 emit(metric, "gauge", label, v)
             elif t is CounterType.TIME:
-                emit(f"{metric}_seconds", "counter", label, v)
+                # one suffix: a key that already says ``_seconds``
+                # (the stage timers) keeps its name
+                if not metric.endswith("_seconds"):
+                    metric += "_seconds"
+                emit(metric, "counter", label, v)
+            elif t is CounterType.SAMPLED:
+                emit(metric, "counter", label, v)
             elif t is CounterType.AVG:
                 emit(f"{metric}_sum", "untyped", label, v["sum"])
                 emit(f"{metric}_count", "untyped", label, v["avgcount"])

@@ -17,6 +17,7 @@ from __future__ import annotations
 import bisect
 import enum
 import threading
+import time
 
 
 class CounterType(enum.Enum):
@@ -25,6 +26,9 @@ class CounterType(enum.Enum):
     TIME = "time"          # accumulated seconds
     AVG = "avg"            # count + sum (time or value averages)
     HISTOGRAM = "histogram"
+    #: a monotone reading taken when the set is dumped (a process
+    #: clock): the value is the schema's ``fn()``, nothing is stored
+    SAMPLED = "sampled"
 
 
 class PerfCounters:
@@ -44,7 +48,7 @@ class PerfCounters:
             elif spec["type"] is CounterType.HISTOGRAM:
                 self._values[key] = [0] * (len(spec["buckets"]) + 1)
                 self._hist_sums[key] = 0.0
-            else:
+            elif spec["type"] is not CounterType.SAMPLED:
                 self._values[key] = 0 if spec["type"] in (
                     CounterType.U64, CounterType.GAUGE
                 ) else 0.0
@@ -90,6 +94,9 @@ class PerfCounters:
             self._hist_sums[key] += value
 
     def get(self, key: str):
+        spec = self._check(key)
+        if spec["type"] is CounterType.SAMPLED:
+            return spec["fn"]()
         with self._lock:
             v = self._values[key]
             return list(v) if isinstance(v, list) else v
@@ -108,13 +115,16 @@ class PerfCounters:
                     self._hist_sums[key] = 0.0
                 elif spec["type"] in (CounterType.U64, CounterType.GAUGE):
                     self._values[key] = 0
-                else:
+                elif spec["type"] is CounterType.TIME:
                     self._values[key] = 0.0
 
     def dump(self) -> dict:
         out: dict[str, object] = {}
         with self._lock:
             for key, spec in self._schema.items():
+                if spec["type"] is CounterType.SAMPLED:
+                    out[key] = spec["fn"]()
+                    continue
                 v = self._values[key]
                 if spec["type"] is CounterType.AVG:
                     out[key] = {"avgcount": v[0], "sum": v[1]}
@@ -152,6 +162,10 @@ class PerfCountersBuilder:
 
     def add_time(self, key: str, desc: str = ""):
         return self._add(key, CounterType.TIME, desc)
+
+    def add_sampled(self, key: str, fn, desc: str = ""):
+        """``fn() -> number``, called at every dump."""
+        return self._add(key, CounterType.SAMPLED, desc, fn=fn)
 
     def add_avg(self, key: str, desc: str = ""):
         return self._add(key, CounterType.AVG, desc)
@@ -216,3 +230,28 @@ class PerfCountersCollection:
 
 # Process-global collection, served by the admin socket's "perf dump".
 perf_collection = PerfCountersCollection()
+
+
+def register_process_counters(
+    collection: PerfCountersCollection = perf_collection,
+) -> PerfCounters:
+    """The ``process`` set: CPU seconds of the whole interpreter
+    (every thread, user + system) beside wall seconds on the tracer's
+    clock. Their ratio over a window is how many cores the host side
+    kept busy: near 1.0 in a one-interpreter cluster means every layer
+    is queueing for the GIL."""
+    return (
+        PerfCountersBuilder(collection, "process")
+        .add_sampled(
+            "cpu_seconds", time.process_time,
+            "CPU seconds of this process, all threads",
+        )
+        .add_sampled(
+            "wall_seconds", time.perf_counter,
+            "wall seconds on the perf_counter clock",
+        )
+        .create_perf_counters()
+    )
+
+
+register_process_counters()

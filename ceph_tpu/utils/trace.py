@@ -10,6 +10,17 @@ tracked per-thread so pipeline code never passes handles explicitly.
 On TPU the same spans also emit ``jax.profiler.TraceAnnotation``
 blocks when profiling is active, so host-side pipeline stages line up
 with device timelines in XLA profile captures.
+
+A stage is timed ONCE: ``span(name, perf=, key=)`` also adds its
+duration to a ``TIME`` perf counter (what the benchmark's per-layer
+metrics read), and ``record`` does the same for an interval that
+starts on one thread and ends on another (queue wait, sub-op wait).
+At most once a second a span's exit leaves a zero-length
+``clock_anchor`` annotation carrying ``perf_counter_ns``: any profiler
+trace then holds the offset between its own clock and
+``Span.start_mono``, so recorded intervals and spans already open when
+the trace started can be laid on the device timeline afterwards
+(``tools/trace_tool.py --xplane``).
 """
 
 from __future__ import annotations
@@ -34,6 +45,12 @@ _TRACE_PREFIX = f"{os.getpid():x}-{secrets.token_hex(2)}"
 #: multichip dryrun (the admin-socket builtin-registration contract).
 #: Sentinel False = unresolved; None = resolved-absent.
 _ANNOTATION_CLS: "object" = False
+
+#: name of the zero-length annotation that ties the profiler's clock
+#: to ``time.perf_counter`` (its ``mono_ns`` stat), and the least
+#: seconds between two of them
+ANCHOR_NAME = "clock_anchor"
+ANCHOR_INTERVAL_S = 1.0
 
 
 def _annotation_cls():
@@ -83,12 +100,15 @@ class Span:
 
 
 class Tracer:
-    def __init__(self, history: int = 512, enabled: bool = True) -> None:
+    #: spans kept: a 4 MiB EC(8,4) write leaves about 40 (stages,
+    #: codec steps, k+m sub-writes), so this holds the last ~100 ops
+    def __init__(self, history: int = 4096, enabled: bool = True) -> None:
         self.enabled = enabled
         self._ids = itertools.count(1)
         self._history: deque[Span] = deque(maxlen=history)
         self._lock = threading.Lock()
         self._tls = threading.local()
+        self._last_anchor = float("-inf")
 
     def _stack(self) -> list[Span]:
         if not hasattr(self._tls, "stack"):
@@ -96,9 +116,19 @@ class Tracer:
         return self._tls.stack
 
     @contextmanager
-    def span(self, name: str, **tags):
+    def span(self, name: str, perf=None, key: str | None = None, **tags):
+        """Time a stage on this thread. With ``perf`` (a PerfCounters
+        set) the same duration is added to its ``TIME`` counter
+        ``key``, whether or not the tracer keeps spans."""
         if not self.enabled:
-            yield None
+            if perf is None:
+                yield None
+                return
+            t0 = time.perf_counter()
+            try:
+                yield None
+            finally:
+                perf.tinc(key, time.perf_counter() - t0)
             return
         stack = self._stack()
         parent = stack[-1].span_id if stack else None
@@ -126,10 +156,51 @@ class Tracer:
         finally:
             if annotation is not None:
                 annotation.__exit__(None, None, None)
-            sp.duration = time.perf_counter() - t0
+            end = time.perf_counter()
+            sp.duration = end - t0
             stack.pop()
+            if perf is not None:
+                perf.tinc(key, sp.duration)
             with self._lock:
                 self._history.append(sp)
+                anchor = end - self._last_anchor >= ANCHOR_INTERVAL_S
+                if anchor:
+                    self._last_anchor = end
+            if anchor and cls is not None:
+                try:
+                    with cls(ANCHOR_NAME, mono_ns=time.perf_counter_ns()):
+                        pass
+                except Exception:
+                    pass
+
+    def record(
+        self, name: str, start_mono: float, end_mono: float, *,
+        trace_id: str | None, parent_id: str | None,
+        perf=None, key: str | None = None, **tags,
+    ) -> Span | None:
+        """An interval that starts on one thread and ends on another
+        (queue wait, sub-op wait), given as two ``perf_counter``
+        readings: a Span in the history and a ``tinc``; no annotation,
+        which is bound to one thread."""
+        if end_mono < start_mono:
+            raise ValueError(
+                f"{name}: interval ends {start_mono - end_mono:.6f} s "
+                "before it starts"
+            )
+        duration = end_mono - start_mono
+        if perf is not None:
+            perf.tinc(key, duration)
+        if not self.enabled:
+            return None
+        sp = Span(
+            f"{_TRACE_PREFIX}-{next(self._ids)}", parent_id, name,
+            time.time() - (time.perf_counter() - start_mono),
+            duration=duration, tags=tags, trace_id=trace_id,
+            start_mono=start_mono,
+        )
+        with self._lock:
+            self._history.append(sp)
+        return sp
 
     def current(self) -> tuple[str | None, str | None]:
         """(trace_id, span_id) of the innermost open span — what a
@@ -171,8 +242,12 @@ class Tracer:
         return [s.as_dict() for s in spans]
 
     def clear(self) -> None:
+        """Forget the history; the next span to close leaves a clock
+        anchor (a caller that clears right after starting a profiler
+        trace gets one at its start)."""
         with self._lock:
             self._history.clear()
+            self._last_anchor = float("-inf")
 
 
 # Process-global tracer.

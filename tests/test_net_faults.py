@@ -258,6 +258,9 @@ class TestDuplicateSubWrites:
                 if key == "resends_absorbed":
                     _PC.absorbed += n
 
+            def tinc(self, key, seconds):
+                pass  # the messenger's send/recv timers
+
         backend.messenger.net_pc = _PC()
         net_faults.configure(11)
         net_faults.add_rule("cli.dup", "osd.0", LinkRule(dup=1.0))

@@ -18,11 +18,23 @@ Inputs, merged together:
   client ops (client → primary → sub-write fan-out), and assemble the
   run's traces — the zero-to-trace smoke.
 
+- ``--xplane DIR``: a ``jax.profiler`` trace taken while the spans
+  were recorded.  The tracer leaves a ``clock_anchor`` annotation in
+  it about once a second (``utils/trace.py``); from those the spans —
+  recorded intervals and spans that were open when the trace started
+  too — are shifted onto the profiler's clock, where the device's ops
+  live.  With ``--live-demo`` the demo records the trace into DIR
+  itself (``--demo-*`` size it; the defaults are tiny).
+
 Outputs:
 
 - the text report on stdout (``--top N`` slowest traces, default 10);
 - ``--chrome OUT.json``: Chrome trace-event JSON for the selected
-  traces.
+  traces; with ``--xplane`` on the profiler's clock, with one more lane
+  per device holding its ops;
+- with ``--xplane``: the device's idle seconds by the innermost stage
+  span open at the time (``STAGE_ORDER``), and for the busiest device
+  ops the stage each call started in.
 
 The assembly core lives in ``ceph_tpu/utils/trace_assembly.py`` —
 loadgen's ``--trace-capture`` and the soak forensics bundle use the
@@ -34,6 +46,130 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+
+
+#: the program's spans, innermost first: an idle stretch of the device
+#: goes to the first of these that was open (``PERF.md`` section 3
+#: names each). Finer than ``benchmark/trace/spans.json``, which the
+#: ledger's ``breakdown`` keeps to its six coarse names.
+STAGE_ORDER = [
+    "codec.fetch", "codec.launch", "codec.h2d", "codec.prep",
+    "ec_write.fanout", "ec_write.txn_build", "ec_write.encode",
+    "ec_write.assemble", "ec_write.plan", "sub_write", "sub_read",
+    "ec_reconstruct", "ec_read.finish", "ec_read.issue", "ec_truncate",
+    "ec_write", "subop_wait", "sub_read_wait", "osd_op", "opq_wait",
+    "client_op",
+]
+
+
+def clock_offset(xplane_path: str) -> "tuple[float, int]":
+    """Seconds to add to a ``Span.start_mono`` to land on the profiler
+    trace's clock, and the number of anchors it is the median of."""
+    import statistics
+
+    from jax.profiler import ProfileData
+
+    from ceph_tpu.utils.trace import ANCHOR_NAME
+
+    offsets = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name != ANCHOR_NAME:
+                    continue
+                mono_ns = dict(e.stats).get("mono_ns")
+                if mono_ns is not None:
+                    offsets.append((e.start_ns - int(mono_ns)) * 1e-9)
+    if not offsets:
+        raise SystemExit(
+            f"{xplane_path}: no {ANCHOR_NAME!r} annotation; was the "
+            "program's tracer on while the profiler ran?"
+        )
+    return statistics.median(offsets), len(offsets)
+
+
+def shift_spans(spans: list[dict], offset: float) -> list[dict]:
+    """The spans that carry a monotonic start, moved onto the profiler
+    trace's clock (``start`` and ``start_mono`` both)."""
+    out = []
+    for s in spans:
+        if s.get("start_mono") is None:
+            continue
+        at = s["start_mono"] + offset
+        out.append({**s, "start": at, "start_mono": at})
+    return out
+
+
+def device_report(trace, spans: list[dict]) -> str:
+    """Idle seconds of the device by stage, and where the busiest
+    device ops started. ``trace``: ``benchmark.trace.xplane.Trace``;
+    ``spans``: shifted onto its clock."""
+    import dataclasses
+
+    from benchmark.trace import xplane
+
+    host = [
+        (s["name"], s["start"], s["start"] + (s.get("duration") or 0.0))
+        for s in spans
+    ]
+    on_clock = dataclasses.replace(trace, host=host)
+    lo, hi = xplane.span_bounds(on_clock)
+    gaps = xplane.idle_gaps(on_clock, lo, hi)
+    busy = sum(xplane.busy_seconds(on_clock).values()) / max(
+        len(on_clock.devices), 1
+    )
+    out = [
+        f"device: {hi - lo:.3f} s of trace, busy {busy:.6f} s a chip, "
+        f"idle {sum(e - s for s, e in gaps):.3f} s, by innermost stage:"
+    ]
+    for name, seconds in xplane.attribute_gaps(
+        on_clock, gaps, STAGE_ORDER, limit=len(STAGE_ORDER) + 1
+    ):
+        out.append(f"  {seconds:10.4f} s  {name}")
+    open_by_name = {
+        n: xplane.union([(s, e) for m, s, e in host if m == n])
+        for n in STAGE_ORDER
+    }
+
+    def stage_at(t: float) -> str:
+        for n in STAGE_ORDER:
+            if any(s <= t < e for s, e in open_by_name[n]):
+                return n
+        return "no span"
+
+    out.append("device ops, by the stage open when each call started:")
+    for short, seconds in xplane.top_ops(on_clock, limit=5):
+        where: dict[str, int] = {}
+        for events in on_clock.devices.values():
+            for name, start, _end in events:
+                if name.split(" = ", 1)[0].strip() == short:
+                    key = stage_at(start)
+                    where[key] = where.get(key, 0) + 1
+        out.append(
+            f"  {short}  {seconds * 1e3:.3f} ms: " + ", ".join(
+                f"{n} in {k}" for k, n in sorted(
+                    where.items(), key=lambda kv: -kv[1]
+                )
+            )
+        )
+    return "\n".join(out)
+
+
+def device_lanes(trace) -> list[dict]:
+    """Chrome events of the device's ops, one lane (pid 2) a chip."""
+    events = []
+    for tid, (plane, ops) in enumerate(sorted(trace.devices.items()), 1):
+        events.append({
+            "name": "thread_name", "ph": "M", "pid": 2, "tid": tid,
+            "args": {"name": plane},
+        })
+        for name, start, end in ops:
+            events.append({
+                "name": name.split(" = ", 1)[0].strip(), "cat": "device",
+                "ph": "X", "ts": start * 1e6, "dur": (end - start) * 1e6,
+                "pid": 2, "tid": tid,
+            })
+    return events
 
 
 def collect_process() -> tuple[list[dict], list[dict]]:
@@ -61,23 +197,45 @@ def _load_ops(path: str) -> list[dict]:
     return list(data)
 
 
-def _live_demo() -> tuple[list[dict], list[dict]]:
-    """Boot a LoadCluster, drive a handful of ops, return the spans."""
+def _live_demo(args) -> tuple[list[dict], list[dict]]:
+    """Boot a LoadCluster, drive a handful of ops, return the spans.
+    With ``--xplane`` the ops run under a profiler trace written there
+    (after one untraced op has compiled what they use)."""
     import numpy as np
 
     from ceph_tpu.loadgen import LoadCluster
     from ceph_tpu.utils.trace import tracer
 
     cluster = LoadCluster(
-        n_osds=5, k=2, m=1, pg_num=4, chunk_size=1024
+        n_osds=args.demo_osds, k=args.demo_k, m=args.demo_m, pg_num=4,
+        chunk_size=args.demo_chunk,
+        client_op_timeout=60.0,
     )
     try:
-        tracer.clear()
         rng = np.random.default_rng(7)
-        for i in range(4):
-            data = rng.integers(0, 256, 4096, np.uint8).tobytes()
-            cluster.io.write(f"demo-{i}", data)
-            cluster.io.read(f"demo-{i}")
+
+        def one(name: str) -> None:
+            data = rng.integers(
+                0, 256, args.demo_object_bytes, np.uint8
+            ).tobytes()
+            cluster.io.write_full(name, data)
+            assert cluster.io.read(name) == data
+
+        if args.xplane:
+            import jax
+
+            one("demo-warm")
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            jax.profiler.start_trace(args.xplane, profiler_options=options)
+        tracer.clear()
+        try:
+            for i in range(args.demo_ops):
+                one(f"demo-{i}")
+        finally:
+            if args.xplane:
+                jax.profiler.stop_trace()
         spans, ops = collect_process()
     finally:
         cluster.shutdown()
@@ -104,6 +262,16 @@ def main(argv: "list[str] | None" = None) -> int:
                    help="slowest traces to report (default 10)")
     p.add_argument("--chrome", default=None, metavar="OUT.json",
                    help="write Chrome trace-event JSON here")
+    p.add_argument("--xplane", default=None, metavar="DIR",
+                   help="profiler trace taken with the spans: put both "
+                        "on one clock, add the device's lanes and its "
+                        "idle time by stage")
+    p.add_argument("--demo-k", type=int, default=2)
+    p.add_argument("--demo-m", type=int, default=1)
+    p.add_argument("--demo-osds", type=int, default=5)
+    p.add_argument("--demo-chunk", type=int, default=1024)
+    p.add_argument("--demo-object-bytes", type=int, default=4096)
+    p.add_argument("--demo-ops", type=int, default=4)
     p.add_argument("--all", action="store_true",
                    help="include incomplete (multi-root/orphaned) "
                         "traces in the report")
@@ -119,11 +287,22 @@ def main(argv: "list[str] | None" = None) -> int:
     for path in args.ops:
         ops.extend(_load_ops(path))
     if args.live_demo:
-        s, o = _live_demo()
+        s, o = _live_demo(args)
         spans.extend(s)
         ops.extend(o)
     if not spans and not ops:
         spans, ops = collect_process()
+    device = None
+    if args.xplane:
+        from benchmark.trace import xplane
+
+        path = xplane.find_xplane(args.xplane)
+        offset, n_anchors = clock_offset(path)
+        spans = shift_spans(spans, offset)
+        ops = []  # live ops carry wall-clock starts only
+        device = xplane.load(path, set())
+        print(f"clock: spans + {offset:.6f} s = the trace's clock "
+              f"(median of {n_anchors} anchors)")
 
     trees = assemble_traces(spans, ops)
     if not args.all:
@@ -131,9 +310,14 @@ def main(argv: "list[str] | None" = None) -> int:
         if complete:
             trees = complete
     print(format_report(trees, top=args.top))
+    if device is not None:
+        print(device_report(device, spans))
     if args.chrome:
+        chrome = chrome_trace(trees[: args.top])
+        if device is not None:
+            chrome["traceEvents"].extend(device_lanes(device))
         with open(args.chrome, "w", encoding="utf-8") as f:
-            json.dump(chrome_trace(trees[: args.top]), f)
+            json.dump(chrome, f)
         print(f"chrome trace: {args.chrome}", file=sys.stderr)
     return 0
 
