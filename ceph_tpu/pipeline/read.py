@@ -161,27 +161,6 @@ def get_min_avail_to_read_shards(
     return reads, True
 
 
-def gather_ro_range(
-    sinfo: StripeInfo, smap: ShardExtentMap, ro_offset: int, length: int
-) -> bytes:
-    """Assemble the rados byte range from per-shard buffers (the inverse
-    of the write path's shard scatter; absent bytes read as zero)."""
-    out = np.zeros(length, dtype=np.uint8)
-    pos, taken = ro_offset, 0
-    while taken < length:
-        chunk_index = pos // sinfo.chunk_size
-        raw = chunk_index % sinfo.k
-        in_chunk = pos % sinfo.chunk_size
-        take = min(sinfo.chunk_size - in_chunk, length - taken)
-        shard_off = (chunk_index // sinfo.k) * sinfo.chunk_size + in_chunk
-        out[taken : taken + take] = smap.get(
-            sinfo.get_shard(raw), shard_off, take
-        )
-        pos += take
-        taken += take
-    return out.tobytes()
-
-
 def reconstruct_shards(
     sinfo: StripeInfo,
     codec,
@@ -510,9 +489,7 @@ class ReadPipeline:
             "ec_read.finish", perf=self.perf, key="finish_seconds"
         ):
             if op.error is None:
-                op.data = gather_ro_range(
-                    self.sinfo, op.result, op.ro_offset, op.length
-                )
+                op.data = op.result.get_ro_range(op.ro_offset, op.length)
                 self.perf.inc("read_bytes", len(op.data))
             else:
                 self.perf.inc("errors")

@@ -959,25 +959,7 @@ class RMWPipeline:
             "ec_write.assemble", perf=self.perf, key="assemble_seconds"
         ):
             new_map = ShardExtentMap(sinfo)
-            pos = op.ro_offset
-            data = np.frombuffer(op.data, dtype=np.uint8)
-            taken = 0
-            while taken < len(op.data):
-                chunk_index = pos // sinfo.chunk_size
-                raw = chunk_index % sinfo.k
-                in_chunk = pos % sinfo.chunk_size
-                take = min(
-                    sinfo.chunk_size - in_chunk, len(op.data) - taken
-                )
-                shard_off = (
-                    (chunk_index // sinfo.k) * sinfo.chunk_size + in_chunk
-                )
-                new_map.insert(
-                    sinfo.get_shard(raw), shard_off,
-                    data[taken : taken + take],
-                )
-                pos += take
-                taken += take
+            new_map.insert_ro_range(op.ro_offset, op.data)
 
             hinfo = self._get_hinfo(op.oid)
             hashed = hinfo.get_total_chunk_size()

@@ -86,6 +86,42 @@ class ShardExtentMap:
                 out[s - offset : e - offset] = buf[s - off : e - off]
         return out
 
+    def _ro_pieces(self, ro_buf, run_buf: np.ndarray, run):
+        """(ro view, run view) pairs of one shard run's pieces, equal
+        in shape: assign one to the other to scatter or to gather."""
+        for ro_at, run_at, rows, width in run.pieces:
+            yield (
+                np.ndarray(
+                    (rows, width), np.uint8, ro_buf, ro_at,
+                    (self.sinfo.stripe_width, 1),
+                ),
+                run_buf[run_at : run_at + rows * width].reshape(rows, width),
+            )
+
+    def insert_ro_range(self, ro_offset: int, data) -> None:
+        """Scatter rados-object bytes at ``ro_offset`` onto the data
+        shards: one strided copy and one ``insert`` per touched shard."""
+        data = np.frombuffer(data, dtype=np.uint8)
+        for run in self.sinfo.ro_range_to_shard_runs(ro_offset, data.size):
+            buf = np.empty(run.end - run.start, dtype=np.uint8)
+            for src, dst in self._ro_pieces(data, buf, run):
+                dst[...] = src
+            self.insert(self.sinfo.get_shard(run.raw_shard), run.start, buf)
+
+    def get_ro_range(self, ro_offset: int, length: int) -> bytes:
+        """Gather the rados byte range from the data shards (the
+        inverse of ``insert_ro_range``; absent bytes read as zero):
+        one ``get`` and one strided copy per touched shard."""
+        out = np.empty(length, dtype=np.uint8)
+        for run in self.sinfo.ro_range_to_shard_runs(ro_offset, length):
+            buf = self.get(
+                self.sinfo.get_shard(run.raw_shard),
+                run.start, run.end - run.start,
+            )
+            for dst, src in self._ro_pieces(out, buf, run):
+                dst[...] = src
+        return out.tobytes()
+
     def contains(self, shard: int, offset: int, length: int) -> bool:
         return self.get_extent_set(shard).contains(offset, length)
 

@@ -16,6 +16,8 @@ Vocabulary (matches the reference):
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 from .extents import ExtentSet
 
 # BlueStore writes whole pages; the reference aligns shard IO to 4K
@@ -54,6 +56,21 @@ def csum_block_range(
     if last > nblocks:
         return None
     return first, last
+
+
+class ShardRun(NamedTuple):
+    """One data shard's share of an ro byte range: the contiguous shard
+    run ``[start, end)`` and where it sits in the range's buffer.
+
+    ``pieces`` holds at most three ``(ro_at, run_at, rows, width)``:
+    ``rows`` blocks of ``width`` bytes, one every ``stripe_width`` bytes
+    from ``ro_at`` in the ro buffer and back to back from ``run_at`` in
+    the run — a partial head chunk, the whole chunks, a partial tail."""
+
+    raw_shard: int
+    start: int
+    end: int
+    pieces: tuple[tuple[int, int, int, int], ...]
 
 
 class StripeInfo:
@@ -157,6 +174,42 @@ class StripeInfo:
         return start // self.k, (end - start) // self.k
 
     # -- range fan-out -------------------------------------------------
+    def ro_range_to_shard_runs(
+        self, ro_offset: int, ro_length: int
+    ) -> list[ShardRun]:
+        """The ro byte range as one contiguous run per touched data
+        shard, in the order the range first touches them (at most
+        ``min(k, chunks touched)`` runs). A shard's bytes of any ro
+        range are one run: a partial head chunk is the first on its
+        shard, a partial tail chunk the last, and every chunk between
+        is whole and follows the previous one in shard space."""
+        if ro_length <= 0:
+            return []
+        cs, k, sw = self.chunk_size, self.k, self.stripe_width
+        end = ro_offset + ro_length
+        first_chunk = ro_offset // cs
+        touched = min(k, (end - 1) // cs - first_chunk + 1)
+        runs = []
+        for raw in ((first_chunk + i) % k for i in range(touched)):
+            start = self.ro_offset_to_shard_offset(ro_offset, raw)
+            stop = self.ro_offset_to_shard_offset(end, raw)
+            head = min(stop, -(-start // cs) * cs) - start
+            rows, tail = divmod(stop - start - head, cs)
+            # shard offset x sits at ro offset (x // cs) * sw + raw * cs
+            # + x % cs; the head starts mid-chunk, block and tail on a
+            # chunk boundary
+            base = raw * cs - ro_offset
+            body = start + head
+            pieces = (
+                ((start // cs) * sw + start % cs + base, 0, 1, head),
+                ((body // cs) * sw + base, head, rows, cs),
+                ((body // cs + rows) * sw + base, head + rows * cs, 1, tail),
+            )
+            runs.append(ShardRun(
+                raw, start, stop, tuple(p for p in pieces if p[2] and p[3])
+            ))
+        return runs
+
     def ro_range_to_shard_extent_set(
         self, ro_offset: int, ro_length: int, parity: bool = False
     ) -> dict[int, ExtentSet]:
@@ -166,20 +219,13 @@ class StripeInfo:
         out: dict[int, ExtentSet] = {}
         if ro_length <= 0:
             return out
-        end = ro_offset + ro_length
-        pos = ro_offset
-        while pos < end:
-            chunk_index = pos // self.chunk_size
-            raw_shard = chunk_index % self.k
-            in_chunk = pos % self.chunk_size
-            take = min(self.chunk_size - in_chunk, end - pos)
-            shard = self.get_shard(raw_shard)
-            shard_off = (chunk_index // self.k) * self.chunk_size + in_chunk
-            out.setdefault(shard, ExtentSet()).insert(shard_off, take)
-            pos += take
+        for run in self.ro_range_to_shard_runs(ro_offset, ro_length):
+            out[self.get_shard(run.raw_shard)] = ExtentSet(
+                [(run.start, run.end)]
+            )
         if parity:
             first = self.ro_offset_to_prev_chunk_offset(ro_offset)
-            last = self.ro_offset_to_next_chunk_offset(end)
+            last = self.ro_offset_to_next_chunk_offset(ro_offset + ro_length)
             for raw in range(self.k, self.k + self.m):
                 out.setdefault(self.get_shard(raw), ExtentSet()).insert(
                     first, last - first

@@ -27,8 +27,8 @@ K, M = 4, 2
 CHUNK = PAGE_SIZE
 
 
-def make_stack(k=K, m=M, chunk=CHUNK):
-    sinfo = StripeInfo(k, m, k * chunk)
+def make_stack(k=K, m=M, chunk=CHUNK, chunk_mapping=None):
+    sinfo = StripeInfo(k, m, k * chunk, chunk_mapping)
     codec = registry.factory(
         "jerasure", {"technique": "reed_sol_van", "k": str(k), "m": str(m)}
     )
@@ -138,6 +138,45 @@ class TestUnalignedOverwrite:
         expect[2 * chunk + 17 : 2 * chunk + 17 + len(patch)] = patch
         backend.down_shards.update({1, 6, 9, 11})  # m losses
         assert reads.read_sync("obj", 0, len(data)) == bytes(expect)
+
+
+    #: (ro_offset, length) read from a three-stripe-and-a-bit object
+    RANGES = {
+        "crosses_chunk_boundary": (CHUNK - 50, 100),
+        "crosses_stripe_boundary": (K * CHUNK - 50, 100),
+        "head_and_tail_across_two_stripes": (
+            CHUNK + 37, 2 * K * CHUNK - CHUNK - 37 + 211
+        ),
+        "whole_object": (0, 3 * K * CHUNK + 999),
+    }
+
+    @pytest.mark.parametrize(
+        "mapping", [None, [5, 0, 1, 2, 3, 4]],
+        ids=["identity", "chunk_mapping"],
+    )
+    @pytest.mark.parametrize("span", RANGES)
+    def test_unaligned_ranges_healthy_and_degraded(self, rng, span, mapping):
+        """The gather's head and tail pieces: a read that starts and
+        ends mid-chunk, over a patched object, with every shard up and
+        with a data and a parity shard down."""
+        rmw, reads, sinfo, _, backend = make_stack(chunk_mapping=mapping)
+        data = bytearray(
+            rng.integers(0, 256, 3 * K * CHUNK + 999, np.uint8).tobytes()
+        )
+        rmw.submit("obj", 0, bytes(data))
+        patch = rng.integers(0, 256, CHUNK + 77, np.uint8).tobytes()
+        at = K * CHUNK - 33  # across the first stripe boundary
+        rmw.submit("obj", at, patch)
+        data[at : at + len(patch)] = patch
+        off, length = self.RANGES[span]
+        assert reads.read_sync("obj", off, length) == bytes(
+            data[off : off + length]
+        )
+        # the stored shards of raw data shard 1 and of the first parity
+        backend.down_shards.update({sinfo.get_shard(1), sinfo.get_shard(K)})
+        assert reads.read_sync("obj", off, length) == bytes(
+            data[off : off + length]
+        )
 
 
 class TestRetry:

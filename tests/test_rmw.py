@@ -32,8 +32,8 @@ K, M = 4, 2
 CHUNK = PAGE_SIZE  # 4K chunks -> 16K stripe
 
 
-def make_pipeline(k=K, m=M, chunk=CHUNK):
-    sinfo = StripeInfo(k, m, k * chunk)
+def make_pipeline(k=K, m=M, chunk=CHUNK, chunk_mapping=None):
+    sinfo = StripeInfo(k, m, k * chunk, chunk_mapping)
     codec = registry.factory(
         "jerasure", {"technique": "reed_sol_van", "k": str(k), "m": str(m)}
     )
@@ -158,6 +158,42 @@ def test_unaligned_sub_page_write(rng):
     expect[37 : 137] = patch
     got = reconstruct_object(pipe, sinfo, codec, "obj", len(base), lost=(0, 4))
     assert got == bytes(expect)
+
+
+#: (ro_offset, length) of a patch over a three-stripe object
+UNALIGNED_PATCHES = {
+    "crosses_chunk_boundary": (CHUNK - 50, 100),
+    "crosses_stripe_boundary": (K * CHUNK - 50, 100),
+    "head_and_tail_across_two_stripes": (
+        CHUNK + 37, 2 * K * CHUNK - CHUNK - 37 + 211
+    ),
+    "grows_the_object_from_mid_chunk": (3 * K * CHUNK - 700, CHUNK + 1500),
+}
+
+
+@pytest.mark.parametrize(
+    "mapping", [None, [5, 0, 1, 2, 3, 4]], ids=["identity", "chunk_mapping"]
+)
+@pytest.mark.parametrize("patch", UNALIGNED_PATCHES)
+def test_unaligned_patch_survives_any_double_loss(rng, patch, mapping):
+    """The scatter's head and tail pieces: a patch that starts and ends
+    mid-chunk lands on the right bytes of the right stored shard, and
+    parity follows it, with and without a chunk_mapping."""
+    pipe, sinfo, codec, _ = make_pipeline(chunk_mapping=mapping)
+    base = bytes(rng.integers(0, 256, 3 * K * CHUNK, dtype=np.uint8))
+    pipe.submit("obj", 0, base)
+    off, length = UNALIGNED_PATCHES[patch]
+    data = bytes(rng.integers(0, 256, length, dtype=np.uint8))
+    pipe.submit("obj", off, data)
+    expect = bytearray(max(len(base), off + length))
+    expect[: len(base)] = base
+    expect[off : off + length] = data
+    assert pipe.object_size("obj") == len(expect)
+    for lost in [(), (0, 1), (2, 5), (3, 4)]:
+        got = reconstruct_object(
+            pipe, sinfo, codec, "obj", len(expect), lost=lost
+        )
+        assert got == bytes(expect), f"lost={lost}"
 
 
 def test_multi_stripe_append_grows_object(rng):
