@@ -2642,14 +2642,21 @@ class OSDDaemon:
             groups.setdefault(
                 (ctx.msg.pool, ctx.pgid), []
             ).append(ctx)
+        from ceph_tpu.pipeline.dispatcher import DeltaTick
+
+        # the wave's parity deltas meet here: one dispatch once every
+        # group has submitted its ops
+        tick = DeltaTick(len(groups))
         with self.peers.subwrite_batching():
             if len(groups) == 1:
-                self._coalesce_run_group(next(iter(groups.values())))
+                self._coalesce_run_group(
+                    next(iter(groups.values())), tick
+                )
             else:
                 threads = [
                     threading.Thread(
-                        target=self._coalesce_run_group, args=(ctxs,),
-                        daemon=True,
+                        target=self._coalesce_run_group,
+                        args=(ctxs, tick), daemon=True,
                         name=f"osd.{self.osd_id}-coal",
                     )
                     for ctxs in groups.values()
@@ -2665,33 +2672,40 @@ class OSDDaemon:
             if ctx.outcome is None:
                 ctx.outcome = ("exc", "coalesced execution stalled")
 
-    def _coalesce_run_group(self, ctxs: "list[_CoalCtx]") -> None:
+    def _coalesce_run_group(self, ctxs: "list[_CoalCtx]", tick) -> None:
         """One PG's slice of a wave, on its own thread. Writes
         PIPELINE: every op submits before the first drain (the RMW
         in-order commit machinery keeps tid order), so the group's
         sub-writes share per-peer frames and its encodes overlap
-        other groups' in the ring."""
+        other groups' in the ring. Ops that encode by parity delta
+        park in ``tick`` and dispatch from ``tick.arrive()``, once the
+        wave's last group has submitted."""
         from ceph_tpu.pipeline import dispatcher as _disp
 
-        with _disp.coalescing_scope():
+        with _disp.coalescing_scope(tick):
             live: list[_CoalCtx] = []
-            for ctx in ctxs:
-                try:
-                    with tracer.continue_trace(
-                        ctx.msg.trace_id, ctx.msg.parent_span
-                    ), tracer.span(
-                        "osd_op", op=ctx.msg.op, oid=ctx.msg.oid,
-                        osd=self.osd_id, tid=ctx.msg.tid,
-                    ):
-                        ctx.trace_ctx = tracer.current()
-                        ctx.pg.rmw.submit(
-                            ctx.msg.oid, ctx.w_offset, ctx.msg.data,
-                            on_commit=lambda op, c=ctx: c.done.append(op),
-                            extra_attrs=ctx.attrs,
-                        )
-                    live.append(ctx)
-                except Exception as e:
-                    ctx.outcome = ("exc", f"{type(e).__name__}: {e}")
+            try:
+                for ctx in ctxs:
+                    try:
+                        with tracer.continue_trace(
+                            ctx.msg.trace_id, ctx.msg.parent_span
+                        ), tracer.span(
+                            "osd_op", op=ctx.msg.op, oid=ctx.msg.oid,
+                            osd=self.osd_id, tid=ctx.msg.tid,
+                        ):
+                            ctx.trace_ctx = tracer.current()
+                            ctx.pg.rmw.submit(
+                                ctx.msg.oid, ctx.w_offset, ctx.msg.data,
+                                on_commit=(
+                                    lambda op, c=ctx: c.done.append(op)
+                                ),
+                                extra_attrs=ctx.attrs,
+                            )
+                        live.append(ctx)
+                    except Exception as e:
+                        ctx.outcome = ("exc", f"{type(e).__name__}: {e}")
+            finally:
+                tick.arrive()  # the other groups wait for this one
             self._coalesce_drain(live)
             for ctx in list(live):
                 if ctx.done and ctx.done[0].error is not None:

@@ -128,17 +128,18 @@ def _dispatch_counters():
     return b.create_perf_counters()
 
 
-def count_route(name: str, *arrays) -> None:
+def count_route(name: str, *arrays, nbytes: int | None = None) -> None:
     """Count one served dispatch under ``name`` and the input bytes it
     carried under ``name_bytes`` — the host/device byte split is what
-    says how much of the traffic reached the chip."""
+    says how much of the traffic reached the chip. ``nbytes`` overrides
+    the arrays' size where they carry zero columns or padding (a
+    batched parity delta counts its real delta pages only)."""
     pc = _dispatch_counters()
     pc.inc("dispatches")
     pc.inc(name)
-    pc.inc(
-        name + "_bytes",
-        sum(int(a.size) * a.dtype.itemsize for a in arrays),
-    )
+    if nbytes is None:
+        nbytes = sum(int(a.size) * a.dtype.itemsize for a in arrays)
+    pc.inc(name + "_bytes", nbytes)
 
 
 def codec_stage(step: str):
@@ -152,6 +153,39 @@ def codec_stage(step: str):
     return tracer.span(
         "codec." + step, perf=_dispatch_counters(), key=step + "_seconds"
     )
+
+
+#: the unit of a batched parity delta: one page of one data column
+#: (the RMW planner page-aligns every parity window)
+DELTA_UNIT = 4096
+#: a delta batch of at most this many units stays on the host GF
+#: tables: the host-or-device decision, taken once on the batch. Read
+#: off the served path (PERF.md section 5, PR 26: both routes in one
+#: run of ``rs84-rbd.randwrite``, a coin a batch): a page on the host
+#: tables costs ~2.2 ms there, a device dispatch 10-15 ms whatever it
+#: carries, so the device wins from six pages on
+DELTA_HOST_UNITS = 5
+
+
+def delta_batch_sizes() -> tuple[int, ...]:
+    """Every unit count a batched delta dispatch can have on the
+    device: batches are zero-padded up to the next power of two, the
+    largest being two pages for each op of a full coalesced tick
+    (``osd_coalesce_max`` x 2). A fixed, small set, so that the device
+    route compiles a known list of programs and traffic cannot meet a
+    new shape mid-window."""
+    from ceph_tpu.utils import config
+
+    top = 2 * int(config.get("osd_coalesce_max"))
+    sizes = [1]
+    while sizes[-1] < top:
+        sizes.append(sizes[-1] * 2)
+    return tuple(sizes)
+
+
+#: (encode bit-matrix, unit length, device route) of the delta programs
+#: already compiled in this process
+_delta_warmed: set[tuple] = set()
 
 
 def _upload(x):
@@ -312,12 +346,15 @@ class BitplaneDispatchMixin:
         bmat_dev: jax.Array,
         stacked: jax.Array,
         op: str,
+        nbytes: int | None = None,
     ) -> jax.Array:
         """Route one device bit-matrix application. Decode and delta
         ride the same fused kernel as encode — the kernel is generic
         over [R*8, C*8] bitmatrices, so reconstruct is a first-class
         on-chip path (the reference treats decode as equally hot:
-        osd/ECUtil.cc:648-729, isa/ErasureCodeIsa.cc:504-516)."""
+        osd/ECUtil.cc:648-729, isa/ErasureCodeIsa.cc:504-516).
+        ``nbytes``: what the route counts as input bytes where
+        ``stacked`` carries zero columns or padding."""
         from ceph_tpu.ops import pallas_encode as pe
         from ceph_tpu.utils import config
 
@@ -358,7 +395,7 @@ class BitplaneDispatchMixin:
             if mesh_dispatch.mesh_supported(
                 mesh, bmat_np.shape, flat.shape
             ):
-                count_route(f"mesh_{op}", flat)
+                count_route(f"mesh_{op}", flat, nbytes=nbytes)
                 with codec_stage("launch"):
                     out = mesh_dispatch.mesh_apply_bitmatrix(
                         mesh, bmat_dev, flat
@@ -369,7 +406,7 @@ class BitplaneDispatchMixin:
             _dispatch_counters().inc("mesh_fallback")
         if config.get("ec_use_pallas") and platform.on_tpu():
             if pe.supported((1,) + stacked.shape[-2:]):
-                count_route(f"pallas_{op}", stacked)
+                count_route(f"pallas_{op}", stacked, nbytes=nbytes)
                 flat = _upload(
                     stacked.reshape((-1,) + stacked.shape[-2:])
                 )
@@ -379,7 +416,7 @@ class BitplaneDispatchMixin:
                         stacked.shape[:-2] + out.shape[-2:]
                     )
             _dispatch_counters().inc("pallas_fallback")
-        count_route(f"einsum_{op}", stacked)
+        count_route(f"einsum_{op}", stacked, nbytes=nbytes)
         stacked = _upload(stacked)
         with codec_stage("launch"):
             return _apply_bitmatrix(bmat_dev, stacked)
@@ -795,3 +832,83 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
             pid: xor_bytes(p, contribs[pid - self.k])
             for pid, p in parity.items()
         }
+
+    def delta_batchable(self) -> bool:
+        """Whether ``delta_contribs`` serves this codec now: the mesh
+        and DCN routes own their dispatch shapes and keep the per-op
+        ``apply_delta``."""
+        from ceph_tpu.parallel import dispatch as mesh_dispatch
+
+        return self._active_mesh() is None and mesh_dispatch.get_dcn() is None
+
+    def delta_contribs(
+        self, cols: np.ndarray, units: np.ndarray
+    ) -> tuple[np.ndarray, int]:
+        """The batched parity delta: ``units`` [U, L] are delta pages
+        (old XOR new) of any number of ops, ``cols`` [U] the raw data
+        column of each. Returns ``(contribs [U, m, L], units sent)``
+        with ``contribs[u, j] = G[k+j, cols[u]] * units[u]``; the caller
+        XORs them onto its old parity windows.
+
+        One decision for the whole batch, in ``_shards_host_route``'s
+        place: up to ``DELTA_HOST_UNITS`` units the host GF tables
+        serve it (units sent = U). Otherwise ONE device dispatch: by
+        linearity a delta on column c is the encode of a stripe that
+        is zero everywhere but c, so the batch stacks as [P, k, L]
+        with the other columns zero, P the next of
+        ``delta_batch_sizes()`` (units sent = P), through the encode
+        bit-matrix and the kernel every encode and decode rides. The
+        route counts the real delta pages as its bytes, not the zero
+        columns or the padding. The first device batch of a process
+        compiles every size of the set, so traffic never meets a new
+        shape in the middle of a run. (Not the first delta: a batch
+        for the host tables would wait seconds for programs it does
+        not use; on the served overwrite traffic one batch in eleven
+        is the device's, so the compile falls in the first seconds.)"""
+        n, ln = units.shape
+        if n <= DELTA_HOST_UNITS:
+            from ceph_tpu.gf import gf_apply_bytes_host
+
+            count_route("host_delta", units)
+            parity_rows = self.generator[self.k :, :]
+            return np.stack([
+                gf_apply_bytes_host(
+                    parity_rows[:, c : c + 1], units[u : u + 1]
+                )
+                for u, c in enumerate(cols)
+            ]), n
+        self._delta_warm(ln)
+        padded = next(p for p in delta_batch_sizes() if p >= n)
+        return self._delta_device(cols, units, padded, units.nbytes), padded
+
+    def _delta_device(
+        self, cols: np.ndarray, units: np.ndarray, padded: int,
+        nbytes: int,
+    ) -> np.ndarray:
+        n, ln = units.shape
+        with codec_stage("prep"):
+            stacked = np.zeros((padded, self.k, ln), np.uint8)
+            stacked[np.arange(n), cols] = units
+        out = self._dispatch_bitmatrix(
+            self._encode_bmat_np, self._encode_bmat, stacked, "delta",
+            nbytes=nbytes,
+        )
+        with codec_stage("fetch"):
+            return np.asarray(out)[:n]
+
+    def _delta_warm(self, unit_len: int) -> None:
+        """Compile the delta program of every batch size, once a
+        process for a given matrix and route (codec objects are rebuilt
+        on every map change; the compiled programs are not)."""
+        from ceph_tpu.utils import config
+
+        key = (
+            self._encode_bmat_np.tobytes(), unit_len,
+            bool(config.get("ec_use_pallas")) and platform.on_tpu(),
+        )
+        if key in _delta_warmed:
+            return
+        _delta_warmed.add(key)
+        none = np.zeros((0, unit_len), np.uint8)
+        for padded in delta_batch_sizes():
+            self._delta_device(none[:, 0], none, padded, 0)

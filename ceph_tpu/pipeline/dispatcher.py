@@ -42,15 +42,37 @@ The round-10 serving tier adds three seams:
   ``batch_faults`` counters). One poisoned op cannot sink its
   batch-mates.
 
+PR 26 adds the parity-delta seam, for small overwrites whose delta is
+a page or two and can only reach the device in company:
+
+- ``delta_batch`` is the one entry for parity deltas: any number of
+  ops' delta pages, each with its raw column, leave as ONE codec call
+  (``MatrixErasureCodec.delta_contribs``), which decides host or
+  device once, on the whole batch;
+- ``DeltaTick`` gathers a coalesced OSD tick: every op of the tick
+  prepares its delta and parks; when the tick's last PG group has
+  submitted, that group's thread sends them all as one
+  ``delta_batch``, and each group goes on with its own ops (place,
+  transactions, fan-out) in order. An op outside a tick is a batch of
+  one through the same ``delta_batch``.
+
+Deltas do not ride the ring: on the served 4 KiB overwrite traffic
+the ring merged the ticks of two OSDs in 7 of 673 batches (PERF.md
+§6, PR 26), which did not pay for a slot format and a thread handoff.
+
 Counters (``perf dump`` section ``ec_stream``): ops, batches,
 batched_ops (ops that shared a dispatch), plus a max-batch gauge,
 batch_faults (multi-op dispatches that failed and split), and
-solo_retries (ops that recovered via solo fallback).
+solo_retries (ops that recovered via solo fallback); for deltas,
+delta_batches (codec calls), delta_batch_ops and delta_batch_units
+(ops and real delta pages they carried) and delta_pad_units (zero
+pages added to reach a compiled size).
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import struct
 import threading
@@ -88,6 +110,17 @@ def _stream_counters():
         "solo_retries", "ops recovered via solo fallback after a "
         "batch fault"
     )
+    b.add_u64_counter(
+        "delta_batches", "parity-delta batches handed to the codec"
+    )
+    b.add_u64_counter("delta_batch_ops", "ops those batches carried")
+    b.add_u64_counter(
+        "delta_batch_units", "real delta pages those batches carried"
+    )
+    b.add_u64_counter(
+        "delta_pad_units",
+        "zero pages added to reach a compiled batch size (device route)",
+    )
     return b.create_perf_counters()
 
 
@@ -96,18 +129,29 @@ _coal_tls = threading.local()
 
 
 @contextlib.contextmanager
-def coalescing_scope():
+def coalescing_scope(tick: "DeltaTick | None" = None):
     """Thread-local scope marking this thread's encodes as part of a
     coalesced tick batch (the OSD daemon enters it around each PG
     group of a wave). Inside it, the shard-map encode routes through
     the streaming ring regardless of ``ec_streaming_dispatch`` —
     concurrent group threads of one tick land their ops in the same
-    ring window and share batched device dispatches. Nesting-safe."""
+    ring window and share batched device dispatches. With ``tick``,
+    the wave's ``DeltaTick``, parity deltas of this thread's ops park
+    there until the whole tick has submitted. Nesting-safe."""
     _coal_tls.depth = getattr(_coal_tls, "depth", 0) + 1
+    outer = getattr(_coal_tls, "tick", None)
+    if tick is not None:
+        _coal_tls.tick = tick
     try:
         yield
     finally:
         _coal_tls.depth -= 1
+        _coal_tls.tick = outer
+
+
+def current_tick() -> "DeltaTick | None":
+    """The ``DeltaTick`` this thread's ops may park in, if any."""
+    return getattr(_coal_tls, "tick", None)
 
 
 def coalescing_active() -> bool:
@@ -118,6 +162,157 @@ def coalescing_active() -> bool:
     from ceph_tpu import native
 
     return native.available()
+
+
+# ------------------------------------------------------------ parity delta
+def delta_batch(codec, members: list) -> list:
+    """The one entry for parity deltas. ``members`` are ``(cols [n]
+    uint8, pages [n, L] uint8, ops)``: delta pages (old XOR new) with
+    the raw data column of each, and how many client ops they belong
+    to. All of them go to the codec as one ``delta_contribs`` call
+    (more than the largest compiled batch: one call per slice), and
+    each member gets back its ``contribs [n, m, L]``, to XOR onto its
+    old parity."""
+    from ceph_tpu.codecs.matrix_codec import delta_batch_sizes
+
+    pc = _stream_counters()
+    if len(members) == 1:
+        cols, pages = members[0][0], members[0][1]
+    else:
+        cols = np.concatenate([m[0] for m in members])
+        pages = np.concatenate([m[1] for m in members])
+    cap = delta_batch_sizes()[-1]
+    outs = []
+    for at in range(0, len(cols), cap):
+        contribs, sent = codec.delta_contribs(
+            cols[at : at + cap], pages[at : at + cap]
+        )
+        pc.inc("delta_batches")
+        pc.inc("delta_pad_units", sent - len(contribs))
+        outs.append(contribs)
+    pc.inc("delta_batch_units", len(cols))
+    pc.inc("delta_batch_ops", sum(m[2] for m in members))
+    out = outs[0] if len(outs) == 1 else np.concatenate(outs)
+    counts = [len(m[0]) for m in members]
+    return [out[e - n : e] for n, e in zip(counts, np.cumsum(counts))]
+
+
+@dataclasses.dataclass
+class _Parked:
+    """One op's delta, held by a ``DeltaTick``."""
+
+    thread: int
+    codec: object
+    cols: np.ndarray
+    pages: np.ndarray
+    resume: Callable
+    #: ``contribs [n, m, L]``, or the exception that took its place
+    result: object = None
+
+
+class DeltaTick:
+    """The parity deltas of one coalesced OSD tick (a wave of ops on
+    distinct objects, run as one thread per PG group). An op that
+    encodes by delta prepares its pages and ``park``s; a group that has
+    submitted all its ops ``arrive``s. The last group in sends every
+    parked delta as one ``delta_batch``; then each group resumes the
+    ops it parked, in the order it parked them, on its own thread."""
+
+    def __init__(self, parties: int, timeout: float = 30.0) -> None:
+        self._parties = parties
+        self._timeout = timeout
+        self._cv = threading.Condition()
+        #: threads that have arrived: nothing of theirs parks any more
+        self._arrived: set[int] = set()
+        self._fired = False
+        self._parked: list[_Parked] = []
+
+    def park(self, codec, cols, pages, resume: Callable) -> bool:
+        """Hold one op's delta; ``resume(contribs | exception)`` runs
+        on this thread, from ``arrive`` or ``flush_thread``. False
+        once this thread has arrived (an op that became ready late):
+        the caller applies its delta itself."""
+        me = threading.get_ident()
+        with self._cv:
+            if me in self._arrived:
+                return False
+            self._parked.append(_Parked(me, codec, cols, pages, resume))
+            return True
+
+    def _take_mine(self) -> list[_Parked]:
+        """This thread's entries, out of the tick; caller holds the
+        lock."""
+        me = threading.get_ident()
+        mine = [e for e in self._parked if e.thread == me]
+        self._parked = [e for e in self._parked if e.thread != me]
+        return mine
+
+    @staticmethod
+    def _apply(entries: list[_Parked]) -> None:
+        """One ``delta_batch`` per codec among ``entries`` (a tick is
+        one pool's as a rule); the answer, or the exception, lands in
+        each entry."""
+        by_codec: dict[tuple, list[_Parked]] = defaultdict(list)
+        for e in entries:
+            by_codec[_codec_signature(e.codec)].append(e)
+        for group in by_codec.values():
+            try:
+                shares = delta_batch(
+                    group[0].codec, [(e.cols, e.pages, 1) for e in group]
+                )
+                for e, share in zip(group, shares):
+                    e.result = share
+            except Exception as err:
+                for e in group:
+                    e.result = err
+
+    def flush_thread(self) -> None:
+        """Send and resume what THIS thread has parked, now: an op
+        that cannot park (a full-stripe write, a packet-layout code)
+        must not overtake the parked ones of its pipeline."""
+        with self._cv:
+            mine = self._take_mine()
+        if mine:
+            self._apply(mine)
+            for e in mine:
+                e.resume(e.result)
+
+    def arrive(self) -> None:
+        """This group has submitted its ops. Blocks until the tick's
+        deltas are back (the last group in sends them), then resumes
+        this thread's parked ops; a group that parked nothing (full
+        writes) waits for nobody. A group that has waited ``timeout``
+        for one that is still submitting sends its own."""
+        me = threading.get_ident()
+        with self._cv:
+            self._arrived.add(me)
+            self._cv.notify_all()
+            if len(self._arrived) >= self._parties:
+                role = "last"
+                batch, self._parked = self._parked, []
+            elif not any(e.thread == me for e in self._parked):
+                return
+            elif self._cv.wait_for(
+                lambda: len(self._arrived) >= self._parties,
+                self._timeout,
+            ):
+                self._cv.wait_for(lambda: self._fired)
+                role = "follower"  # its answers are in its entries
+                batch = self._take_mine()
+            else:
+                role = "alone"
+                batch = self._take_mine()
+        if role != "follower":
+            self._apply(batch)
+        if role == "last":
+            with self._cv:
+                # the other groups find their answers here
+                self._parked.extend(e for e in batch if e.thread != me)
+                self._fired = True
+                self._cv.notify_all()
+        for e in batch:
+            if e.thread == me:
+                e.resume(e.result)
 
 
 class StreamingDispatcher:

@@ -9,20 +9,28 @@ Semantics kept from the reference's design note:
 - At most ONE outstanding backend read at a time (per PG in the
   reference; per cache instance here) — reads for later ops queue.
 - IO is never reordered: an op's ready callback fires only after every
-  earlier op on the same object has fired, even if its data arrived
-  first.
+  earlier op of this cache has fired, even if its data arrived first
+  (the pg log takes appends in tid order, which is submission order).
 - ``write_done`` publishes the just-written buffers back into the cache
   so immediately-following partial writes of the same stripe hit.
 
-Event-driven and single-threaded by design: the reference drives this
-from the PG's event loop; the TPU pipeline drives it from the host
-dispatch loop between device batches. No locks needed.
+Event-driven: the reference drives this from the PG's event loop. Here
+two kinds of thread drive it: the op worker (``execute``, and
+``read_done`` under its synchronous backend read) and the messenger
+threads that deliver the last sub-write ack (``write_done``). One lock
+guards the bookkeeping and is never held across a callback or a
+backend read; ready callbacks run one at a time, in order, on whichever
+thread is draining them, so two threads can neither fire one op twice
+nor dispatch two ops out of order (seen on the chip as ``non-monotonic
+log append`` once small overwrites kept 32 ops in flight, PR 26).
 """
 
 from __future__ import annotations
 
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from collections.abc import Callable
+
+from ceph_tpu.utils.lockdep import DebugLock
 
 from .extents import ExtentSet
 from .shard_map import ShardExtentMap
@@ -77,8 +85,16 @@ class ECExtentCache:
         self._data: dict[str, ShardExtentMap] = {}
         self._present: dict[str, dict[int, ExtentSet]] = {}
         self._ops: dict[str, list[CacheOp]] = {}
+        #: every op not yet invoked, in submission order
+        self._fifo: deque[CacheOp] = deque()
         self._read_queue: list[CacheOp] = []
         self._active_read: CacheOp | None = None
+        #: guards all of the above; a leaf lock (nothing is called out
+        #: of the cache while it is held)
+        self._lock = DebugLock("extent_cache")
+        #: ops claimed as ready, waiting for the one draining thread
+        self._ready: deque[CacheOp] = deque()
+        self._draining = False
         # counters (perf-counter hookup later)
         self.stat_hits = 0
         self.stat_misses = 0
@@ -94,56 +110,61 @@ class ECExtentCache:
         cb: Callable[[CacheOp], None],
     ) -> CacheOp:
         op = CacheOp(oid, to_read or {}, to_write, object_size, cb)
-        for line in op.lines():
-            key = (oid, line)
-            self._lines[key] = self._lines.get(key, 0) + 1
-            self._lines.move_to_end(key)
+        with self._lock:
+            for line in op.lines():
+                key = (oid, line)
+                self._lines[key] = self._lines.get(key, 0) + 1
+                self._lines.move_to_end(key)
         return op
 
     def execute(self, ops: list[CacheOp]) -> None:
-        for op in ops:
-            self._ops.setdefault(op.oid, []).append(op)
-            missing = self._missing(op)
-            if missing:
-                self.stat_misses += 1
-                self._read_queue.append(op)
-            else:
-                self.stat_hits += 1
+        with self._lock:
+            for op in ops:
+                self._ops.setdefault(op.oid, []).append(op)
+                self._fifo.append(op)
+                missing = self._missing(op)
+                if missing:
+                    self.stat_misses += 1
+                    self._read_queue.append(op)
+                else:
+                    self.stat_hits += 1
         self._maybe_issue_read()
         self._progress()
 
-    def read_done(self, oid: str, smap: ShardExtentMap) -> None:
-        """Backend read completed: publish data, continue the queue."""
+    def _publish(self, oid: str, smap: ShardExtentMap) -> None:
         data = self._data.setdefault(oid, ShardExtentMap(self.sinfo))
         present = self._present.setdefault(oid, {})
         for shard in smap.shards():
             for start, end in smap.get_extent_set(shard):
                 data.insert(shard, start, smap.get(shard, start, end - start))
                 present.setdefault(shard, ExtentSet()).insert(start, end - start)
-        if self._active_read is not None and self._active_read.oid == oid:
-            self._active_read = None
+
+    def read_done(self, oid: str, smap: ShardExtentMap) -> None:
+        """Backend read completed: publish data, continue the queue."""
+        with self._lock:
+            self._publish(oid, smap)
+            if self._active_read is not None and self._active_read.oid == oid:
+                self._active_read = None
         self._maybe_issue_read()
         self._progress()
 
     def write_done(self, op: CacheOp, written: ShardExtentMap) -> None:
         """Op complete: publish written buffers, unpin, evict as needed."""
-        data = self._data.setdefault(op.oid, ShardExtentMap(self.sinfo))
-        present = self._present.setdefault(op.oid, {})
-        for shard in written.shards():
-            for start, end in written.get_extent_set(shard):
-                data.insert(shard, start, written.get(shard, start, end - start))
-                present.setdefault(shard, ExtentSet()).insert(start, end - start)
-        op.done = True
-        for line in op.lines():
-            key = (op.oid, line)
-            if key in self._lines:
-                self._lines[key] -= 1
-        q = self._ops.get(op.oid, [])
-        if op in q:
-            q.remove(op)
-        if not q:
-            self._ops.pop(op.oid, None)
-        self._evict()
+        with self._lock:
+            self._publish(op.oid, written)
+            op.done = True
+            for line in op.lines():
+                key = (op.oid, line)
+                if key in self._lines:
+                    self._lines[key] -= 1
+            q = self._ops.get(op.oid, [])
+            if op in q:
+                q.remove(op)
+            if not q:
+                self._ops.pop(op.oid, None)
+            if not op.invoked and op in self._fifo:
+                self._fifo.remove(op)  # aborted before it was ready
+            self._evict()
         self._progress()
         # reads queued while this op held the FIFO (e.g. a truncate's
         # invalidation re-queuing a former cache hit) issue now
@@ -151,9 +172,16 @@ class ECExtentCache:
 
     def on_change(self) -> None:
         """Drop everything not pinned (PG interval change analog)."""
-        self._read_queue.clear()
-        self._active_read = None
-        self._evict(force_all=True)
+        with self._lock:
+            self._active_read = None
+            self._evict(force_all=True)
+            # ops still waiting keep their place and their read (they
+            # dispatch and are fenced by the new interval); the next
+            # call into the cache issues it
+            self._read_queue = [
+                op for op in self._fifo
+                if not op.done and self._missing(op)
+            ]
 
     def invalidate_object(self, oid: str) -> None:
         """Drop one object's cached CONTENT (truncate invalidation):
@@ -163,16 +191,17 @@ class ECExtentCache:
         extents nothing will produce; the read issues only after the
         invalidating op's write_done, so it sees post-truncate
         stores."""
-        self._data.pop(oid, None)
-        self._present.pop(oid, None)
-        for op in self._ops.get(oid, []):
-            if (
-                not op.invoked
-                and not op.done
-                and op not in self._read_queue
-                and self._missing(op)
-            ):
-                self._read_queue.append(op)
+        with self._lock:
+            self._data.pop(oid, None)
+            self._present.pop(oid, None)
+            for op in self._ops.get(oid, []):
+                if (
+                    not op.invoked
+                    and not op.done
+                    and op not in self._read_queue
+                    and self._missing(op)
+                ):
+                    self._read_queue.append(op)
 
     # -- internals ------------------------------------------------------
     def _present_set(self, oid: str, shard: int) -> ExtentSet:
@@ -187,33 +216,67 @@ class ECExtentCache:
         return out
 
     def _maybe_issue_read(self) -> None:
-        while self._active_read is None and self._read_queue:
-            op = self._read_queue.pop(0)
-            if op.done:
-                continue
-            missing = self._missing(op)
-            if not missing:
-                continue  # satisfied by an earlier op's read
-            self._active_read = op
+        while True:
+            with self._lock:
+                if self._active_read is not None or not self._read_queue:
+                    return
+                op = self._read_queue.pop(0)
+                if op.done:
+                    continue
+                missing = self._missing(op)
+                if not missing:
+                    continue  # satisfied by an earlier op's read
+                self._active_read = op
             self.backend_read(op.oid, missing)
             # backend_read may call read_done synchronously (memstore),
             # clearing _active_read — loop handles that.
 
+    def _claim_ready(self) -> None:
+        """Move every op that may run now from the FIFO to the ready
+        queue, strictly in submission order; caller holds the lock."""
+        while self._fifo:
+            op = self._fifo[0]
+            if op.done:
+                self._fifo.popleft()
+                continue
+            if self._ops.get(op.oid, [op])[0] is not op:
+                # An earlier op on the object is invoked but still in
+                # its queue: its write hasn't landed (write_done removes
+                # completed ops). A later op must NOT proceed against
+                # pre-write cache state — that encodes stale data into
+                # parity. Serialize.
+                return
+            if self._missing(op):
+                return  # never reorder: stop at first unready op
+            op.result = self._snapshot(op)
+            op.invoked = True
+            self._fifo.popleft()
+            self._ready.append(op)
+
     def _progress(self) -> None:
-        """Fire ready callbacks strictly FIFO per object."""
-        for oid, q in list(self._ops.items()):
-            for op in list(q):
-                if op.invoked:
-                    # Invoked but still in the queue = its write hasn't
-                    # landed (write_done removes completed ops). A later
-                    # op must NOT proceed against pre-write cache state
-                    # — that encodes stale data into parity. Serialize.
-                    break
-                if self._missing(op):
-                    break  # never reorder: stop at first unready op
-                op.result = self._snapshot(op)
-                op.invoked = True
+        """Fire ready callbacks strictly FIFO, one at a time: a thread
+        that finds another one draining leaves its ops to it (and a
+        callback that re-enters here, through a synchronous ack, leaves
+        them to its own caller's loop)."""
+        with self._lock:
+            self._claim_ready()
+            if self._draining or not self._ready:
+                return
+            self._draining = True
+        try:
+            while True:
+                with self._lock:
+                    if not self._ready:
+                        self._draining = False
+                        return
+                    op = self._ready.popleft()
                 op.cb(op)
+                with self._lock:
+                    self._claim_ready()
+        except BaseException:
+            with self._lock:
+                self._draining = False
+            raise
 
     def _snapshot(self, op: CacheOp) -> ShardExtentMap:
         smap = ShardExtentMap(self.sinfo)

@@ -41,6 +41,7 @@ from ceph_tpu.utils.crash_points import crash_points
 from ceph_tpu.utils.optracker import NULL_OP, op_tracker
 from ceph_tpu.utils.trace import tracer
 
+from .dispatcher import current_tick
 from .extent_cache import CacheOp, ECExtentCache
 from .extents import ExtentSet
 from .hashinfo import HashInfo
@@ -237,6 +238,9 @@ class ClientOp:
         self.t_last_ack: float | None = None
         #: TIME counter that wait goes to (set with the fan-out's kind)
         self.wait_key: str | None = None
+        #: perf_counter when the cache took the op with something to
+        #: read; ``rmw_read_wait`` runs from here to ``_cache_ready``
+        self.t_read_start: float | None = None
 
 
 class ShardBackend:
@@ -455,6 +459,33 @@ class RMWPipeline:
                 "ec_write.assemble: chunk loop and old-data merge",
             )
             .add_time("encode_seconds", "ec_write.encode: the codec call")
+            # the old-data read of an RMW, and the parity-delta encode
+            # step by step; ``rmw_read_ops`` / ``delta_ops`` are their
+            # denominators
+            .add_u64_counter(
+                "rmw_read_ops", "writes whose plan read old data or parity"
+            )
+            .add_u64_counter("rmw_read_bytes", "bytes those plans read")
+            .add_time(
+                "rmw_read_seconds",
+                "rmw_read_wait: the cache taking the op to its old data "
+                "being there (sub-reads, or an earlier op on the object)",
+            )
+            .add_u64_counter("delta_ops", "writes that encoded by delta")
+            .add_time(
+                "delta_prepare_seconds",
+                "ec_write.delta_prepare: delta pages and old parity",
+            )
+            .add_time(
+                "delta_apply_seconds",
+                "ec_write.delta_apply: prepared to contributions back "
+                "(in a coalesced tick: the wait for the tick's one "
+                "dispatch)",
+            )
+            .add_time(
+                "delta_place_seconds",
+                "ec_write.delta_place: XOR onto old parity, insert",
+            )
             .add_time(
                 "txn_build_seconds",
                 "ec_write.txn_build: k+m transactions, pg-log append",
@@ -611,6 +642,8 @@ class RMWPipeline:
                     "parity_delta_ops" if op.plan.do_parity_delta
                     else "full_stripe_ops"
                 )
+                if op.plan.to_read:
+                    op.t_read_start = time.perf_counter()
                 op.cache_op = self.cache.prepare(
                     oid,
                     op.plan.to_read,
@@ -940,12 +973,29 @@ class RMWPipeline:
             self._abort_op(op, err)
             return
         op.tracked.mark_event("cache_ready")
+        if op.t_read_start is not None:
+            # across threads: the cache may hand the op on from the
+            # thread that ended another op's read or write
+            tracer.record(
+                "rmw_read_wait", op.t_read_start, time.perf_counter(),
+                trace_id=op.write_ctx[0], parent_id=op.write_ctx[1],
+                perf=self.perf, key="rmw_read_seconds",
+                oid=op.oid, tid=op.tid,
+            )
+            self.perf.inc("rmw_read_ops")
+            self.perf.inc("rmw_read_bytes", op.plan.read_bytes())
+        self._in_write_ctx(op, lambda: self._cache_ready_inner(op))
+
+    def _in_write_ctx(self, op: ClientOp, step: Callable[[], None]) -> None:
+        """Run one step of a write's dispatch inside its ``ec_write``
+        context, aborting the op in order on any failure. Usually the
+        span is still open; an op that queued behind another on its
+        object gets here from that op's ack, and a parked delta from
+        its tick's ``arrive``, after its own ec_write closed: either
+        way the stages are its."""
         try:
-            # usually still inside ec_write; an op that queued behind
-            # another on its object gets here from that op's ack, after
-            # its own ec_write closed: either way the stages are its
             with tracer.continue_trace(*op.write_ctx):
-                self._cache_ready_inner(op)
+                step()
         except Exception as e:
             self._abort_op(op, e)
 
@@ -978,13 +1028,18 @@ class RMWPipeline:
                                 shard, s, old_map.get(shard, s, e - s)
                             )
         self.perf.inc("encode_ops")
+        if op.plan.do_parity_delta:
+            self._encode_by_delta(op, new_map, old_map, hinfo, new_size)
+            return
+        tick = current_tick()
+        if tick is not None:
+            # deltas this thread parked are older ops of this pipeline:
+            # they dispatch first (the pg log takes tids in order)
+            tick.flush_thread()
         with tracer.span(
             "ec_write.encode", perf=self.perf, key="encode_seconds"
         ):
-            if op.plan.do_parity_delta:
-                new_map.encode_parity_delta(self.codec, old_map)
-                hinfo.clear()  # overwrite invalidates cumulative crcs
-            elif new_map.ro_range()[0] == hashed:
+            if new_map.ro_range()[0] == hashed:
                 new_map.encode(
                     self.codec, hinfo, old_size=hashed,
                     csum_block=self.csum_block,
@@ -995,7 +1050,72 @@ class RMWPipeline:
                 new_map.encode(self.codec, csum_block=self.csum_block)
                 if hashed:
                     hinfo.clear()
+        self._dispatch_encoded(op, new_map, new_size)
 
+    def _delta_stage(self, step: str):
+        return tracer.span(
+            "ec_write.delta_" + step, perf=self.perf,
+            key=f"delta_{step}_seconds",
+        )
+
+    def _encode_by_delta(
+        self, op: ClientOp, new_map: ShardExtentMap,
+        old_map: ShardExtentMap, hinfo: HashInfo, new_size: int,
+    ) -> None:
+        """``ShardExtentMap.encode_parity_delta``'s three steps, with
+        the middle one shared: inside a coalesced tick the prepared
+        delta parks in the tick (dispatcher.DeltaTick) and this op
+        goes on from ``_delta_resume`` once the tick's one dispatch is
+        back; anywhere else it is applied here, a batch of one."""
+        self.perf.inc("delta_ops")
+        with tracer.span(
+            "ec_write.encode", perf=self.perf, key="encode_seconds"
+        ):
+            encode_ctx = tracer.current()
+            with self._delta_stage("prepare"):
+                work = new_map.delta_prepare(self.codec, old_map)
+            hinfo.clear()  # overwrite invalidates cumulative crcs
+        t_prepared = time.perf_counter()
+
+        def resume(contribs) -> None:
+            self._in_write_ctx(op, lambda: self._delta_resume(
+                op, new_map, work, new_size, contribs, encode_ctx,
+                t_prepared,
+            ))
+
+        tick = current_tick()
+        if tick is not None:
+            if (
+                work is not None and work.windows is None
+                and tick.park(self.codec, work.cols, work.pages, resume)
+            ):
+                return
+            tick.flush_thread()  # see _cache_ready_inner
+        resume(
+            None if work is None else new_map.delta_apply(self.codec, work)
+        )
+
+    def _delta_resume(
+        self, op: ClientOp, new_map: ShardExtentMap, work, new_size: int,
+        contribs, encode_ctx: tuple, t_prepared: float,
+    ) -> None:
+        if isinstance(contribs, BaseException):
+            raise contribs
+        tracer.record(
+            "ec_write.delta_apply", t_prepared, time.perf_counter(),
+            trace_id=encode_ctx[0], parent_id=encode_ctx[1],
+            perf=self.perf, key="delta_apply_seconds",
+        )
+        if work is not None:
+            with tracer.span(
+                "ec_write.encode", perf=self.perf, key="encode_seconds"
+            ), self._delta_stage("place"):
+                new_map.delta_place(work, contribs)
+        self._dispatch_encoded(op, new_map, new_size)
+
+    def _dispatch_encoded(
+        self, op: ClientOp, new_map: ShardExtentMap, new_size: int
+    ) -> None:
         # size publishes BEFORE the dispatch: synchronous sub-write
         # acks can complete this op and cascade the NEXT queued op's
         # dispatch from inside _generate_transactions — assigning

@@ -17,6 +17,8 @@ pipeline); codec calls move them through jax and back.
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 from ceph_tpu.codecs.matrix_codec import codec_stage
@@ -24,6 +26,22 @@ from ceph_tpu.codecs.matrix_codec import codec_stage
 from .extents import ExtentSet
 from .hashinfo import HashInfo
 from .stripe import PAGE_SIZE, StripeInfo, align_page_next, align_page_prev
+
+
+@dataclasses.dataclass
+class DeltaWork:
+    """One op's prepared parity delta (``ShardExtentMap.delta_prepare``)."""
+
+    #: shard offset of the parity window
+    lo: int
+    #: unit form: raw column of each unit; its delta page; the page of
+    #: the window it lands on; the m old parity windows [m, W]
+    cols: "np.ndarray | None" = None
+    pages: "np.ndarray | None" = None
+    at: "np.ndarray | None" = None
+    parity: "np.ndarray | None" = None
+    #: per-op form: (deltas, old parity) as ``codec.apply_delta`` takes
+    windows: "tuple | None" = None
 
 
 class ShardExtentMap:
@@ -395,26 +413,103 @@ class ShardExtentMap:
                 [np.asarray(parity[k + j]) for j in range(len(parity))]
             )
 
-    def encode_parity_delta(self, codec, old_map: "ShardExtentMap") -> None:
+    def encode_parity_delta(
+        self, codec, old_map: "ShardExtentMap"
+    ) -> None:
         """Parity-delta RMW (ECUtil.cc:542-588): for each data shard
         present here, delta = old XOR new; parity' = parity XOR
         sum_i G[:,i] * delta_i. ``old_map`` must hold the old data AND
-        old parity over this map's window."""
+        old parity over this map's window.
+
+        Three steps: ``delta_prepare``, ``delta_apply``,
+        ``delta_place``. The RMW pipeline calls them itself, timing
+        each, so that a coalesced tick can send all its ops' deltas
+        between prepare and place as one dispatch
+        (dispatcher.DeltaTick); this is the same path for a caller
+        with one op."""
+        work = self.delta_prepare(codec, old_map)
+        if work is not None:
+            self.delta_place(work, self.delta_apply(codec, work))
+
+    def delta_prepare(
+        self, codec, old_map: "ShardExtentMap"
+    ) -> "DeltaWork | None":
+        """What one op's parity delta is made of, or None where this
+        map wrote nothing.
+
+        For a matrix code the unit is one page of one data column:
+        ``pages[u] = old XOR new`` over page ``at[u]`` of the window,
+        zero wherever this map did not write, with the raw column
+        ``cols[u]``; ``parity`` holds the m old parity windows. That
+        form batches across ops (``dispatcher.delta_batch``).
+        Packet-layout codes (``PARITY_DELTA_CHUNK_GRANULARITY``: the
+        packet decomposition is per chunk, so windows widen to chunk
+        boundaries), CLAY and any codec while a mesh or DCN route owns
+        its dispatches keep the per-op form, whole windows for
+        ``codec.apply_delta`` (``windows``), behind the same three
+        steps."""
         from ceph_tpu.codecs.interface import Flag
+        from ceph_tpu.codecs.matrix_codec import DELTA_UNIT
 
         k, m = self.sinfo.k, self.sinfo.m
         lo, hi = self._slice_window()
         if hi <= lo:
-            return
-        # Packet-layout codes need chunk-shaped delta windows: the
-        # packet decomposition is per-chunk, so the window is widened
-        # to chunk boundaries and every buffer reshaped [n_chunks, cs]
-        # (delta outside the written extents is zero by construction,
-        # and the planner chunk-aligned the parity reads/writes).
+            return None
         chunk_gran = bool(
             codec.get_flags() & Flag.PARITY_DELTA_CHUNK_GRANULARITY
         )
+        batchable = (
+            not chunk_gran
+            and codec.get_sub_chunk_count() == 1
+            and getattr(codec, "delta_batchable", lambda: False)()
+        )
+        if not batchable:
+            return self._delta_windows(codec, old_map, lo, hi, chunk_gran)
+        # Only bytes this map actually wrote may differ: a page is zero
+        # outside them, so the delta is zero there (a page filled from
+        # this map's gaps would XOR the old data OUT of the parity —
+        # silent corruption).
+        runs = []  # (raw, written bytes, their shard offset, first page, first unit)
+        n = 0
+        for raw in range(k):
+            for off, buf in self._bufs.get(self.sinfo.get_shard(raw), ()):
+                s, e = max(off, lo), min(off + buf.size, hi)
+                if s < e:
+                    first = (s - lo) // DELTA_UNIT
+                    count = -(-(e - lo) // DELTA_UNIT) - first
+                    runs.append((raw, buf[s - off : e - off], s, first, n))
+                    n += count
+        if not n:
+            return None
+        pages = np.zeros(n * DELTA_UNIT, dtype=np.uint8)
+        cols = np.empty(n, dtype=np.uint8)
+        at = np.empty(n, dtype=np.intp)
+        for i, (raw, new, s, first, u) in enumerate(runs):
+            end = runs[i + 1][4] if i + 1 < len(runs) else n
+            cols[u:end] = raw
+            at[u:end] = np.arange(first, first + end - u)
+            # delta is plain GF addition: XOR on the host
+            rel = u * DELTA_UNIT + (s - lo) - first * DELTA_UNIT
+            np.bitwise_xor(
+                old_map.get(self.sinfo.get_shard(raw), s, new.size), new,
+                out=pages[rel : rel + new.size],
+            )
+        parity = np.stack([
+            old_map.get(self.sinfo.get_shard(k + j), lo, hi - lo)
+            for j in range(m)
+        ])
+        return DeltaWork(lo, cols, pages.reshape(n, DELTA_UNIT), at, parity)
+
+    def _delta_windows(
+        self, codec, old_map: "ShardExtentMap", lo: int, hi: int,
+        chunk_gran: bool,
+    ) -> "DeltaWork | None":
+        """The per-op form: whole delta and parity windows."""
+        k, m = self.sinfo.k, self.sinfo.m
+        shape = None
         if chunk_gran:
+            # the planner chunk-aligned the parity reads/writes; delta
+            # outside the written extents is zero by construction
             cs = self.sinfo.chunk_size
             lo = (lo // cs) * cs
             hi = -(-hi // cs) * cs
@@ -424,10 +519,6 @@ class ShardExtentMap:
             shard = self.sinfo.get_shard(raw)
             if shard not in self._bufs:
                 continue
-            # Only bytes this map actually wrote may differ: fill the
-            # rest of the window from old so delta is zero there (a
-            # zero-filled gap would otherwise XOR the old data OUT of
-            # the parity — silent corruption).
             old = old_map.get(shard, lo, hi - lo)
             new = old.copy()
             for off, end in self.get_extent_set(shard):
@@ -435,24 +526,43 @@ class ShardExtentMap:
                 e = min(end, hi)
                 if s < e:
                     new[s - lo : e - lo] = self.get(shard, s, e - s)
-            # delta is plain GF addition: XOR on the host (a device
-            # round-trip per shard would serialize k dispatches)
-            d = np.bitwise_xor(np.asarray(old), np.asarray(new))
+            d = np.bitwise_xor(old, new)
             deltas[raw] = d.reshape(shape) if chunk_gran else d
         if not deltas:
-            return
+            return None
         parity_in = {}
         for j in range(m):
-            p = np.asarray(
-                old_map.get(self.sinfo.get_shard(k + j), lo, hi - lo)
-            )
+            p = old_map.get(self.sinfo.get_shard(k + j), lo, hi - lo)
             parity_in[k + j] = p.reshape(shape) if chunk_gran else p
-        parity_out = codec.apply_delta(deltas, parity_in)
+        return DeltaWork(lo, windows=(deltas, parity_in))
+
+    def delta_apply(self, codec, work: "DeltaWork"):
+        """The codec's part: ``contribs [n, m, unit]`` of the unit
+        form (a batch of one through ``dispatcher.delta_batch``), or
+        the new parity windows of the per-op form."""
+        if work.windows is None:
+            from .dispatcher import delta_batch
+
+            return delta_batch(codec, [(work.cols, work.pages, 1)])[0]
+        k, m = self.sinfo.k, self.sinfo.m
+        parity_out = codec.apply_delta(*work.windows)
         with codec_stage("fetch"):
-            fetched = [np.asarray(parity_out[k + j]) for j in range(m)]
+            return [np.asarray(parity_out[k + j]) for j in range(m)]
+
+    def delta_place(self, work: "DeltaWork", contribs) -> None:
+        """New parity into this map: the old windows with every unit's
+        contribution XORed onto its page."""
+        k, m = self.sinfo.k, self.sinfo.m
+        if work.windows is None:
+            unit = work.pages.shape[1]
+            paged = work.parity.reshape(m, -1, unit)
+            for u, page in enumerate(work.at):
+                paged[:, page] ^= contribs[u]
+            contribs = work.parity
         for j in range(m):
             self.insert(
-                self.sinfo.get_shard(k + j), lo, fetched[j].reshape(-1)
+                self.sinfo.get_shard(k + j), work.lo,
+                contribs[j].reshape(-1),
             )
 
     def decode(self, codec, want: set[int], object_size: int) -> None:
