@@ -145,8 +145,8 @@ class _CoalCtx:
 
     __slots__ = (
         "conn", "msg", "spec", "pgid", "epoch", "pg", "w_offset",
-        "result_size", "attrs", "trunc_attrs", "done", "outcome",
-        "size", "trace_ctx",
+        "result_size", "attrs", "trunc_attrs", "cuts", "done",
+        "outcome", "size", "trace_ctx",
     )
 
     def __init__(self, conn, msg, spec, pgid, epoch) -> None:
@@ -160,6 +160,9 @@ class _CoalCtx:
         self.result_size = 0
         self.attrs = None
         self.trunc_attrs = None
+        #: a writefull that shrinks the object: it alone runs the
+        #: truncate half (with ``trunc_attrs``) after its write
+        self.cuts = False
         #: (trace_id, osd_op span id) captured at submit: later batch
         #: phases (the writefull truncate half) re-enter this context
         #: so their sub-op spans stay under the op's primary subtree
@@ -2433,9 +2436,18 @@ class OSDDaemon:
                     msg, self._op_truncate(pg, msg)
                 )
             if msg.op == "writefull":
-                # write-then-shrink under one lock scope: the object
-                # is exactly the payload afterwards (rados_write_full).
-                # The reqid window stamps ONLY the final sub-op: a
+                # the object is exactly the payload afterwards
+                # (rados_write_full). Nothing to cut (a new object, a
+                # same-size rewrite, a grow): ONE rmw write that
+                # carries the reqid window, one fan-out — there is no
+                # half-applied state for a crash to leave behind.
+                msg.offset = 0
+                if not self._writefull_cuts(pg, msg):
+                    return self._record_completed(
+                        msg, self._op_write(pg, msg)
+                    )
+                # A true shrink: write-then-truncate under one lock
+                # scope. The reqid window stamps ONLY the truncate: a
                 # crash between the two would otherwise make every
                 # resend replay the half-applied state (stale tail
                 # never cut); with the write unstamped, the resend
@@ -2613,18 +2625,24 @@ class OSDDaemon:
                 ctx.attrs = self._req_attr_for(
                     pg, msg.oid, msg.reqid, ctx.result_size
                 )
-            else:  # writefull: write half stays reqid-unstamped (a
-                # crash between write and shrink must re-run both —
-                # see the serial handler), the truncate half carries
-                # the window. Window state is frozen for the whole
-                # batch (_op_lock held; all window mutations are in
-                # serial phases), so precomputing here is exact.
+            else:  # writefull: the window rides the write when
+                # nothing shrinks (one fan-out); a true shrink keeps
+                # its write half reqid-unstamped (a crash between
+                # write and cut must re-run both — see the serial
+                # handler) and the truncate half carries the window.
+                # Window state is frozen for the whole batch (_op_lock
+                # held; all window mutations are in serial phases), so
+                # precomputing here is exact.
                 ctx.w_offset = 0
                 ctx.result_size = len(msg.data)
-                ctx.attrs = None
-                ctx.trunc_attrs = self._req_attr_for(
+                ctx.cuts = self._writefull_cuts(pg, msg)
+                window = self._req_attr_for(
                     pg, msg.oid, msg.reqid, len(msg.data)
                 )
+                if ctx.cuts:
+                    ctx.trunc_attrs = window
+                else:
+                    ctx.attrs = window
         except Exception as e:
             to_send.append((ctx.conn, OSDOpReply(
                 msg.tid, ctx.epoch, error="eio",
@@ -2716,9 +2734,10 @@ class OSDDaemon:
                     # stalled (a truncate queued behind it would only
                     # deepen the wedge — the serial path raises here)
                     live.remove(ctx)
-            # writefull second half: the shrink that makes the object
-            # exactly the payload (pipelined + drained the same way)
-            trunc = [c for c in live if c.msg.op == "writefull"]
+            # a shrinking writefull's second half: the cut that makes
+            # the object exactly the payload (pipelined + drained the
+            # same way)
+            trunc = [c for c in live if c.cuts]
             for ctx in trunc:
                 ctx.done = []
                 try:
@@ -3382,6 +3401,16 @@ class OSDDaemon:
         return OSDOpReply(
             msg.tid, self.osdmap.epoch, size=pg.rmw.object_size(msg.oid)
         )
+
+    def _writefull_cuts(self, pg: _PG, msg: OSDOp) -> bool:
+        """Whether a writefull needs its truncate half. Only a payload
+        shorter than the object leaves a tail to cut (the size once
+        every op queued on the object has applied counts, so a stalled
+        write cannot grow it back afterwards); an empty payload is a
+        no-op in the write pipeline, so its truncate does the work."""
+        cur = self._object_size(pg, msg.oid)  # prime attrs on takeover
+        n = len(msg.data)
+        return n == 0 or max(cur, pg.rmw.projected_size(msg.oid)) > n
 
     def _op_truncate(self, pg: _PG, msg: OSDOp) -> OSDOpReply:
         """rados_trunc: msg.offset carries the new size. Rides the

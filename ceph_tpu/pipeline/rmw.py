@@ -615,10 +615,7 @@ class RMWPipeline:
             return op.tid
 
         self._projected_sizes[oid] = max(
-            self._projected_sizes.get(
-                oid, self._object_sizes.get(oid, 0)
-            ),
-            ro_offset + len(data),
+            self.projected_size(oid), ro_offset + len(data)
         )
         op.trace_ctx = tracer.current()
         op.wait_key = "subop_wait_seconds"
@@ -721,9 +718,7 @@ class RMWPipeline:
         old bytes there, and cutting the data shards without
         re-encoding would leave the stripe inconsistent (a degraded
         read would decode the pre-truncate content back to life)."""
-        old_size_now = self._projected_sizes.get(
-            oid, self._object_sizes.get(oid, 0)
-        )
+        old_size_now = self.projected_size(oid)
         if new_size < old_size_now:
             sw = self.sinfo.stripe_width
             boundary_end = min(-(-new_size // sw) * sw, old_size_now)
@@ -884,6 +879,12 @@ class RMWPipeline:
 
     def object_size(self, oid: str) -> int:
         return self._object_sizes.get(oid, 0)
+
+    def projected_size(self, oid: str) -> int:
+        """The size once every op submitted so far has applied: a
+        write still queued on the object counts, where ``object_size``
+        only moves at dispatch."""
+        return self._projected_sizes.get(oid, self.object_size(oid))
 
     def forget_object(self, oid: str) -> None:
         """Drop all in-memory per-object state — the peering

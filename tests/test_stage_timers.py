@@ -33,8 +33,10 @@ WRITE_STAGES = [
     "opq_wait", "osd_op", "ec_write", "ec_write.plan",
     "ec_write.assemble", "ec_write.encode", "codec.prep", "codec.h2d",
     "codec.launch", "codec.fetch", "ec_write.txn_build",
-    "ec_write.fanout", "sub_write", "subop_wait", "ec_truncate",
+    "ec_write.fanout", "sub_write", "subop_wait",
 ]
+#: a ``writefull`` that shrinks its object keeps the second half
+SHRINK_STAGES = ["ec_write", "sub_write", "subop_wait", "ec_truncate"]
 READ_STAGES = [
     "opq_wait", "osd_op", "ec_read.issue", "sub_read", "sub_read_wait",
     "ec_reconstruct", "codec.prep", "codec.h2d", "codec.launch",
@@ -48,13 +50,17 @@ WRITE_COUNTERS = [
     ("osd.*.rmw", "assemble_seconds"), ("osd.*.rmw", "encode_seconds"),
     ("osd.*.rmw", "txn_build_seconds"), ("osd.*.rmw", "fanout_seconds"),
     ("osd.*.rmw", "subop_wait_seconds"),
-    ("osd.*.rmw", "truncate_seconds"),
-    ("osd.*.rmw", "truncate_wait_seconds"),
     ("ec_dispatch", "prep_seconds"), ("ec_dispatch", "h2d_seconds"),
     ("ec_dispatch", "launch_seconds"), ("ec_dispatch", "fetch_seconds"),
     ("*.net", "send_seconds"), ("*.net", "recv_seconds"),
     ("osd.*.store", "apply_seconds"),
     ("process", "cpu_seconds"), ("process", "wall_seconds"),
+]
+SHRINK_COUNTERS = [
+    ("osd.*.rmw", "write_seconds"), ("osd.*.rmw", "subop_wait_seconds"),
+    ("osd.*.rmw", "truncate_seconds"),
+    ("osd.*.rmw", "truncate_wait_seconds"),
+    ("osd.*.store", "apply_seconds"),
 ]
 READ_COUNTERS = [
     ("osd.*.opq", "wait_seconds"), ("osd.*.opq", "service_seconds"),
@@ -109,8 +115,9 @@ class Leg:
 
 
 @pytest.fixture(scope="module")
-def legs():
-    """(write leg, degraded-read leg) of one object."""
+def all_legs():
+    """(write leg, degraded-read leg) of one object, and the leg of a
+    ``writefull`` that halves another."""
     from ceph_tpu.loadgen import LoadCluster
 
     with config.override(
@@ -138,6 +145,11 @@ def legs():
 
             cluster.io.write_full("warm", data)  # compiles
             write = run(lambda: cluster.io.write_full("obj", data))
+            cluster.io.write_full("long", data)
+            shrink = run(
+                lambda: cluster.io.write_full("long", data[:PAYLOAD // 2])
+            )
+            assert cluster.io.read("long") == data[:PAYLOAD // 2]
             # lose a DATA shard of the object, not its primary
             primary = cluster.mon.osdmap.primary(cluster.pool, "obj")
             acting = cluster.mon.osdmap.object_to_acting(
@@ -155,7 +167,17 @@ def legs():
             assert got[0] == data
         finally:
             cluster.shutdown()
-    return write, read
+    return write, read, shrink
+
+
+@pytest.fixture(scope="module")
+def legs(all_legs):
+    return all_legs[:2]
+
+
+@pytest.fixture(scope="module")
+def shrink(all_legs):
+    return all_legs[2]
 
 
 # ------------------------------------------------------------ the op's tree
@@ -167,6 +189,15 @@ def test_write_stage_is_in_the_ops_tree(legs, name):
     for span in found:
         assert span["trace_id"] == write.root["trace_id"]
         assert write.descends_from_root(span), name
+
+
+@pytest.mark.parametrize("name", SHRINK_STAGES)
+def test_shrink_stage_is_in_the_ops_tree(shrink, name):
+    found = shrink.named(name)
+    assert found, f"no {name!r} span among {sorted({s['name'] for s in shrink.spans})}"
+    for span in found:
+        assert span["trace_id"] == shrink.root["trace_id"]
+        assert shrink.descends_from_root(span), name
 
 
 @pytest.mark.parametrize("name", READ_STAGES)
@@ -223,10 +254,24 @@ def test_write_stage_parents(legs):
     (wait,) = write.named("opq_wait")
     assert wait["parent_id"] == write.root["span_id"]
     assert osd_op["parent_id"] == write.root["span_id"]
-    # the write's and the truncate's waits hang off the op, not ec_write
-    assert {s["parent_id"] for s in write.named("subop_wait")} == {
+    # one fan-out, and its wait hangs off the op, not ec_write
+    (subop_wait,) = write.named("subop_wait")
+    assert subop_wait["parent_id"] == osd_op["span_id"]
+    assert not write.named("ec_truncate")
+
+
+def test_shrink_stage_parents(shrink):
+    """The second half of a shrinking ``writefull`` runs under the same
+    ``osd_op`` as the first, and so do both waits."""
+    (osd_op,) = shrink.named("osd_op")
+    (ec_write,) = shrink.named("ec_write")
+    (ec_truncate,) = shrink.named("ec_truncate")
+    assert ec_write["parent_id"] == ec_truncate["parent_id"] == (
         osd_op["span_id"]
-    }
+    )
+    waits = shrink.named("subop_wait")
+    assert len(waits) == 2
+    assert {s["parent_id"] for s in waits} == {osd_op["span_id"]}
 
 
 def test_stages_account_for_the_ec_write_span(legs):
@@ -246,6 +291,11 @@ def test_stages_account_for_the_ec_write_span(legs):
 def test_write_moves_every_stage_counter(legs, set_glob, key):
     write, _ = legs
     assert moved(write.delta, set_glob, key) > 0
+
+
+@pytest.mark.parametrize("set_glob,key", SHRINK_COUNTERS)
+def test_shrink_moves_every_stage_counter(shrink, set_glob, key):
+    assert moved(shrink.delta, set_glob, key) > 0
 
 
 @pytest.mark.parametrize("set_glob,key", READ_COUNTERS)
@@ -275,9 +325,10 @@ def test_op_counts_match_the_spans(legs):
     assert moved(write.delta, "osd.*.rmw", "write_ops") == len(
         write.named("ec_write.encode")
     ) == moved(write.delta, "osd.*.rmw", "encode_ops") == 1
+    # a new object: nothing to cut, so no second half
     assert moved(write.delta, "osd.*.rmw", "truncate_ops") == len(
         write.named("ec_truncate")
-    ) == 1
+    ) == 0
     assert moved(write.delta, "ec_dispatch", "dispatches") == len(
         write.named("codec.launch")
     ) == 1
@@ -291,13 +342,25 @@ def test_op_counts_match_the_spans(legs):
     assert moved(read.delta, "osd.*.read", "reconstruct_ops") == len(
         read.named("ec_reconstruct")
     ) == 1
-    # k+m stores took the write and then the truncate
-    assert moved(write.delta, "osd.*.store", "txns") == 2 * (K + M)
+    # k+m stores took the write, once
+    assert moved(write.delta, "osd.*.store", "txns") == K + M
     assert moved(write.delta, "osd.*.store", "txn_bytes") == (
         PAYLOAD * (K + M) // K
     )
     assert moved(read.delta, "osd.*.store", "reads") >= K
     assert moved(read.delta, "osd.*.store", "read_bytes") >= PAYLOAD
+
+
+def test_a_shrinking_writefull_is_two_fanouts(shrink):
+    assert moved(shrink.delta, "osd.*.rmw", "encode_ops") == len(
+        shrink.named("ec_write")
+    ) == 1
+    assert moved(shrink.delta, "osd.*.rmw", "truncate_ops") == len(
+        shrink.named("ec_truncate")
+    ) == 1
+    # k+m stores took the write and then the truncate
+    assert moved(shrink.delta, "osd.*.store", "txns") == 2 * (K + M)
+    assert len(shrink.named("sub_write")) == 2 * (K + M)
 
 
 def test_wire_and_client_bytes(legs):
