@@ -10,8 +10,8 @@ content verified against the original, and the two-column
 
 Timing contract: like the reference tool, each iteration is a
 host-driven dispatch and the clock covers the full per-call path,
-launch and transfer included. ``bench.py`` times the device program
-alone (on-device loop + trip-count differencing).
+launch and transfer included. A kernel's time on the device alone is
+the benchmark's (``benchmark/``: ``codec_roofline``, from the trace).
 
 Two further workloads cover BASELINE.md configs 4-5 (which the
 reference drives through the same tool plus Checksummer):

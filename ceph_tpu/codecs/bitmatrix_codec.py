@@ -42,7 +42,6 @@ from .interface import Flag
 from .matrix_codec import (
     BitplaneDispatchMixin,
     DecodeTableCache,
-    _dispatch_counters,
     count_route,
     dev_bmat,
 )
@@ -302,48 +301,46 @@ class BitMatrixCodec(BitplaneDispatchMixin, ErasureCodeBase):
     def _apply_packet_matrix(
         self,
         mat01: np.ndarray,
-        stacked: jax.Array,
+        shards: list,
         op: str,
         tables: "tuple[np.ndarray, jax.Array] | None" = None,
-    ) -> jax.Array:
-        """Apply a packet-level 0/1 matrix to [..., S, N] chunks via
-        the shared engine: packetize, route (host / mesh / DCN /
-        XOR-schedule / Pallas / einsum), de-packetize. ``tables``
-        passes precomputed bit-expanded forms (the encode path keeps
-        them resident)."""
+    ) -> list:
+        """Apply a packet-level 0/1 matrix to per-shard [..., N]
+        chunks via the shared engine, on the route ``_plan_route``
+        names; returns the output shards. The multi-operand schedule
+        kernel takes the shard arrays as they are: no [.., n, chunk]
+        stack, no packetize reshape. Every other route (mesh / host /
+        packetized XOR-schedule / Pallas / einsum) stacks, packetizes
+        and de-packetizes. ``tables`` passes precomputed bit-expanded
+        forms (the encode path keeps them resident)."""
         from ceph_tpu.utils import config
 
-        packets = self._to_packets(stacked)
-        multi = self._mesh_routable(packets) or self._dcn_routable(
-            packets
+        route = self._route_shards(
+            shards, self.w, host_tables=True, mat01=mat01
         )
-        if not multi and self._host_sized(packets):
+        if route == "sched_shards":
+            return self._run_sched_shards(mat01, shards, self.w, op)
+        packets = self._to_packets(self._stack(shards))
+        if route == "host":
             from ceph_tpu.gf import gf_apply_bytes_host
 
             count_route(f"host_{op}", packets)
             out = gf_apply_bytes_host(mat01, np.asarray(packets))
-            return self._to_chunks(out)
-        out = None
-        if config.get("ec_use_sched") and not multi:
+        elif route == "sched":
             # schedule-native route: sparse packet matrices ARE XOR
             # networks (jerasure_schedule_encode's insight), and the
             # round-11 optimizer CSE-compresses denser shapes —
             # inverted decode tables, parity-delta columns — under
             # the op-count gate. Matrices still over the gate, or
-            # shapes no schedule kernel can tile, fall through to the
-            # MXU engine — counted here, the terminal schedule probe
-            # (the shards-form probe upstream never counts).
-            rows = self._schedule_rows(mat01)
-            if rows is None:
-                _dispatch_counters().inc("sched_rejected_density")
-            elif not xor_schedule.supported(
-                (1,) + packets.shape[-2:]
-            ):
-                _dispatch_counters().inc("sched_rejected_shape")
-            else:
-                count_route(f"sched_{op}", packets)
-                out = xor_schedule.xor_schedule_apply(rows, packets)
-        if out is None:
+            # shapes no schedule kernel can tile, ride the MXU engine
+            count_route(f"sched_{op}", packets)
+            out = xor_schedule.xor_schedule_apply(
+                xor_schedule.routable_schedule(
+                    mat01, config.get("ec_sched_opt")
+                ),
+                packets,
+            )
+        else:
             if tables:
                 bm_np, bm_dev = tables
             else:
@@ -352,64 +349,31 @@ class BitMatrixCodec(BitplaneDispatchMixin, ErasureCodeBase):
                     self._tables, key, bm_np,
                     isinstance(packets, jax.core.Tracer),
                 )
-            out = self._dispatch_bitmatrix(bm_np, bm_dev, packets, op)
-        return self._to_chunks(out)
+            out = self._dispatch_bitmatrix(
+                bm_np, bm_dev, packets, op, route=route
+            )
+        chunks = self._to_chunks(out)
+        return [chunks[..., j, :] for j in range(chunks.shape[-2])]
 
     def _host_bits(self, mat01: np.ndarray):
         """(bit-expanded HOST matrix, cache key) for a packet 0/1
-        matrix — the one source of truth for the ("bits", ...) cache
-        (shared with the DCN worker's host-side decode)."""
+        matrix — the one source of truth for the ("bits", ...)
+        cache."""
         key = ("bits", mat01.tobytes())
         return self._tables.get(
             key, lambda: gf_matrix_to_bitmatrix(mat01)
         ), key
 
-    def _try_sched_shards(
-        self, mat01: np.ndarray, shards: list, op: str
-    ):
-        """The no-copy hot path: route a packet-matrix apply through
-        the multi-operand schedule kernel, shard arrays in, shard
-        arrays out — no [.., n, chunk] stack, no packetize reshape
-        (both are real relayout copies on TPU; see
-        ops/xor_schedule.py). Returns the list of output shards, or
-        None when any precondition fails (over-gate matrix, off-TPU,
-        VMEM-oversized chunks, mesh/DCN installed, host-sized numpy
-        input — each of those keeps its existing route). Rejections
-        are NOT counted here: the packetized probe in
-        _apply_packet_matrix is the terminal one."""
-        return self._sched_shards_route(
-            mat01, shards, self.w, op, count_reject=False
-        )
-
-    def _schedule_rows(self, mat01: np.ndarray):
-        """The route's schedule for a 0/1 packet matrix — the CSE'd
-        multi-level program under ``ec_sched_opt`` (gated on post-CSE
-        op count), the pinned selection form otherwise (gated on raw
-        density) — or None when the matrix stays over its gate.
-        Cached process-wide in ops.xor_schedule (schedules depend
-        only on the matrix bytes)."""
-        from ceph_tpu.utils import config
-
-        return xor_schedule.routable_schedule(
-            mat01, config.get("ec_sched_opt")
-        )
-
     def encode_chunks(
         self, data: dict[int, jax.Array]
     ) -> dict[int, jax.Array]:
-        shards = self._shard_list(data)
-        outs = self._try_sched_shards(
-            self.coding_bitmatrix, shards, "encode"
-        )
-        if outs is not None:
-            return {self.k + i: outs[i] for i in range(self.m)}
         parity = self._apply_packet_matrix(
             self.coding_bitmatrix,
-            self._stack_data(data),
+            self._shard_list(data),
             "encode",
             tables=(self._encode_bmat_np, self._encode_bmat),
         )
-        return {self.k + i: parity[..., i, :] for i in range(self.m)}
+        return {self.k + i: parity[i] for i in range(self.m)}
 
     def decode_chunks(
         self,
@@ -424,18 +388,12 @@ class BitMatrixCodec(BitplaneDispatchMixin, ErasureCodeBase):
         dec01 = self._host_tables.get(
             key, lambda: self._build_decode_bitmatrix(present, want)
         )
-        shard_list = [chunks[i] for i in present]
-        outs = self._try_sched_shards(dec01, shard_list, "decode")
-        if outs is not None:
-            result = {w: chunks[w] for w in want_to_read if w in chunks}
-            for idx, wshard in enumerate(want):
-                result[wshard] = outs[idx]
-            return result
-        stacked = self._stack(shard_list)
-        out = self._apply_packet_matrix(dec01, stacked, "decode")
+        outs = self._apply_packet_matrix(
+            dec01, [chunks[i] for i in present], "decode"
+        )
         result = {w: chunks[w] for w in want_to_read if w in chunks}
         for idx, wshard in enumerate(want):
-            result[wshard] = out[..., idx, :]
+            result[wshard] = outs[idx]
         return result
 
     # -- parity delta (RMW) -------------------------------------------
@@ -462,18 +420,12 @@ class BitMatrixCodec(BitplaneDispatchMixin, ErasureCodeBase):
         w = self.w
         pcols = [c * w + t for c in cols for t in range(w)]
         mat01 = np.ascontiguousarray(self.coding_bitmatrix[:, pcols])
-        shard_list = [delta[c] for c in cols]
-        outs = self._try_sched_shards(mat01, shard_list, "delta")
-        if outs is not None:
-            return {
-                pid: xor_bytes(p, outs[pid - self.k])
-                for pid, p in parity.items()
-            }
-        stacked = self._stack(shard_list)
-        contrib = self._apply_packet_matrix(mat01, stacked, "delta")
+        contrib = self._apply_packet_matrix(
+            mat01, [delta[c] for c in cols], "delta"
+        )
         out = {}
         for pid, p in parity.items():
-            c = contrib[..., pid - self.k, :]
+            c = contrib[pid - self.k]
             if isinstance(p, np.ndarray) and isinstance(c, np.ndarray):
                 out[pid] = np.bitwise_xor(p, c)
             else:

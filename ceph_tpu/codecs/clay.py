@@ -1313,7 +1313,7 @@ class ClayCodec(ErasureCodeBase):
         zsel = np.asarray(planes)
         # host path keeps numpy: the inner decode's dispatch then
         # serves small ops from host GF tables and ROUTES large ones
-        # (mesh/DCN take host-staged inputs only); converting to
+        # (the mesh takes host-staged inputs too); converting to
         # device arrays here barred both and forced einsum
         conv = jnp.asarray if traced else np.ascontiguousarray
         known = {

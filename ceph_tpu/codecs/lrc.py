@@ -28,7 +28,7 @@ Round 11 — the schedule route for local repair: the kml form accepts
 reed_sol_van layout), which generates the local layers on the ``xor``
 plugin — Azure-LRC-style XOR local parities. Their encode, repair,
 and parity-delta rows are then 0/1-valued, so the inner dispatch
-rides the schedule-native XOR engine (matrix_codec._try_sched_bytes,
+rides the schedule-native XOR engine (matrix_codec.as_01_matrix,
 w=1: one multi-operand VPU kernel over the local group, ``sched_*``
 counters) instead of streaming a bit-plane matrix through the MXU —
 the fixed-engine rate the ``lrc_local_repair_gbps`` bench row
@@ -49,7 +49,7 @@ from ceph_tpu import PLUGIN_ABI_VERSION
 
 from .base import ErasureCodeBase, to_int
 from .interface import ErasureCodeProfile, Flag, SubChunkPlan
-from .matrix_codec import BitplaneDispatchMixin, count_route
+from .matrix_codec import BitplaneDispatchMixin
 from .registry import registry
 
 
@@ -312,22 +312,15 @@ class LrcCodec(BitplaneDispatchMixin, ErasureCodeBase):
         self, data: dict[int, jax.Array]
     ) -> dict[int, jax.Array]:
         """All layers as one matrix apply (see init)."""
-        import numpy as np
-
-        shards, xp = self._shard_list_xp(data)
-        if self._shards_host_route(shards, xp is np):
-            from ceph_tpu.gf import gf_apply_bytes_host
-
-            count_route("host_encode", *shards)
-            out = gf_apply_bytes_host(
-                self._composite, np.stack(shards, axis=-2)
+        shards = self._shard_list(data)
+        route = self._route_shards(shards, host_tables=True)
+        if route == "host":
+            outs = self._run_host_tables(self._composite, shards, "encode")
+        else:
+            outs = self._dispatch_bitmatrix_shards(
+                self._comp_bmat_np, self._comp_bmat, shards, "encode",
+                route,
             )
-            return {
-                self.k + j: out[..., j, :] for j in range(self.m)
-            }
-        outs = self._dispatch_bitmatrix_shards(
-            self._comp_bmat_np, self._comp_bmat, shards, "encode"
-        )
         return {self.k + j: outs[j] for j in range(self.m)}
 
     def _encode_layered(

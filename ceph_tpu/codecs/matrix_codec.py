@@ -50,7 +50,6 @@ def _dispatch_counters():
 
     b = PerfCountersBuilder(perf_collection, "ec_dispatch")
     routes = {
-        "dcn": "fanned across DCN hosts",
         "mesh": "sharded over the mesh",
         "pallas": "served by the Pallas kernel",
         "einsum": "served by the einsum engine",
@@ -118,12 +117,6 @@ def _dispatch_counters():
         "dispatches where a mesh was installed but neither the stripe "
         "batch nor the lane axis divided dp (the shard axis always "
         "zero-pads to sp) and a single-chip route served the op",
-    )
-    b.add_u64_counter(
-        "dcn_fallback",
-        "dispatches where the DCN cluster failed mid-op (host death / "
-        "timeout): the cluster is uninstalled, a single-host route "
-        "serves the op, and the operator re-installs after repair",
     )
     return b.create_perf_counters()
 
@@ -244,29 +237,35 @@ class DecodeTableCache:
         return val
 
 
+def as_01_matrix(mat) -> np.ndarray | None:
+    """``mat`` as a contiguous uint8 0/1 matrix, or None when any
+    entry is a generic GF(2^8) coefficient. Over the subfield {0,1}
+    each output chunk is a pure XOR of input chunks, so the schedule
+    kernels apply with packet == chunk (w=1): how LRC xor-local-parity
+    repair (a single all-ones decode row) and the xor plugin's parity
+    ride the schedule engine. Generic rows are not schedule-eligible,
+    which is not a rejection: no counter."""
+    mat = np.asarray(mat)
+    if mat.size == 0 or int(mat.max()) > 1:
+        return None
+    return np.ascontiguousarray(mat, dtype=np.uint8)
+
+
 class BitplaneDispatchMixin:
     """The device-dispatch engine shared by every bit-plane codec
-    family: route one bitmatrix application to host GF tables (small
-    numpy inputs), the mesh (when installed), the Pallas MXU kernel
-    (on TPU, tileable shapes), or the XLA einsum engine — with every
-    route visible in the ``ec_dispatch`` counters. The byte matrix
-    families (jerasure RS/Cauchy, ISA) and the packet bit-matrix
-    families (liberation/blaum_roth/liber8tion) both dispatch here;
-    the reference splits these across jerasure_matrix_encode vs
-    jerasure_schedule_encode, but on TPU they are one engine."""
+    family: route one bitmatrix application to the mesh (when
+    installed), host GF tables (small numpy inputs), the schedule
+    kernels (sparse 0/1 matrices), the Pallas MXU kernel (on TPU,
+    tileable shapes), or the XLA einsum engine — with every route
+    visible in the ``ec_dispatch`` counters. The byte matrix families
+    (jerasure RS/Cauchy, ISA) and the packet bit-matrix families
+    (liberation/blaum_roth/liber8tion) both dispatch here; the
+    reference splits these across jerasure_matrix_encode vs
+    jerasure_schedule_encode, but on TPU they are one engine.
 
-    @staticmethod
-    def _host_sized(*arrays) -> bool:
-        """Small host-side inputs skip device dispatch entirely: below
-        the threshold, launch and transfer latency dwarfs the GF math."""
-        from ceph_tpu.utils import config
-
-        limit = config.get("ec_host_dispatch_bytes")
-        return (
-            limit > 0
-            and all(isinstance(a, np.ndarray) for a in arrays)
-            and sum(a.nbytes for a in arrays) <= limit
-        )
+    ``_plan_route`` is the one place that orders the routes; every
+    dispatch site asks it (``_route`` / ``_route_shards``) and
+    executes the route it names."""
 
     @staticmethod
     def _active_mesh():
@@ -283,16 +282,12 @@ class BitplaneDispatchMixin:
 
         return mesh_dispatch.get_mesh()
 
-    def _mesh_routable(self, stacked) -> bool:
-        return self._mesh_routable_shape(stacked.shape)
-
     def _mesh_routable_shape(self, shape) -> bool:
         """True when a mesh is active AND this dispatch shape will
         actually ride it — the host small-op shortcut stays available
         for shapes that would only hit mesh_fallback (device launch
         latency dwarfs the GF math there, same as without a mesh).
-        ``shape`` is the stacked [..., n_shards, chunk] form; the
-        sched-shards route probes with its would-be stacked shape."""
+        ``shape`` is the stacked [..., n_shards, chunk] form."""
         mesh = self._active_mesh()
         if mesh is None:
             return False
@@ -309,36 +304,160 @@ class BitplaneDispatchMixin:
     @staticmethod
     def _stack(vals: list):
         """Stack shard buffers along the shard axis, KEEPING host
-        arrays host-side (np): the DCN route ships bytes, and the
-        host GF shortcut reads them in place — converting to device
-        arrays here would bar both. One policy for every family."""
+        arrays host-side (np): the host GF shortcut reads them in
+        place, and the device routes time their upload as
+        ``codec.h2d``. One policy for every family."""
         if all(isinstance(v, np.ndarray) for v in vals):
             return np.stack(vals, axis=-2)
         return jnp.stack(vals, axis=-2)
 
-    def _dcn_routable(self, stacked) -> bool:
-        return self._dcn_routable_shape(
-            stacked.shape, isinstance(stacked, np.ndarray)
-        )
+    def _plan_route(
+        self,
+        shape,
+        host_staged: bool,
+        nbytes: int,
+        *,
+        host_tables: bool = False,
+        shard_shape=None,
+        mat01: np.ndarray | None = None,
+        w: int = 1,
+        csum_block: int | None = None,
+    ) -> tuple[str | None, tuple[str, ...]]:
+        """Which route serves one bit-matrix application: the ONE
+        place the precedence is written. No side effects: it returns
+        ``(route, missed)``, the route's name and the ``ec_dispatch``
+        counters of the tiers that were enabled, outranked the route
+        and could not take the shape; the site that asked counts them
+        and executes the route.
 
-    def _dcn_routable_shape(self, shape, host_staged: bool) -> bool:
-        """True when a DCN cluster is installed AND this host-staged
-        shape will ride it — like _mesh_routable, this must outrank
-        the host small-op shortcut, or default-config dispatches
-        (< ec_host_dispatch_bytes) would silently never leave the
-        host."""
-        from ceph_tpu.parallel import dispatch as mesh_dispatch
+        What the caller holds decides which tiers are on offer:
+        ``shape`` is the stacked [..., C, N] form (packetized for a
+        packet code: C = shards x w, N = chunk / w); ``host_staged``
+        and ``nbytes`` say whether the inputs are numpy and how large;
+        ``host_tables``: the caller has the byte matrix the host GF
+        tables take; ``shard_shape``: the inputs are per-shard
+        operands of this one shape, so the shards-form kernels (no
+        stack relayout) can take them; ``mat01`` / ``w``: a 0/1
+        packet matrix, which makes the schedule kernels eligible;
+        ``csum_block``: the caller wants parity AND block checksums
+        from one pass.
 
-        dcn = mesh_dispatch.get_dcn()
-        if dcn is None or not host_staged:
-            return False
-        c = shape[-2]
-        flat_shape = (
-            int(np.prod(shape[:-2], initial=1)),
-            c,
-            shape[-1],
+        The order: an installed mesh that supports the shape (it
+        owns that shape whatever else could serve it) > [the fused
+        encode+csum kernel, shards form before stacked, where
+        ``csum_block`` asks for it: that question ends there, and
+        None sends the caller to a plain encode, which asks again] >
+        host tables for host-staged inputs within
+        ``ec_host_dispatch_bytes`` > schedule kernel, shards form (a
+        TPU kernel) before the stacked form (packet layouts only; XLA
+        serves it off the chip) > Pallas shards form (whole-chunk
+        device-resident operands) > Pallas stacked > XLA einsum."""
+        from ceph_tpu.ops import pallas_encode as pe
+        from ceph_tpu.utils import config
+
+        if self._mesh_routable_shape(shape):
+            return "mesh", ()
+        c, n = shape[-2:]
+        tpu = platform.on_tpu()
+        pallas = bool(config.get("ec_use_pallas"))
+        if csum_block is not None:
+            if not (
+                pallas
+                and config.get("ec_fused_csum")
+                and (tpu or config.get("ec_fused_csum_interpret"))
+            ):
+                return None, ()
+            if (
+                shard_shape is not None
+                and not host_staged
+                and pe.fused_csum_shards_supported(
+                    c, shard_shape, csum_block
+                )
+            ):
+                return "fused_shards", ()
+            flat_shape = (int(np.prod(shape[:-2], initial=1)), c, n)
+            if pe.fused_csum_supported(flat_shape, csum_block):
+                return "fused", ()
+            return None, ("fused_fallback",)
+        if (
+            host_tables
+            and host_staged
+            and 0 < nbytes <= config.get("ec_host_dispatch_bytes")
+        ):
+            return "host", ()
+        missed = []
+        if (
+            mat01 is not None
+            and config.get("ec_use_sched")
+            and (tpu or w > 1)
+        ):
+            sched = xor_schedule.routable_schedule(
+                mat01, config.get("ec_sched_opt")
+            )
+            if sched is None:
+                missed.append("sched_rejected_density")
+            elif (
+                tpu
+                and shard_shape is not None
+                and xor_schedule.shards_supported(
+                    c // w,
+                    xor_schedule._n_rows(sched) // w,
+                    w,
+                    shard_shape,
+                    xor_schedule._linearize(sched)[1]
+                    if isinstance(sched, xor_schedule.Schedule)
+                    else 0,
+                )
+            ):
+                return "sched_shards", ()
+            elif w > 1 and xor_schedule.supported((1, c, n)):
+                return "sched", ()
+            else:
+                missed.append("sched_rejected_shape")
+        if (
+            pallas
+            and tpu
+            and w == 1
+            and shard_shape is not None
+            and not host_staged
+            and pe.shards_supported(c, shard_shape)
+        ):
+            return "pallas_shards", tuple(missed)
+        # the stacked MXU tiers: the only ones a mesh that is
+        # installed but cannot split the shape counts itself out of
+        if self._active_mesh() is not None:
+            missed.append("mesh_fallback")
+        if pallas and tpu:
+            if pe.supported((1, c, n)):
+                return "pallas", tuple(missed)
+            missed.append("pallas_fallback")
+        return "einsum", tuple(missed)
+
+    def _route(self, shape, host_staged: bool, nbytes: int, **held):
+        """Ask ``_plan_route`` and count what it reports missed: the
+        ``*_fallback`` and ``sched_rejected_*`` counters move once a
+        dispatch, at the site that asked."""
+        route, missed = self._plan_route(
+            shape, host_staged, nbytes, **held
         )
-        return dcn.supported((0, c * 8), flat_shape)
+        for name in missed:
+            _dispatch_counters().inc(name)
+        return route
+
+    def _route_shards(self, shards: list, w: int = 1, **held):
+        """``_route`` for a list of per-shard operands (``w`` packets
+        a chunk): the stacked shape they would have, and their own
+        shape for the shards-form kernels."""
+        first = shards[0].shape
+        uniform = all(s.shape == first for s in shards[1:])
+        return self._route(
+            first[:-1] + (len(shards) * w, first[-1] // w),
+            all(isinstance(v, np.ndarray) for v in shards),
+            sum(int(v.size) * v.dtype.itemsize for v in shards),
+            shard_shape=first if uniform else None,
+            w=w,
+            **held,
+        )
 
     def _dispatch_bitmatrix(
         self,
@@ -347,178 +466,81 @@ class BitplaneDispatchMixin:
         stacked: jax.Array,
         op: str,
         nbytes: int | None = None,
+        route: str | None = None,
     ) -> jax.Array:
-        """Route one device bit-matrix application. Decode and delta
-        ride the same fused kernel as encode — the kernel is generic
-        over [R*8, C*8] bitmatrices, so reconstruct is a first-class
-        on-chip path (the reference treats decode as equally hot:
+        """Run one bit-matrix application on the stacked [..., C, N]
+        form over the mesh, the Pallas kernel or the einsum engine,
+        whichever ``route`` names (asked here when the caller has not
+        asked already). Decode and delta ride the same fused kernel
+        as encode — the kernel is generic over [R*8, C*8]
+        bitmatrices, so reconstruct is a first-class on-chip path
+        (the reference treats decode as equally hot:
         osd/ECUtil.cc:648-729, isa/ErasureCodeIsa.cc:504-516).
         ``nbytes``: what the route counts as input bytes where
         ``stacked`` carries zero columns or padding."""
-        from ceph_tpu.ops import pallas_encode as pe
-        from ceph_tpu.utils import config
+        if route is None:
+            route = self._route(
+                stacked.shape,
+                isinstance(stacked, np.ndarray),
+                int(stacked.size) * stacked.dtype.itemsize,
+            )
+        if route == "mesh":
+            from ceph_tpu.parallel import dispatch as mesh_dispatch
 
-        # DCN outranks every single-host route: with a multi-host
-        # cluster installed, host-staged dispatches fan out across OS
-        # processes (the AsyncMessenger sub-op fan-out over the data-
-        # center network). Device-resident inputs stay on this chip —
-        # shipping them through the control plane would force a sync.
-        from ceph_tpu.parallel import dispatch as mesh_dispatch
-
-        dcn = mesh_dispatch.get_dcn()
-        if dcn is not None and isinstance(stacked, np.ndarray):
             flat = stacked.reshape((-1,) + stacked.shape[-2:])
-            if dcn.supported(bmat_np.shape, flat.shape):
-                try:
-                    out = dcn.apply_bitmatrix(bmat_np, flat)
-                    count_route(f"dcn_{op}", flat)
-                    return out.reshape(
-                        stacked.shape[:-2] + out.shape[-2:]
-                    )
-                except Exception as e:
-                    # a dead/hung host must not wedge the data path:
-                    # uninstall the cluster (every later op would pay
-                    # the timeout again) and serve this op on a
-                    # single-host route. The operator re-installs
-                    # after repairing the cluster.
-                    _dispatch_counters().inc("dcn_fallback")
-                    mesh_dispatch.set_dcn(None)
-                    from ceph_tpu.utils.log import get_logger
-
-                    get_logger("ec-dcn").error(
-                        "DCN dispatch failed; cluster uninstalled:",
-                        type(e).__name__, str(e)[:200],
-                    )
-        mesh = self._active_mesh()
-        if mesh is not None:
-            flat = stacked.reshape((-1,) + stacked.shape[-2:])
-            if mesh_dispatch.mesh_supported(
-                mesh, bmat_np.shape, flat.shape
-            ):
-                count_route(f"mesh_{op}", flat, nbytes=nbytes)
-                with codec_stage("launch"):
-                    out = mesh_dispatch.mesh_apply_bitmatrix(
-                        mesh, bmat_dev, flat
-                    )
-                    return out.reshape(
-                        stacked.shape[:-2] + out.shape[-2:]
-                    )
-            _dispatch_counters().inc("mesh_fallback")
-        if config.get("ec_use_pallas") and platform.on_tpu():
-            if pe.supported((1,) + stacked.shape[-2:]):
-                count_route(f"pallas_{op}", stacked, nbytes=nbytes)
-                flat = _upload(
-                    stacked.reshape((-1,) + stacked.shape[-2:])
+            count_route(f"mesh_{op}", flat, nbytes=nbytes)
+            with codec_stage("launch"):
+                out = mesh_dispatch.mesh_apply_bitmatrix(
+                    self._active_mesh(), bmat_dev, flat
                 )
-                with codec_stage("launch"):
-                    out = pe.gf_encode_bitplane_pallas(bmat_np, flat)
-                    return out.reshape(
-                        stacked.shape[:-2] + out.shape[-2:]
-                    )
-            _dispatch_counters().inc("pallas_fallback")
+                return out.reshape(
+                    stacked.shape[:-2] + out.shape[-2:]
+                )
+        if route == "pallas":
+            from ceph_tpu.ops import pallas_encode as pe
+
+            count_route(f"pallas_{op}", stacked, nbytes=nbytes)
+            flat = _upload(
+                stacked.reshape((-1,) + stacked.shape[-2:])
+            )
+            with codec_stage("launch"):
+                out = pe.gf_encode_bitplane_pallas(bmat_np, flat)
+                return out.reshape(
+                    stacked.shape[:-2] + out.shape[-2:]
+                )
         count_route(f"einsum_{op}", stacked, nbytes=nbytes)
         stacked = _upload(stacked)
         with codec_stage("launch"):
             return _apply_bitmatrix(bmat_dev, stacked)
 
-    def _sched_shards_route(
-        self,
-        mat01: np.ndarray,
-        shards: list,
-        w: int,
-        op: str,
-        count_reject: bool = False,
-    ):
-        """Shared schedule-engine shards dispatch for a 0/1 packet
-        matrix (w packets per chunk; w=1 means whole-chunk byte
-        rows). Builds the route's schedule — CSE-optimized multi-
-        level program under ``ec_sched_opt`` (default), the pinned
-        selection form otherwise — gates it on post-CSE op count /
-        raw density respectively, and serves the op through the
-        multi-operand schedule kernel: shard arrays in, shard arrays
-        out, no stack relayout. Returns the output shard list, or
-        None when any precondition fails (each of those keeps its
-        existing route).
+    def _run_host_tables(
+        self, mat: np.ndarray, shards: list, op: str
+    ) -> list:
+        """The ``host`` route: the byte matrix over the stacked numpy
+        shards on the host GF tables, numpy in and out."""
+        from ceph_tpu.gf import gf_apply_bytes_host
 
-        ``count_reject`` marks the TERMINAL schedule probe for an op:
-        only that site increments ``sched_rejected_density`` /
-        ``sched_rejected_shape``, so one logical dispatch counts one
-        rejection even when several kernel forms probe it. Rejections
-        are only counted for ops the schedule engine would otherwise
-        have owned — host-sized and mesh/DCN-routed shapes bail first
-        (those routes outrank the schedule the same way they outrank
-        Pallas)."""
+        count_route(f"host_{op}", *shards)
+        out = gf_apply_bytes_host(mat, np.stack(shards, axis=-2))
+        return [out[..., j, :] for j in range(out.shape[-2])]
+
+    def _run_sched_shards(
+        self, mat01: np.ndarray, shards: list, w: int, op: str
+    ) -> list:
+        """The ``sched_shards`` route: the multi-operand schedule
+        kernel, shard arrays in, shard arrays out, no [.., n, chunk]
+        stack and no packetize reshape (both are real relayout copies
+        on TPU; see ops/xor_schedule.py). The schedule is the one the
+        planner gated: the CSE-optimized multi-level program under
+        ``ec_sched_opt`` (default), the pinned selection form
+        otherwise; cached process-wide by the matrix's bytes."""
         from ceph_tpu.utils import config
 
-        if not config.get("ec_use_sched") or not platform.on_tpu():
-            return None
-        shape = shards[0].shape
-        if any(s.shape != shape for s in shards[1:]):
-            return None
-        if self._host_sized(*shards):
-            return None
-        # mesh/DCN routing operates on the stacked form and outranks
-        # single-chip paths; probe with the would-be stacked shape
-        probe = shape[:-1] + (len(shards) * w, shape[-1] // w)
-        if self._mesh_routable_shape(probe) or self._dcn_routable_shape(
-            probe, all(isinstance(s, np.ndarray) for s in shards)
-        ):
-            return None
+        count_route(f"sched_{op}", *shards)
         sched = xor_schedule.routable_schedule(
             mat01, config.get("ec_sched_opt")
         )
-        if sched is None:
-            if count_reject:
-                _dispatch_counters().inc("sched_rejected_density")
-            return None
-        n_slots = 0
-        if isinstance(sched, xor_schedule.Schedule):
-            n_slots = xor_schedule._linearize(sched)[1]
-        if not xor_schedule.shards_supported(
-            len(shards), xor_schedule._n_rows(sched) // w, w, shape,
-            n_slots,
-        ):
-            if count_reject:
-                _dispatch_counters().inc("sched_rejected_shape")
-            return None
-        count_route(f"sched_{op}", *shards)
         return xor_schedule.xor_schedule_apply_shards(sched, shards, w)
-
-    def _try_sched_bytes(
-        self, mat: np.ndarray, shards: list, op: str
-    ):
-        """w=1 schedule route for GF(2^8) BYTE matrices whose entries
-        are all 0/1: over the subfield {0,1} each output chunk is a
-        pure XOR of input chunks, so the packet engine applies with
-        packet == chunk. This is how LRC xor-local-parity repair (a
-        single all-ones decode row) and the xor plugin's parity ride
-        the schedule engine. Generic GF coefficient rows never
-        qualify and bail on the cheap max() probe with no counter —
-        they are not schedule-eligible, not rejected. This is the
-        byte codecs' terminal schedule probe, so rejections count."""
-        mat = np.asarray(mat)
-        if mat.size == 0 or int(mat.max()) > 1:
-            return None
-        return self._sched_shards_route(
-            np.ascontiguousarray(mat, dtype=np.uint8), shards, 1, op,
-            count_reject=True,
-        )
-
-    def _shards_host_route(self, shards: list, host_staged: bool) -> bool:
-        """One gate for every per-shard dispatch site: small host-
-        staged inputs take the host GF tables UNLESS a mesh/DCN wants
-        the shape (those routes outrank the host shortcut — see
-        _active_mesh)."""
-        if not host_staged:
-            return False
-        shape = shards[0].shape[:-1] + (
-            len(shards), shards[0].shape[-1]
-        )
-        return (
-            not self._mesh_routable_shape(shape)
-            and not self._dcn_routable_shape(shape, True)
-            and self._host_sized(*shards)
-        )
 
     def _dispatch_bitmatrix_shards(
         self,
@@ -526,6 +548,7 @@ class BitplaneDispatchMixin:
         bmat_dev: jax.Array,
         shards: list,
         op: str,
+        route: str | None = None,
     ) -> list:
         """Per-shard-operand route: device inputs that fit the
         shards-form Pallas kernel skip the [.., C, N] stack entirely
@@ -536,29 +559,23 @@ class BitplaneDispatchMixin:
         route to any c <= pallas_encode.SHARDS_MAX_C: cauchy k=10
         encode and wide SHEC survivor sets now ride it, where the
         round-5 block-diagonal rule (s*c <= 16) forced them through
-        the stacked path. DCN/mesh routes and the einsum fallback
+        the stacked path. The mesh route and the einsum fallback
         still take the stacked tensor. Returns one array per output
-        row-group (R = bitmatrix rows / 8)."""
-        from ceph_tpu.ops import pallas_encode as pe
-        from ceph_tpu.utils import config
+        row-group (R = bitmatrix rows / 8). ``route``: the planner's
+        answer where the caller has asked already."""
+        if route is None:
+            route = self._route_shards(shards)
+        if route == "pallas_shards":
+            from ceph_tpu.ops import pallas_encode as pe
 
-        c = len(shards)
-        shape = shards[0].shape[:-1] + (c, shards[0].shape[-1])
-        host_staged = all(isinstance(v, np.ndarray) for v in shards)
-        if (
-            not host_staged
-            and config.get("ec_use_pallas")
-            and platform.on_tpu()
-            and pe.shards_supported(c, shards[0].shape)
-            and not self._mesh_routable_shape(shape)
-            and not self._dcn_routable_shape(shape, host_staged)
-        ):
             count_route(f"pallas_{op}", *shards)
             with codec_stage("launch"):
                 return pe.gf_encode_bitplane_pallas_shards(bmat_np, shards)
         with codec_stage("prep"):
             stacked = self._stack(list(shards))
-        out = self._dispatch_bitmatrix(bmat_np, bmat_dev, stacked, op)
+        out = self._dispatch_bitmatrix(
+            bmat_np, bmat_dev, stacked, op, route=route
+        )
         return [out[..., j, :] for j in range(out.shape[-2])]
 
 
@@ -595,8 +612,10 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
     def encode_chunks(
         self, data: dict[int, jax.Array]
     ) -> dict[int, jax.Array]:
-        shards, xp = self._shard_list_xp(data)
-        parity = self._encode_shards(shards, xp)
+        parity = self._apply_byte_matrix(
+            self.generator[self.k :, :], self._shard_list(data),
+            "encode", None,
+        )
         return {self.k + i: parity[i] for i in range(self.m)}
 
     def encode_chunks_with_csums(
@@ -611,27 +630,15 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         route runs on TPU, or off-TPU in Pallas interpreter mode when
         ``ec_fused_csum_interpret`` is set (tests/CI)."""
         from ceph_tpu.ops import pallas_encode as pe
-        from ceph_tpu.utils import config
 
-        if not (
-            config.get("ec_fused_csum") and config.get("ec_use_pallas")
-        ):
-            return None, None
-        interpret = None
-        if not platform.on_tpu():
-            if not config.get("ec_fused_csum_interpret"):
-                return None, None
-            interpret = True
         shards, _xp = self._shard_list_xp(data)
+        route = self._route_shards(shards, csum_block=csum_block)
+        if route not in ("fused_shards", "fused"):
+            # the mesh owns the shape, or no fused kernel serves it
+            return None, None
         c = len(shards)
-        shape = shards[0].shape[:-1] + (c, shards[0].shape[-1])
-        if self._mesh_routable_shape(shape) or self._dcn_routable_shape(
-            shape, all(isinstance(v, np.ndarray) for v in shards)
-        ):
-            return None, None  # multi-chip routes own those shapes
-        if pe.fused_csum_shards_supported(
-            c, shards[0].shape, csum_block
-        ) and not all(isinstance(v, np.ndarray) for v in shards):
+        interpret = None if platform.on_tpu() else True
+        if route == "fused_shards":
             # device-resident per-shard inputs skip the stack relayout
             count_route("fused_encode", *shards)
             with codec_stage("launch"):
@@ -643,18 +650,11 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
                 {self.k + j: parity[j] for j in range(self.m)},
                 csums,
             )
-        stacked_shape = (
-            (int(np.prod(shards[0].shape[:-1], initial=1)),)
-            + (c, shards[0].shape[-1])
-        )
-        if not pe.fused_csum_supported(stacked_shape, csum_block):
-            _dispatch_counters().inc("fused_fallback")
-            return None, None
         count_route("fused_encode", *shards)
         with codec_stage("prep"):
             stacked = self._stack(list(shards))
             lead = stacked.shape[:-2]
-            flat = stacked.reshape(stacked_shape)
+            flat = stacked.reshape((-1,) + stacked.shape[-2:])
         flat = _upload(flat)
         with codec_stage("launch"):
             parity, csums = pe.gf_encode_csum_bitplane_pallas(
@@ -669,27 +669,33 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
             csums,
         )
 
-    def _encode_shards(self, shards: list, xp) -> list:
-        """Dispatch the parity matmul: host GF tables for small numpy
-        inputs, the schedule engine for 0/1 parity rows (the xor
-        plugin / LRC xor-local layers), the shards-form Pallas MXU
-        kernel on TPU for per-shard device arrays, the stacked routes
-        otherwise."""
-        if self._shards_host_route(shards, xp is np):
-            from ceph_tpu.gf import gf_apply_bytes_host
-
-            count_route("host_encode", *shards)
-            out = gf_apply_bytes_host(
-                self.generator[self.k :, :], np.stack(shards, axis=-2)
+    def _apply_byte_matrix(
+        self, mat: np.ndarray, shards: list, op: str, key: tuple | None
+    ) -> list:
+        """Apply the GF(2^8) byte matrix ``mat`` to per-shard operands
+        on the route ``_plan_route`` names: host GF tables for small
+        numpy inputs (numpy out), the schedule engine where every
+        entry is 0/1 (the xor plugin / LRC xor-local layers, LRC local
+        repair rows), otherwise the MXU routes on ``mat``'s bit-matrix:
+        the encode matrix's resident copy for ``key`` None, else the
+        LRU's under ``key`` (host copy cached, device copy through
+        ``dev_bmat`` so a trace never caches its own tracer)."""
+        mat01 = as_01_matrix(mat)
+        route = self._route_shards(shards, host_tables=True, mat01=mat01)
+        if route == "host":
+            return self._run_host_tables(mat, shards, op)
+        if route == "sched_shards":
+            return self._run_sched_shards(mat01, shards, 1, op)
+        if key is None:
+            bmat_np, bmat_dev = self._encode_bmat_np, self._encode_bmat
+        else:
+            bmat_np = self._tables.get(
+                key, lambda: gf_matrix_to_bitmatrix(mat)
             )
-            return [out[..., j, :] for j in range(self.m)]
-        outs = self._try_sched_bytes(
-            self.generator[self.k :, :], shards, "encode"
-        )
-        if outs is not None:
-            return outs
+            traced = any(isinstance(v, jax.core.Tracer) for v in shards)
+            bmat_dev = dev_bmat(self._tables, key, bmat_np, traced)
         return self._dispatch_bitmatrix_shards(
-            self._encode_bmat_np, self._encode_bmat, shards, "encode"
+            bmat_np, bmat_dev, shards, op, route
         )
 
     # -- decode -------------------------------------------------------
@@ -706,39 +712,15 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         if not want:
             return {w: chunks[w] for w in want_to_read}
         key = (tuple(present), tuple(want))
-        shards = [chunks[i] for i in present]
-        host_staged = all(isinstance(v, np.ndarray) for v in shards)
-        if self._shards_host_route(shards, host_staged):
-            from ceph_tpu.gf import gf_apply_bytes_host
-
-            count_route("host_decode", *shards)
-            mat = self._host_tables.get(
-                key, lambda: self._build_decode_bytes(present, want)
-            )
-            out = gf_apply_bytes_host(mat, np.stack(shards, axis=-2))
-            outs = [out[..., j, :] for j in range(len(want))]
-        else:
-            # 0/1 decode rows (XOR-parity local groups: the common
-            # LRC local repair) ride the schedule engine as w=1
-            # whole-chunk packets — _build_decode_bytes is the same
-            # host matrix the host route caches, so the probe shares
-            # its table
-            mat = self._host_tables.get(
-                key, lambda: self._build_decode_bytes(present, want)
-            )
-            outs = self._try_sched_bytes(mat, shards, "decode")
-            if outs is None:
-                bmat_np = self._tables.get(
-                    key, lambda: self._build_decode_bmat(present, want)
-                )
-                traced = any(
-                    isinstance(v, jax.core.Tracer) for v in shards
-                )
-                outs = self._dispatch_bitmatrix_shards(
-                    bmat_np,
-                    dev_bmat(self._tables, key, bmat_np, traced),
-                    shards, "decode",
-                )
+        # one host byte matrix for the host tables, the schedule
+        # probe (0/1 decode rows, the common LRC local repair) and the
+        # bit-matrix of the MXU routes
+        mat = self._host_tables.get(
+            key, lambda: self._build_decode_bytes(present, want)
+        )
+        outs = self._apply_byte_matrix(
+            mat, [chunks[i] for i in present], "decode", key
+        )
         result = {w: chunks[w] for w in want_to_read if w in chunks}
         for idx, w in enumerate(want):
             result[w] = outs[idx]
@@ -789,57 +771,29 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         one small matmul over just the changed columns.
         """
         cols = sorted(delta)
-        shards = [delta[c] for c in cols]
-        host_staged = all(isinstance(v, np.ndarray) for v in shards)
-        if self._shards_host_route(shards, host_staged):
-            from ceph_tpu.gf import gf_apply_bytes_host
-
-            count_route("host_delta", *shards)
-            contrib = gf_apply_bytes_host(
-                self.generator[self.k :, cols],
-                np.stack(shards, axis=-2),
-            )
-            return {
-                pid: np.bitwise_xor(
-                    np.asarray(p), contrib[..., pid - self.k, :]
-                )
-                for pid, p in parity.items()
-            }
-
-        key = ("delta", tuple(cols))
-        # 0/1 delta columns (xor plugin / LRC xor-local layers): the
-        # parity-delta contribution is a pure XOR program — the
-        # schedule engine's w=1 form
-        contribs = self._try_sched_bytes(
-            self.generator[self.k :, cols], shards, "delta"
+        contribs = self._apply_byte_matrix(
+            self.generator[self.k :, cols],
+            [delta[c] for c in cols],
+            "delta",
+            ("delta", tuple(cols)),
         )
-        if contribs is None:
-            bmat_np = self._tables.get(
-                key,
-                lambda: gf_matrix_to_bitmatrix(
-                    self.generator[self.k :, cols]
-                ),
+        out = {}
+        for pid, p in parity.items():
+            c = contribs[pid - self.k]
+            # the host tables answer in numpy, and the parity stays there
+            out[pid] = (
+                np.bitwise_xor(np.asarray(p), c)
+                if isinstance(c, np.ndarray)
+                else xor_bytes(p, c)
             )
-            traced = any(
-                isinstance(v, jax.core.Tracer) for v in shards
-            )
-            contribs = self._dispatch_bitmatrix_shards(
-                bmat_np,
-                dev_bmat(self._tables, key, bmat_np, traced),
-                shards, "delta",
-            )
-        return {
-            pid: xor_bytes(p, contribs[pid - self.k])
-            for pid, p in parity.items()
-        }
+        return out
 
     def delta_batchable(self) -> bool:
-        """Whether ``delta_contribs`` serves this codec now: the mesh
-        and DCN routes own their dispatch shapes and keep the per-op
-        ``apply_delta``."""
-        from ceph_tpu.parallel import dispatch as mesh_dispatch
-
-        return self._active_mesh() is None and mesh_dispatch.get_dcn() is None
+        """Whether ``delta_contribs`` serves this codec now: an
+        installed mesh owns its dispatch shapes and keeps the per-op
+        ``apply_delta``. (Asked before any batch has a shape, so this
+        is ``_plan_route``'s first question without its shape.)"""
+        return self._active_mesh() is None
 
     def delta_contribs(
         self, cols: np.ndarray, units: np.ndarray
@@ -850,7 +804,7 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         with ``contribs[u, j] = G[k+j, cols[u]] * units[u]``; the caller
         XORs them onto its old parity windows.
 
-        One decision for the whole batch, in ``_shards_host_route``'s
+        One decision for the whole batch, in the byte threshold's
         place: up to ``DELTA_HOST_UNITS`` units the host GF tables
         serve it (units sent = U). Otherwise ONE device dispatch: by
         linearity a delta on column c is the encode of a stripe that

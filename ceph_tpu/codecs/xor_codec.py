@@ -9,7 +9,7 @@ pair GF global parities with *XOR local parities*, and that is this
 plugin's job here: ``codecs/lrc.py`` uses it for generated local
 layers under ``local_parity=xor``, so local-group repair rows are
 0/1-valued and ride the schedule-native XOR engine (the round-11
-``_try_sched_bytes`` w=1 route: encode, decode, AND parity-delta all
+``as_01_matrix`` w=1 route: encode, decode, AND parity-delta all
 dispatch as pure XOR programs with ``sched_*`` counter visibility)
 instead of streaming a bit-plane matrix through the MXU.
 

@@ -37,9 +37,6 @@ class LoadCluster:
         client_max_attempts: int = 10,
         use_mesh: bool = False,
         mesh_devices: int | None = None,
-        dcn_hosts: int = 0,
-        dcn_devices_per_host: int = 1,
-        dcn_data_timeout: float = 60.0,
     ) -> None:
         if n_osds < k + m:
             raise ValueError(f"need >= k+m={k + m} OSDs, got {n_osds}")
@@ -49,43 +46,22 @@ class LoadCluster:
         self.chunk_size = chunk_size
         self._tick_period = tick_period
         # -- multi-chip tier wired into the LIVE path (round-10): the
-        # daemons run in-process, so the process-wide dispatch mesh /
-        # DCN cluster (parallel/dispatch.py) IS the live data path —
-        # every RMW encode, degraded decode and recovery rebuild the
-        # daemons run from here on rides the collective fan-out, the
-        # way the reference's sub-op fan-out is its distributed
-        # backend. Installed BEFORE the daemons boot so even the
-        # first op routes over it; shutdown() restores what was there.
+        # daemons run in-process, so the process-wide dispatch mesh
+        # (parallel/dispatch.py) IS the live data path — every RMW
+        # encode, degraded decode and recovery rebuild the daemons
+        # run from here on rides the collective fan-out, the way the
+        # reference's sub-op fan-out is its distributed backend.
+        # Installed BEFORE the daemons boot so even the first op
+        # routes over it; shutdown() restores what was there.
         self.mesh = None
-        self.dcn = None
-        self._prev_mesh = self._prev_dcn = None
-        if use_mesh or dcn_hosts:
+        self._prev_mesh = None
+        if use_mesh:
             from ceph_tpu.parallel import dispatch as mesh_dispatch
+            from ceph_tpu.parallel import make_ec_mesh
 
             self._prev_mesh = mesh_dispatch.get_mesh()
-            self._prev_dcn = mesh_dispatch.get_dcn()
-            if dcn_hosts:
-                from ceph_tpu.parallel.dcn import DcnCluster
-
-                self.dcn = DcnCluster(
-                    n_hosts=dcn_hosts,
-                    devices_per_host=dcn_devices_per_host,
-                ).start()
-                self._dcn_data_timeout = dcn_data_timeout
-                # the data path must fail FAST into the single-host
-                # fallback when a host dies mid-op (the client's
-                # retry ladder is seconds, not the raw op timeout)
-                self.dcn.apply_bitmatrix = (
-                    lambda bm, data, timeout=dcn_data_timeout,
-                    _orig=self.dcn.apply_bitmatrix:
-                    _orig(bm, data, timeout=timeout)
-                )
-                mesh_dispatch.set_dcn(self.dcn)
-            if use_mesh:
-                from ceph_tpu.parallel import make_ec_mesh
-
-                self.mesh = make_ec_mesh(mesh_devices, k=k)
-                mesh_dispatch.set_mesh(self.mesh)
+            self.mesh = make_ec_mesh(mesh_devices, k=k)
+            mesh_dispatch.set_mesh(self.mesh)
         self.mon = Monitor()
         self.daemons: dict[int, OSDDaemon] = {}
         self.stores: dict[int, object] = {}
@@ -358,24 +334,6 @@ class LoadCluster:
         profile = dict(self.mon.osdmap.profiles[spec.profile_name])
         return registry.factory(spec.plugin, profile)
 
-    # -- multi-chip controls -------------------------------------------
-    def kill_dcn_host(self, rank: int = 1) -> None:
-        """Hard-kill one DCN host process mid-run (the VERDICT r5 #8
-        scenario): the next op's collective faults, the codec
-        dispatcher uninstalls the cluster and serves the op on a
-        single-host route, and the client's retry ladder carries any
-        op parked behind the fault to completion."""
-        if self.dcn is None:
-            raise RuntimeError("no DCN cluster installed")
-        self.dcn.procs[rank].kill()
-
-    def dcn_live(self) -> bool:
-        """True while the DCN cluster is still the installed dispatch
-        route (a mid-run host fault uninstalls it)."""
-        from ceph_tpu.parallel import dispatch as mesh_dispatch
-
-        return mesh_dispatch.get_dcn() is self.dcn and self.dcn is not None
-
     def shutdown(self) -> None:
         from ceph_tpu.msg.messenger import net_faults
 
@@ -385,10 +343,7 @@ class LoadCluster:
         self.client.shutdown()
         for d in self.daemons.values():
             d.stop()
-        if self.mesh is not None or self.dcn is not None:
+        if self.mesh is not None:
             from ceph_tpu.parallel import dispatch as mesh_dispatch
 
             mesh_dispatch.set_mesh(self._prev_mesh)
-            mesh_dispatch.set_dcn(self._prev_dcn)
-            if self.dcn is not None:
-                self.dcn.stop()

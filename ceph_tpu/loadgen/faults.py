@@ -33,9 +33,7 @@ NET_ACTIONS = ("net_flaky", "net_partition", "net_clear")
 class FaultEvent:
     #: fire once the run's completed-op counter reaches this
     at_op: int
-    #: "kill" | "revive" | "dcn_kill" (hard-kill a DCN host process
-    #: mid-run — the multi-chip msgr fault; ``osd`` carries the host
-    #: rank, default 1) | "net_flaky" (arm the seeded link-fault
+    #: "kill" | "revive" | "net_flaky" (arm the seeded link-fault
     #: profile in ``profile``) | "net_partition" (partition the
     #: victim's links; ``osd``/picker chooses the victim) |
     #: "net_clear" (clear the plane and heal partitions)
@@ -50,9 +48,7 @@ class FaultEvent:
     profile: dict = field(default_factory=dict)
 
     def __post_init__(self) -> None:
-        if self.action not in (
-            "kill", "revive", "dcn_kill", *NET_ACTIONS
-        ):
+        if self.action not in ("kill", "revive", *NET_ACTIONS):
             raise ValueError(f"unknown fault action {self.action!r}")
         if isinstance(self.osd, str):
             if self.action not in ("kill", "net_partition"):
@@ -86,7 +82,6 @@ class FaultSchedule:
         #: the bespoke direct-state poll's stamp, kept beside the
         #: stats one so the two derivations stay cross-checkable
         self.recovered_legacy_at: float | None = None
-        self.dcn_killed_at: float | None = None
         self.killed: list[int] = []
         self._net_armed = False
 
@@ -105,10 +100,6 @@ class FaultSchedule:
                 self._apply(ev, cluster)
 
     def _apply(self, ev: FaultEvent, cluster) -> None:
-        if ev.action == "dcn_kill":
-            cluster.kill_dcn_host(1 if ev.osd is None else ev.osd)
-            self.dcn_killed_at = time.monotonic()
-            return
         if ev.action == "net_flaky":
             cluster.net_flaky(**ev.profile)
             self._net_armed = True

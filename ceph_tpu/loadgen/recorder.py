@@ -9,8 +9,8 @@ after the fact.
 Device-clock mode: every op's host-measured latency carries a
 constant floor of dispatch and transfer overhead. ``DeviceClock``
 measures the op's device program once with trip-count differencing
-(iterated on-device loop, min-of-reps — the bench.py methodology,
-which cancels per-dispatch overhead by construction) and the recorder
+(iterated on-device loop, min-of-reps, which cancels per-dispatch
+overhead by construction) and the recorder
 then reports device-clock percentiles as
 
     p_dev(x) = host_p(x) - host_min + dev_per_op
@@ -194,7 +194,7 @@ class DeviceClock:
 
     The measured quantity is the ONE thing the host clock cannot see:
     how long the op's device program actually runs. An iterated on-device loop (feedback-patched so
-    iterations are serially dependent — bench.py methodology note 1)
+    iterations are serially dependent)
     is timed at two trip counts; the differenced per-iteration time
     carries no per-dispatch term.
     """

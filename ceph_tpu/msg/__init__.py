@@ -7,7 +7,7 @@ frames, per-segment crc32c). The TPU framework splits that role in two
 
 - intra-slice shard fan-out rides ICI as XLA collectives
   (``ceph_tpu.parallel``) — no host messaging at all;
-- host-to-host (the DCN tier) uses this package: the same framed,
+- host-to-host (the data-center network) uses this package: the same framed,
   crc-protected wire protocol carrying typed, versioned sub-op
   messages between shard servers.
 

@@ -36,9 +36,7 @@ MSG_GET_ATTRS = 118           # per-shard attr fetch (scrub consensus)
 MSG_GET_ATTRS_REPLY = 119
 MSG_WATCH_NOTIFY = 120        # MWatchNotify (daemon -> watcher push)
 MSG_NOTIFY_ACK = 121          # watcher ack back to the primary
-MSG_DCN_HELLO = 122           # DCN worker-host handshake
-MSG_DCN_CMD = 123             # DCN control-plane op broadcast
-MSG_DCN_REPLY = 124           # DCN per-host op result
+# 122-124 are retired ids: never give them to a new message
 MSG_PG_INFO = 125             # peering info exchange (MOSDPGInfo)
 MSG_PG_INFO_REPLY = 126
 MSG_PG_ACTIVATE = 127         # interval activation (les push)
@@ -816,76 +814,6 @@ class NotifyAck:
         return cls(h["notify_id"], h["cookie"])
 
 
-@dataclass
-class DcnHello:
-    """DCN host-process handshake: which rank this is and what slice
-    of the global device mesh it owns (the multi-controller analog of
-    the messenger's peer identification)."""
-
-    rank: int
-    n_processes: int
-    local_devices: int
-    global_devices: int
-
-    def encode(self) -> list[bytes]:
-        return [_header("dcn_hello", {
-            "rank": self.rank, "n": self.n_processes,
-            "local": self.local_devices, "global": self.global_devices,
-        })]
-
-    @classmethod
-    def decode(cls, segments: list[bytes]) -> "DcnHello":
-        h = _parse(segments[0], "dcn_hello")
-        return cls(h["rank"], h["n"], h["local"], h["global"])
-
-
-@dataclass
-class DcnCmd:
-    """One DCN control-plane op. Every host receives the SAME op
-    metadata (the multi-controller SPMD discipline: identical program
-    on every host) with its OWN shard-slice payload — the sub-op
-    shard fan-out of MOSDECSubOpWrite mapped onto hosts."""
-
-    tid: int
-    kind: str          # "encode" | "decode" | "shutdown"
-    meta: dict         # json-serializable op parameters
-    payload: bytes = b""   # this host's shard-slice bytes
-
-    def encode(self) -> list[bytes]:
-        return [
-            _header("dcn_cmd", {
-                "tid": self.tid, "op": self.kind, "meta": self.meta,
-            }),
-            self.payload,
-        ]
-
-    @classmethod
-    def decode(cls, segments: list[bytes]) -> "DcnCmd":
-        h = _parse(segments[0], "dcn_cmd")
-        return cls(h["tid"], h["op"], h["meta"], segments[1])
-
-
-@dataclass
-class DcnReply:
-    tid: int
-    rank: int
-    meta: dict
-    payload: bytes = b""
-
-    def encode(self) -> list[bytes]:
-        return [
-            _header("dcn_reply", {
-                "tid": self.tid, "rank": self.rank, "meta": self.meta,
-            }),
-            self.payload,
-        ]
-
-    @classmethod
-    def decode(cls, segments: list[bytes]) -> "DcnReply":
-        h = _parse(segments[0], "dcn_reply")
-        return cls(h["tid"], h["rank"], h["meta"], segments[1])
-
-
 _DECODERS = {
     MSG_EC_SUB_WRITE: ECSubWrite.decode,
     MSG_EC_SUB_WRITE_REPLY: ECSubWriteReply.decode,
@@ -901,9 +829,6 @@ _DECODERS = {
     MSG_GET_ATTRS_REPLY: GetAttrsReply.decode,
     MSG_WATCH_NOTIFY: WatchNotify.decode,
     MSG_NOTIFY_ACK: NotifyAck.decode,
-    MSG_DCN_HELLO: DcnHello.decode,
-    MSG_DCN_CMD: DcnCmd.decode,
-    MSG_DCN_REPLY: DcnReply.decode,
     MSG_PG_INFO: PGInfo.decode,
     MSG_PG_INFO_REPLY: PGInfoReply.decode,
     MSG_PG_ACTIVATE: PGActivate.decode,
@@ -929,9 +854,6 @@ _TYPE_OF = {
     GetAttrsReply: MSG_GET_ATTRS_REPLY,
     WatchNotify: MSG_WATCH_NOTIFY,
     NotifyAck: MSG_NOTIFY_ACK,
-    DcnHello: MSG_DCN_HELLO,
-    DcnCmd: MSG_DCN_CMD,
-    DcnReply: MSG_DCN_REPLY,
     PGInfo: MSG_PG_INFO,
     PGInfoReply: MSG_PG_INFO_REPLY,
     PGActivate: MSG_PG_ACTIVATE,

@@ -1,4 +1,4 @@
-"""Socket messenger — the AsyncMessenger analog (host/DCN tier).
+"""Socket messenger — the AsyncMessenger analog (host-to-host tier).
 
 Mirrors the roles of msg/async/AsyncMessenger.{h,cc}: a ``Messenger``
 binds a listening address and dispatches inbound typed messages to its

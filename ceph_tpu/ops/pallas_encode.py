@@ -18,7 +18,8 @@ The v5 layout removes the tax:
   unpack needs, F % 4 == 0) — no stripe duplication, no block
   diagonal. Every MAC outside the pad columns touches real data:
   useful_frac = C/F (1.0 for the flagship C=8 and every C % 4 == 0
-  family; see ``mac_stats``).
+  family): 64*R*F/C MACs per data byte, of which 64*R touch real
+  data.
 - **Stripes batch on the grid and the LANE axis, not the
   contraction.** Each grid step carries S stripes; their bit planes
   are unpacked per stripe and concatenated along lanes into one
@@ -86,23 +87,6 @@ def _pick_lane_batch(batch: int, tile: int) -> int:
     while s < 8 and batch % (2 * s) == 0 and 2 * s * tile <= LANE_WIDTH_TARGET:
         s *= 2
     return s
-
-
-def mac_stats(c: int, r: int) -> dict:
-    """Clocked-vs-useful MAC accounting for the zero-waste packing.
-
-    One output byte row costs an [8R, 8F] x [8F, lane] stream; per
-    data byte that is 64*R*F/C MACs of which 64*R touch real data
-    (the pad columns are the only structural zeros left). bench.py
-    reports ``mxu_useful_util_frac`` from this — the round-5 packing
-    clocked 2x this count with useful_frac 0.5 by construction."""
-    pad = (-c) % 4
-    f = c + pad
-    return {
-        "pad_cols": pad,
-        "macs_per_byte": 64.0 * r * f / c,
-        "useful_frac": c / f,
-    }
 
 
 # ---------------------------------------------------------------- legacy
@@ -364,7 +348,7 @@ def supported(data_shape: tuple[int, ...]) -> bool:
 SHARDS_SB = 8
 #: shards-form lane-tile cap, set in round 5 when 64 KiB tiles crashed
 #: that libtpu's Mosaic compiler at c=8 and measured no better than
-#: 32 KiB where they compiled (experiments/exp_r5_byteshards2.py).
+#: 32 KiB where they compiled (a round-5 A/B run).
 #: libtpu 0.0.34 compiles 64 KiB (AOT, round 21); whether it is faster
 #: is not measured, so the cap stays
 SHARDS_MAX_TILE = 32768

@@ -36,10 +36,10 @@ bit-equal to the un-optimized schedule and to the host GF engine.
 Execution model: parity packet q is the XOR of the data packets (and
 intermediates) its program selects, executed as one Pallas VPU kernel
 blocked over (stripe, lane-tile). No MXU, no bit-plane unpack — the
-blocks stream (cols + rows) packets per stripe against HBM, which on
-v5e measured 553-621 GB/s data-in at the r4 bench geometry
-(experiments/exp_r5_sched.py), ~0.7x the pure-read roofline, while
-the VPU work per block tracks the schedule's op count.
+blocks stream (cols + rows) packets per stripe against HBM, which a
+round-5 A/B run read at 553-621 GB/s data-in (before the benchmark:
+no cell runs the schedule route yet, so not re-measured), while the
+VPU work per block tracks the schedule's op count.
 
 Gate math (round 11): the un-optimized route keeps the original
 traffic-ratio gate — (ones + rows) <= MAX_TRAFFIC_RATIO * cols, the
@@ -49,8 +49,7 @@ writes) <= MAX_OP_RATIO * cols. Minimal-density encode matrices pass
 both (ratio 2.0-2.2 post-CSE); inverted decode matrices (~50% ones,
 raw ratio 7-8, rejected by the old gate) compress under CSE to ratio
 ~2.5 and now ride the schedule route, as do LRC xor-local-parity
-repair rows — the r11 superopt targets (experiments/
-exp_r11_sched_superopt.py).
+repair rows — the r11 superopt targets.
 """
 
 from __future__ import annotations
@@ -210,8 +209,7 @@ def schedule_xors(sel) -> int:
 def cse_stats(mat01: np.ndarray) -> dict:
     """Optimizer scorecard for one matrix: raw ones / selection-form
     XORs / post-CSE XORs / intermediate count / scratch-slot peak.
-    Consumed by bench.py's sched-superopt phase and the golden
-    op-count regression pins."""
+    Consumed by the golden op-count regression pins."""
     m = np.asarray(mat01, dtype=np.uint8)
     rows = schedule_rows(m)
     sched = optimize_schedule(m)
@@ -567,9 +565,9 @@ def _sched_shards_fn(
     as in-kernel lane slices. The single-operand form pays a real
     relayout copy for the [B, k, chunk] stack and the packetize
     reshape (TPU tiles the minor-most two dims, so those reshapes
-    move every byte); this form never materializes either — measured
-    407 vs ~100 GB/s data-in on the r4 bench geometry
-    (experiments/exp_r5_multiop.py). ``Schedule`` programs execute
+    move every byte); this form never materializes either (a
+    round-5 A/B run read 407 vs ~100 GB/s data-in; before the
+    benchmark, not re-measured). ``Schedule`` programs execute
     their linearized op list with intermediates in a VMEM scratch ref
     (sb rows per live slot, recycled at last use)."""
     p = chunk // w

@@ -16,9 +16,9 @@ same dispatch-counter visibility the single-chip routes have
 
 The RMW and read pipelines need no code of their own for this: their
 device work flows through ``codec.encode_chunks`` /
-``decode_chunks`` / ``apply_delta``, all of which land in
-``MatrixErasureCodec._dispatch_bitmatrix`` — the one router this
-module feeds. ``__graft_entry__.dryrun_multichip`` drives a full
+``decode_chunks`` / ``apply_delta``, all of which ask
+``BitplaneDispatchMixin._plan_route``, the one router this module
+feeds, and run the mesh route in ``_dispatch_bitmatrix``. ``__graft_entry__.dryrun_multichip`` drives a full
 RMW write and a reconstruct read through this route on the virtual
 8-device mesh; ``tests/test_mesh_pipeline.py`` forces it on for a
 cluster round trip.
@@ -35,13 +35,6 @@ from jax.sharding import Mesh
 # operator installed.
 _mesh: Mesh | None = None
 
-#: the installed multi-HOST cluster (parallel/dcn.DcnCluster): when
-#: present, host-staged codec dispatches fan out across OS-process
-#: hosts — the operator installing it IS the opt-in, mirroring the
-#: reference where configuring the messenger's peer map turns a
-#: single-daemon build into a cluster member
-_dcn = None
-
 
 def set_mesh(mesh: Mesh | None) -> None:
     """Install (or clear) the process-wide EC dispatch mesh."""
@@ -53,16 +46,6 @@ def get_mesh() -> Mesh | None:
     return _mesh
 
 
-def set_dcn(cluster) -> None:
-    """Install (or clear) the process-wide DCN dispatch cluster."""
-    global _dcn
-    _dcn = cluster
-
-
-def get_dcn():
-    return _dcn
-
-
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh | None):
     """Scoped mesh activation (tests, dryruns)."""
@@ -72,17 +55,6 @@ def use_mesh(mesh: Mesh | None):
         yield mesh
     finally:
         set_mesh(prev)
-
-
-@contextlib.contextmanager
-def use_dcn(cluster):
-    """Scoped DCN-cluster activation (tests, dryruns)."""
-    prev = get_dcn()
-    set_dcn(cluster)
-    try:
-        yield cluster
-    finally:
-        set_dcn(prev)
 
 
 def mesh_supported(

@@ -10,7 +10,7 @@ The TPU-native design replaces that with SPMD over a Mesh:
   holds a subset of data shards; parity is an XOR-reduction across
   devices, expressed as an integer ``psum`` over bit-plane counts
   followed by mod 2. XLA lowers the psum onto ICI; on multi-host
-  meshes the same program spans DCN with no code change — that IS the
+  meshes the same program spans the hosts' network with no code change — that IS the
   framework's distributed communication backend.
 
 GF(2) trick making the collective cheap: parity bits are (sum of

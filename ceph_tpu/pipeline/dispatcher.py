@@ -29,9 +29,9 @@ The round-10 serving tier adds three seams:
 
 - ``coalescing_scope()`` — a thread-local scope the OSD daemon's
   coalesced tick batch enters around each PG group's execution:
-  inside it, ``ShardExtentMap`` routes encodes through the ring even
-  when ``ec_streaming_dispatch`` is off, so concurrent groups of one
-  tick share batched device dispatches;
+  inside it, and only there, ``ShardExtentMap`` routes encodes
+  through the ring, so concurrent groups of one tick share batched
+  device dispatches;
 - fused encode+csum ops stage through the SAME ring (``submit`` with
   ``csum_block``): a fused group stacks every member's chunks into
   one ``encode_chunks_with_csums`` dispatch — the whole coalesced
@@ -133,9 +133,9 @@ def coalescing_scope(tick: "DeltaTick | None" = None):
     """Thread-local scope marking this thread's encodes as part of a
     coalesced tick batch (the OSD daemon enters it around each PG
     group of a wave). Inside it, the shard-map encode routes through
-    the streaming ring regardless of ``ec_streaming_dispatch`` —
-    concurrent group threads of one tick land their ops in the same
-    ring window and share batched device dispatches. With ``tick``,
+    the streaming ring: concurrent group threads of one tick land
+    their ops in the same ring window and share batched device
+    dispatches. With ``tick``,
     the wave's ``DeltaTick``, parity deltas of this thread's ops park
     there until the whole tick has submitted. Nesting-safe."""
     _coal_tls.depth = getattr(_coal_tls, "depth", 0) + 1
@@ -667,7 +667,7 @@ def _codec_signature(codec) -> tuple:
 
 def dispatcher_for(codec) -> StreamingDispatcher:
     """Shared dispatcher per codec SIGNATURE (lazily created) — the
-    seam ShardExtentMap uses when ``ec_streaming_dispatch`` is on.
+    seam ShardExtentMap uses inside a coalesced tick.
     Ops from every PG with the same EC profile share one ring and
     batch together."""
     key = _codec_signature(codec)
@@ -677,16 +677,6 @@ def dispatcher_for(codec) -> StreamingDispatcher:
             d = StreamingDispatcher(codec)
             _global[key] = d
         return d
-
-
-def streaming_enabled() -> bool:
-    from ceph_tpu.utils import config
-
-    if not config.get("ec_streaming_dispatch"):
-        return False
-    from ceph_tpu import native
-
-    return native.available()
 
 
 def shutdown_all() -> None:

@@ -357,23 +357,19 @@ class ShardExtentMap:
 
     @staticmethod
     def _ring_routable(codec, nbytes: int) -> bool:
-        """One gate for both ring routes: streaming config on, OR this
-        thread is inside a coalesced OSD tick (dispatcher.
-        coalescing_scope) — concurrent tick groups stage into the same
-        ring window either way. Sub-chunk codecs (CLAY) give chunk
-        geometry meaning beyond byte count, and ops beyond a ring slot
-        can't stage — both keep the per-op path."""
-        from .dispatcher import (
-            coalescing_active,
-            dispatcher_for,
-            streaming_enabled,
-        )
+        """One gate for both ring routes: this thread is inside a
+        coalesced OSD tick (dispatcher.coalescing_scope), whose
+        concurrent groups stage into the same ring window. Sub-chunk
+        codecs (CLAY) give chunk geometry meaning beyond byte count,
+        and ops beyond a ring slot can't stage — both keep the per-op
+        path."""
+        from .dispatcher import coalescing_active, dispatcher_for
 
-        if codec.get_sub_chunk_count() != 1:
-            return False
-        if not (streaming_enabled() or coalescing_active()):
-            return False
-        return nbytes <= dispatcher_for(codec).max_op_bytes
+        return (
+            codec.get_sub_chunk_count() == 1
+            and coalescing_active()
+            and nbytes <= dispatcher_for(codec).max_op_bytes
+        )
 
     @staticmethod
     def _ring_encode_csum(codec, data, cs: int, cb: int):
@@ -393,10 +389,9 @@ class ShardExtentMap:
     @staticmethod
     def _dispatch_encode(codec, data: np.ndarray) -> np.ndarray:
         """[k, L] host -> [m, L] host through the codec's dispatch.
-        With ``ec_streaming_dispatch`` on — or inside a coalesced OSD
-        tick — the op rides the native staging ring and shares a
-        batched device dispatch with other concurrent ops
-        (pipeline/dispatcher.py)."""
+        Inside a coalesced OSD tick the op rides the native staging
+        ring and shares a batched device dispatch with other
+        concurrent ops (pipeline/dispatcher.py)."""
         from .dispatcher import dispatcher_for
 
         k = data.shape[0]
@@ -444,8 +439,8 @@ class ShardExtentMap:
         form batches across ops (``dispatcher.delta_batch``).
         Packet-layout codes (``PARITY_DELTA_CHUNK_GRANULARITY``: the
         packet decomposition is per chunk, so windows widen to chunk
-        boundaries), CLAY and any codec while a mesh or DCN route owns
-        its dispatches keep the per-op form, whole windows for
+        boundaries), CLAY and any codec while a mesh owns its
+        dispatches keep the per-op form, whole windows for
         ``codec.apply_delta`` (``windows``), behind the same three
         steps."""
         from ceph_tpu.codecs.interface import Flag

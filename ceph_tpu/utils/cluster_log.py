@@ -10,7 +10,7 @@ CLUSTER" — the first file a red soak run is triaged from.
 Here the daemons share one process, so the aggregation point is a
 process-global bounded ring of structured events.  Each event carries:
 
-- ``ts``        wall-clock stamp (merging across DCN host processes
+- ``ts``        wall-clock stamp (merging across OS processes
                 aligns on wall time)
 - ``daemon``    the reporting daemon ("mon", "osd.3", ...)
 - ``type``      a stable event-type slug ("osd_down", "slow_op",

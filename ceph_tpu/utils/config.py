@@ -91,10 +91,6 @@ OPTIONS: tuple[Option, ...] = (
              "(ECExtentCache LRU analog)", min=0,
     ),
     Option(
-        "ec_stripe_batch", int, 8,
-        desc="stripes batched per device encode dispatch", min=1,
-    ),
-    Option(
         "csum_type", str, "crc32c",
         desc="checksum algorithm for block verification "
              "(bluestore_csum_type analog)",
@@ -173,14 +169,6 @@ OPTIONS: tuple[Option, ...] = (
              "delta over it (stripes on dp, shards on sp with zero-pad, "
              "ring-XOR combine); falls back per-dispatch when neither "
              "the stripe batch nor the lane axis divides dp",
-    ),
-    Option(
-        "ec_streaming_dispatch", bool, False,
-        desc="route pipeline encodes through the native-ring streaming "
-             "dispatcher: concurrent small ops across PGs aggregate "
-             "into one batched device dispatch with completion "
-             "callbacks (SURVEY step-4 staging tier; sharded op "
-             "queue role, osd/OSD.cc:9874)",
     ),
     Option(
         "objecter_inflight_per_osd", int, 64,
@@ -362,11 +350,6 @@ OPTIONS: tuple[Option, ...] = (
         desc="JSONL sink for cluster-log events (the ceph.log file "
              "role); empty = in-memory ring only. Applied when the "
              "first event lands after the value changes",
-    ),
-    Option(
-        "jax_platform", str, "",
-        desc="force a JAX platform (cpu/tpu); empty = environment",
-        level="dev",
     ),
     Option(
         "ec_host_dispatch_bytes", int, 1 << 20,

@@ -56,7 +56,7 @@ def device_identity() -> dict:
 
 def require_tpu() -> dict:
     """The gate for entry points that measure (``chip_smoke.py``,
-    ``bench.py``): return the device identity, or raise naming what
+    ``benchmark/run.py``): return the device identity, or raise naming what
     JAX found instead. Off-TPU the kernel wrappers' ``interpret=None``
     convenience would run every Pallas kernel in the interpreter and
     report its times under device names — so those programs stop

@@ -34,8 +34,8 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-#: process-unique prefix so trace ids stay distinct across the DCN
-#: tier's OS-process hosts (the blkin trace-id role)
+#: process-unique prefix so trace ids stay distinct across OS
+#: processes (the blkin trace-id role)
 _TRACE_PREFIX = f"{os.getpid():x}-{secrets.token_hex(2)}"
 
 #: jax.profiler.TraceAnnotation, resolved ONCE on first span instead
@@ -68,7 +68,7 @@ def _annotation_cls():
 @dataclass
 class Span:
     #: globally unique (process-prefixed) — parent links survive
-    #: merging dump_historic output across DCN host processes, where
+    #: merging dump_historic output across OS processes, where
     #: bare per-process counters would collide
     span_id: str
     parent_id: str | None

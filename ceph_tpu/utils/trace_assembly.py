@@ -6,8 +6,8 @@ unique ``span_id``, its ``parent_id`` (which crosses the wire on
 OSDOp/ECSubWrite/ECSubRead messages), and the end-to-end ``trace_id``
 one client op's spans share across the client, the primary, and every
 replica.  This module turns a merged pile of span dumps (one process's
-``dump_historic_ops``, or several processes' dumps concatenated — the
-DCN hosts' admin sockets serve the same format) back into per-trace
+``dump_historic_ops``, or several processes' dumps concatenated: every
+process's admin socket serves the same format) back into per-trace
 span TREES, finds each tree's critical path with per-stage
 attribution, and emits:
 

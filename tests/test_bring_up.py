@@ -1,12 +1,9 @@
 """Round-21 bring-up seams: one compile-cache location, a native
-library keyed to the host that loads it, a bench that cannot hide the
-device, and a tree that no longer describes the remote-device plug-in
-it was once written behind."""
+library keyed to the host that loads it, and a tree that no longer
+describes the remote-device plug-in it was once written behind."""
 
 import os
 import re
-import subprocess
-import sys
 
 import pytest
 
@@ -80,63 +77,6 @@ def test_native_loader_refuses_foreign_build(tmp_path, monkeypatch):
     assert native.crc32c(0xFFFFFFFF, b"123456789") == 0x1CF96D7C
     with open(foreign, "rb") as f:
         assert f.read().startswith(b"not loadable")
-
-
-# --------------------------------------------------------------- bench
-def test_bench_refuses_cpu_and_names_it():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py")],
-        cwd=ROOT, capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode != 0
-    assert "platform='cpu'" in proc.stderr
-    assert proc.stdout.strip() == ""
-
-
-def test_bench_phase_that_raises_fails_the_run(monkeypatch, capsys):
-    """No phase's failure is swallowed: it lands in ``failed_phases``
-    and the exit code is non-zero."""
-    import json
-
-    import bench
-    from ceph_tpu.utils import platform
-
-    monkeypatch.setattr(platform, "enable_compile_cache", lambda: "")
-    monkeypatch.setattr(
-        platform, "require_tpu",
-        lambda: {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
-    )
-    for name in dir(bench):
-        if name.startswith("_measure_"):
-            monkeypatch.setattr(bench, name, lambda *a, **kw: None)
-    monkeypatch.setattr(
-        bench, "_measure_device_path", lambda *a, **kw: 100.0
-    )
-
-    def boom(*_a, **_kw):
-        raise RuntimeError("Mosaic said no")
-
-    monkeypatch.setattr(bench, "_measure_checksums", boom)
-    assert bench.main() == 1
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["failed_phases"] == {
-        "checksums": "RuntimeError: Mosaic said no"
-    }
-    assert out["device"]["kind"] == "TPU v5 lite"
-    assert out["value"] == 100.0
-
-    monkeypatch.setattr(bench, "_measure_checksums", lambda *a: None)
-    assert bench.main() == 0  # and a clean run still exits 0
-    capsys.readouterr()
-
-
-def test_bench_unknown_device_is_an_error():
-    import bench
-
-    assert bench.published_peaks("TPU v5 lite")["hbm_gbps"] == 819.0
-    with pytest.raises(RuntimeError, match="no published peaks"):
-        bench.published_peaks("TPU v9 imaginary")
 
 
 # ---------------------------------------------------------- the tree

@@ -246,58 +246,6 @@ def test_cluster_hammer_under_membership_thrash():
                 pass
 
 
-class TestDcnConcurrentDispatch:
-    def test_parallel_apply_bitmatrix_no_cross_delivery(self):
-        """Multiple threads dispatching through one DcnCluster: tids
-        must not race (a raced tid cross-delivers payloads — the
-        silent-corruption case the tid lock exists for)."""
-        import threading
-
-        import numpy as np
-
-        from ceph_tpu.gf import (
-            gf_apply_bytes_host,
-            gf_matrix_to_bitmatrix,
-            vandermonde_rs_matrix,
-        )
-        from ceph_tpu.parallel.dcn import DcnCluster
-
-        k, m = 4, 2
-        g = vandermonde_rs_matrix(k, m)
-        bm = gf_matrix_to_bitmatrix(g[k:, :])
-        rng = np.random.default_rng(17)
-        inputs = [
-            rng.integers(0, 256, (2, k, 2048), np.uint8)
-            for _ in range(12)
-        ]
-        expects = [
-            np.asarray(gf_apply_bytes_host(g[k:, :], d)) for d in inputs
-        ]
-        results: dict[int, np.ndarray] = {}
-        errors: list[Exception] = []
-        with DcnCluster(n_hosts=2, devices_per_host=2) as dcn:
-            def worker(i):
-                try:
-                    results[i] = dcn.apply_bitmatrix(bm, inputs[i])
-                except Exception as e:
-                    errors.append(e)
-
-            threads = [
-                threading.Thread(target=worker, args=(i,))
-                for i in range(len(inputs))
-            ]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join(timeout=120)
-        assert not errors, errors[0]
-        for i, exp in enumerate(expects):
-            np.testing.assert_array_equal(
-                results[i], exp,
-                err_msg=f"op {i} got another op's output (tid race)",
-            )
-
-
 class TestQuorumConcurrentCommands:
     def test_parallel_commands_serialize_without_forking(self):
         """Concurrent proxied commands must serialize through the

@@ -1,6 +1,6 @@
 """Round-10 serving-tier gate: async objecter, per-tick op
 coalescing, batched sub-write fan-out, ring-level error isolation,
-and the mesh/DCN tier serving LIVE cluster ops.
+and the mesh tier serving LIVE cluster ops.
 
 The load-bearing pins:
 
@@ -15,9 +15,7 @@ The load-bearing pins:
   everything it accepted, and exports the op_coalesced/batch_size
   counter pair;
 - ECSubWriteBatch framing round-trips;
-- a live cluster serves ops over the mesh route, and the VERDICT r5
-  #8 scenario holds: DCN at hosts >= 3 with a mid-op host kill —
-  every op retried to completion, zero verify failures.
+- a live cluster serves ops over the mesh route.
 """
 
 import threading
@@ -417,48 +415,4 @@ def test_mesh_serves_live_cluster_ops():
         cluster.shutdown()
     assert pc.get("mesh_encode") > before, (
         "live writes never rode the mesh route"
-    )
-
-
-def test_dcn_hosts3_mid_op_host_kill_retried_to_completion():
-    """VERDICT r5 #8: DCN at hosts >= 3 serving LIVE cluster ops, one
-    host hard-killed mid-run (the msgr fault): the codec dispatcher
-    fails over to the single-host route, every op completes, zero
-    verify failures, exactly-once accounting."""
-    from ceph_tpu.codecs.matrix_codec import _dispatch_counters
-    from ceph_tpu.loadgen import (
-        LoadCluster,
-        WorkloadSpec,
-        run_spec,
-    )
-    from ceph_tpu.loadgen.faults import FaultEvent, FaultSchedule
-
-    pc = _dispatch_counters()
-    before_enc = pc.get("dcn_encode")
-    before_fb = pc.get("dcn_fallback")
-    cluster = LoadCluster(
-        n_osds=6, k=3, m=2, pg_num=4, chunk_size=2048,
-        pool="dcnpool", dcn_hosts=3, dcn_data_timeout=4.0,
-    )
-    try:
-        spec = WorkloadSpec(
-            mix={"seq_write": 2, "read": 1},
-            object_size=12288, max_objects=8, queue_depth=6,
-            total_ops=24, seed=0xDC4,
-        )
-        faults = FaultSchedule([FaultEvent(at_op=8, action="dcn_kill")])
-        report = run_spec(cluster, spec, faults)
-        assert not cluster.dcn_live(), (
-            "host kill did not uninstall the DCN route"
-        )
-    finally:
-        cluster.shutdown()
-    assert report["errors"] == 0, report.get("error_samples")
-    assert report["verify_failures"] == 0
-    assert report["exactly_once"]
-    assert pc.get("dcn_encode") > before_enc, (
-        "no live op ever rode the DCN route before the kill"
-    )
-    assert pc.get("dcn_fallback") > before_fb, (
-        "the kill never exercised the fault path"
     )

@@ -160,14 +160,14 @@ class TestAdminSocket:
 
     def test_config_roundtrip(self):
         assert (
-            admin_socket.execute("config set", name="ec_stripe_batch",
+            admin_socket.execute("config set", name="osd_coalesce_max",
                                  value="16")
             == 16
         )
-        assert admin_socket.execute("config get", name="ec_stripe_batch") == 16
+        assert admin_socket.execute("config get", name="osd_coalesce_max") == 16
         from ceph_tpu.utils.config import config
 
-        config.rm("ec_stripe_batch")
+        config.rm("osd_coalesce_max")
 
     def test_unknown_command(self):
         with pytest.raises(KeyError):

@@ -331,7 +331,9 @@ def test_shape_rejection_counter(rng, sched_interpret):
     )
     before = pc.get("sched_rejected_shape")
     codec._apply_packet_matrix(
-        codec.coding_bitmatrix, stacked, "encode"
+        codec.coding_bitmatrix,
+        [stacked[:, i, :] for i in range(4)],
+        "encode",
     )
     assert pc.get("sched_rejected_shape") > before
 

@@ -111,11 +111,11 @@ def test_oversized_op_rejected(codec):
 
 
 def test_pipeline_routes_through_dispatcher(rng):
-    """ec_streaming_dispatch on: ShardExtentMap.encode rides the ring
-    (ops counter moves) and parity matches the per-op path."""
+    """Inside a coalesced tick's scope ShardExtentMap.encode rides the
+    ring (ops counter moves) and parity matches the per-op path."""
+    from ceph_tpu.pipeline.dispatcher import coalescing_scope, shutdown_all
     from ceph_tpu.pipeline.shard_map import ShardExtentMap
     from ceph_tpu.pipeline.stripe import StripeInfo
-    from ceph_tpu.utils import config
 
     codec = registry.factory("isa", {"k": "4", "m": "2"})
     sinfo = StripeInfo(4, 2, 4 * 4096)
@@ -134,14 +134,10 @@ def test_pipeline_routes_through_dispatcher(rng):
     ref = build()
     pc = _stream_counters()
     before = pc.get("ops")
-    old = config.get("ec_streaming_dispatch")
     try:
-        config.set("ec_streaming_dispatch", True)
-        got = build()
+        with coalescing_scope():
+            got = build()
     finally:
-        config.set("ec_streaming_dispatch", old)
-        from ceph_tpu.pipeline.dispatcher import shutdown_all
-
         shutdown_all()
     assert pc.get("ops") > before
     for j in range(2):

@@ -8,7 +8,7 @@ Inputs, merged together:
 
 - ``--spans FILE`` (repeatable): a JSON file holding a list of span
   dicts — exactly what ``admin_socket execute("dump_historic_ops")``
-  returns.  DCN host processes dump the same format through their own
+  returns.  Other processes dump the same format through their own
   admin sockets; feed one file per process and the wire-carried
   trace/parent ids stitch the trees across processes.
 - ``--ops FILE`` (repeatable): a ``dump_ops_in_flight`` dump (the
