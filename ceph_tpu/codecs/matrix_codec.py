@@ -444,6 +444,15 @@ class BitplaneDispatchMixin:
             _dispatch_counters().inc(name)
         return route
 
+    def _route_stacked(self, stacked, **held):
+        """``_route`` for one stacked [..., C, N] operand."""
+        return self._route(
+            stacked.shape,
+            isinstance(stacked, np.ndarray),
+            int(stacked.size) * stacked.dtype.itemsize,
+            **held,
+        )
+
     def _route_shards(self, shards: list, w: int = 1, **held):
         """``_route`` for a list of per-shard operands (``w`` packets
         a chunk): the stacked shape they would have, and their own
@@ -479,11 +488,7 @@ class BitplaneDispatchMixin:
         ``nbytes``: what the route counts as input bytes where
         ``stacked`` carries zero columns or padding."""
         if route is None:
-            route = self._route(
-                stacked.shape,
-                isinstance(stacked, np.ndarray),
-                int(stacked.size) * stacked.dtype.itemsize,
-            )
+            route = self._route_stacked(stacked)
         if route == "mesh":
             from ceph_tpu.parallel import dispatch as mesh_dispatch
 
@@ -618,6 +623,32 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         )
         return {self.k + i: parity[i] for i in range(self.m)}
 
+    def encode_stacked(self, stacked):
+        """``encode_chunks`` for data that is stacked already,
+        [..., k, N] on the host or the device: the same route and
+        counters, parity stacked [..., m, N] (numpy off the host
+        tables, a device array otherwise; one fetch for the caller).
+        The stacked tiers take the array as it is, with no unstack and
+        no second stack."""
+        mat = self.generator[self.k :, :]
+        mat01 = as_01_matrix(mat)
+        route = self._route_stacked(
+            stacked,
+            shard_shape=stacked.shape[:-2] + stacked.shape[-1:],
+            host_tables=True,
+            mat01=mat01,
+        )
+        if route in ("mesh", "pallas", "einsum"):
+            return self._dispatch_bitmatrix(
+                self._encode_bmat_np, self._encode_bmat, stacked,
+                "encode", route=route,
+            )
+        # the host tables and the shards-form kernels take shard operands
+        return self._stack(self._apply_byte_matrix(
+            mat, [stacked[..., i, :] for i in range(self.k)],
+            "encode", None, route,
+        ))
+
     def encode_chunks_with_csums(
         self, data: dict[int, jax.Array], csum_block: int
     ):
@@ -633,44 +664,59 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
 
         shards, _xp = self._shard_list_xp(data)
         route = self._route_shards(shards, csum_block=csum_block)
-        if route not in ("fused_shards", "fused"):
-            # the mesh owns the shape, or no fused kernel serves it
-            return None, None
-        c = len(shards)
-        interpret = None if platform.on_tpu() else True
         if route == "fused_shards":
             # device-resident per-shard inputs skip the stack relayout
             count_route("fused_encode", *shards)
             with codec_stage("launch"):
                 parity, csums = pe.gf_encode_csum_bitplane_pallas_shards(
                     self._encode_bmat_np, shards, csum_block,
-                    interpret=interpret,
+                    interpret=None if platform.on_tpu() else True,
                 )
             return (
                 {self.k + j: parity[j] for j in range(self.m)},
                 csums,
             )
-        count_route("fused_encode", *shards)
+        if route != "fused":
+            # the mesh owns the shape, or no fused kernel serves it
+            return None, None
         with codec_stage("prep"):
             stacked = self._stack(list(shards))
-            lead = stacked.shape[:-2]
-            flat = stacked.reshape((-1,) + stacked.shape[-2:])
-        flat = _upload(flat)
-        with codec_stage("launch"):
-            parity, csums = pe.gf_encode_csum_bitplane_pallas(
-                self._encode_bmat_np, flat, csum_block,
-                interpret=interpret,
-            )
-            n = shards[0].shape[-1]
-            parity = parity.reshape(lead + (self.m, n))
-            csums = csums.reshape(lead + (c + self.m, n // csum_block))
+        parity, csums = self._run_fused(stacked, csum_block)
         return (
             {self.k + j: parity[..., j, :] for j in range(self.m)},
             csums,
         )
 
+    def encode_stacked_with_csums(self, stacked, csum_block: int):
+        """``encode_chunks_with_csums`` for data that is stacked
+        already, [..., k, N]: ``(parity [..., m, N], csums)``, both
+        device arrays, or ``(None, None)``. The kernel's own layout in
+        and out: nothing is stacked, and the caller fetches the parity
+        once."""
+        if self._route_stacked(stacked, csum_block=csum_block) != "fused":
+            return None, None
+        return self._run_fused(stacked, csum_block)
+
+    def _run_fused(self, stacked, csum_block: int):
+        """The ``fused`` route on the stacked [..., k, N] form."""
+        from ceph_tpu.ops import pallas_encode as pe
+
+        count_route("fused_encode", stacked)
+        lead, (c, n) = stacked.shape[:-2], stacked.shape[-2:]
+        flat = _upload(stacked.reshape((-1, c, n)))
+        with codec_stage("launch"):
+            parity, csums = pe.gf_encode_csum_bitplane_pallas(
+                self._encode_bmat_np, flat, csum_block,
+                interpret=None if platform.on_tpu() else True,
+            )
+            return (
+                parity.reshape(lead + (self.m, n)),
+                csums.reshape(lead + (c + self.m, n // csum_block)),
+            )
+
     def _apply_byte_matrix(
-        self, mat: np.ndarray, shards: list, op: str, key: tuple | None
+        self, mat: np.ndarray, shards: list, op: str, key: tuple | None,
+        route: str | None = None,
     ) -> list:
         """Apply the GF(2^8) byte matrix ``mat`` to per-shard operands
         on the route ``_plan_route`` names: host GF tables for small
@@ -679,9 +725,13 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         repair rows), otherwise the MXU routes on ``mat``'s bit-matrix:
         the encode matrix's resident copy for ``key`` None, else the
         LRU's under ``key`` (host copy cached, device copy through
-        ``dev_bmat`` so a trace never caches its own tracer)."""
+        ``dev_bmat`` so a trace never caches its own tracer). ``route``:
+        the planner's answer where the caller has asked already."""
         mat01 = as_01_matrix(mat)
-        route = self._route_shards(shards, host_tables=True, mat01=mat01)
+        if route is None:
+            route = self._route_shards(
+                shards, host_tables=True, mat01=mat01
+            )
         if route == "host":
             return self._run_host_tables(mat, shards, op)
         if route == "sched_shards":

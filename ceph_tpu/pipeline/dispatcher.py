@@ -34,7 +34,7 @@ The round-10 serving tier adds three seams:
   device dispatches;
 - fused encode+csum ops stage through the SAME ring (``submit`` with
   ``csum_block``): a fused group stacks every member's chunks into
-  one ``encode_chunks_with_csums`` dispatch — the whole coalesced
+  one ``encode_stacked_with_csums`` dispatch — the whole coalesced
   tick pays one HBM pass for data, parity AND block csums;
 - per-op error isolation: a failed MULTI-op batch no longer fails
   every member — each op retries SOLO through the codec, and only
@@ -542,18 +542,17 @@ class StreamingDispatcher:
 
         def one(payload: np.ndarray):
             nc = payload.shape[1] // cs
-            chunks = payload.reshape(k, nc, cs).transpose(1, 0, 2)
-            pm, csums = self.codec.encode_chunks_with_csums(
-                {i: chunks[:, i, :] for i in range(k)}, cb
+            parity, csums = self.codec.encode_stacked_with_csums(
+                np.ascontiguousarray(
+                    payload.reshape(k, nc, cs).transpose(1, 0, 2)
+                ),
+                cb,
             )
-            if pm is None:
+            if parity is None:
                 return (None, None)
-            m = len(pm)
-            out = np.stack(
-                [np.asarray(pm[k + j]) for j in range(m)], axis=1
-            )  # [nc, m, cs]
+            out = np.asarray(parity)  # [nc, m, cs]
             return (
-                out.transpose(1, 0, 2).reshape(m, nc * cs),
+                out.transpose(1, 0, 2).reshape(-1, nc * cs),
                 np.asarray(csums),
             )
 
@@ -574,15 +573,13 @@ class StreamingDispatcher:
                     p.reshape(k, nc, cs).transpose(1, 0, 2)
                 )
                 pos += nc
-            pm, csums = self.codec.encode_chunks_with_csums(
-                {i: stacked[:, i, :] for i in range(k)}, cb
+            parity, csums = self.codec.encode_stacked_with_csums(
+                stacked, cb
             )
-            if pm is None:
+            if parity is None:
                 return [(None, None)] * len(members)
-            m = len(pm)
-            out = np.stack(
-                [np.asarray(pm[k + j]) for j in range(m)], axis=1
-            )  # [padded, m, cs]
+            out = np.asarray(parity)  # [padded, m, cs]
+            m = out.shape[1]
             csums = np.asarray(csums)
             results: list = []
             pos = 0

@@ -33,8 +33,6 @@ from collections import OrderedDict
 from collections.abc import Callable
 from dataclasses import dataclass, field
 
-import numpy as np
-
 from ceph_tpu.codecs.interface import Flag
 from ceph_tpu.store import Transaction
 from ceph_tpu.utils.crash_points import crash_points
@@ -1195,7 +1193,10 @@ class RMWPipeline:
                 end = min(end, shard_size)
                 if end <= start:
                     continue
-                buf = bytes(result.get(shard, start, end - start))
+                # the one copy a shard: the store and the wire want
+                # ``bytes``, and ``written`` keeps the same immutable
+                # object (a view of the run in, no copy out)
+                buf = result.get(shard, start, end - start).tobytes()
                 # fused-kernel csums ride the sub-write when they
                 # describe this exact range (block-aligned within the
                 # encode window) — the store adopts them instead of
@@ -1208,7 +1209,7 @@ class RMWPipeline:
                     )
                 else:
                     txn.write(op.oid, start, buf)
-                written.insert(shard, start, np.frombuffer(buf, np.uint8))
+                written.insert(shard, start, buf)
             self._stamp_identity(
                 txn, op.oid, shard, new_size,
                 (self.epoch, op.tid), hinfo_bytes, op.extra_attrs,

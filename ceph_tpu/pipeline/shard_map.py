@@ -45,7 +45,15 @@ class DeltaWork:
 
 
 class ShardExtentMap:
-    """shard -> sorted disjoint (offset, buffer) runs, plus codec drivers."""
+    """shard -> sorted disjoint (offset, buffer) runs, plus codec drivers.
+
+    **Ownership.** A run's buffer is never written in place once it is
+    placed: an overlapping or abutting ``insert`` builds a new merged
+    buffer, and ``_bufs`` is touched by no other module. Placed buffers
+    are marked read-only, so a violation raises instead of corrupting a
+    map that the extent cache keeps across ops. That is what lets
+    ``insert`` take a buffer without copying it and ``get`` hand out a
+    view of one."""
 
     def __init__(self, sinfo: StripeInfo) -> None:
         self.sinfo = sinfo
@@ -55,14 +63,49 @@ class ShardExtentMap:
         #: uint32[nblocks] ZERO-INIT per-block crc32c)}} — the blocks
         #: cover each shard's encode window contiguously
         self.csums: "dict | None" = None
+        #: ``(rows, stripes)`` of the whole stripes ``insert_ro_range``
+        #: last scattered from an immutable buffer: ``rows`` [k, n, chunk]
+        #: holds the data shards' runs, ``stripes`` [n, k, chunk] is that
+        #: buffer, which is the layout the kernels take. ``_stripe_major``
+        #: uses it only while the map still holds exactly those runs
+        self._whole: "tuple | None" = None
 
     # -- buffer management --------------------------------------------
+    @staticmethod
+    def _owned(data) -> np.ndarray:
+        """``data`` as a flat, read-only uint8 array the map may keep:
+        itself where nobody can write to it afterwards, else a copy."""
+        if isinstance(data, bytes):
+            return np.frombuffer(data, dtype=np.uint8)
+        arr = np.asarray(data)
+        if not (
+            arr.dtype == np.uint8
+            and arr.flags.c_contiguous
+            and (arr.base is None or not arr.flags.writeable)
+        ):
+            # a bytearray, a memoryview, a writable view: whoever holds
+            # the memory can still write to it
+            arr = np.array(
+                np.frombuffer(data, dtype=np.uint8)
+                if isinstance(data, (bytearray, memoryview)) else arr,
+                dtype=np.uint8,
+            )
+        # an array that owns its bytes changes hands here; a read-only
+        # one is immutable by its maker's word
+        arr.flags.writeable = False
+        return arr.reshape(-1)
+
     def insert(self, shard: int, offset: int, data) -> None:
         """Insert bytes at a shard offset, coalescing adjacent/overlapping
-        runs (later inserts win on overlap, matching extent_map assign)."""
-        arr = np.frombuffer(bytes(data), dtype=np.uint8).copy() \
-            if isinstance(data, (bytes, bytearray, memoryview)) \
-            else np.asarray(data, dtype=np.uint8).reshape(-1).copy()
+        runs (later inserts win on overlap, matching extent_map assign).
+
+        The map takes ``data`` over without a copy where it can: a
+        ``bytes`` object, a read-only array, or a contiguous uint8 array
+        that owns its memory, which is the caller's no longer (it turns
+        read-only; pass a copy to go on writing). Anything else is
+        copied once. Only a run that overlaps or abuts another allocates
+        a merged buffer."""
+        arr = self._owned(data)
         if arr.size == 0:
             return
         runs = self._bufs.setdefault(shard, [])
@@ -77,11 +120,16 @@ class ShardExtentMap:
                 overlapping.append((off, buf))
                 merged_start = min(merged_start, off)
                 merged_end = max(merged_end, off + buf.size)
-        out = np.zeros(merged_end - merged_start, dtype=np.uint8)
-        for off, buf in overlapping:
-            out[off - merged_start : off - merged_start + buf.size] = buf
-        out[new_start - merged_start : new_end - merged_start] = arr
-        keep.append((merged_start, out))
+        if (merged_start, merged_end) != (new_start, new_end):
+            # the touching runs and the new one cover the merged range
+            # between them, so nothing needs zeroing
+            out = np.empty(merged_end - merged_start, dtype=np.uint8)
+            for off, buf in overlapping:
+                out[off - merged_start : off - merged_start + buf.size] = buf
+            out[new_start - merged_start : new_end - merged_start] = arr
+            out.flags.writeable = False
+            arr = out
+        keep.append((merged_start, arr))
         keep.sort(key=lambda t: t[0])
         self._bufs[shard] = keep
 
@@ -95,13 +143,21 @@ class ShardExtentMap:
 
     def get(self, shard: int, offset: int, length: int) -> np.ndarray:
         """Read a range; absent bytes read as zero (the shared
-        zero-buffer convention)."""
+        zero-buffer convention). Read-only either way: a view of the
+        run that covers the range, which a later ``insert`` leaves as it
+        was, or a zero-filled array where the range has a hole. Copy it
+        to write to it."""
+        runs = self._bufs.get(shard, ())
+        for off, buf in runs:
+            if off <= offset and offset + length <= off + buf.size:
+                return buf[offset - off : offset - off + length]
         out = np.zeros(length, dtype=np.uint8)
-        for off, buf in self._bufs.get(shard, []):
+        for off, buf in runs:
             s = max(offset, off)
             e = min(offset + length, off + buf.size)
             if s < e:
                 out[s - offset : e - offset] = buf[s - off : e - off]
+        out.flags.writeable = False
         return out
 
     def _ro_pieces(self, ro_buf, run_buf: np.ndarray, run):
@@ -118,8 +174,28 @@ class ShardExtentMap:
 
     def insert_ro_range(self, ro_offset: int, data) -> None:
         """Scatter rados-object bytes at ``ro_offset`` onto the data
-        shards: one strided copy and one ``insert`` per touched shard."""
+        shards. Whole stripes go in one strided copy into one
+        [k, n_chunks, chunk] array whose rows become the shards' runs
+        (``encode`` then finds them stacked already); any other range
+        takes one strided copy and one ``insert`` per touched shard.
+        ``data`` is copied either way and stays the caller's; an
+        immutable one (``bytes``) is also kept as it is, being the
+        stripes in the layout the kernels take."""
+        fixed = isinstance(data, bytes)
         data = np.frombuffer(data, dtype=np.uint8)
+        k, cs, sw = self.sinfo.k, self.sinfo.chunk_size, self.sinfo.stripe_width
+        if data.size and ro_offset % sw == 0 and data.size % sw == 0:
+            lo, n = ro_offset // k, data.size // sw
+            stripes = data.reshape(n, k, cs)
+            rows = np.empty((k, n, cs), dtype=np.uint8)
+            rows[...] = stripes.transpose(1, 0, 2)
+            rows.flags.writeable = False  # its rows are placed as views
+            for raw in range(k):
+                self.insert(
+                    self.sinfo.get_shard(raw), lo, rows[raw].reshape(-1)
+                )
+            self._whole = (rows, stripes) if fixed else None
+            return
         for run in self.sinfo.ro_range_to_shard_runs(ro_offset, data.size):
             buf = np.empty(run.end - run.start, dtype=np.uint8)
             for src, dst in self._ro_pieces(data, buf, run):
@@ -225,6 +301,43 @@ class ShardExtentMap:
         lo, hi = self.ro_range()
         return lo, hi
 
+    def _stripe_major(self, lo: int, n_chunks: int) -> np.ndarray:
+        """The k data shards over ``n_chunks`` chunks from ``lo`` as
+        [n_chunks, k, chunk]: the stacked form the codecs' kernels take.
+        Where the map holds exactly the whole stripes that
+        ``insert_ro_range`` scattered from an immutable buffer, that
+        buffer is this array already; otherwise one strided copy per
+        shard (a hole reads zero)."""
+        k, cs = self.sinfo.k, self.sinfo.chunk_size
+        if self._whole is not None:
+            rows, stripes = self._whole
+
+            def is_row(raw: int) -> bool:
+                runs = self._bufs.get(self.sinfo.get_shard(raw), ())
+                return (
+                    len(runs) == 1
+                    and runs[0][0] == lo
+                    and runs[0][1].size == n_chunks * cs
+                    and runs[0][1].ctypes.data == rows[raw].ctypes.data
+                )
+
+            if rows.shape[1] == n_chunks and all(map(is_row, range(k))):
+                return stripes
+        out = np.empty((n_chunks, k, cs), dtype=np.uint8)
+        for raw in range(k):
+            out[:, raw, :] = self.get(
+                self.sinfo.get_shard(raw), lo, n_chunks * cs
+            ).reshape(n_chunks, cs)
+        return out
+
+    def _shard_major(self, lo: int, length: int) -> np.ndarray:
+        """The k data shards over ``[lo, lo+length)`` as [k, length],
+        the form the staging ring takes: one copy."""
+        return np.stack([
+            self.get(self.sinfo.get_shard(raw), lo, length)
+            for raw in range(self.sinfo.k)
+        ])
+
     def encode(self, codec, hashinfo: HashInfo | None = None,
                old_size: int | None = None,
                csum_block: int | None = None) -> None:
@@ -241,7 +354,14 @@ class ShardExtentMap:
         ``self.csums`` for the sub-write path to carry to the stores)
         and the HashInfo append is seeded from those kernel csums via
         crc chaining — the bytes are hashed exactly once, on device.
-        """
+
+        The data reaches a codec that takes the stacked form
+        (``encode_stacked``) once, as ``_stripe_major`` gives it: with
+        no host copy at all for whole stripes that ``insert_ro_range``
+        placed. Parity comes back as one host array per dispatch, is
+        laid out [m, n_chunks, chunk] in one strided copy, and its rows
+        become the parity runs by ownership: the data runs are read,
+        never copied or changed."""
         k, m = self.sinfo.k, self.sinfo.m
         self.csums = None
         lo0, hi0 = self._slice_window()
@@ -257,63 +377,48 @@ class ShardExtentMap:
         lo = (lo0 // cs) * cs
         hi = -(-hi0 // cs) * cs
         n_chunks = (hi - lo) // cs
-        with codec_stage("prep"):
-            data = np.stack(
-                [
-                    self.get(self.sinfo.get_shard(r), lo, hi - lo).reshape(
-                        n_chunks, cs
-                    )
-                    for r in range(k)
-                ]
-            )
         parity = csums = None
         cb = csum_block
         if (
             cb
             and cs % cb == 0
             and lo % cb == 0
-            and hasattr(codec, "encode_chunks_with_csums")
+            and hasattr(codec, "encode_stacked_with_csums")
         ):
             # Coalesced/streaming route first: the fused op stages in
             # the ring and shares ONE encode+csum dispatch with every
             # other op of the tick window (the same batching win the
             # plain encode gets below). (None, None) = the fused
             # kernel can't serve the geometry; fall through per-op.
-            staged = self._ring_encode_csum(codec, data, cs, cb)
-            if staged is not None:
-                parity2d, csums = staged
-                if parity2d is not None:
-                    parity = parity2d.reshape(m, n_chunks, cs)
-            if csums is None:
-                parity_map, csums = codec.encode_chunks_with_csums(
-                    {i: data[i] for i in range(k)}, cb
+            if self._ring_routable(codec, k * (hi - lo)):
+                parity, csums = self._ring_encode_csum(
+                    codec, lo, n_chunks, cb
                 )
-                if parity_map is not None:
-                    # the first np.asarray waits for the kernel; the
-                    # csum words come back with the parity
+            if csums is None:
+                with codec_stage("prep"):
+                    stripes = self._stripe_major(lo, n_chunks)
+                stacked, csums = codec.encode_stacked_with_csums(
+                    stripes, cb
+                )
+                if stacked is not None:
+                    # np.asarray waits for the kernel; the csum words
+                    # come back with the parity
                     with codec_stage("fetch"):
-                        parity = np.stack(
-                            [np.asarray(parity_map[k + j])
-                             for j in range(m)]
-                        )
+                        parity = self._shard_rows(np.asarray(stacked))
                         csums = np.asarray(csums)
         if parity is None:
-            parity = self._dispatch_encode(codec, data)
+            parity = self._dispatch_encode(codec, lo, n_chunks)
         for j in range(m):
-            self.insert(
-                self.sinfo.get_shard(k + j), lo, parity[j].reshape(-1)
-            )
+            self.insert(self.sinfo.get_shard(k + j), lo, parity[j])
         if csums is not None:
             # [n_chunks, k+m, cs/cb] -> per shard the window's linear
             # block sequence (chunk-major, matching the shard's byte
             # stream at offsets lo + i*cb)
-            arr = np.asarray(csums)
+            per_shard = self._shard_rows(np.asarray(csums))
             self.csums = {
                 "block": cb,
                 "shards": {
-                    self.sinfo.get_shard(raw): (
-                        lo, np.ascontiguousarray(arr[:, raw, :]).reshape(-1)
-                    )
+                    self.sinfo.get_shard(raw): (lo, per_shard[raw])
                     for raw in range(k + m)
                 },
             }
@@ -356,6 +461,19 @@ class ShardExtentMap:
                     )
 
     @staticmethod
+    def _shard_rows(stacked: np.ndarray) -> list[np.ndarray]:
+        """[n_chunks, rows, width] as a codec's kernel returns it ->
+        one flat array per row, the shard's own byte (or word) order:
+        one strided copy into a fresh [rows, n_chunks, width], whose
+        rows own nothing else and can be placed as they are."""
+        n_chunks, rows, width = stacked.shape
+        out = np.empty((rows, n_chunks, width), dtype=stacked.dtype)
+        out[...] = stacked.transpose(1, 0, 2)
+        out = out.reshape(rows, n_chunks * width)
+        out.flags.writeable = False
+        return list(out)
+
+    @staticmethod
     def _ring_routable(codec, nbytes: int) -> bool:
         """One gate for both ring routes: this thread is inside a
         coalesced OSD tick (dispatcher.coalescing_scope), whose
@@ -371,42 +489,46 @@ class ShardExtentMap:
             and nbytes <= dispatcher_for(codec).max_op_bytes
         )
 
-    @staticmethod
-    def _ring_encode_csum(codec, data, cs: int, cb: int):
-        """Stage one fused encode+csum op in the ring, or None when
-        the ring isn't routable for it. ``data`` is [k, n_chunks, cs];
-        returns ``(parity [m, L] | None, csums | None)``."""
+    def _ring_encode_csum(self, codec, lo: int, n_chunks: int, cb: int):
+        """Stage one fused encode+csum op in the ring: ``(parity
+        [m, L] | None, csums | None)``."""
         from .dispatcher import dispatcher_for
 
-        if not ShardExtentMap._ring_routable(codec, data.nbytes):
-            return None
-        k, n_chunks, _cs = data.shape
-        return dispatcher_for(codec).encode_csum_sync(
-            np.ascontiguousarray(data).reshape(k, n_chunks * cs),
-            cb, n_chunks,
-        )
+        with codec_stage("prep"):
+            flat = self._shard_major(lo, n_chunks * self.sinfo.chunk_size)
+        return dispatcher_for(codec).encode_csum_sync(flat, cb, n_chunks)
 
-    @staticmethod
-    def _dispatch_encode(codec, data: np.ndarray) -> np.ndarray:
-        """[k, L] host -> [m, L] host through the codec's dispatch.
-        Inside a coalesced OSD tick the op rides the native staging
-        ring and shares a batched device dispatch with other
+    def _dispatch_encode(self, codec, lo: int, n_chunks: int):
+        """Parity of the data shards over ``n_chunks`` chunks from
+        ``lo``, one flat host array a parity shard, through the codec's
+        dispatch. Inside a coalesced OSD tick the op rides the native
+        staging ring and shares a batched device dispatch with other
         concurrent ops (pipeline/dispatcher.py)."""
         from .dispatcher import dispatcher_for
 
-        k = data.shape[0]
-        flat = data.reshape(k, -1)
-        if ShardExtentMap._ring_routable(codec, flat.nbytes):
-            return dispatcher_for(codec).encode_sync(flat).reshape(
-                (-1,) + data.shape[1:]
-            )
-        parity = codec.encode_chunks(
-            {i: np.asarray(data[i]) for i in range(k)}
-        )
+        k, cs = self.sinfo.k, self.sinfo.chunk_size
+        if self._ring_routable(codec, k * n_chunks * cs):
+            with codec_stage("prep"):
+                flat = self._shard_major(lo, n_chunks * cs)
+            return dispatcher_for(codec).encode_sync(flat)
+        if hasattr(codec, "encode_stacked"):
+            with codec_stage("prep"):
+                stripes = self._stripe_major(lo, n_chunks)
+            stacked = codec.encode_stacked(stripes)
+            with codec_stage("fetch"):
+                return self._shard_rows(np.asarray(stacked))
+        # a codec with no stacked entry takes the runs' own views
+        parity = codec.encode_chunks({
+            raw: self.get(
+                self.sinfo.get_shard(raw), lo, n_chunks * cs
+            ).reshape(n_chunks, cs)
+            for raw in range(k)
+        })
         with codec_stage("fetch"):
-            return np.stack(
-                [np.asarray(parity[k + j]) for j in range(len(parity))]
-            )
+            return [
+                np.asarray(parity[k + j]).reshape(-1)
+                for j in range(len(parity))
+            ]
 
     def encode_parity_delta(
         self, codec, old_map: "ShardExtentMap"
@@ -554,6 +676,7 @@ class ShardExtentMap:
             for u, page in enumerate(work.at):
                 paged[:, page] ^= contribs[u]
             contribs = work.parity
+            contribs.flags.writeable = False  # its rows are placed as views
         for j in range(m):
             self.insert(
                 self.sinfo.get_shard(k + j), work.lo,
@@ -634,10 +757,8 @@ class ShardExtentMap:
         n_chunks = (hi - lo) // cs
         with codec_stage("prep"):
             chunks = {
-                raw: np.asarray(
-                    self.get(sinfo.get_shard(raw), lo, hi - lo).reshape(
-                        n_chunks, cs
-                    )
+                raw: self.get(sinfo.get_shard(raw), lo, hi - lo).reshape(
+                    n_chunks, cs
                 )
                 for raw in present_raw
             }
