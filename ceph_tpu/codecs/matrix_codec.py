@@ -180,6 +180,41 @@ def delta_batch_sizes() -> tuple[int, ...]:
 #: already compiled in this process
 _delta_warmed: set[tuple] = set()
 
+#: stripes in the largest batched dispatch (``encode_batch``: the
+#: staging ring's): a 4 MiB object's worth at k=8, chunk 4096, so the
+#: top program is the one a whole 4 MiB write compiles anyway
+BATCH_MAX_STRIPES = 128
+#: the smallest compiled batch, and the step from one size to the next
+_BATCH_MIN_STRIPES, _BATCH_STEP = 2, 4
+
+
+def batch_sizes() -> tuple[int, ...]:
+    """Every stripe count a batched dispatch can have on the device: a
+    batch is zero-padded up to the next of 2, 8, 32, 128 (a zero stripe
+    encodes to zero parity, and its rows are dropped), the largest
+    being ``BATCH_MAX_STRIPES``; more than that is more than one
+    dispatch. Steps of four, not two: the device's share of a dispatch
+    is microseconds at any of these sizes and a zero stripe costs the
+    host a memset, while each size is a Mosaic compilation that the
+    pool's first batch waits for (``_batch_warm``: 11 s cold for the
+    four, PERF.md section 5, against a client's 15 s op timeout).
+    A fixed, small set, so that traffic cannot meet a new shape in the
+    middle of a window."""
+    sizes = [min(_BATCH_MIN_STRIPES, BATCH_MAX_STRIPES)]
+    while sizes[-1] < BATCH_MAX_STRIPES:
+        sizes.append(min(sizes[-1] * _BATCH_STEP, BATCH_MAX_STRIPES))
+    return tuple(sizes)
+
+
+def padded_size(stripes: int) -> int:
+    """The size of ``batch_sizes()`` that ``stripes`` ride as."""
+    return next(p for p in batch_sizes() if p >= stripes)
+
+
+#: (encode bit-matrix, chunk length, csum block) of the fused batch
+#: programs already compiled in this process
+_batch_warmed: set[tuple] = set()
+
 
 def _upload(x):
     """``codec.h2d``: host data onto the device, where the jitted call
@@ -630,22 +665,32 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
         tables, a device array otherwise; one fetch for the caller).
         The stacked tiers take the array as it is, with no unstack and
         no second stack."""
-        mat = self.generator[self.k :, :]
-        mat01 = as_01_matrix(mat)
-        route = self._route_stacked(
-            stacked,
-            shard_shape=stacked.shape[:-2] + stacked.shape[-1:],
-            host_tables=True,
-            mat01=mat01,
+        return self._encode_routed(stacked, self._route_plain(
+            stacked.shape, isinstance(stacked, np.ndarray),
+            int(stacked.size) * stacked.dtype.itemsize,
+        ))
+
+    def _route_plain(self, shape, host_staged: bool, nbytes: int):
+        """The planner's answer for a plain encode of the stacked
+        [..., k, N] form."""
+        return self._route(
+            shape, host_staged, nbytes, host_tables=True,
+            shard_shape=tuple(shape[:-2]) + tuple(shape[-1:]),
+            mat01=as_01_matrix(self.generator[self.k :, :]),
         )
+
+    def _encode_routed(self, stacked, route, nbytes: int | None = None):
+        """A plain encode of ``stacked`` on the route the planner
+        named."""
         if route in ("mesh", "pallas", "einsum"):
             return self._dispatch_bitmatrix(
                 self._encode_bmat_np, self._encode_bmat, stacked,
-                "encode", route=route,
+                "encode", nbytes=nbytes, route=route,
             )
         # the host tables and the shards-form kernels take shard operands
         return self._stack(self._apply_byte_matrix(
-            mat, [stacked[..., i, :] for i in range(self.k)],
+            self.generator[self.k :, :],
+            [stacked[..., i, :] for i in range(self.k)],
             "encode", None, route,
         ))
 
@@ -697,11 +742,121 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
             return None, None
         return self._run_fused(stacked, csum_block)
 
-    def _run_fused(self, stacked, csum_block: int):
-        """The ``fused`` route on the stacked [..., k, N] form."""
+    def encode_batch(
+        self, members: list, csum_block: int = 0
+    ) -> tuple[np.ndarray, "np.ndarray | None", int]:
+        """A coalesced tick's encodes as ONE dispatch (the staging
+        ring's entry, ``pipeline/dispatcher.py``): ``members`` are
+        host arrays [n_i, k, N], stripe-major (an object's own
+        layout), at most ``BATCH_MAX_STRIPES`` stripes together.
+        Returns ``(parity [sum n_i, m, N], csums [sum n_i, k+m, N //
+        csum_block] | None, stripes sent)``, numpy: ``csums`` where
+        ``csum_block`` asks and the fused kernel serves it (None sends
+        the caller to its host checksums, the parity is there either
+        way).
+
+        One decision for the whole batch, from its real bytes, by the
+        one planner. The host tables (a plain encode within
+        ``ec_host_dispatch_bytes``) take the members run together,
+        stripes sent = the real ones. A device route: the members are
+        copied ONCE into a stack of the next of ``batch_sizes()``,
+        only the pad rows zeroed (a lone member of such a size is the
+        stack); the route counts the real bytes, not the padding. On
+        the chip the first fused batch of a geometry compiles every
+        size of the set (``_batch_warm``), so traffic never meets a
+        new shape in the middle of a run."""
+        total = sum(a.shape[0] for a in members)
+        k, n = members[0].shape[-2:]
+        shape, nbytes = (total, k, n), total * k * n
+        route = None
+        if csum_block:
+            route = self._route(shape, True, nbytes, csum_block=csum_block)
+        if route == "fused":
+            self._batch_warm(n, csum_block)
+        else:
+            # no checksums from this pass (or a mesh owns the shape):
+            # a plain encode, asked as one
+            route = self._route_plain(shape, True, nbytes)
+            if route == "host":
+                out = self._encode_routed(
+                    members[0] if len(members) == 1
+                    else np.concatenate(members),
+                    route,
+                )
+                return out, None, total
+        padded = padded_size(total)
+        parity, csums = self._batch_device(
+            members, padded, route, csum_block, nbytes
+        )
+        return parity[:total], (
+            None if csums is None else csums[:total]
+        ), padded
+
+    def _batch_device(
+        self, members: list, padded: int, route: str, csum_block: int,
+        nbytes: int,
+    ):
+        """One device dispatch of ``members`` stacked to ``padded``
+        stripes on ``route``, fetched: numpy ``(parity, csums |
+        None)`` of the padded stack."""
+        with codec_stage("prep"):
+            if len(members) == 1 and members[0].shape[0] == padded:
+                stack = members[0]
+            else:
+                stack = np.empty(
+                    (padded,) + members[0].shape[1:], np.uint8
+                )
+                at = 0
+                for a in members:
+                    stack[at : at + a.shape[0]] = a
+                    at += a.shape[0]
+                stack[at:] = 0
+        csums = None
+        if route == "fused":
+            parity, csums = self._run_fused(stack, csum_block, nbytes)
+        else:
+            parity = self._encode_routed(stack, route, nbytes)
+        with codec_stage("fetch"):
+            return np.asarray(parity), (
+                None if csums is None else np.asarray(csums)
+            )
+
+    def _batch_warm(self, n: int, csum_block: int) -> None:
+        """On the chip, compile the fused batch program of every size
+        for this geometry, side by side, while the geometry's first
+        batch waits for all of them (so no op of the pool completes,
+        and no warm-up can end, before they exist): once a process for
+        a given matrix (codec objects are rebuilt on every map change;
+        the compiled programs are not). Off the chip nothing is
+        measured, a window is a test's few seconds and the
+        interpreter's compilations are dear: a size compiles when it
+        is first met."""
+        if not platform.on_tpu():
+            return
+        key = (self._encode_bmat_np.tobytes(), n, csum_block)
+        if key in _batch_warmed:
+            return
+        _batch_warmed.add(key)
+        from concurrent.futures import ThreadPoolExecutor
+
+        def compile_one(padded: int) -> None:
+            self._batch_device(
+                [np.zeros((padded, self.k, n), np.uint8)], padded,
+                "fused", csum_block, 0,
+            )
+
+        with ThreadPoolExecutor(len(batch_sizes())) as pool:
+            list(pool.map(compile_one, batch_sizes()))
+
+    def _run_fused(
+        self, stacked, csum_block: int, nbytes: int | None = None
+    ):
+        """The ``fused`` route on the stacked [..., k, N] form;
+        ``nbytes``: what it counts as input bytes where ``stacked``
+        carries padding."""
         from ceph_tpu.ops import pallas_encode as pe
 
-        count_route("fused_encode", stacked)
+        count_route("fused_encode", stacked, nbytes=nbytes)
         lead, (c, n) = stacked.shape[:-2], stacked.shape[-2:]
         flat = _upload(stacked.reshape((-1, c, n)))
         with codec_stage("launch"):

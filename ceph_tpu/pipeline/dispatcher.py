@@ -1,6 +1,6 @@
-"""Streaming dispatcher: the native staging ring feeding batched
-device dispatches — SURVEY.md §7 step 4 assembled (host ring ->
-staging -> batched device dispatch -> completion callbacks).
+"""Streaming dispatcher: the staging ring feeding batched device
+dispatches — SURVEY.md §7 step 4 assembled (host ring -> staging ->
+batched device dispatch -> completion callbacks).
 
 The role it fills is the reference's sharded op queues
 (osd/OSD.cc:9874-9933): many client ops across many PGs land on a
@@ -12,18 +12,24 @@ it per 4-64 KiB write.
 Shape of the machinery:
 
 - producers (OSD daemons, RMW pipelines, any thread) ``submit()``
-  ops into the native MPMC ring (native/src/ceph_tpu_native.cc,
-  ``ctpu_ring_*``) as header+payload slots; the ring is the
-  bounded staging tier — backpressure is a blocking push;
-- ONE dispatcher thread drains the ring: it blocks for the first op,
-  then keeps popping until the ring is momentarily empty past the
-  batching window or ``max_batch`` is reached;
-- ops group by (k, chunk_len) signature; each group stacks into one
-  [B, k, L] batch, encodes through the codec's normal dispatch
-  (device kernel / mesh / einsum — the codec router decides), and
-  completion callbacks fire with each op's parity rows;
-- ``encode_sync`` is the synchronous facade for pipeline callers:
-  submit + wait, with concurrency across threads supplying the batch.
+  ops into the ring, a bounded queue of ``_RingOp``: the stripes stay
+  where the submitter has them ([n, k, chunk], an object's own
+  layout; one process, and nobody writes a placed run), so the one
+  copy an op pays is into its batch's stack. The ring is the bounded
+  staging tier — backpressure is a blocking ``submit``;
+- ONE dispatcher thread drains the ring: it blocks for the first op
+  and takes every op queued with it (up to ``max_batch``) under one
+  hold of the ring's lock;
+- ops group by (k, chunk, csum block); each group is one
+  ``codec.encode_batch``: ONE route decision from the batch's real
+  bytes by the codec's planner (a device route stacks the members
+  once, padded to the next of ``matrix_codec.batch_sizes()``, a
+  bounded set of shapes that a geometry's first fused batch compiles
+  on the chip), and completion callbacks fire with each op's parity
+  and csum rows;
+- ``encode_sync`` / ``encode_csum_sync`` are the synchronous facade
+  for pipeline callers: submit + wait, with concurrency across
+  threads supplying the batch.
 
 The round-10 serving tier adds three seams:
 
@@ -33,14 +39,18 @@ The round-10 serving tier adds three seams:
   through the ring, so concurrent groups of one tick share batched
   device dispatches;
 - fused encode+csum ops stage through the SAME ring (``submit`` with
-  ``csum_block``): a fused group stacks every member's chunks into
-  one ``encode_stacked_with_csums`` dispatch — the whole coalesced
-  tick pays one HBM pass for data, parity AND block csums;
+  ``csum_block``): the whole coalesced tick pays one pass for data,
+  parity AND block csums;
 - per-op error isolation: a failed MULTI-op batch no longer fails
   every member — each op retries SOLO through the codec, and only
   the op that actually faults surfaces its error (``solo_retries`` /
   ``batch_faults`` counters). One poisoned op cannot sink its
   batch-mates.
+
+Spans: ``ring_wait`` (recorded across threads: an op's submit to the
+firing of the batch that carries it, a child of the stage that
+submitted it), ``ring.fire`` on the ``ec-stream`` thread with the
+codec's ``codec.*`` steps under it, ``ring.deliver`` (the callbacks).
 
 PR 26 adds the parity-delta seam, for small overwrites whose delta is
 a page or two and can only reach the device in company:
@@ -63,7 +73,12 @@ the ring merged the ticks of two OSDs in 7 of 673 batches (PERF.md
 Counters (``perf dump`` section ``ec_stream``): ops, batches,
 batched_ops (ops that shared a dispatch), plus a max-batch gauge,
 batch_faults (multi-op dispatches that failed and split), and
-solo_retries (ops that recovered via solo fallback); for deltas,
+solo_retries (ops that recovered via solo fallback);
+ring_wait_seconds, fire_seconds, deliver_seconds (the spans' timers);
+fused_batches, fused_batch_ops, fused_batch_stripes and
+fused_pad_stripes (batches that asked for csums, the ops and real
+stripes they carried, the zero stripes a device route added); for
+deltas,
 delta_batches (codec calls), delta_batch_ops and delta_batch_units
 (ops and real delta pages they carried) and delta_pad_units (zero
 pages added to reach a compiled size).
@@ -74,19 +89,19 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import struct
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from collections.abc import Callable
 
 import numpy as np
 from ceph_tpu.utils import lockdep
-from ceph_tpu.utils.lockdep import DebugLock
+from ceph_tpu.utils.lockdep import DebugLock, DebugRLock
+from ceph_tpu.utils.trace import tracer
 
-#: slot header: op id, k, chunk count, chunk size, csum block
-#: (csum block 0 = plain encode; then the payload is [k, n*cs] flat)
-_HDR = struct.Struct("<QHHII")
+#: an op of more bytes is no small op: it takes the per-op path
+#: (``ShardExtentMap._ring_routable``) and ``submit`` refuses it
+MAX_OP_BYTES = 256 << 10
 
 
 @functools.lru_cache(maxsize=1)
@@ -109,6 +124,25 @@ def _stream_counters():
     b.add_u64_counter(
         "solo_retries", "ops recovered via solo fallback after a "
         "batch fault"
+    )
+    b.add_time(
+        "ring_wait_seconds",
+        "ring_wait: an op's submit to the firing of the batch that "
+        "carries it, summed over ops",
+    )
+    b.add_time("fire_seconds", "ring.fire: the batches of one drain")
+    b.add_time("deliver_seconds", "ring.deliver: their callbacks")
+    b.add_u64_counter(
+        "fused_batches", "codec batches that asked for parity and csums"
+    )
+    b.add_u64_counter("fused_batch_ops", "ops those batches carried")
+    b.add_u64_counter(
+        "fused_batch_stripes", "real stripes those batches carried"
+    )
+    b.add_u64_counter(
+        "fused_pad_stripes",
+        "zero stripes added to reach a compiled batch size (device "
+        "route)",
     )
     b.add_u64_counter(
         "delta_batches", "parity-delta batches handed to the codec"
@@ -155,13 +189,8 @@ def current_tick() -> "DeltaTick | None":
 
 
 def coalescing_active() -> bool:
-    """True on a thread currently inside ``coalescing_scope`` (with
-    the native ring present to stage into)."""
-    if getattr(_coal_tls, "depth", 0) <= 0:
-        return False
-    from ceph_tpu import native
-
-    return native.available()
+    """True on a thread currently inside ``coalescing_scope``."""
+    return getattr(_coal_tls, "depth", 0) > 0
 
 
 # ------------------------------------------------------------ parity delta
@@ -315,108 +344,131 @@ class DeltaTick:
                 e.resume(e.result)
 
 
+@dataclasses.dataclass
+class _RingOp:
+    """One encode staged in the ring. The stripes stay where the
+    submitter has them (one process, and a placed run is never written
+    in place: ``ShardExtentMap``), so the only copy is the one into
+    the batch's stack."""
+
+    callback: Callable
+    #: [n, k, N] host array, stripe-major
+    stripes: np.ndarray
+    #: 0 = parity only
+    csum_block: int
+    t_submit: float
+    #: (trace id, span id) of the stage that submitted it
+    trace: tuple
+    #: its callback has been called
+    answered: bool = False
+
+    def answer(self, result) -> None:
+        """Call the callback, once; what it raises is logged."""
+        if self.answered:
+            return
+        self.answered = True
+        try:
+            self.callback(result)
+        except Exception:
+            from ceph_tpu.utils.log import get_logger
+
+            get_logger("ec-stream").error("completion callback raised")
+
+
+def _slices(members: "list[_RingOp]", cap: int):
+    """``members`` in order, cut wherever the next would take a batch
+    over ``cap`` stripes."""
+    batch, held = [], 0
+    for op in members:
+        n = op.stripes.shape[0]
+        if batch and held + n > cap:
+            yield batch
+            batch, held = [], 0
+        batch.append(op)
+        held += n
+    if batch:
+        yield batch
+
+
 class StreamingDispatcher:
     """Aggregates concurrent small encodes into batched dispatches."""
 
     def __init__(
-        self,
-        codec,
-        *,
-        capacity: int = 128,
-        slot_bytes: int = (256 << 10) + _HDR.size,
-        max_batch: int = 128,
-        window_s: float = 0.0005,
+        self, codec, *, capacity: int = 128, max_batch: int = 128
     ) -> None:
-        # Defaults size the ring for its small-op mission (the native
-        # ring allocates capacity*slot_bytes EAGERLY — 32 MiB here,
-        # not the 512 MiB a 1 MiB slot would pin); oversized ops take
-        # the per-op path (see max_op_bytes / shard_map routing).
-        from ceph_tpu.native import RingBuffer
-
+        # The ring is the bounded staging tier: at ``capacity`` ops
+        # ``submit`` blocks (backpressure), and the drain thread takes
+        # what is queued, up to ``max_batch``, at once.
         self.codec = codec
+        self.capacity = capacity
         self.max_batch = max_batch
-        self.window_s = window_s
-        self._ring = RingBuffer(capacity, slot_bytes)
-        self._slot_payload = slot_bytes - _HDR.size
-        self._lock = DebugLock("dispatcher.ring")
-        self._next_id = 0
-        #: op id -> (callback, k, chunk_len)
-        self._pending: dict[int, tuple[Callable, int, int]] = {}
+        self._ring: deque[_RingOp] = deque()
+        self._cv = threading.Condition(DebugRLock("dispatcher.ring"))
         self._closed = False
         self._thread = threading.Thread(
             target=self._drain_loop, name="ec-stream", daemon=True
         )
         self._thread.start()
 
-    @property
-    def max_op_bytes(self) -> int:
-        """Largest [k, L] payload one slot can stage."""
-        return self._slot_payload
-
     # -- producer side --------------------------------------------------
     def submit(
         self,
-        data: np.ndarray,
-        callback: Callable[[np.ndarray], None],
+        stripes: np.ndarray,
+        callback: Callable,
         csum_block: int = 0,
-        n_chunks: int = 1,
-    ) -> int:
-        """Queue one encode of ``data`` [k, L] uint8; ``callback``
-        fires (dispatcher thread) with the parity [m, L].
+    ) -> None:
+        """Queue one encode of ``stripes`` [n, k, N] uint8 (stripe-
+        major: a whole-stripe object's own layout, so a client's
+        buffer goes in as it is); it is read, never written, and must
+        not change until ``callback`` has fired. ``callback`` fires on
+        the dispatcher thread with ``(parity [n, m, N], csums)``, or
+        with the exception that took their place.
 
-        With ``csum_block`` > 0 the op is a FUSED encode+csum: ``L``
-        is ``n_chunks * chunk_size`` (chunk-major per shard) and the
-        callback receives ``(parity [m, L], csums [n_chunks, k+m,
-        cs/cb])`` — or ``(None, None)`` when no fused kernel route
-        serves the geometry (callers keep their per-op fallback)."""
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        if data.ndim != 2:
-            raise ValueError(f"want [k, L], got {data.shape}")
-        k, ln = data.shape
-        if k * ln > self._slot_payload:
+        With ``csum_block`` > 0 the op asks for a FUSED encode+csum:
+        ``csums`` is [n, k+m, N / csum_block] zero-init crc32c words,
+        or None where no fused pass serves the geometry (the parity is
+        there either way; callers keep their host checksums)."""
+        from ceph_tpu.codecs.matrix_codec import BATCH_MAX_STRIPES
+
+        if stripes.ndim != 3 or stripes.dtype != np.uint8:
+            raise ValueError(f"want uint8 [n, k, N], got {stripes.shape}")
+        if (
+            stripes.nbytes > MAX_OP_BYTES
+            or stripes.shape[0] > BATCH_MAX_STRIPES
+        ):
             raise ValueError(
-                f"op {k}x{ln} exceeds slot payload {self._slot_payload}"
+                f"op {stripes.shape} exceeds the ring's "
+                f"{MAX_OP_BYTES} bytes / {BATCH_MAX_STRIPES} stripes"
             )
-        if ln % max(n_chunks, 1):
-            raise ValueError(f"L={ln} not divisible into {n_chunks}")
-        with self._lock:
-            if self._closed:
-                raise RuntimeError("dispatcher stopped")
-            op_id = self._next_id
-            self._next_id += 1
-            self._pending[op_id] = (callback, k, ln)
-        slot = (
-            _HDR.pack(op_id, k, n_chunks, ln // max(n_chunks, 1),
-                      csum_block)
-            + data.tobytes()
+        if csum_block and stripes.shape[2] % csum_block:
+            raise ValueError(
+                f"chunk {stripes.shape[2]} not whole blocks of {csum_block}"
+            )
+        op = _RingOp(
+            callback, stripes, csum_block, time.perf_counter(),
+            tracer.current(),
         )
-        if not self._ring.push(slot, blocking=True):
-            # the ring refused the slot (closed by a concurrent
-            # stop()): fail loudly — a silent drop would wedge the
-            # encode_sync waiter forever
-            with self._lock:
-                self._pending.pop(op_id, None)
-            raise RuntimeError("dispatcher stopped")
+        with self._cv:
+            self._cv.wait_for(
+                lambda: self._closed or len(self._ring) < self.capacity
+            )
+            if self._closed:
+                # fail loudly: a silent drop would wedge the
+                # encode_sync waiter forever
+                raise RuntimeError("dispatcher stopped")
+            self._ring.append(op)
+            self._cv.notify_all()
         _stream_counters().inc("ops")
-        return op_id
 
-    def encode_sync(self, data: np.ndarray) -> np.ndarray:
-        """Submit + wait; the batch forms from OTHER threads' ops
-        arriving inside the window. A codec failure for the batch
-        re-raises here (the callback receives the exception)."""
-        out = self._submit_wait(data, 0, 1)
-        return out
+    def encode_sync(self, stripes: np.ndarray) -> np.ndarray:
+        """Submit + wait: parity [n, m, N]. The batch forms from OTHER
+        threads' ops queued with this one. A codec failure for the
+        batch re-raises here (the callback receives the exception)."""
+        return self.encode_csum_sync(stripes, 0)[0]
 
-    def encode_csum_sync(
-        self, data: np.ndarray, csum_block: int, n_chunks: int
-    ):
-        """Fused submit + wait: ``data`` [k, n_chunks*cs] chunk-major;
-        returns ``(parity [m, L], csums [n_chunks, k+m, cs/cb])`` or
-        ``(None, None)`` when the fused kernel can't serve the
-        geometry."""
-        return self._submit_wait(data, csum_block, n_chunks)
-
-    def _submit_wait(self, data, csum_block, n_chunks):
+    def encode_csum_sync(self, stripes: np.ndarray, csum_block: int):
+        """Fused submit + wait: ``(parity [n, m, N], csums [n, k+m,
+        N / csum_block] | None)``."""
         ev = threading.Event()
         out: list = []
 
@@ -424,7 +476,7 @@ class StreamingDispatcher:
             out.append(result)
             ev.set()
 
-        self.submit(data, cb, csum_block=csum_block, n_chunks=n_chunks)
+        self.submit(stripes, cb, csum_block=csum_block)
         # lockdep checkpoint: waiting out a batched device dispatch is
         # a blocking call (the "dispatcher.submit_wait" waiver covers
         # the op path's own encode work)
@@ -437,207 +489,122 @@ class StreamingDispatcher:
     # -- dispatcher thread ----------------------------------------------
     def _drain_loop(self) -> None:
         while True:
-            first = self._ring.pop(blocking=True)
-            if first is None:  # closed and drained
-                return
-            ops = [first]
-            # Self-clocking batch assembly (deadline + occupancy
-            # hybrid, round 4): drain whatever is ALREADY queued, then
-            # fire the moment the ring runs empty — waiting out the
-            # window only added latency, because the next batch forms
-            # naturally from the backlog that accumulates while THIS
-            # dispatch is on the device (arrival rate x service time).
-            # The window now only bounds a torn burst: producers
-            # observed mid-enqueue get one short grace period instead
-            # of a full window.
-            deadline = time.monotonic() + self.window_s
-            grace_used = False
-            while len(ops) < self.max_batch:
-                nxt = self._ring.pop(blocking=False)
-                if nxt is not None:
-                    ops.append(nxt)
-                    continue
-                if grace_used or time.monotonic() >= deadline:
-                    break
-                grace_used = True
-                time.sleep(0.00005)
+            # Self-clocking batch assembly: block for the first op,
+            # take whatever is ALREADY queued with it, and fire — the
+            # next batch forms from the backlog that accumulates while
+            # THIS one is being served (arrival rate x service time).
+            with self._cv:
+                self._cv.wait_for(lambda: self._ring or self._closed)
+                if not self._ring:  # closed and drained
+                    return
+                ops = [
+                    self._ring.popleft()
+                    for _ in range(min(len(self._ring), self.max_batch))
+                ]
+                self._cv.notify_all()
             try:
                 self._fire(ops)
-            except Exception:
+            except Exception as e:
                 # The drain thread must survive ANYTHING — a dead
                 # drain wedges every producer on the full ring. _fire
                 # already routes per-group failures to callbacks; this
-                # catches bookkeeping bugs.
+                # catches bookkeeping bugs, and nobody waits for ever.
                 from ceph_tpu.utils.log import get_logger
 
                 get_logger("ec-stream").error(
                     "drain iteration failed; continuing"
                 )
+                for op in ops:
+                    op.answer(e)
 
-    def _fire(self, slots: list[bytes]) -> None:
+    def _fire(self, ops: "list[_RingOp]") -> None:
+        """One drain of the ring: its ops grouped by geometry (k,
+        chunk, csum block), each group one codec batch (more where it
+        holds over ``BATCH_MAX_STRIPES`` stripes), then the callbacks."""
+        from ceph_tpu.codecs.matrix_codec import BATCH_MAX_STRIPES
+
         pc = _stream_counters()
-        #: plain encodes group by flat shape; fused group by chunk
-        #: geometry + csum block (members stack on the chunk axis)
-        plain: dict[tuple[int, int], list[tuple[int, np.ndarray]]] = (
-            defaultdict(list)
-        )
-        fused: dict[
-            tuple[int, int, int], list[tuple[int, int, np.ndarray]]
-        ] = defaultdict(list)
-        for raw in slots:
-            op_id, k, nc, cs, cb = _HDR.unpack_from(raw)
-            ln = nc * cs
-            payload = np.frombuffer(
-                raw, np.uint8, count=k * ln, offset=_HDR.size
-            ).reshape(k, ln)
-            if cb:
-                fused[(k, cs, cb)].append((op_id, nc, payload))
-            else:
-                plain[(k, ln)].append((op_id, payload))
-        for (k, ln), members in plain.items():
-            results = self._fire_plain(pc, k, members)
-            self._deliver(members, results)
-        for (k, cs, cb), fmembers in fused.items():
-            results = self._fire_fused(pc, k, cs, cb, fmembers)
-            self._deliver(fmembers, results)
-
-    def _fire_plain(self, pc, k, members) -> list:
-        try:
-            stacked = np.stack([p for _, p in members])  # [B, k, L]
-            parity = self.codec.encode_chunks(
-                {i: stacked[:, i, :] for i in range(k)}
-            )
-            m = len(parity)
-            out = np.stack(
-                [np.asarray(parity[k + j]) for j in range(m)],
-                axis=1,
-            )  # [B, m, L]
-            results: list = [out[i] for i in range(len(members))]
-            pc.inc("batches")
-            if len(members) > 1:
-                pc.inc("batched_ops", len(members))
-            if len(members) > pc.get("max_batch"):
-                pc.set("max_batch", len(members))
-            return results
-        except Exception as e:
-            return self._solo_fallback(
-                pc, members, e,
-                lambda payload: self._encode_one(k, payload),
-            )
-
-    def _encode_one(self, k: int, payload: np.ndarray) -> np.ndarray:
-        parity = self.codec.encode_chunks(
-            {i: payload[None, i, :] for i in range(k)}
-        )
-        return np.stack(
-            [np.asarray(parity[k + j])[0] for j in range(len(parity))]
-        )
-
-    def _fire_fused(self, pc, k, cs, cb, members) -> list:
-        """One fused encode+csum dispatch for the whole group: every
-        member's chunks stack on the batch axis, so the coalesced
-        tick's data, parity and block csums are one HBM pass. A
-        ``(None, None)`` kernel answer (geometry unservable) is a
-        clean per-member result — callers fall back per-op."""
-
-        def one(payload: np.ndarray):
-            nc = payload.shape[1] // cs
-            parity, csums = self.codec.encode_stacked_with_csums(
-                np.ascontiguousarray(
-                    payload.reshape(k, nc, cs).transpose(1, 0, 2)
-                ),
-                cb,
-            )
-            if parity is None:
-                return (None, None)
-            out = np.asarray(parity)  # [nc, m, cs]
-            return (
-                out.transpose(1, 0, 2).reshape(-1, nc * cs),
-                np.asarray(csums),
-            )
-
-        try:
-            counts = [nc for _, nc, _ in members]
-            total = sum(counts)
-            # The fused kernel is jitted on shape: every new sum(nc)
-            # would be a fresh Mosaic compile in the middle of a tick.
-            # Pad the stripe batch to the next power of two — a zero
-            # stripe encodes to zero parity (ZERO_INPUT_ZERO_OUTPUT)
-            # and its rows are never delivered — so a tick's batch
-            # sizes hit at most log2(max) compiled programs.
-            padded = 1 << (total - 1).bit_length()
-            stacked = np.zeros((padded, k, cs), np.uint8)
-            pos = 0
-            for _, nc, p in members:
-                stacked[pos : pos + nc] = (
-                    p.reshape(k, nc, cs).transpose(1, 0, 2)
+        groups: dict[tuple, list[_RingOp]] = defaultdict(list)
+        for op in ops:
+            groups[op.stripes.shape[1:] + (op.csum_block,)].append(op)
+        done: list[tuple[_RingOp, object]] = []
+        with tracer.span(
+            "ring.fire", perf=pc, key="fire_seconds", ops=len(ops)
+        ):
+            now = time.perf_counter()
+            for op in ops:
+                tracer.record(
+                    "ring_wait", op.t_submit, max(now, op.t_submit),
+                    trace_id=op.trace[0], parent_id=op.trace[1],
+                    perf=pc, key="ring_wait_seconds",
                 )
-                pos += nc
-            parity, csums = self.codec.encode_stacked_with_csums(
-                stacked, cb
-            )
-            if parity is None:
-                return [(None, None)] * len(members)
-            out = np.asarray(parity)  # [padded, m, cs]
-            m = out.shape[1]
-            csums = np.asarray(csums)
-            results: list = []
-            pos = 0
-            for nc in counts:
-                sl = out[pos : pos + nc]  # [nc, m, cs]
-                results.append((
-                    sl.transpose(1, 0, 2).reshape(m, nc * cs),
-                    csums[pos : pos + nc],
-                ))
-                pos += nc
-            pc.inc("batches")
-            if len(members) > 1:
-                pc.inc("batched_ops", len(members))
-            if len(members) > pc.get("max_batch"):
-                pc.set("max_batch", len(members))
-            return results
-        except Exception as e:
-            return self._solo_fallback(
-                pc, members, e, lambda payload: one(payload)
-            )
+            for members in groups.values():
+                for batch in _slices(members, BATCH_MAX_STRIPES):
+                    done.extend(zip(batch, self._encode(pc, batch)))
+        with tracer.span(
+            "ring.deliver", perf=pc, key="deliver_seconds", ops=len(ops)
+        ):
+            for op, result in done:
+                op.answer(result)
 
-    def _solo_fallback(self, pc, members, batch_err, one) -> list:
-        """Per-op error isolation: a failed MULTI-op dispatch retries
+    def _encode(self, pc, members: "list[_RingOp]") -> list:
+        """One codec batch: each member's ``(parity, csums | None)``,
+        views of the batch's arrays. A failed MULTI-op batch retries
         each member solo so one poisoned op cannot fail its
         batch-mates; a solo failure delivers the error to that member
         alone (a waiting encode_sync re-raises it; nobody hangs)."""
-        if len(members) == 1:
-            return [batch_err]
-        pc.inc("batch_faults")
-        results: list = []
-        for member in members:
-            payload = member[-1]
-            try:
-                results.append(one(payload))
-                pc.inc("solo_retries")
-            except Exception as solo_err:
-                results.append(solo_err)
-        return results
-
-    def _deliver(self, members, results) -> None:
-        for idx, member in enumerate(members):
-            op_id = member[0]
-            with self._lock:
-                cb, _, _ = self._pending.pop(op_id)
-            try:
-                cb(results[idx])
-            except Exception:
-                from ceph_tpu.utils.log import get_logger
-
-                get_logger("ec-stream").error(
-                    "completion callback raised for op", op_id
+        counts = [op.stripes.shape[0] for op in members]
+        total, cb = sum(counts), members[0].csum_block
+        arrays = [op.stripes for op in members]
+        try:
+            if hasattr(self.codec, "encode_batch"):
+                # one route decision, one copy a member, a bounded set
+                # of padded shapes (MatrixErasureCodec.encode_batch)
+                parity, csums, sent = self.codec.encode_batch(arrays, cb)
+            else:
+                stack = arrays[0] if len(arrays) == 1 else (
+                    np.concatenate(arrays)
                 )
+                k = stack.shape[1]
+                out = self.codec.encode_chunks(
+                    {i: stack[:, i, :] for i in range(k)}
+                )
+                parity = np.stack(
+                    [np.asarray(out[k + j]) for j in range(len(out))],
+                    axis=1,
+                )
+                csums, sent = None, total
+        except Exception as e:
+            if len(members) == 1:
+                return [e]
+            pc.inc("batch_faults")
+            results = []
+            for op in members:
+                results.extend(self._encode(pc, [op]))
+                if not isinstance(results[-1], BaseException):
+                    pc.inc("solo_retries")
+            return results
+        pc.inc("batches")
+        if cb:
+            pc.inc("fused_batches")
+            pc.inc("fused_batch_ops", len(members))
+            pc.inc("fused_batch_stripes", total)
+            pc.inc("fused_pad_stripes", sent - total)
+        if len(members) > 1:
+            pc.inc("batched_ops", len(members))
+        if len(members) > pc.get("max_batch"):
+            pc.set("max_batch", len(members))
+        ends = np.cumsum(counts)
+        return [
+            (parity[e - n : e], None if csums is None else csums[e - n : e])
+            for n, e in zip(counts, ends)
+        ]
 
     # -- lifecycle -------------------------------------------------------
     def stop(self) -> None:
-        with self._lock:
+        with self._cv:
             self._closed = True
-        self._ring.close()
+            self._cv.notify_all()
         self._thread.join(timeout=5)
 
 

@@ -330,14 +330,6 @@ class ShardExtentMap:
             ).reshape(n_chunks, cs)
         return out
 
-    def _shard_major(self, lo: int, length: int) -> np.ndarray:
-        """The k data shards over ``[lo, lo+length)`` as [k, length],
-        the form the staging ring takes: one copy."""
-        return np.stack([
-            self.get(self.sinfo.get_shard(raw), lo, length)
-            for raw in range(self.sinfo.k)
-        ])
-
     def encode(self, codec, hashinfo: HashInfo | None = None,
                old_size: int | None = None,
                csum_block: int | None = None) -> None:
@@ -377,36 +369,43 @@ class ShardExtentMap:
         lo = (lo0 // cs) * cs
         hi = -(-hi0 // cs) * cs
         n_chunks = (hi - lo) // cs
-        parity = csums = None
+        parity = stacked = csums = None
         cb = csum_block
-        if (
+        if not (
             cb
             and cs % cb == 0
             and lo % cb == 0
             and hasattr(codec, "encode_stacked_with_csums")
         ):
-            # Coalesced/streaming route first: the fused op stages in
-            # the ring and shares ONE encode+csum dispatch with every
-            # other op of the tick window (the same batching win the
-            # plain encode gets below). (None, None) = the fused
-            # kernel can't serve the geometry; fall through per-op.
-            if self._ring_routable(codec, k * (hi - lo)):
-                parity, csums = self._ring_encode_csum(
-                    codec, lo, n_chunks, cb
+            cb = 0
+        ring = self._ring_routable(codec, n_chunks)
+        if ring or cb:
+            with codec_stage("prep"):
+                stripes = self._stripe_major(lo, n_chunks)
+            if ring:
+                # Coalesced/streaming route: the op stages in the ring
+                # and shares ONE dispatch (fused encode+csum where
+                # ``cb`` asks) with every other op of the tick window.
+                # The parity comes back either way; csums None = no
+                # fused pass serves the geometry, and the host
+                # checksums stand in.
+                from .dispatcher import dispatcher_for
+
+                stacked, csums = dispatcher_for(codec).encode_csum_sync(
+                    stripes, cb
                 )
-            if csums is None:
-                with codec_stage("prep"):
-                    stripes = self._stripe_major(lo, n_chunks)
+            else:
                 stacked, csums = codec.encode_stacked_with_csums(
                     stripes, cb
                 )
-                if stacked is not None:
-                    # np.asarray waits for the kernel; the csum words
-                    # come back with the parity
-                    with codec_stage("fetch"):
-                        parity = self._shard_rows(np.asarray(stacked))
-                        csums = np.asarray(csums)
-        if parity is None:
+        if stacked is not None:
+            # np.asarray waits for the kernel; the csum words come
+            # back with the parity
+            with codec_stage("fetch"):
+                parity = self._shard_rows(np.asarray(stacked))
+                if csums is not None:
+                    csums = np.asarray(csums)
+        else:
             parity = self._dispatch_encode(codec, lo, n_chunks)
         for j in range(m):
             self.insert(self.sinfo.get_shard(k + j), lo, parity[j])
@@ -473,44 +472,29 @@ class ShardExtentMap:
         out.flags.writeable = False
         return list(out)
 
-    @staticmethod
-    def _ring_routable(codec, nbytes: int) -> bool:
-        """One gate for both ring routes: this thread is inside a
+    def _ring_routable(self, codec, n_chunks: int) -> bool:
+        """The gate of the ring route: this thread is inside a
         coalesced OSD tick (dispatcher.coalescing_scope), whose
         concurrent groups stage into the same ring window. Sub-chunk
         codecs (CLAY) give chunk geometry meaning beyond byte count,
-        and ops beyond a ring slot can't stage — both keep the per-op
-        path."""
-        from .dispatcher import coalescing_active, dispatcher_for
+        and an op beyond the ring's small-op bound is no small op —
+        both keep the per-op path."""
+        from ceph_tpu.codecs.matrix_codec import BATCH_MAX_STRIPES
+
+        from .dispatcher import MAX_OP_BYTES, coalescing_active
 
         return (
             codec.get_sub_chunk_count() == 1
             and coalescing_active()
-            and nbytes <= dispatcher_for(codec).max_op_bytes
+            and n_chunks <= BATCH_MAX_STRIPES
+            and n_chunks * self.sinfo.stripe_width <= MAX_OP_BYTES
         )
-
-    def _ring_encode_csum(self, codec, lo: int, n_chunks: int, cb: int):
-        """Stage one fused encode+csum op in the ring: ``(parity
-        [m, L] | None, csums | None)``."""
-        from .dispatcher import dispatcher_for
-
-        with codec_stage("prep"):
-            flat = self._shard_major(lo, n_chunks * self.sinfo.chunk_size)
-        return dispatcher_for(codec).encode_csum_sync(flat, cb, n_chunks)
 
     def _dispatch_encode(self, codec, lo: int, n_chunks: int):
         """Parity of the data shards over ``n_chunks`` chunks from
         ``lo``, one flat host array a parity shard, through the codec's
-        dispatch. Inside a coalesced OSD tick the op rides the native
-        staging ring and shares a batched device dispatch with other
-        concurrent ops (pipeline/dispatcher.py)."""
-        from .dispatcher import dispatcher_for
-
+        own dispatch (the per-op path)."""
         k, cs = self.sinfo.k, self.sinfo.chunk_size
-        if self._ring_routable(codec, k * n_chunks * cs):
-            with codec_stage("prep"):
-                flat = self._shard_major(lo, n_chunks * cs)
-            return dispatcher_for(codec).encode_sync(flat)
         if hasattr(codec, "encode_stacked"):
             with codec_stage("prep"):
                 stripes = self._stripe_major(lo, n_chunks)

@@ -19,6 +19,7 @@ The load-bearing pins:
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -209,7 +210,6 @@ def test_ring_solo_fallback_isolates_batch_fault():
     from ceph_tpu.codecs.registry import registry
     from ceph_tpu.pipeline.dispatcher import (
         StreamingDispatcher,
-        _HDR,
         _stream_counters,
     )
 
@@ -220,31 +220,21 @@ def test_ring_solo_fallback_isolates_batch_fault():
         before = (pc.get("batch_faults"), pc.get("solo_retries"))
         rng = np.random.default_rng(3)
         payloads = [
-            rng.integers(0, 256, (3, 4096), np.uint8) for _ in range(3)
+            rng.integers(0, 256, (1, 3, 4096), np.uint8) for _ in range(3)
         ]
         results: dict[int, object] = {}
-        slots = []
-        with disp._lock:
-            for idx, p in enumerate(payloads):
-                disp._pending[1000 + idx] = (
-                    lambda r, i=idx: results.__setitem__(i, r),
-                    3, 4096,
-                )
-                slots.append(
-                    _HDR.pack(1000 + idx, 3, 1, 4096, 0) + p.tobytes()
-                )
-        disp._fire(slots)
+        disp._fire(_stage(disp, payloads, results, 0))
         assert set(results) == {0, 1, 2}
         for idx, p in enumerate(payloads):
             parity = codec.encode_chunks(
-                {i: p[None, i, :] for i in range(3)}
+                {i: p[:, i, :] for i in range(3)}
             )
             want = np.stack(
-                [np.asarray(parity[3 + j])[0] for j in range(2)]
+                [np.asarray(parity[3 + j]) for j in range(2)], axis=1
             )
             got = results[idx]
             assert not isinstance(got, Exception), got
-            np.testing.assert_array_equal(got, want)
+            np.testing.assert_array_equal(got[0], want)
         after = (pc.get("batch_faults"), pc.get("solo_retries"))
         assert after[0] == before[0] + 1
         assert after[1] == before[1] + 3
@@ -252,15 +242,26 @@ def test_ring_solo_fallback_isolates_batch_fault():
         disp.stop()
 
 
+def _stage(disp, payloads, results, csum_block):
+    """``payloads`` as ring ops that were never pushed, so that one
+    ``_fire`` sees exactly this batch."""
+    from ceph_tpu.pipeline.dispatcher import _RingOp
+
+    return [
+        _RingOp(
+            lambda r, i=idx: results.__setitem__(i, r),
+            p, csum_block, time.perf_counter(), (None, None),
+        )
+        for idx, p in enumerate(payloads)
+    ]
+
+
 def test_ring_fused_csum_batch_matches_per_op():
     """Fused encode+csum ops stacked into one ring dispatch produce
     the same parity AND per-block csums as the per-op fused call
     (interpret mode off-TPU)."""
     from ceph_tpu.codecs.registry import registry
-    from ceph_tpu.pipeline.dispatcher import (
-        StreamingDispatcher,
-        _HDR,
-    )
+    from ceph_tpu.pipeline.dispatcher import StreamingDispatcher
 
     with config.override(
         ec_fused_csum=True, ec_use_pallas=True,
@@ -272,38 +273,22 @@ def test_ring_fused_csum_batch_matches_per_op():
             rng = np.random.default_rng(4)
             cs, cb = 2048, 512
             ops = [
-                rng.integers(0, 256, (2, nc, cs), np.uint8)
+                rng.integers(0, 256, (nc, 2, cs), np.uint8)
                 for nc in (1, 2)
             ]
             results: dict[int, object] = {}
-            slots = []
-            with disp._lock:
-                for idx, chunks in enumerate(ops):
-                    nc = chunks.shape[1]
-                    disp._pending[2000 + idx] = (
-                        lambda r, i=idx: results.__setitem__(i, r),
-                        2, nc * cs,
-                    )
-                    slots.append(
-                        _HDR.pack(2000 + idx, 2, nc, cs, cb)
-                        + np.ascontiguousarray(chunks).tobytes()
-                    )
-            disp._fire(slots)
-            for idx, chunks in enumerate(ops):
+            disp._fire(_stage(disp, ops, results, cb))
+            for idx, stripes in enumerate(ops):
                 got = results[idx]
                 assert not isinstance(got, Exception), got
-                parity2d, csums = got
+                parity, csums = got
                 pm, want_csums = codec.encode_chunks_with_csums(
-                    {i: chunks[i] for i in range(2)}, cb
+                    {i: stripes[:, i, :] for i in range(2)}, cb
                 )
-                assert (parity2d is None) == (pm is None)
-                if pm is None:
-                    continue  # geometry unservable here: clean refusal
-                nc = chunks.shape[1]
-                want = np.stack(
-                    [np.asarray(pm[2 + j]) for j in range(1)], axis=1
-                ).transpose(1, 0, 2).reshape(1, nc * cs)
-                np.testing.assert_array_equal(parity2d, want)
+                assert pm is not None and csums is not None
+                np.testing.assert_array_equal(
+                    parity[:, 0, :], np.asarray(pm[2])
+                )
                 np.testing.assert_array_equal(
                     np.asarray(csums), np.asarray(want_csums)
                 )

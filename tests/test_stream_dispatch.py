@@ -1,4 +1,4 @@
-"""Streaming dispatcher: native-ring staging -> batched device
+"""Streaming dispatcher: ring staging -> batched device
 dispatch -> completion callbacks (SURVEY §7 step 4; the sharded op
 queue role, osd/OSD.cc:9874-9933).
 """
@@ -9,10 +9,6 @@ import numpy as np
 import pytest
 
 from ceph_tpu.codecs.registry import registry
-
-native = pytest.importorskip("ceph_tpu.native")
-if not native.available():
-    pytest.skip("native runtime unavailable", allow_module_level=True)
 
 from ceph_tpu.pipeline.dispatcher import (
     StreamingDispatcher,
@@ -25,17 +21,18 @@ def codec():
     return registry.factory("isa", {"k": "4", "m": "2"})
 
 
-def _host_parity(codec, data):
+def _host_parity(codec, stripes):
+    """Parity [n, m, N] of stripe-major ``stripes`` [n, k, N]."""
     parity = codec.encode_chunks(
-        {i: np.asarray(data[i]) for i in range(data.shape[0])}
+        {i: np.asarray(stripes[:, i, :]) for i in range(stripes.shape[1])}
     )
-    return np.stack([np.asarray(parity[4 + j]) for j in range(2)])
+    return np.stack([np.asarray(parity[4 + j]) for j in range(2)], axis=1)
 
 
 def test_single_op_roundtrip(rng, codec):
     d = StreamingDispatcher(codec)
     try:
-        data = rng.integers(0, 256, (4, 8192), np.uint8)
+        data = rng.integers(0, 256, (2, 4, 4096), np.uint8)
         out = d.encode_sync(data)
         np.testing.assert_array_equal(out, _host_parity(codec, data))
     finally:
@@ -45,12 +42,12 @@ def test_single_op_roundtrip(rng, codec):
 def test_concurrent_ops_batch_and_match(rng, codec):
     """Many threads submit concurrently; every result is bit-exact
     and at least some ops shared a dispatch (the whole point)."""
-    d = StreamingDispatcher(codec, window_s=0.002)
+    d = StreamingDispatcher(codec)
     pc = _stream_counters()
     before = pc.get("batched_ops")
     try:
         datas = [
-            rng.integers(0, 256, (4, 4096), np.uint8) for _ in range(64)
+            rng.integers(0, 256, (1, 4, 4096), np.uint8) for _ in range(64)
         ]
         outs: list = [None] * 64
         errs: list = []
@@ -79,10 +76,10 @@ def test_concurrent_ops_batch_and_match(rng, codec):
 
 
 def test_mixed_shapes_group_separately(rng, codec):
-    d = StreamingDispatcher(codec, window_s=0.002)
+    d = StreamingDispatcher(codec)
     try:
-        a = rng.integers(0, 256, (4, 4096), np.uint8)
-        b = rng.integers(0, 256, (4, 8192), np.uint8)
+        a = rng.integers(0, 256, (1, 4, 4096), np.uint8)
+        b = rng.integers(0, 256, (1, 4, 8192), np.uint8)
         results = {}
         done = threading.Barrier(3)
 
@@ -100,11 +97,14 @@ def test_mixed_shapes_group_separately(rng, codec):
 
 
 def test_oversized_op_rejected(codec):
-    d = StreamingDispatcher(codec, slot_bytes=4096)
+    from ceph_tpu.pipeline.dispatcher import MAX_OP_BYTES
+
+    d = StreamingDispatcher(codec)
     try:
         with pytest.raises(ValueError):
             d.submit(
-                np.zeros((4, 4096), np.uint8), lambda p: None
+                np.zeros((MAX_OP_BYTES // 4096 // 4 + 1, 4, 4096), np.uint8),
+                lambda p: None,
             )
     finally:
         d.stop()

@@ -54,8 +54,10 @@ import sys
 #: ledger's ``breakdown`` keeps to its six coarse names.
 STAGE_ORDER = [
     "codec.fetch", "codec.launch", "codec.h2d", "codec.prep",
+    "ring.deliver", "ring.fire",
     "ec_write.fanout", "ec_write.txn_build", "ec_write.delta_place",
-    "ec_write.delta_prepare", "ec_write.encode", "ec_write.delta_apply",
+    "ec_write.delta_prepare", "ring_wait", "ec_write.encode",
+    "ec_write.delta_apply",
     "ec_write.assemble", "ec_write.plan", "sub_write", "sub_read",
     "ec_reconstruct", "ec_read.finish", "ec_read.issue", "ec_truncate",
     "ec_write", "subop_wait", "sub_read_wait", "rmw_read_wait", "osd_op",
