@@ -28,6 +28,7 @@ nothing is armed the cost is one attribute check per frame.
 
 from __future__ import annotations
 
+import errno
 import fnmatch
 import heapq
 import itertools
@@ -40,6 +41,7 @@ from collections.abc import Callable
 from . import secure as secure_mod
 from . import shm_ring
 from .messages import decode_message, message_type
+from . import wire
 from .wire import BadFrame, decode_frame, encode_frame
 from ceph_tpu.utils import lockdep
 from ceph_tpu.utils.lockdep import DebugLock
@@ -406,10 +408,12 @@ def make_net_perf(name: str):
     """A messenger's ``net`` counter set (``perf dump`` section
     ``osd.<id>.net`` / ``<client>.net``, Prometheus via the exporter).
     Traffic first: frames and bytes each way, with the seconds spent
-    framing + writing (``send_seconds``: encode and ``sendall`` under
-    the send lock) and reading + parsing (``recv_seconds``: from a
-    frame's header in hand to its message decoded, so an idle link
-    adds nothing). Then what the seeded fault plane did to this
+    framing + writing (``send_seconds``: the message's encode, the
+    frame's crc32c and the socket write, under the send lock) and
+    reading + parsing (``recv_seconds``: from a frame's header in hand
+    to its message decoded, so an idle link adds nothing), and
+    ``io_calls``: how often the messenger left the interpreter for
+    those frames. Then what the seeded fault plane did to this
     daemon's links, and what the dedup tiers absorbed — the
     observability half of the chaos contract (injected faults MUST
     show up here, absorbed duplicates MUST show up there, and the
@@ -427,6 +431,11 @@ def make_net_perf(name: str):
             "recv_seconds",
             "frame header in hand to message decoded (body read, "
             "CRC check, decode)",
+        )
+        .add_u64_counter(
+            "io_calls",
+            "times the messenger left the interpreter for a frame: a "
+            "recv, a sendall, a codec call, a native frame send/recv",
         )
         .add_u64_counter(
             "frames_dropped", "frames dropped by fault injection"
@@ -483,6 +492,17 @@ class Connection:
         self._seq = 0
         self.alive = True
         self._tx = self._rx = None
+        # native frame I/O works on the bare descriptor, so the
+        # descriptor must outlive every call that took it: calls are
+        # counted in and out under this lock, and a close that finds
+        # one in flight is left to the last of them
+        self._fd_lock = DebugLock("msgr.fd")
+        self._fd_users = 0
+        self._fd_close_pending = False
+        self._rx_frames = None  # native.FrameReceiver, made when first used
+        # times the interpreter was left for the frame being written
+        # (under the send lock) and for the one being read (the reader)
+        self._tx_calls = self._rx_calls = 0
         if messenger.secret is not None:
             try:
                 self._handshake(is_client)
@@ -493,6 +513,16 @@ class Connection:
                 except OSError:
                     pass
                 raise
+        #: what the link is decides whether the native codec may take
+        #: the socket: clear (no AES-GCM session), uncompressed, and on
+        #: a kernel descriptor (a shm-ring end is not one). Whether it
+        #: does is asked per frame (``wire.frame_io``: native tier
+        #: loaded, ``msgr_native_codec`` on).
+        self._kernel_clear = (
+            self._tx is None
+            and not messenger.compress
+            and isinstance(sock, socket.socket)
+        )
         self._reader = threading.Thread(target=self._read_loop, daemon=True)
         self._reader.start()
 
@@ -556,43 +586,102 @@ class Connection:
             return
         self._send_now(msg)
 
+    def _frame_io(self):
+        """The native module when this frame's I/O is the codec's."""
+        return wire.frame_io() if self._kernel_clear else None
+
+    def _fd_enter(self) -> int:
+        """The socket's descriptor for one native call; it stays open
+        until the matching :meth:`_fd_exit`."""
+        with self._fd_lock:
+            fd = -1 if self._fd_close_pending else self.sock.fileno()
+            if fd < 0:
+                raise OSError(errno.EBADF, "connection closed")
+            self._fd_users += 1
+            return fd
+
+    def _fd_exit(self) -> None:
+        with self._fd_lock:
+            self._fd_users -= 1
+            last = self._fd_close_pending and not self._fd_users
+        if last:
+            self._close_sock()
+
+    def _close_sock(self) -> None:
+        with self._fd_lock:
+            self.alive = False
+            if self._fd_users:
+                self._fd_close_pending = True
+                return
+        try:
+            self.sock.close()
+        except OSError:
+            pass
+
     def _send_now(self, msg) -> None:
         pc = self.messenger.net_pc
         with self._send_lock:
             t0 = time.perf_counter()
             self._seq += 1
-            # Sealing must happen under the send lock: the AEAD tx
-            # counter and the socket write have to agree on order.
-            frame = encode_frame(
-                message_type(msg),
-                self._seq,
-                msg.encode(),
-                compress=self.messenger.compress,
-                secure=self._tx,
-            )
+            segments = msg.encode()
+            io = self._frame_io()
+            self._tx_calls = 1  # the write itself
             try:
-                self.sock.sendall(frame)
+                if io is not None:
+                    # framed, checksummed and written in the one call
+                    fd = self._fd_enter()
+                    try:
+                        nbytes = wire.send_frame(
+                            io, fd, message_type(msg), self._seq, segments
+                        )
+                    finally:
+                        self._fd_exit()
+                else:
+                    # Sealing must happen under the send lock: the AEAD
+                    # tx counter and the socket write have to agree on
+                    # order.
+                    frame = encode_frame(
+                        message_type(msg),
+                        self._seq,
+                        segments,
+                        compress=self.messenger.compress,
+                        secure=self._tx,
+                        tally=self._tally_tx,
+                    )
+                    self.sock.sendall(frame)
+                    nbytes = len(frame)
             except OSError as e:
                 self.alive = False
                 raise ConnectionError(str(e)) from e
             if pc is not None:
                 pc.inc("frames_sent")
-                pc.inc("bytes_sent", len(frame))
+                pc.inc("bytes_sent", nbytes)
+                pc.inc("io_calls", self._tx_calls)
                 pc.tinc("send_seconds", time.perf_counter() - t0)
+
+    def _tally_tx(self, n: int) -> None:
+        self._tx_calls += n
+
+    def _tally_rx(self, n: int) -> None:
+        self._rx_calls += n
 
     def _read_exact(self, n: int) -> bytes:
         buf = b""
         while len(buf) < n:
             chunk = self.sock.recv(n - len(buf))
+            self._rx_calls += 1
             if not chunk:
                 raise EOFError
             buf += chunk
         return buf
 
-    def _read_loop(self) -> None:
-        # per frame: bytes read, and the clock at the header's arrival
-        # (the wait for it is an idle link, not receive work)
+    def _recv_py(self):
+        """One frame through the Python path: (msg_type, segments,
+        framed bytes, clock at the header's arrival, io calls)."""
+        # bytes read, and the clock at the header's arrival (the wait
+        # for it is an idle link, not receive work)
         got = [0, 0.0]
+        self._rx_calls = 0
 
         def read_counted(n: int) -> bytes:
             buf = self._read_exact(n)
@@ -601,19 +690,39 @@ class Connection:
             got[0] += n
             return buf
 
+        msg_type, _seq, segments = decode_frame(
+            read_counted, secure=self._rx, tally=self._tally_rx
+        )
+        return msg_type, segments, got[0], got[1], self._rx_calls
+
+    def _recv_native(self, io):
+        """One frame read and verified by the codec itself; same
+        result as :meth:`_recv_py`."""
+        rx = self._rx_frames
+        if rx is None:
+            rx = self._rx_frames = io.FrameReceiver()
+        fd = self._fd_enter()
+        try:
+            msg_type, _seq, segments = wire.recv_frame(io, fd, rx)
+        finally:
+            self._fd_exit()
+        return msg_type, segments, rx.frame_bytes, rx.info.t_header, rx.calls
+
+    def _read_loop(self) -> None:
         try:
             while True:
-                got[0] = 0
-                msg_type, _seq, segments = decode_frame(
-                    read_counted, secure=self._rx
+                io = self._frame_io()
+                msg_type, segments, nbytes, t_hdr, calls = (
+                    self._recv_py() if io is None else self._recv_native(io)
                 )
                 msg = decode_message(msg_type, segments)
                 pc = self.messenger.net_pc
                 if pc is not None:
                     pc.inc("frames_recv")
-                    pc.inc("bytes_recv", got[0])
+                    pc.inc("bytes_recv", nbytes)
+                    pc.inc("io_calls", calls)
                     pc.tinc(
-                        "recv_seconds", time.perf_counter() - got[1]
+                        "recv_seconds", time.perf_counter() - t_hdr
                     )
                 if net_faults.active and self.peer_name is not None:
                     # inbound half of the link (peer → me): replies on
@@ -637,20 +746,18 @@ class Connection:
             # timeouts on a wedged link.
             pass
         finally:
-            self.alive = False
-            try:
-                self.sock.close()
-            except OSError:
-                pass
+            self._close_sock()
             self.messenger._conn_closed(self)
 
     def close(self) -> None:
         self.alive = False
+        # wakes a reader blocked in recv — Python's or the native
+        # codec's — with EOF, and a writer with EPIPE
         try:
             self.sock.shutdown(socket.SHUT_RDWR)
         except OSError:
             pass
-        self.sock.close()
+        self._close_sock()
 
 
 class Messenger:

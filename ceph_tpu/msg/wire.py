@@ -29,10 +29,18 @@ table + per-segment crc32c assemble/verify in one C call each
 (native/src/ceph_tpu_native.cc frame codec), gated on
 ``msgr_native_codec`` and ``CEPH_TPU_NO_NATIVE``, bit-identical to
 the pure-Python path kept below as the fallback and oracle.
+
+Where a link is clear, uncompressed and on a kernel socket, the same
+codec takes the descriptor (``send_frame`` / ``recv_frame``): a frame
+is framed, checksummed and written, or read, verified and handed over,
+inside one native call, so the messenger leaves the interpreter once a
+frame and not once a codec call and once a socket call. Same bytes,
+same checks in the same order, same ``BadFrame`` texts.
 """
 
 from __future__ import annotations
 
+import os
 import struct
 import zlib
 
@@ -59,6 +67,13 @@ class BadFrame(Exception):
 
 def _crc(data: bytes) -> int:
     return _crc32c_host(CRC_SEED, data)
+
+
+def _inflate(seg: bytes) -> bytes:
+    try:
+        return zlib.decompress(seg)
+    except zlib.error as e:
+        raise BadFrame(f"segment inflate failed: {e}") from e
 
 
 # Native frame codec (ceph_tpu_native.cc frame_encode/frame_verify):
@@ -100,9 +115,12 @@ def encode_frame(
     segments: list[bytes],
     compress: bool = False,
     secure=None,
+    tally=None,
 ) -> bytes:
     """Frame ``segments``; ``secure`` is a secure.SecureSession for
-    AES-GCM sealing (tx direction) or None for crc mode."""
+    AES-GCM sealing (tx direction) or None for crc mode. ``tally(n)``,
+    where given, is told of every call into the native tier (the
+    messenger's ``io_calls``)."""
     if not 0 < len(segments) <= MAX_SEGMENTS:
         raise ValueError(f"1..{MAX_SEGMENTS} segments, got {len(segments)}")
     flags = 0
@@ -121,7 +139,11 @@ def encode_frame(
         return hdr + _SECHDR.pack(counter, len(ct)) + ct
     codec = _codec()
     if codec is not None:
+        if tally is not None:
+            tally(1)
         return codec.frame_encode(msg_type, flags, seq, segments)
+    if tally is not None and _native() is not None:
+        tally(len(segments))  # a crc32c call a segment
     out = bytearray(_HDR.pack(MAGIC, msg_type, flags, len(segments), seq))
     for seg in segments:
         out += _SEG.pack(len(seg), _crc(seg))
@@ -130,9 +152,12 @@ def encode_frame(
     return bytes(out)
 
 
-def decode_frame(read_exact, secure=None) -> tuple[int, int, list[bytes]]:
+def decode_frame(
+    read_exact, secure=None, tally=None
+) -> tuple[int, int, list[bytes]]:
     """Parse one frame from ``read_exact(n) -> bytes`` (raises
     ``EOFError`` at stream end). Returns (msg_type, seq, segments).
+    ``tally`` as in :func:`encode_frame` (``read_exact`` counts its own).
     Compressed frames are transparently inflated AFTER CRC (or AEAD)
     checks. ``secure`` is the rx-direction secure.SecureSession; a
     secure frame arriving without one (or vice versa) is rejected —
@@ -173,10 +198,7 @@ def decode_frame(read_exact, secure=None) -> tuple[int, int, list[bytes]]:
             seg = body[pos : pos + length]
             pos += length
             if flags & FLAG_COMPRESSED:
-                try:
-                    seg = zlib.decompress(seg)
-                except zlib.error as e:
-                    raise BadFrame(f"segment inflate failed: {e}") from e
+                seg = _inflate(seg)
             segments.append(seg)
         return msg_type, seq, segments
     # Clear mode: one read for the whole segment table, one for the
@@ -193,6 +215,8 @@ def decode_frame(read_exact, secure=None) -> tuple[int, int, list[bytes]]:
         total += length
     payload = read_exact(total)
     codec = _codec()
+    if tally is not None and _native() is not None:
+        tally(1 if codec is not None else nseg)
     if codec is not None:
         bad = codec.frame_verify(table_raw, payload)
         if bad == -2:
@@ -212,12 +236,63 @@ def decode_frame(read_exact, secure=None) -> tuple[int, int, list[bytes]]:
                 f"segment crc mismatch: got {_crc(seg):#x} want {crc:#x}"
             )
         if flags & FLAG_COMPRESSED:
-            try:
-                seg = zlib.decompress(seg)
-            except zlib.error as e:
-                raise BadFrame(f"segment inflate failed: {e}") from e
+            seg = _inflate(seg)
         segments.append(seg)
     return msg_type, seq, segments
+
+
+# -- the codec takes the socket ------------------------------------------
+def frame_io():
+    """The native module where a frame may be written and read by the
+    codec itself (loaded AND ``msgr_native_codec`` on), else None. The
+    messenger asks per frame, and only for a link that is clear,
+    uncompressed and on a kernel descriptor."""
+    return _codec()
+
+
+def send_frame(io, fd: int, msg_type: int, seq: int, segments) -> int:
+    """Frame ``segments`` and write them to ``fd`` in one native call
+    (the bytes are ``encode_frame``'s). Returns the frame's length;
+    raises ``OSError`` as ``sendall`` would."""
+    if not 0 < len(segments) <= MAX_SEGMENTS:
+        raise ValueError(f"1..{MAX_SEGMENTS} segments, got {len(segments)}")
+    n = io.frame_send(fd, msg_type, 0, seq, segments)
+    if n < 0:
+        raise OSError(-n, os.strerror(-n))
+    return n
+
+
+def recv_frame(io, fd: int, rx) -> tuple[int, int, list[bytes]]:
+    """Read and verify one clear frame from ``fd`` through ``rx`` (an
+    ``io.FrameReceiver``, which afterwards holds the frame's length,
+    the ``time.perf_counter`` reading at which its header was complete,
+    and the native calls made). Returns what ``decode_frame`` returns
+    and raises what it raises over a socket: ``EOFError``, ``OSError``,
+    ``BadFrame``."""
+    rc, segments = rx.recv(fd)
+    info = rx.info
+    if rc == io.FRAME_DONE:
+        if info.flags & FLAG_COMPRESSED:
+            segments = [_inflate(seg) for seg in segments]
+        return info.msg_type, info.seq, segments
+    if rc == io.FRAME_EOF:
+        raise EOFError
+    if rc == io.BAD_MAGIC:
+        raise BadFrame(f"bad magic {bytes(info.magic)!r}")
+    if rc == io.BAD_FLAGS:
+        raise BadFrame(f"unsupported flags {info.flags:#x}")
+    if rc == io.BAD_NSEG:
+        raise BadFrame(f"bad segment count {info.nseg}")
+    if rc == io.BAD_SECURE:
+        raise BadFrame("secure-mode mismatch: frame sealed but session clear")
+    if rc == io.BAD_LENGTH:
+        raise BadFrame(f"segment too large: {info.lens[info.bad]}")
+    if rc == io.BAD_CRC:
+        raise BadFrame(
+            f"segment crc mismatch: segment {info.bad}"
+            f" got {info.got:#x} want {info.crcs[info.bad]:#x}"
+        )
+    raise OSError(-rc, os.strerror(-rc))
 
 
 def frame_from_buffer(buf: bytes, secure=None) -> tuple[int, int, list[bytes]]:

@@ -148,6 +148,23 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.ctpu_frame_verify.argtypes = [
         ctypes.c_char_p, ctypes.c_uint32, ctypes.c_char_p, ctypes.c_uint64,
     ]
+    # frame socket I/O: the codec takes the descriptor (one call a frame)
+    lib.ctpu_frame_send.restype = ctypes.c_int64
+    lib.ctpu_frame_send.argtypes = [
+        ctypes.c_int, ctypes.c_uint32, ctypes.c_uint32, ctypes.c_uint64,
+        ctypes.c_uint32,
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+    ]
+    lib.ctpu_frame_recv.restype = ctypes.c_int
+    lib.ctpu_frame_recv.argtypes = [
+        ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64,
+        ctypes.POINTER(FrameInfo),
+    ]
+    lib.ctpu_frame_recv_body.restype = ctypes.c_int
+    lib.ctpu_frame_recv_body.argtypes = [
+        ctypes.c_int, ctypes.POINTER(FrameInfo),
+        ctypes.POINTER(ctypes.c_void_p),
+    ]
 
 
 def _load() -> ctypes.CDLL | None:
@@ -203,6 +220,18 @@ def crc32c_bytes(init: int, data) -> int:
 
 
 # -- frame codec ---------------------------------------------------------
+def _seg_arrays(segments):
+    """(count, char* array, length array) of a frame's segments; the
+    pointers are into the ``bytes`` objects, which the array keeps."""
+    segs = [s if isinstance(s, bytes) else bytes(s) for s in segments]
+    nseg = len(segs)
+    return (
+        nseg,
+        (ctypes.c_char_p * nseg)(*segs),
+        (ctypes.c_uint64 * nseg)(*map(len, segs)),
+    )
+
+
 def frame_encode(msg_type: int, flags: int, seq: int, segments) -> bytes:
     """Assemble a clear-mode wire frame (header + segment table with
     per-segment crc32c + payloads) in one native call. ``segments`` is
@@ -212,12 +241,9 @@ def frame_encode(msg_type: int, flags: int, seq: int, segments) -> bytes:
     lib = _load()
     if lib is None:
         raise RuntimeError("native runtime unavailable")
-    segs = [s if isinstance(s, bytes) else bytes(s) for s in segments]
-    nseg = len(segs)
-    total = 16 + nseg * 8 + sum(len(s) for s in segs)
+    nseg, ptrs, lens = _seg_arrays(segments)
+    total = 16 + nseg * 8 + sum(lens)
     out = bytearray(total)
-    ptrs = (ctypes.c_char_p * nseg)(*segs)
-    lens = (ctypes.c_uint64 * nseg)(*[len(s) for s in segs])
     written = lib.ctpu_frame_encode(
         msg_type, flags, seq, nseg, ptrs, lens,
         (ctypes.c_uint8 * total).from_buffer(out),
@@ -241,6 +267,124 @@ def frame_verify(table, payload) -> int:
     if not isinstance(payload, bytes):
         payload = bytes(payload)
     return lib.ctpu_frame_verify(table, len(table) // 8, payload, len(payload))
+
+
+# -- frame socket I/O ----------------------------------------------------
+class FrameInfo(ctypes.Structure):
+    """``struct ctpu_frame_info``: what ``ctpu_frame_recv`` learned of
+    a frame (same layout as the C side)."""
+
+    _fields_ = [
+        ("seq", ctypes.c_uint64),
+        ("total", ctypes.c_uint64),
+        ("t_header", ctypes.c_double),
+        ("msg_type", ctypes.c_uint32),
+        ("flags", ctypes.c_uint32),
+        ("nseg", ctypes.c_uint32),
+        ("bad", ctypes.c_uint32),
+        ("got", ctypes.c_uint32),
+        ("lens", ctypes.c_uint32 * 8),
+        ("crcs", ctypes.c_uint32 * 8),
+        ("magic", ctypes.c_uint8 * 4),
+    ]
+
+
+#: ctpu_frame_recv / ctpu_frame_recv_body results (negative values
+#: above -1000 are -errno)
+FRAME_EOF, FRAME_DONE, FRAME_BODY = 0, 1, 2
+BAD_MAGIC, BAD_FLAGS, BAD_NSEG, BAD_SECURE, BAD_LENGTH, BAD_CRC = (
+    -1001, -1002, -1003, -1004, -1005, -1006,
+)
+
+#: a payload up to this size is read by the call that read its header
+#: (every ack, reply without data, sub-read request, 8 KiB sub-write)
+FRAME_SCRATCH_BYTES = 64 * 1024
+#: of a larger payload, segments below this size still land in the
+#: scratch buffer (the json headers); the others get a buffer each
+_SEG_OWN_BYTES = 4096
+assert 8 * _SEG_OWN_BYTES <= FRAME_SCRATCH_BYTES
+
+# a bytes object of n bytes that nobody has filled (or zeroed) yet: the
+# C API's own way to build one, written through its pointer before any
+# other reference to it exists
+_new_bytes = ctypes.pythonapi.PyBytes_FromStringAndSize
+_new_bytes.restype = ctypes.py_object
+_new_bytes.argtypes = [ctypes.c_char_p, ctypes.c_ssize_t]
+
+
+def frame_send(fd: int, msg_type: int, flags: int, seq: int, segments) -> int:
+    """Frame ``segments`` and write the frame to descriptor ``fd`` in
+    one native call: crc32c a segment, header and table on the stack,
+    a gather write of the segments from where they lie. The bytes on
+    the wire are :func:`frame_encode`'s. Returns the frame's length or
+    ``-errno``."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native runtime unavailable")
+    nseg, ptrs, lens = _seg_arrays(segments)
+    return lib.ctpu_frame_send(fd, msg_type, flags, seq, nseg, ptrs, lens)
+
+
+class FrameReceiver:
+    """One connection's receive state for the native read: the scratch
+    buffer small payloads land in and the :class:`FrameInfo` the call
+    fills. Used by one thread (the connection's reader)."""
+
+    def __init__(self) -> None:
+        lib = _load()
+        if lib is None:
+            raise RuntimeError("native runtime unavailable")
+        self._lib = lib
+        self._scratch = bytearray(FRAME_SCRATCH_BYTES)
+        self._view = memoryview(self._scratch)
+        self._addr = ctypes.addressof(
+            ctypes.c_uint8.from_buffer(self._scratch)
+        )
+        self.info = FrameInfo()
+        #: native calls the last :meth:`recv` made
+        self.calls = 0
+
+    @property
+    def frame_bytes(self) -> int:
+        """Framed length of the frame last read."""
+        return 16 + 8 * self.info.nseg + self.info.total
+
+    def recv(self, fd: int) -> tuple[int, "list[bytes] | None"]:
+        """Read one frame from ``fd``: ``(FRAME_DONE, its verified
+        segments)``, or ``(rc, None)`` with ``rc`` ``FRAME_EOF``, a
+        ``BAD_*`` code (``info`` says which segment) or ``-errno``. One
+        native call where the payload fits the scratch buffer, else
+        two: the second reads every segment of ``_SEG_OWN_BYTES`` or
+        more straight into a ``bytes`` of its own."""
+        info = self.info
+        self.calls = 1
+        rc = self._lib.ctpu_frame_recv(
+            fd, self._addr, FRAME_SCRATCH_BYTES, info
+        )
+        if rc not in (FRAME_DONE, FRAME_BODY):
+            return rc, None
+        lens = info.lens[: info.nseg]
+        segs: list = [None] * len(lens)
+        if rc == FRAME_BODY:
+            self.calls = 2
+            bufs = (ctypes.c_void_p * len(lens))()
+            pos = 0
+            for i, n in enumerate(lens):
+                if n < _SEG_OWN_BYTES:
+                    bufs[i] = self._addr + pos
+                    pos += n
+                else:
+                    segs[i] = _new_bytes(None, n)
+                    bufs[i] = ctypes.cast(segs[i], ctypes.c_void_p)
+            rc = self._lib.ctpu_frame_recv_body(fd, info, bufs)
+            if rc != FRAME_DONE:
+                return rc, None
+        pos = 0
+        for i, n in enumerate(lens):
+            if segs[i] is None:
+                segs[i] = bytes(self._view[pos : pos + n])
+                pos += n
+        return rc, segs
 
 
 # -- GF region ops -------------------------------------------------------

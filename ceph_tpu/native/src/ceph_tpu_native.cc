@@ -12,15 +12,25 @@
 //  * A blocking MPMC ring buffer of fixed slots — the host staging
 //    queue of the dispatch pipeline (host ring -> pinned staging ->
 //    device batches; SURVEY.md §7 step 4).
+//  * The wire frame codec (msg/wire.py): header + segment table +
+//    per-segment crc32c in one call, and, given the socket's
+//    descriptor, the frame's write or read in that same call.
 //
 // Plain C ABI so ctypes loads it with no binding generator.
 
+#include <cerrno>
 #include <chrono>
 #include <cstdint>
 #include <cstring>
 #include <condition_variable>
+#include <ctime>
 #include <mutex>
 #include <new>
+
+#include <poll.h>
+#include <sys/socket.h>
+#include <sys/types.h>
+#include <sys/uio.h>
 
 #if defined(__SSE4_2__)
 #include <nmmintrin.h>
@@ -316,28 +326,36 @@ uint32_t ctpu_crc32c_buf(uint32_t crc, const char* data, size_t len) {
     return ctpu_crc32c(crc, reinterpret_cast<const uint8_t*>(data), len);
 }
 
+static void put_header(uint8_t* p, uint32_t msg_type, uint32_t flags,
+                       uint32_t nseg, uint64_t seq) {
+    p[0] = 'C'; p[1] = 'T'; p[2] = 'v'; p[3] = '2';
+    p[4] = msg_type & 0xFF; p[5] = (msg_type >> 8) & 0xFF;
+    p[6] = flags & 0xFF;
+    p[7] = nseg & 0xFF;
+    for (int b = 0; b < 8; b++) p[8 + b] = (seq >> (8 * b)) & 0xFF;
+}
+
+// One table entry: the segment's length and its crc32c.
+static void put_entry(uint8_t* e, const uint8_t* seg, uint64_t len) {
+    uint32_t crc = ctpu_crc32c(0xFFFFFFFFu, seg, len);
+    for (int b = 0; b < 4; b++) e[b] = (len >> (8 * b)) & 0xFF;
+    for (int b = 0; b < 4; b++) e[4 + b] = (crc >> (8 * b)) & 0xFF;
+}
+
 // Assemble header + table + payloads into `out` (caller sizes it as
 // 16 + nseg*8 + sum(lens)). Returns total bytes written.
 size_t ctpu_frame_encode(uint32_t msg_type, uint32_t flags, uint64_t seq,
                          uint32_t nseg, const char* const* segs,
                          const uint64_t* lens, uint8_t* out) {
     uint8_t* p = out;
-    p[0] = 'C'; p[1] = 'T'; p[2] = 'v'; p[3] = '2';
-    p[4] = msg_type & 0xFF; p[5] = (msg_type >> 8) & 0xFF;
-    p[6] = flags & 0xFF;
-    p[7] = nseg & 0xFF;
-    for (int b = 0; b < 8; b++) p[8 + b] = (seq >> (8 * b)) & 0xFF;
+    put_header(p, msg_type, flags, nseg, seq);
     p += 16;
     uint8_t* table = p;
     p += size_t(nseg) * 8;
     for (uint32_t i = 0; i < nseg; i++) {
         const uint8_t* seg = reinterpret_cast<const uint8_t*>(segs[i]);
         uint64_t len = lens[i];
-        uint32_t crc = ctpu_crc32c(0xFFFFFFFFu, seg, len);
-        for (int b = 0; b < 4; b++)
-            table[i * 8 + b] = (len >> (8 * b)) & 0xFF;
-        for (int b = 0; b < 4; b++)
-            table[i * 8 + 4 + b] = (crc >> (8 * b)) & 0xFF;
+        put_entry(table + i * 8, seg, len);
         std::memcpy(p, seg, len);
         p += len;
     }
@@ -367,6 +385,207 @@ int ctpu_frame_verify(const char* table_c, uint32_t nseg,
     }
     if (off != payload_len) return -2;
     return -1;
+}
+
+// ------------------------------------------------------ frame socket I/O
+// The codec takes the socket (msg/messenger.py, clear uncompressed
+// links on a kernel descriptor): a frame is framed, checksummed and
+// written — or read, verified and handed over — inside ONE call, so
+// the caller leaves the interpreter once a frame instead of once a
+// codec call and once a socket call. Same bytes on the wire as
+// ctpu_frame_encode; same checks, in the same order, as
+// wire.decode_frame. A descriptor in non-blocking mode (a Python
+// socket with a timeout) is waited on with poll().
+
+enum : int {
+    CTPU_FRAME_EOF = 0,        // peer closed (at or inside a frame)
+    CTPU_FRAME_DONE = 1,       // whole frame read and verified
+    CTPU_FRAME_BODY = 2,       // header + table read, payload pending
+    CTPU_BAD_MAGIC = -1001,
+    CTPU_BAD_FLAGS = -1002,
+    CTPU_BAD_NSEG = -1003,
+    CTPU_BAD_SECURE = -1004,   // sealed frame on a clear session
+    CTPU_BAD_LENGTH = -1005,   // segment over MAX_SEGMENT_BYTES (info.bad)
+    CTPU_BAD_CRC = -1006,      // segment info.bad: info.got != crcs[bad]
+};
+
+static const uint32_t CTPU_MAX_SEGMENTS = 8;           // wire.MAX_SEGMENTS
+static const uint32_t CTPU_MAX_SEGMENT_BYTES = 1u << 30;
+
+// What ctpu_frame_recv learned of a frame; the caller keeps one a
+// connection (native.FrameReceiver mirrors the layout).
+struct ctpu_frame_info {
+    uint64_t seq;
+    uint64_t total;      // payload bytes (sum of lens)
+    double t_header;     // CLOCK_MONOTONIC (time.perf_counter's clock
+                         // on Linux) when the header was complete
+    uint32_t msg_type;
+    uint32_t flags;
+    uint32_t nseg;
+    uint32_t bad;        // offending segment (BAD_LENGTH / BAD_CRC)
+    uint32_t got;        // its computed crc (BAD_CRC)
+    uint32_t lens[8];
+    uint32_t crcs[8];
+    uint8_t magic[4];    // as received (BAD_MAGIC names it)
+};
+
+static int wait_fd(int fd, short events) {
+    struct pollfd p = {fd, events, 0};
+    for (;;) {
+        if (poll(&p, 1, -1) >= 0) return 0;
+        if (errno != EINTR) return -errno;
+    }
+}
+
+// Exactly n bytes into buf: 1, 0 at EOF, -errno.
+static int recv_all(int fd, uint8_t* buf, size_t n) {
+    while (n > 0) {
+        ssize_t r = recv(fd, buf, n, MSG_WAITALL);
+        if (r > 0) {
+            buf += r;
+            n -= static_cast<size_t>(r);
+        } else if (r == 0) {
+            return CTPU_FRAME_EOF;
+        } else if (errno == EAGAIN || errno == EWOULDBLOCK) {
+            int rc = wait_fd(fd, POLLIN);
+            if (rc < 0) return rc;
+        } else if (errno != EINTR) {
+            return -errno;
+        }
+    }
+    return 1;
+}
+
+// Frame `segs` and write header, table and the segments from where
+// they lie (gather write; a short write resumes where it stopped).
+// Returns the frame's length, or -errno.
+int64_t ctpu_frame_send(int fd, uint32_t msg_type, uint32_t flags,
+                        uint64_t seq, uint32_t nseg,
+                        const char* const* segs, const uint64_t* lens) {
+    if (nseg == 0 || nseg > CTPU_MAX_SEGMENTS) return -EINVAL;
+    for (uint32_t i = 0; i < nseg; i++)
+        if (lens[i] > 0xFFFFFFFFull) return -EMSGSIZE;
+    uint8_t head[16 + CTPU_MAX_SEGMENTS * 8];
+    put_header(head, msg_type, flags, nseg, seq);
+    struct iovec iov[1 + CTPU_MAX_SEGMENTS];
+    int cnt = 1;
+    int64_t total = 16 + int64_t(nseg) * 8;
+    iov[0].iov_base = head;
+    iov[0].iov_len = static_cast<size_t>(total);
+    for (uint32_t i = 0; i < nseg; i++) {
+        const uint8_t* seg = reinterpret_cast<const uint8_t*>(segs[i]);
+        put_entry(head + 16 + i * 8, seg, lens[i]);
+        if (lens[i] == 0) continue;
+        iov[cnt].iov_base = const_cast<uint8_t*>(seg);
+        iov[cnt].iov_len = static_cast<size_t>(lens[i]);
+        cnt++;
+        total += static_cast<int64_t>(lens[i]);
+    }
+    struct iovec* v = iov;
+    while (cnt > 0) {
+        struct msghdr mh;
+        std::memset(&mh, 0, sizeof mh);
+        mh.msg_iov = v;
+        mh.msg_iovlen = static_cast<size_t>(cnt);
+        ssize_t w = sendmsg(fd, &mh, MSG_NOSIGNAL);
+        if (w < 0) {
+            if (errno == EINTR) continue;
+            if (errno == EAGAIN || errno == EWOULDBLOCK) {
+                int rc = wait_fd(fd, POLLOUT);
+                if (rc < 0) return rc;
+                continue;
+            }
+            return -errno;
+        }
+        size_t left = static_cast<size_t>(w);
+        while (cnt > 0 && left >= v->iov_len) {
+            left -= v->iov_len;
+            v++;
+            cnt--;
+        }
+        if (cnt > 0) {
+            v->iov_base = static_cast<uint8_t*>(v->iov_base) + left;
+            v->iov_len -= left;
+        }
+    }
+    return total;
+}
+
+static uint32_t le32(const uint8_t* p) {
+    return uint32_t(p[0]) | uint32_t(p[1]) << 8 | uint32_t(p[2]) << 16
+        | uint32_t(p[3]) << 24;
+}
+
+// Read one frame's header and table, check them as wire.decode_frame
+// does, and — where the payload fits `scratch` — read and verify the
+// payload too (CTPU_FRAME_DONE: the segments lie back to back from
+// scratch[0]). A larger payload is left on the socket
+// (CTPU_FRAME_BODY) for ctpu_frame_recv_body, into buffers the caller
+// sizes from info->lens.
+int ctpu_frame_recv(int fd, uint8_t* scratch, uint64_t cap,
+                    ctpu_frame_info* info) {
+    uint8_t hdr[16];
+    int rc = recv_all(fd, hdr, sizeof hdr);
+    if (rc <= 0) return rc;
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    info->t_header = double(ts.tv_sec) + double(ts.tv_nsec) * 1e-9;
+    std::memcpy(info->magic, hdr, 4);
+    if (std::memcmp(hdr, "CTv2", 4) != 0) return CTPU_BAD_MAGIC;
+    info->msg_type = uint32_t(hdr[4]) | uint32_t(hdr[5]) << 8;
+    info->flags = hdr[6];
+    info->nseg = hdr[7];
+    info->seq = 0;
+    for (int b = 0; b < 8; b++) info->seq |= uint64_t(hdr[8 + b]) << (8 * b);
+    if (info->flags & ~0x03u) return CTPU_BAD_FLAGS;
+    if (info->nseg == 0 || info->nseg > CTPU_MAX_SEGMENTS)
+        return CTPU_BAD_NSEG;
+    if (info->flags & 0x02u) return CTPU_BAD_SECURE;
+    uint8_t table[CTPU_MAX_SEGMENTS * 8];
+    rc = recv_all(fd, table, size_t(info->nseg) * 8);
+    if (rc <= 0) return rc;
+    info->total = 0;
+    for (uint32_t i = 0; i < info->nseg; i++) {
+        info->lens[i] = le32(table + i * 8);
+        info->crcs[i] = le32(table + i * 8 + 4);
+        if (info->lens[i] > CTPU_MAX_SEGMENT_BYTES) {
+            info->bad = i;
+            return CTPU_BAD_LENGTH;
+        }
+        info->total += info->lens[i];
+    }
+    if (info->total > cap) return CTPU_FRAME_BODY;
+    rc = recv_all(fd, scratch, static_cast<size_t>(info->total));
+    if (rc <= 0) return rc;
+    const uint8_t* p = scratch;
+    for (uint32_t i = 0; i < info->nseg; i++) {
+        info->got = ctpu_crc32c(0xFFFFFFFFu, p, info->lens[i]);
+        if (info->got != info->crcs[i]) {
+            info->bad = i;
+            return CTPU_BAD_CRC;
+        }
+        p += info->lens[i];
+    }
+    return CTPU_FRAME_DONE;
+}
+
+// The payload ctpu_frame_recv left on the socket: segment i straight
+// into bufs[i] (info->lens[i] bytes), every crc32c checked before the
+// call returns CTPU_FRAME_DONE.
+int ctpu_frame_recv_body(int fd, ctpu_frame_info* info,
+                         uint8_t* const* bufs) {
+    for (uint32_t i = 0; i < info->nseg; i++) {
+        int rc = recv_all(fd, bufs[i], info->lens[i]);
+        if (rc <= 0) return rc;
+    }
+    for (uint32_t i = 0; i < info->nseg; i++) {
+        info->got = ctpu_crc32c(0xFFFFFFFFu, bufs[i], info->lens[i]);
+        if (info->got != info->crcs[i]) {
+            info->bad = i;
+            return CTPU_BAD_CRC;
+        }
+    }
+    return CTPU_FRAME_DONE;
 }
 
 }  // extern "C"

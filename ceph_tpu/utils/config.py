@@ -241,10 +241,13 @@ OPTIONS: tuple[Option, ...] = (
         "msgr_native_codec", bool, True,
         desc="assemble/verify clear-mode wire frames through the "
              "native C codec (one call per frame: header + segment "
-             "table + per-segment crc32c) when the native tier loads; "
-             "off (or CEPH_TPU_NO_NATIVE) routes every frame through "
-             "the bit-identical pure-Python path — the codec A/B's "
-             "baseline arm",
+             "table + per-segment crc32c) when the native tier loads, "
+             "and on a clear, uncompressed link over a kernel socket "
+             "let that call do the socket I/O too (framed and written, "
+             "or read and verified, without coming back to the "
+             "interpreter in between); off (or CEPH_TPU_NO_NATIVE) "
+             "routes every frame through the bit-identical pure-Python "
+             "codec and Python socket I/O — the codec A/B's baseline arm",
     ),
     Option(
         "msgr_transport", str, "tcp",
