@@ -5,18 +5,26 @@ Once the window has closed, a sample of the objects that the window
 wrote or read is drawn from the seed. For each:
 
 - the client reads it back and the bytes equal the seed's;
-- the k+m shards in the OSDs' stores equal ``reference/``'s encode of
-  those bytes, byte for byte (a healthy read never touches parity, so
-  only this catches a wrong parity from the device);
+- the k+m shards in the OSDs' stores equal the encode, by the pool's
+  plain reference, of those bytes at the object's length now, byte for
+  byte (a healthy read never touches parity, so only this catches a
+  wrong parity from the device);
 - every store's cumulative crc32c of every shard equals the
   reference's wherever it covers the whole shard, and it has to cover
-  it where the object is as first written (the program folds the
-  kernel's per-4-KiB words into it; an overwrite may clear it or leave
-  it short by design, and scrub then passes over it);
+  it where the object was created and since then only appended to (the
+  program folds the kernel's per-4-KiB words into it and carries it
+  from one append to the next; an overwrite may clear it or leave it
+  short by design, and scrub then passes over it);
 - the reference rebuilds the object from a seeded choice of k stored
   shards with a parity shard among them (any k of k+m suffice).
 
-Every comparison is exact: its limit is 0 mismatches."""
+And for a sample, drawn from the seed, of the names that the window
+deleted and that are absent now: the client's ``stat`` and ``read``
+answer ENOENT, and no OSD's store holds a key of any of its shards.
+
+The reference is the configuration's own (``files.reference``): this
+file imports no pool's code by name. Every comparison is exact: its limit is 0
+mismatches."""
 
 from __future__ import annotations
 
@@ -24,11 +32,20 @@ import json
 
 import numpy as np
 
-from .reference import crc32c, rs_vandermonde
+from . import files
+from .reference import crc32c
 from .traffic import generator as traffic
 
 HINFO_ATTR = "hinfo_key"
 CRC_SEED = 0xFFFFFFFF
+
+
+def _draw(items: list[int], seed: int, salt: int, count: int) -> list[int]:
+    """At most ``count`` of ``items``, drawn from the seed."""
+    if len(items) <= count:
+        return items
+    rng = np.random.default_rng(traffic._seed_words(seed) + [salt])
+    return sorted(rng.choice(items, size=count, replace=False).tolist())
 
 
 def sample_objects(gen, seed: int, count: int) -> list[int]:
@@ -40,12 +57,19 @@ def sample_objects(gen, seed: int, count: int) -> list[int]:
         s.idx for s in issued
         if s.ok and gen.objects[s.idx].exists and not gen.objects[s.idx].busy
     })
-    rng = np.random.default_rng(traffic._seed_words(seed) + [0xC4EC])
-    if len(touched) > count:
-        touched = sorted(
-            rng.choice(touched, size=count, replace=False).tolist()
-        )
-    return touched
+    return _draw(touched, seed, 0xC4EC, count)
+
+
+def sample_deleted(gen, seed: int, count: int) -> list[int]:
+    """Up to ``count`` names that a delete issued in the window removed
+    and that nothing has made again, drawn from the seed."""
+    issued, _ = gen.window_samples()
+    gone = sorted({
+        s.idx for s in issued
+        if s.ok and s.kind == "delete" and not gen.objects[s.idx].exists
+        and not gen.objects[s.idx].busy and not gen.objects[s.idx].retired
+    })
+    return _draw(gone, seed, 0xDE1E, count)
 
 
 def _shard_keys(store) -> dict[tuple[str, int], str]:
@@ -60,13 +84,17 @@ def _shard_keys(store) -> dict[tuple[str, int], str]:
 
 def check(cluster, gen, config: dict, seed: int, count: int) -> dict:
     """The numbers compared, each a count of mismatches with limit 0,
-    and how many of each were compared."""
+    how many of each were compared, and ``client_ops``, the ops this
+    comparison sent through the client (the objecter's ledger counts
+    them)."""
     pool = config["pool"]
     k, m, chunk = pool["k"], pool["m"], pool["chunk_size"]
+    reference = files.reference(config)
     out = {
-        "objects": 0, "shards": 0, "csum_objects": 0,
+        "objects": 0, "shards": 0, "csum_objects": 0, "deleted_objects": 0,
         "read_mismatch": 0, "shard_mismatch": 0, "shard_missing": 0,
-        "csum_mismatch": 0, "decode_mismatch": 0,
+        "csum_mismatch": 0, "decode_mismatch": 0, "delete_visible": 0,
+        "shard_leftover": 0, "client_ops": 0,
     }
     picked = sample_objects(gen, seed, count)
     keys = {osd: _shard_keys(store) for osd, store in cluster.stores.items()}
@@ -75,16 +103,14 @@ def check(cluster, gen, config: dict, seed: int, count: int) -> dict:
     for idx in picked:
         st = gen.objects[idx]
         oid = gen.oid(idx)
-        image = traffic.expected_image(
-            gen.seed, idx, st.version, st.n_patches, gen.object_size,
-            gen.max_patch,
-        )
+        image = gen.image(idx)
         out["objects"] += 1
+        out["client_ops"] += 1
         if bytes(cluster.io.read(oid)) != image:
             out["read_mismatch"] += 1
-        want = rs_vandermonde.shards_of(image, k, m, chunk)
+        want = reference.shards_of(image, k, m, chunk)
         stored: dict[int, np.ndarray] = {}
-        fresh = st.version == 1 and st.n_patches == 0
+        fresh = gen.append_only(idx)
         hinfos: list[dict] = []
         acting = cluster.mon.osdmap.object_to_acting(cluster.pool, oid)
         for shard, osd in enumerate(acting):
@@ -116,31 +142,49 @@ def check(cluster, gen, config: dict, seed: int, count: int) -> dict:
             use = list(rng.permutation(parity)[:n_par]) + list(
                 rng.permutation(data)[: k - n_par]
             )
-            rebuilt = rs_vandermonde.decode_data(
+            rebuilt = reference.decode_data(
                 {int(s): stored[int(s)] for s in use}, k, m
             )
-            if rs_vandermonde.object_from_data_shards(
+            if reference.object_from_data_shards(
                 rebuilt, len(image), chunk
             ) != image:
                 out["decode_mismatch"] += 1
         else:
             out["decode_mismatch"] += 1
-    if hashed_objects:
-        # one byte-serial pass over every reference shard of every
-        # object that has them, at once
-        rows = np.concatenate([want for want, _ in hashed_objects], axis=0)
+    # one byte-serial pass over every reference shard of every object
+    # of one length that has them, at once
+    by_width: dict[int, list] = {}
+    for want, hinfos in hashed_objects:
+        by_width.setdefault(want.shape[1], []).append((want, hinfos))
+    for width, group in by_width.items():
+        rows = np.concatenate([want for want, _ in group], axis=0)
         hashes = crc32c.crc32c_rows(CRC_SEED, rows).reshape(-1, k + m)
-        for (want, hinfos), row in zip(hashed_objects, hashes):
+        for (_want, hinfos), row in zip(group, hashes):
             expect = [int(v) for v in row]
             for hinfo in hinfos:
-                if hinfo["total_chunk_size"] != want.shape[1] or [
+                if hinfo["total_chunk_size"] != width or [
                     int(v) for v in hinfo["hashes"]
                 ] != expect:
                     out["csum_mismatch"] += 1
+    for idx in sample_deleted(gen, seed, count):
+        oid = gen.oid(idx)
+        out["deleted_objects"] += 1
+        for ask in (cluster.io.stat, cluster.io.read):
+            out["client_ops"] += 1
+            try:
+                ask(oid)
+            except FileNotFoundError:
+                continue
+            out["delete_visible"] += 1
+        out["shard_leftover"] += sum(
+            (oid, shard) in keys[osd]
+            for osd in keys for shard in range(k + m)
+        )
     return out
 
 
 LIMITS = {
     "read_mismatch": 0, "shard_mismatch": 0, "shard_missing": 0,
-    "csum_mismatch": 0, "decode_mismatch": 0,
+    "csum_mismatch": 0, "decode_mismatch": 0, "delete_visible": 0,
+    "shard_leftover": 0,
 }

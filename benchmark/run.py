@@ -80,7 +80,7 @@ def boot(cell: dict, config: dict):
         n_osds=cluster["n_osds"], k=pool["k"], m=pool["m"],
         pg_num=pool["pg_num"], chunk_size=pool["chunk_size"],
         plugin=pool["plugin"], technique=pool["technique"],
-        use_mesh=bool(mesh), mesh_devices=mesh or None,
+        d=pool.get("d"), use_mesh=bool(mesh), mesh_devices=mesh or None,
         client_op_timeout=cell["client"]["op_timeout_s"],
         client_max_attempts=cell["client"]["max_attempts"],
     )
@@ -184,19 +184,36 @@ def preload(cluster, cell, config, seed, gen_cls):
 
 
 def warm_up(gen, log, cell) -> None:
-    """Ops of the cell's own mix until ``min_ops`` are done and no
-    compilation has ended for ``quiet_s``."""
+    """Ops of the cell's own mix until ``min_ops`` are done, every
+    counter the cell lists under ``warmup.moved`` has moved, and no
+    compilation has ended for ``quiet_s``. ``moved`` is for a program
+    that compiles on a batch the traffic sends only now and then (the
+    overwrite cell's first device delta batch compiles every size of
+    its set): without it a quiet stretch before that batch ends the
+    warm-up and the compilations fall into the window."""
+    from . import counters
+
     spec = cell["warmup"]
+    wanted = spec.get("moved", [])
+    before = counters.snapshot() if wanted else {}
+
+    def still() -> list[str]:
+        now = counters.snapshot() if wanted else {}
+        return [c for c in wanted if now.get(c, 0) <= before.get(c, 0)]
 
     def warm() -> bool:
         quiet = time.perf_counter() - max(log.last(), gen_started)
-        return gen.completed() >= spec["min_ops"] and quiet >= spec["quiet_s"]
+        return (
+            gen.completed() >= spec["min_ops"] and quiet >= spec["quiet_s"]
+            and not still()
+        )
 
     gen_started = time.perf_counter()
     took = clock.wait_until(
         "warmup", cell["deadlines_s"]["warmup"], warm,
         lambda: f"{gen.completed()} ops done (need {spec['min_ops']}), last "
-                f"compilation {time.perf_counter() - log.last():.1f} s ago",
+                f"compilation {time.perf_counter() - log.last():.1f} s ago, "
+                f"counters not moved: {still()}",
     )
     say(f"warm-up: {gen.completed()} ops in {took:.2f} s, "
         f"{len(log.events)} compilations so far "
@@ -303,22 +320,28 @@ def parse(argv):
     return ap.parse_args(argv)
 
 
-def apply_rehearsal(cell: dict, config: dict) -> None:
+def apply_rehearsal(cell: dict, config: dict, mix: dict) -> None:
     tiny = cell.get("rehearse", {})
+    if "names" in mix:
+        mix["names"] = tiny.get("names", mix["names"])
     config["object_size"] = tiny.get("object_size", config["object_size"])
     config["pool"]["pg_num"] = tiny.get("pg_num", config["pool"]["pg_num"])
     cell["preload_objects"] = min(
         cell["preload_objects"], tiny.get("preload_objects", 0)
     )
     cell["warmup"]["min_ops"] = tiny.get("warmup_min_ops", 8)
+    # the CPU's routes are not the chip's: nothing to wait for
+    cell["warmup"].pop("moved", None)
     cell["check_objects"] = tiny.get("check_objects", 4)
 
 
 def run(args, alarm: clock.Alarm, state: dict) -> int:
     cell = files.cell(args.workload)
     config = files.config(cell["config"])
+    mix = files.mix(cell["traffic"])
+    files.reference(config)  # a pool with no reference ends here
     if args.rehearse:
-        apply_rehearsal(cell, config)
+        apply_rehearsal(cell, config, mix)
     budget = alarm_seconds(cell, args.seconds)
     alarm.arm(budget - (time.perf_counter() - _T0), state["alarm_line"])
     say(f"cell {args.workload} seed {args.seed} seconds {args.seconds:g} "
@@ -370,8 +393,8 @@ def run(args, alarm: clock.Alarm, state: dict) -> int:
     standing_fault(cluster, cell)
 
     gen = Generator(
-        cluster.io, files.mix(cell["traffic"]), config["object_size"],
-        config["queue_depth"], args.seed,
+        cluster.io, mix, config["object_size"], config["queue_depth"],
+        args.seed,
     )
     if loader is not None:
         gen.adopt(loader)
@@ -410,19 +433,26 @@ def run(args, alarm: clock.Alarm, state: dict) -> int:
     )
     ledger = _objecter_ledger() - objecter_before
     numbers["ledger_gap"] = abs(gen.issued - gen.accounted) + abs(
-        # the check's own reads went through the same objecter
-        ledger - gen.issued - numbers["objects"]
+        # the check's own ops went through the same objecter
+        ledger - gen.issued - numbers.pop("client_ops")
     )
     numbers["failed_ops"] = failed
     limits = {**check.LIMITS, "ledger_gap": 0, "failed_ops": 0}
-    say("check: " + " ".join(
-        f"{k}={numbers[k]}/limit {limits[k]}" for k in limits
-    ) + f" over objects={numbers['objects']} shards={numbers['shards']} "
-        f"csum_objects={numbers['csum_objects']}")
-    correct = (
-        all(numbers[k] <= limits[k] for k in limits)
-        and numbers["objects"] > 0 and numbers["shards"] > 0
+    # what has to have been compared at all: a mix that deletes has to
+    # have deleted
+    compared = ["objects", "shards", "csum_objects", "deleted_objects"]
+    deletes = any(c["op"] == "delete" for c in mix["classes"])
+    needed = ["objects", "shards"] + ["deleted_objects"] * deletes
+    correct = all(numbers[k] <= limits[k] for k in limits) and all(
+        numbers[k] > 0 for k in needed
     )
+    # every number compared beside its limit: the result line's last
+    # key, and the run's last lines on standard error
+    checked = {
+        **{k: {"value": numbers[k], "limit": limits[k]} for k in limits},
+        **{k: {"value": numbers[k], "at_least": int(k in needed)}
+           for k in compared},
+    }
 
     extra = {}
     if args.trace:
@@ -458,8 +488,9 @@ def run(args, alarm: clock.Alarm, state: dict) -> int:
         device.pop("busy_s", None)
         device.pop("window_s", None)
     state["result"] = result_line(
-        correct, attempted, failed, metrics, device, **extra
+        correct, attempted, failed, metrics, device, **extra, checked=checked
     )
+    state["checked"] = checked
     return 0 if correct else 1
 
 
@@ -516,6 +547,9 @@ def main(argv=None) -> int:
                 say(f"shutdown: {type(e).__name__}: {e}")
         sys.stdout.flush()
         sys.stderr.flush()
+    for name, row in state.get("checked", {}).items():
+        print("check", name, json.dumps(row), file=sys.stderr)
+    sys.stderr.flush()
     if "result" in state:
         print(state["result"], flush=True)
     alarm.disarm()

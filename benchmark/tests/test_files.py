@@ -119,6 +119,130 @@ def test_configs_and_chips():
     assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
 
 
+# ------------------------------------- a pool's reference, found by name
+XOR_REFERENCE = '''
+"""k data shards and their XOR: the plain reference of a k+1 pool."""
+import numpy as np
+
+
+def shards_of(obj, k, m, chunk_size, pool):
+    assert m == 1 and pool["plugin"] == "xor"
+    stripe = k * chunk_size
+    n = -(-len(obj) // stripe)
+    buf = np.zeros(n * stripe, np.uint8)
+    buf[: len(obj)] = np.frombuffer(obj, np.uint8)
+    data = buf.reshape(n, k, chunk_size).transpose(1, 0, 2).reshape(k, -1)
+    return np.concatenate(
+        [data, np.bitwise_xor.reduce(data, axis=0)[None]], axis=0
+    )
+
+
+def decode_data(shards, k, m):
+    missing = [s for s in range(k) if s not in shards]
+    out = {s: shards[s] for s in range(k) if s in shards}
+    if missing:
+        out[missing[0]] = np.bitwise_xor.reduce(
+            np.stack(list(shards.values())), axis=0
+        )
+    return np.stack([out[s] for s in range(k)])
+
+
+def object_from_data_shards(data, size, chunk_size):
+    k = data.shape[0]
+    return data.reshape(k, -1, chunk_size).transpose(1, 0, 2).reshape(
+        -1
+    )[:size].tobytes()
+'''
+
+
+def test_a_configuration_without_the_key_gets_rs_vandermonde():
+    for c in BENCH["configs"]:
+        config = files.config(c["name"])
+        assert "reference" not in config["pool"]
+        assert files.reference(config).name == "rs_vandermonde"
+    ref = files.reference(files.config("rs84-64k"))
+    shards = ref.shards_of(bytes(range(256)) * 256, 8, 4, 4096)
+    assert shards.shape == (12, 8192)
+    data = ref.decode_data({s: shards[s] for s in range(4, 12)}, 8, 4)
+    assert ref.object_from_data_shards(data, 65536, 4096) == (
+        bytes(range(256)) * 256
+    )
+
+
+def test_a_reference_is_a_file_and_gets_the_pool_where_it_asks(
+    tmp_path, monkeypatch
+):
+    (tmp_path / "xor21.py").write_text(XOR_REFERENCE)
+    monkeypatch.setattr(files, "REFERENCE_DIR", str(tmp_path))
+    config = {"name": "toy", "pool": {
+        "plugin": "xor", "k": 2, "m": 1, "reference": "xor21",
+    }}
+    ref = files.reference(config)
+    assert ref.name == "xor21"
+    shards = ref.shards_of(b"ab" * 8, 2, 1, 4)  # takes ``pool`` itself
+    assert shards.shape == (3, 8)
+    assert bytes(shards[2]) == bytes(
+        a ^ b for a, b in zip(bytes(shards[0]), bytes(shards[1]))
+    )
+    data = ref.decode_data({1: shards[1], 2: shards[2]}, 2, 1)
+    assert ref.object_from_data_shards(data, 16, 4) == b"ab" * 8
+
+
+def test_a_name_with_no_file_or_a_file_short_of_a_function_says_which(
+    tmp_path, monkeypatch
+):
+    monkeypatch.setattr(files, "REFERENCE_DIR", str(tmp_path))
+    config = {"name": "toy", "pool": {"k": 2, "m": 1, "reference": "nope"}}
+    with pytest.raises(FileNotFoundError, match="nope"):
+        files.reference(config)
+    (tmp_path / "half.py").write_text(
+        "def shards_of(obj, k, m, chunk_size): ...\n"
+    )
+    config["pool"]["reference"] = "half"
+    with pytest.raises(
+        AttributeError, match="decode_data, object_from_data_shards"
+    ):
+        files.reference(config)
+
+
+#: in the child, before ``main``: the cell's pool becomes a k=2 m=1 XOR
+#: pool whose reference is a file in a directory of the test's own
+TOY_POOL = '''
+import benchmark.files as F
+F.REFERENCE_DIR = {directory!r}
+_config = F.config
+def config(name):
+    c = _config(name)
+    c["pool"].update(plugin="xor", k=2, m=1, reference={reference!r})
+    return c
+F.config = config
+'''
+
+
+def test_the_check_uses_the_reference_the_configuration_names(tmp_path):
+    from .helpers import run_cell
+
+    (tmp_path / "xor21.py").write_text(XOR_REFERENCE)
+    prelude = TOY_POOL.format(directory=str(tmp_path), reference="xor21")
+    code, last, out, _took = run_cell("rs84-64k.write", prelude=prelude)
+    assert code == 0 and last["correct"], out
+    assert last["checked"]["shards"]["value"] == 3 * (
+        last["checked"]["objects"]["value"]
+    )
+    # the same pool held against the Vandermonde code: every parity
+    # shard differs (its first parity row is not all ones)
+    prelude = TOY_POOL.format(directory=files.REFERENCE_DIR,
+                              reference="rs_vandermonde")
+    code, last, out, _took = run_cell("rs84-64k.write", prelude=prelude)
+    assert code != 0 and last["correct"] is False, out
+    assert last["checked"]["shard_mismatch"]["value"] >= 1
+    # and a name with no file ends the run before the cluster boots
+    prelude = TOY_POOL.format(directory=str(tmp_path), reference="nope")
+    code, last, out, took = run_cell("rs84-64k.write", prelude=prelude)
+    assert code != 0 and last is None
+    assert "pool.reference is 'nope'" in out and "boot:" not in out
+
+
 def test_files_under_paths_are_named_from_allowed_characters():
     allowed = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for base, dirs, names in os.walk(files.HERE):

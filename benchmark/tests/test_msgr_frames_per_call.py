@@ -7,13 +7,12 @@ path (a codec call and ``sendall``; three ``recv`` or more and a codec
 call) 0.33 or less. ``io_calls`` is the denominator on purpose: a
 program without the counter reads nothing, not 0."""
 
-import json
 
 import pytest
 
 from benchmark import files, metrics
 
-from .helpers import run_cell
+from .helpers import rehearsal_readings, run_cell
 
 NAME = "msgr_frames_per_call"
 
@@ -82,7 +81,4 @@ def test_a_cell_reads_the_native_share(cell):
         cell, trace=1, devices=files.cell(cell)["chips"]
     )
     assert code == 0 and last["correct"], text
-    readings = json.loads(next(
-        ln for ln in text.splitlines() if "rehearsal readings" in ln
-    ).split("): ", 1)[1])["metrics"]
-    assert 0.67 <= readings[NAME] <= 1.0
+    assert 0.67 <= rehearsal_readings(text)[NAME] <= 1.0

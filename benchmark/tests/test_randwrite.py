@@ -4,7 +4,6 @@ metrics read the counters they name, and ``correct`` turns false when a
 parity page that a patch rewrote is tampered with in a store."""
 
 import fnmatch
-import json
 import time
 
 import pytest
@@ -12,10 +11,9 @@ import pytest
 from benchmark import files, metrics
 from benchmark.traffic import generator as G
 
-from .helpers import run_cell
+from .helpers import PRINT_COUNTER_NAMES, counters_and_readings, run_cell
 from .test_correct import check_numbers
 from .test_generator import DictIo
-from .test_stage_metrics import PRINT_COUNTER_NAMES
 
 CELL = "rs84-rbd.randwrite"
 NEW_METRICS = [
@@ -97,7 +95,7 @@ def test_metric_file_agrees_with_its_entry(name):
     spec, listed = files.metric(name), entry(name)
     for key in ("name", "unit", "better", "source", "layer", "moves"):
         assert spec[key] == listed[key], key
-    assert listed["workloads"] == [CELL]
+    assert CELL in listed["workloads"]
     assert spec["reader"] in ("counter_ratio", "latency_tail")
 
 
@@ -172,14 +170,7 @@ def rehearsal():
         CELL, trace=1, prelude=PRINT_COUNTER_NAMES
     )
     assert code == 0 and last["correct"], text
-    lines = text.splitlines()
-    names = json.loads(next(
-        ln for ln in lines if ln.startswith("COUNTERS ")
-    )[len("COUNTERS "):])
-    readings = json.loads(next(
-        ln for ln in lines if "rehearsal readings" in ln
-    ).split("): ", 1)[1])["metrics"]
-    return names, readings
+    return counters_and_readings(text)
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
@@ -239,6 +230,62 @@ def test_a_tampered_parity_page_of_a_patched_object_is_caught():
     assert "TAMPERED" in out, out
     assert last is not None and last["correct"] is False, out
     assert code != 0
-    numbers = check_numbers(out)
+    numbers = check_numbers(last)
     assert numbers["shard_mismatch"] >= 1, numbers
     assert numbers["read_mismatch"] == 0 and numbers["ledger_gap"] == 0
+
+
+# ----- the warm-up waits for the first device delta batch (PR 32, refusal
+# round: one run in thirteen on the chip ended its warm-up in a quiet
+# stretch before that batch, and its four programs compiled in the window)
+def test_the_cell_waits_for_its_kernel_counter_and_a_rehearsal_does_not():
+    from benchmark import run as R
+
+    cell = files.cell(CELL)
+    assert cell["warmup"]["moved"] == [cell["codec_kernel"]["counter"]]
+    R.apply_rehearsal(cell, files.config(cell["config"]), files.mix("randwrite"))
+    assert "moved" not in cell["warmup"]
+
+
+class _Gen:
+    def completed(self) -> int:
+        return 10**6
+
+
+class _Log:
+    events: list = []
+
+    def last(self) -> float:
+        return 0.0
+
+    def total_seconds(self) -> float:
+        return 0.0
+
+
+@pytest.mark.parametrize("moved", [[], ["set:key"]])
+def test_warm_up_ends_only_once_the_listed_counters_have_moved(
+    monkeypatch, moved
+):
+    from benchmark import clock, counters
+    from benchmark import run as R
+
+    t0 = time.monotonic()
+    # the counter moves 0.4 s in; ops and quiet are there from the start
+    monkeypatch.setattr(
+        counters, "snapshot",
+        lambda: {"set:key": 5 + (time.monotonic() - t0 > 0.4)},
+    )
+    cell = {
+        "warmup": {"min_ops": 1, "quiet_s": 0.0, "moved": moved},
+        "deadlines_s": {"warmup": 5},
+    }
+    R.warm_up(_Gen(), _Log(), cell)
+    took = time.monotonic() - t0
+    assert (took >= 0.4) == bool(moved), took
+    # a counter that never moves is a missed deadline that names it
+    cell = {
+        "warmup": {"min_ops": 1, "quiet_s": 0.0, "moved": ["set:other"]},
+        "deadlines_s": {"warmup": 0.2},
+    }
+    with pytest.raises(clock.DeadlineMissed, match="set:other"):
+        R.warm_up(_Gen(), _Log(), cell)

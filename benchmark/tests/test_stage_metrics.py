@@ -6,13 +6,12 @@ gives a number there. A CPU rehearsal proves names and plumbing, never
 a time."""
 
 import fnmatch
-import json
 
 import pytest
 
 from benchmark import files
 
-from .helpers import run_cell
+from .helpers import PRINT_COUNTER_NAMES, counters_and_readings, run_cell
 
 STAGE_METRICS = [
     "opq_wait_ms", "osd_op_cpu_pct", "msgr_ms_per_mb", "wire_bytes_ratio",
@@ -23,19 +22,6 @@ STAGE_METRICS = [
     "read_finish_ms",
 ]
 CELLS = [w["name"] for w in files.benchmark_json()["workloads"]]
-
-#: in the child, before ``main``: print the window's counter names
-PRINT_COUNTER_NAMES = '''
-import json
-import benchmark.counters as C
-_delta = C.delta
-def delta(before, after):
-    moved = _delta(before, after)
-    print("COUNTERS " + json.dumps(sorted(moved)), flush=True)
-    return moved
-C.delta = delta
-'''
-
 
 def entry(name: str) -> dict:
     return next(
@@ -57,14 +43,7 @@ def rehearsals():
             cell, trace=1, devices=chips, prelude=PRINT_COUNTER_NAMES
         )
         assert code == 0 and last["correct"], text
-        lines = text.splitlines()
-        names = json.loads(next(
-            ln for ln in lines if ln.startswith("COUNTERS ")
-        )[len("COUNTERS "):])
-        readings = json.loads(next(
-            ln for ln in lines if "rehearsal readings" in ln
-        ).split("): ", 1)[1])["metrics"]
-        out[cell] = (names, readings)
+        out[cell] = counters_and_readings(text)
     return out
 
 
@@ -81,9 +60,24 @@ def test_file_loads_and_agrees_with_its_entry(name):
     assert set(cells_of(name)) <= set(CELLS)
 
 
-def test_the_entries_are_the_last_of_the_list_in_this_order():
+def test_the_entries_are_listed_once_each():
+    """Membership, not position: every later PR appends entries."""
     names = [m["name"] for m in files.benchmark_json()["per_layer"]]
-    assert names[-len(STAGE_METRICS):] == STAGE_METRICS
+    for name in STAGE_METRICS:
+        assert names.count(name) == 1, name
+
+
+def test_every_cell_that_writes_lists_the_write_stages():
+    """Open question 15, closed by PR 32: a cell whose mix writes runs
+    every write stage and reads its stores, so it reports them."""
+    writes = {"write_new", "write_full", "write_patch", "append"}
+    for cell in CELLS:
+        mix = files.mix(files.cell(cell)["traffic"])
+        if writes & {c["op"] for c in mix["classes"]}:
+            for name in ("ec_write_assemble_ms", "ec_write_encode_ms",
+                         "ec_write_txn_ms", "ec_write_fanout_ms",
+                         "subop_wait_ms", "store_txn_ms"):
+                assert cell in cells_of(name), (cell, name)
 
 
 @pytest.mark.parametrize("name", STAGE_METRICS)
