@@ -382,7 +382,7 @@ class _PGBackend:
                 self.recovering.add(i)
         return out
 
-    def read_shard_async(self, shard, oid, extents, cb) -> None:
+    def read_shard_async(self, shard, oid, extents, cb, select=None) -> None:
         osd = self.acting[shard]
         key = shard_key(oid, shard)
         if osd == SHARD_NONE or (
@@ -402,12 +402,12 @@ class _PGBackend:
             ):
                 self.daemon.local.read_shard_async(
                     self.daemon.osd_id, key, extents,
-                    lambda _s, res: cb(shard, res),
+                    lambda _s, res: cb(shard, res), select=select,
                 )
         else:
             self.daemon.peers.read_shard_async(
                 osd, key, extents, lambda _s, res: cb(shard, res),
-                logical=shard,
+                logical=shard, select=select,
             )
 
     def read_shard(self, shard, oid, extents):
@@ -2265,6 +2265,7 @@ class OSDDaemon:
         self.local.read_shard_async(
             self.osd_id, msg.oid,
             ExtentSet((s, e) for s, e in msg.extents), reply,
+            select=msg.select(),
         )
 
     def _handle_pg_list(self, conn: Connection, msg: PGList) -> None:

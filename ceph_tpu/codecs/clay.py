@@ -31,12 +31,26 @@ TPU-first deltas from the reference:
   (host-cached), not recursive codec calls.
 - ``is_repair`` is genuinely enabled (the reference currently disables
   it pending its new-EC refactor, ErasureCodeClay.cc:356-368; we
-  implement the documented pre-refactor semantics).
+  implement the documented pre-refactor semantics). On the served
+  path that shows in ``pipeline/read.py``:
+  ``get_min_avail_to_read_shards`` asks ``minimum_to_decode`` for the
+  MISSING wanted shards only, so a client's read of an object whose
+  dead OSD held a data shard takes the d-helper sub-chunk plan
+  (recovery of any one shard likewise); the helpers nobody else wants
+  bytes from go out as one extent plus the plan's runs
+  (``SubchunkSelect``) and come back packed, and
+  ``_repair_fractional`` hands all the window's chunks to
+  ``repair_window`` at once.
+- The served path runs compiled programs, one a repair
+  (``repair_window``) and one an encode (``encode_stacked``), cached
+  for the process by the code's parameters and the padded batch: the
+  host path below stays as the eager entry and the oracle.
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import numpy as np
@@ -53,6 +67,76 @@ from .registry import registry
 
 def _pow_int(a: int, x: int) -> int:
     return a**x
+
+
+#: compiled repair and encode programs, by the code's parameters and
+#: the batch's shape: codec objects are rebuilt for every PG and on
+#: every map change, the programs are the process's
+_PROGRAMS: dict[tuple, object] = {}
+_PROGRAMS_LOCK = threading.Lock()
+#: (parameters, shape) -> set once every lost chunk's repair program
+#: of that shape exists (``ClayCodec._repair_warm``)
+_WARMED: dict[tuple, threading.Event] = {}
+#: a batch is zero-padded to one of these many chunks, so that objects
+#: of every size share a handful of programs and the pair-transform
+#: kernels (eight stripes a block) stay on the path
+MIN_BATCH = 8
+
+
+class _Program:
+    """A jitted function whose first call, the one that compiles, is
+    made by one thread alone: the OSDs' op workers meet a new shape
+    together, and a dozen compilations of the one program side by side
+    cost the set-up what one costs."""
+
+    def __init__(self, fn, counters: tuple[str, str]) -> None:
+        self.fn = jax.jit(fn)
+        #: what a run counts in ``ec_dispatch``: the route of its
+        #: bit-matrix kernel, and the counter that takes its bytes
+        self.route, self.bytes_counter = counters
+        self._first = threading.Lock()
+        self._compiled = False
+
+    def __call__(self, arg):
+        if self._compiled:
+            return self.fn(arg)
+        with self._first:
+            out = self.fn(arg)
+            self._compiled = True
+        return out
+
+    def run(self, arr: np.ndarray, axis: int, batch: int) -> np.ndarray:
+        """One dispatch of ``arr``, zero-padded to ``batch`` along its
+        batch ``axis`` (both codes are linear: zero chunks in, zero
+        out): counted once, its bytes under ``bytes_counter``, then
+        one upload, one launch, one fetch, each a ``codec.*`` stage.
+        The result's leading axis is the batch, cut back."""
+        import jax.numpy as jnp
+
+        from .matrix_codec import _dispatch_counters, codec_stage, count_route
+
+        n = arr.shape[axis]
+        count_route(self.route, nbytes=0)
+        _dispatch_counters().inc(self.bytes_counter, arr.nbytes)
+        with codec_stage("prep"):
+            if n != batch:
+                shape = list(arr.shape)
+                shape[axis] = batch
+                padded = np.zeros(shape, np.uint8)
+                padded[(slice(None),) * axis + (slice(0, n),)] = arr
+                arr = padded
+        with codec_stage("h2d"):
+            dev = jnp.asarray(arr)
+        with codec_stage("launch"):
+            out = self(dev)
+        with codec_stage("fetch"):
+            return np.asarray(out)[:n]
+
+
+def batch_size(n: int) -> int:
+    """The padded batch that carries ``n`` chunks: the next power of
+    two, at least ``MIN_BATCH``."""
+    return max(MIN_BATCH, 1 << (n - 1).bit_length())
 
 
 @functools.lru_cache(maxsize=256)
@@ -610,17 +694,27 @@ class ClayCodec(ErasureCodeBase):
 
         n = self.q * self.t
         zsel = np.asarray(planes)
+        # the group's planes folded onto the lane axis: at 256 B
+        # sub-chunks a plane alone is under the kernels' lane tile and
+        # the decode fell to einsum; a group is planes x sub-chunk wide
+        shape = next(iter(U.values())).shape
+        folded = shape[:-2] + (len(planes) * shape[-1],)
         known = {
-            node: jnp.asarray(U[node][..., zsel, :])
+            node: jnp.asarray(U[node][..., zsel, :]).reshape(folded)
             for node in range(n)
             if node not in erased
         }
         out = self.mds.decode_chunks(set(erased), known)
+        unfolded = shape[:-2] + (len(planes), shape[-1])
         for node in erased:
             if traced:
-                U[node] = U[node].at[..., zsel, :].set(out[node])
+                U[node] = U[node].at[..., zsel, :].set(
+                    out[node].reshape(unfolded)
+                )
             else:
-                U[node][..., zsel, :] = np.asarray(out[node])
+                U[node][..., zsel, :] = np.asarray(out[node]).reshape(
+                    unfolded
+                )
 
     # -- fractional repair ---------------------------------------------
     def repair(
@@ -794,6 +888,210 @@ class ClayCodec(ErasureCodeBase):
         return {
             lost: out if traced else jax.numpy.asarray(out)
         }
+
+    # -- the served path: one program a repair, one an encode ----------
+    def _signature(self) -> tuple:
+        return (
+            self.k, self.m, self.d, self.scalar_mds,
+            self.profile.get("technique") or "",
+        )
+
+    def _kernels_fit(self, b: int, sc: int) -> bool:
+        """Do the pair transforms of a repair of ``b`` chunks at ``sc``
+        bytes a sub-chunk run on ops/clay_kernels.py?"""
+        from ceph_tpu.ops import clay_kernels
+        from ceph_tpu.utils import config
+
+        return bool(
+            config.get("ec_clay_kernels")
+            and self.scalar_mds in ("jerasure", "isa")
+            and clay_kernels.supported(b, sc, self.q, self.t)
+        )
+
+    def _program(self, key: tuple, build):
+        key = (self._signature(),) + key
+        with _PROGRAMS_LOCK:
+            program = _PROGRAMS.get(key)
+            if program is None:
+                program = _PROGRAMS[key] = build()
+        return program
+
+    def _repair_program(self, lost: int, ids: tuple, b: int, w: int):
+        # what the trace branches on is part of the program's name
+        kernels = self._kernels_fit(b, w // (self.sub_chunk_no // self.q))
+
+        def build():
+            codec = self
+
+            def clay_repair(helpers):
+                return codec.repair(
+                    {lost}, {cid: helpers[i] for i, cid in enumerate(ids)}
+                )[lost]
+
+            # one dispatch a repair: counted under the inner decode's
+            # route, its bytes once, under how the pair transforms run
+            known = self.q * (self.t - 1) - (self.k + self.m - 1 - len(ids))
+            route, _ = self.mds._plan_route((b, known, w), False, 0)
+            return _Program(clay_repair, (
+                f"{route}_decode",
+                "clay_kernel_bytes" if kernels else "clay_fallback_bytes",
+            ))
+
+        return self._program(("repair", lost, ids, b, w, kernels), build)
+
+    def repair_window(
+        self, lost: int, helper_ids, helpers: np.ndarray
+    ) -> np.ndarray:
+        """Repair chunk ``lost`` of a window of chunks at once.
+
+        ``helpers`` is ``[d, n, r*sc]``: row i holds helper
+        ``helper_ids[i]``'s repair sub-chunks (the plan's runs, packed
+        in plane order) of each of the window's n chunks. Returns the
+        lost chunks, ``[n, chunk]``, on the host.
+
+        One upload, one launch of one compiled program, one fetch:
+        the pair transforms, the inner MDS decode (planes and stripes
+        on its lane axis) and the scatter all inside ``repair``'s
+        traced path, jitted per (lost chunk, helpers, shape) once a
+        process. The first repair of a shape compiles every lost
+        chunk's program (``_repair_warm``)."""
+        ids = tuple(int(i) for i in helper_ids)
+        n, w = helpers.shape[1:]
+        b = batch_size(n)
+        self._repair_warm(b, w)
+        return self._repair_program(lost, ids, b, w).run(helpers, 1, b)
+
+    def _repair_warm(self, b: int, w: int) -> None:
+        """On the chip, compile the repair program of every chunk for
+        this shape, side by side, while the shape's first repair (and
+        any that arrives meanwhile) waits for all of them: a dead OSD
+        holds another shard in every PG, so traffic would otherwise
+        meet the k+m programs one by one, some inside a measured
+        window. Once a process. Off the chip nothing is measured and
+        the interpreter's compilations are dear: a program compiles
+        when it is first met."""
+        from ceph_tpu.utils import platform
+
+        if not platform.on_tpu():
+            return
+        key = (self._signature(), b, w)
+        with _PROGRAMS_LOCK:
+            done = _WARMED.get(key)
+            first = done is None
+            if first:
+                done = _WARMED[key] = threading.Event()
+        if not first:
+            done.wait()
+            return
+        from concurrent.futures import ThreadPoolExecutor
+
+        chunks = set(range(self.k + self.m))
+
+        def compile_one(lost: int) -> None:
+            ids = tuple(sorted(
+                self.minimum_to_decode({lost}, chunks - {lost})
+            ))
+            jax.block_until_ready(
+                self._repair_program(lost, ids, b, w)(
+                    np.zeros((len(ids), b, w), np.uint8)
+                )
+            )
+
+        try:
+            with ThreadPoolExecutor(len(chunks)) as pool:
+                list(pool.map(compile_one, sorted(chunks)))
+        finally:
+            done.set()
+
+    def _whole_rows(self) -> bool:
+        """Data and parity fill whole rows of the node grid and the
+        pair matrix is the canonical one, [[3, 2], [2, 3]], its own
+        inverse: the geometry ``_encode_whole`` is written for."""
+        return (
+            self.nu == 0
+            and self.k % self.q == 0
+            and self.scalar_mds in ("jerasure", "isa")
+            and self._pair_coeffs((0, 1), 2) == (3, 2)
+            and self._pair_coeffs((1, 0), 3) == (3, 2)
+            and self._pair_coeffs((2, 3), 0) == (3, 2)
+            and self._pair_coeffs((3, 2), 1) == (3, 2)
+        )
+
+    def _encode_whole(self, stripes):
+        """The layered encode as three whole-tensor steps, for tracers
+        (``_decode_layered`` with whole parity rows erased has one
+        score group: every plane holds exactly one parity dot).
+
+        With the planes of a chunk viewed as [q^y, q, q^(t-1-y)]
+        around digit y, the partner of node x of row y in the plane
+        with digit d is node d in the plane with digit x: the
+        transpose of the two axes, and a dot (x == d) is its own
+        partner. The canonical pair transform is then
+        ``f(H) = H ^ 2*(H ^ H^T)`` for every member, dots included,
+        and it is its own inverse. So: U of each data row = f(row);
+        U of the parity nodes = the scalar code's encode of all data
+        U, planes and stripes on the lane axis; parity row = f(its
+        U). A dozen fused ops and one kernel, where the plane-by-plane
+        trace is some three thousand and half a second on the chip."""
+        import jax.numpy as jnp
+
+        q, t = self.q, self.t
+        b, _k, cs = stripes.shape
+
+        def f(row, y):  # [b, q, chunk] of row y
+            h = row.reshape(b, q, q**y, q, cs // q ** (y + 1))
+            return (h ^ _gf_mul2(h ^ jnp.swapaxes(h, 1, 3))).reshape(
+                b, q, cs
+            )
+
+        rows = self.k // q
+        u = jnp.concatenate(
+            [f(stripes[:, y * q : (y + 1) * q], y) for y in range(rows)],
+            axis=1,
+        )
+        pu = self.mds._dispatch_bitmatrix(
+            self.mds._encode_bmat_np, self.mds._encode_bmat, u, "encode"
+        )
+        return jnp.concatenate(
+            [
+                f(pu[:, j * q : (j + 1) * q], rows + j)
+                for j in range(t - rows)
+            ],
+            axis=1,
+        )
+
+    def encode_stacked(self, stacked):
+        """Parity of ``[n, k, chunk]`` stripes as ``[n, m, chunk]``:
+        the layered encode (``encode_chunks`` on tracers) as one
+        compiled program a padded batch size, one upload and one
+        launch where the host path ran 64 planes of numpy and a
+        dispatch a score group. The write pipeline's entry
+        (``ShardExtentMap._dispatch_encode``)."""
+        import jax.numpy as jnp
+
+        stacked = np.asarray(stacked)
+        n, k, cs = stacked.shape
+        b = batch_size(n)
+
+        def build():
+            codec = self
+
+            def clay_encode(stripes):
+                if codec._whole_rows():
+                    return codec._encode_whole(stripes)
+                parity = codec.encode_chunks(
+                    {i: stripes[:, i, :] for i in range(k)}
+                )
+                return jnp.stack(
+                    [parity[k + j] for j in range(codec.m)], axis=1
+                )
+
+            route, _ = self.mds._plan_route((b, k + self.nu, cs), False, 0)
+            return _Program(
+                clay_encode, (f"{route}_encode", f"{route}_encode_bytes")
+            )
+
+        return self._program(("encode", b, cs), build).run(stacked, 0, b)
 
     # -- fast repair (aloof-free: d = k+m-1) ---------------------------
     def _repair_fast(
@@ -1071,18 +1369,14 @@ class ClayCodec(ErasureCodeBase):
         import numpy as _np
 
         from ceph_tpu.ops import clay_kernels
-        from ceph_tpu.utils import config, platform
+        from ceph_tpu.utils import platform
 
         q, t = self.q, self.t
         r = self.sub_chunk_no // q
         sample = helper[next(iter(helper))]
         lead = sample.shape[:-2]
         b = int(_np.prod(lead, initial=1))
-        if (
-            not config.get("ec_clay_kernels")
-            or self.scalar_mds not in ("jerasure", "isa")
-            or not clay_kernels.supported(b, sc, q, t)
-        ):
+        if not self._kernels_fit(b, sc):
             return None
         import jax.numpy as jnp
 
@@ -1114,10 +1408,14 @@ class ClayCodec(ErasureCodeBase):
         bdev = dev_bmat(self.mds._tables, key, bmat_np, True)
         groups = plan["groups"]
         if len(groups) == 1:
-            dec = self.mds._dispatch_bitmatrix_shards(
-                bmat_np, bdev, [U[nd] for nd in present], "decode"
+            # planes and stripes are on the lane axis already ([b,
+            # r*sc] a node): the stacked kernel, as in _repair_fast
+            # (102 GB/s shards form against 267 stacked at c=8)
+            dec = self.mds._dispatch_bitmatrix(
+                bmat_np, bdev,
+                jnp.stack([U[nd] for nd in present], axis=-2), "decode",
             )
-            Uw = dict(zip(want, dec))
+            Uw = {nd: dec[..., i, :] for i, nd in enumerate(want)}
         else:
             Uv = {nd: U[nd].reshape(b, r, sc) for nd in present}
             Uwb = {

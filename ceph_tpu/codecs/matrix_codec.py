@@ -89,6 +89,16 @@ def _dispatch_counters():
         "fused_encode_bytes", "input bytes of the fused encodes"
     )
     b.add_u64_counter(
+        "clay_kernel_bytes",
+        "helper bytes of the CLAY repairs whose pair transforms ran on "
+        "the plane-blocked Pallas kernels (ops/clay_kernels.py)",
+    )
+    b.add_u64_counter(
+        "clay_fallback_bytes",
+        "helper bytes of the CLAY repairs whose pair transforms ran as "
+        "XLA ops (kernels gated off, or a geometry they do not take)",
+    )
+    b.add_u64_counter(
         "fused_fallback",
         "fused encode+csum requests the kernel could not serve "
         "(untileable shape / non-TPU without interpret) — parity "

@@ -202,19 +202,34 @@ class ECSubWriteBatchReply:
 @dataclass
 class ECSubRead:
     """Per-shard read sub-op: oid -> extent list (+ sub-chunk
-    selectors, the CLAY plumbing of ECCommon.h:85)."""
+    selectors, the CLAY plumbing of ECCommon.h:85). With ``subchunks``
+    the extents are whole chunks, ``chunk`` gives (chunk size,
+    sub-chunks a chunk), and the reply carries, per extent, only the
+    selected (index, count) runs of each chunk, packed
+    (``pipeline.extents.SubchunkSelect``)."""
 
     tid: int
     shard: int
     oid: str
     extents: list[tuple[int, int]]  # (start, end) pairs
     subchunks: list[tuple[int, int]] | None = None
+    chunk: tuple[int, int] | None = None
     #: logical EC shard index the caller believes this store holds;
     #: the server cross-checks it against the stored SI attr so a
     #: CRUSH remap can't serve misplaced bytes (None = don't check).
     logical: int | None = None
     trace_id: str | None = None
     parent_span: str | None = None
+
+    def select(self):
+        """The selector this sub-read carries, or None."""
+        if self.subchunks is None or self.chunk is None:
+            return None
+        from ceph_tpu.pipeline.extents import SubchunkSelect
+
+        return SubchunkSelect(
+            self.chunk[0], self.chunk[1], tuple(self.subchunks)
+        )
 
     def encode(self) -> list[bytes]:
         h = {
@@ -225,6 +240,8 @@ class ECSubRead:
             "subchunks": self.subchunks,
             "logical": self.logical,
         }
+        if self.chunk is not None:
+            h["chunk"] = list(self.chunk)
         if self.trace_id is not None:
             h["trace"] = [self.trace_id, self.parent_span]
         return [_header("sub_read", h)]
@@ -240,6 +257,7 @@ class ECSubRead:
             h["oid"],
             [tuple(e) for e in h["extents"]],
             [tuple(s) for s in sub] if sub is not None else None,
+            tuple(h["chunk"]) if h.get("chunk") else None,
             h.get("logical"),
             trace[0],
             trace[1],

@@ -149,6 +149,7 @@ class ShardServer:
                     msg.oid,
                     ExtentSet((s, e) for s, e in msg.extents),
                     reply,
+                    select=msg.select(),
                 )
 
 
@@ -495,6 +496,7 @@ class NetShardBackend:
         extents,
         cb: Callable[[int, object], None],
         logical: int | None = None,
+        select=None,
     ) -> None:
         from ceph_tpu.pipeline.read import ShardReadError
 
@@ -513,6 +515,9 @@ class NetShardBackend:
             tid, shard, oid, [(s, e) for s, e in extents], logical=logical,
             trace_id=t_id, parent_span=t_span,
         )
+        if select is not None:
+            msg.subchunks = list(select.runs)
+            msg.chunk = (select.chunk_size, select.sub_count)
         self._register(
             tid, shard, oid, on_reply, is_read=True,
             resend=lambda: self._conn(shard).send(msg),

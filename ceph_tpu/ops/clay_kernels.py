@@ -70,6 +70,11 @@ STEP_BYTES = 4 << 20
 #: class).  Mosaic compiles ~64 refs comfortably; wider geometries
 #: fall back to the XLA paths.
 MAX_REFS = 64
+#: the device events' names (``%<name>.<n>`` in a trace): pinned, as
+#: ``pallas_encode``'s are, so that a trace tells the two pair-transform
+#: stages of a repair from the inner MDS decode between them
+UNCOUPLED_KERNEL_NAME = "_clay_uncoupled"
+COUPLE_KERNEL_NAME = "_clay_couple"
 
 
 def supported(b: int, sc: int, q: int, t: int) -> bool:
@@ -287,6 +292,7 @@ def _uncoupled_fn(
             out_specs=out_specs,
             out_shape=out_shapes,
             interpret=interpret,
+            name=UNCOUPLED_KERNEL_NAME,
         )(*operands)
         return [o.reshape(b, r * sc) for o in outs]
 
@@ -381,6 +387,7 @@ def _couple_scatter_fn(
                 (b, num_seq, q, seq * sc), jnp.uint8
             ),
             interpret=interpret,
+            name=COUPLE_KERNEL_NAME,
         )(*arrs).reshape(b, q * r * sc)
 
     return apply

@@ -9,8 +9,11 @@ and never reach the device.
 
 from __future__ import annotations
 
+import dataclasses
 from bisect import bisect_right
 from collections.abc import Iterable, Iterator
+
+import numpy as np
 
 
 class ExtentSet:
@@ -142,4 +145,77 @@ class ExtentSet:
             s2 = (s // granularity) * granularity
             e2 = -(-e // granularity) * granularity
             out.insert(s2, e2 - s2)
+        return out
+
+
+@dataclasses.dataclass(frozen=True)
+class SubchunkSelect:
+    """Of every ``chunk_size`` chunk, ``sub_count`` sub-chunks long, the
+    (index, count) sub-chunk ``runs``: the selector a CLAY repair plan
+    gives a helper (ECSubRead's subchunks, ECCommon.h:85). A sub-read
+    that carries one names whole chunks as its extents and gets the
+    selected bytes back packed, chunk after chunk, the runs in order:
+    one extent and at most q^(t-1) runs on the wire and in the store's
+    read, where the byte extents would be a run a chunk each."""
+
+    chunk_size: int
+    sub_count: int
+    runs: tuple[tuple[int, int], ...]
+
+    @property
+    def sub_bytes(self) -> int:
+        return self.chunk_size // self.sub_count
+
+    @property
+    def packed_chunk(self) -> int:
+        """Bytes of one chunk that the runs select."""
+        return sum(count for _i, count in self.runs) * self.sub_bytes
+
+    def byte_extents(self, window: ExtentSet) -> ExtentSet:
+        """The byte ranges selected inside chunk-aligned ``window``."""
+        cs, sub = self.chunk_size, self.sub_bytes
+        out = ExtentSet()
+        for start, end in window:
+            for c in range(start - start % cs, end, cs):
+                for index, count in self.runs:
+                    lo = max(c + index * sub, start)
+                    hi = min(c + (index + count) * sub, end)
+                    out.insert(lo, hi - lo)
+        return out
+
+    def select_into(self, chunks: np.ndarray, out: np.ndarray) -> None:
+        """``out[n, packed_chunk]`` = the runs of ``chunks`` (n whole
+        chunks, flat). A repair plan's runs are evenly spaced and
+        equally long, so they are one strided slice; anything else is
+        a slice a run."""
+        n, sub = out.shape[0], self.sub_bytes
+        (first, count), step = self.runs[0], 0
+        if len(self.runs) > 1:
+            step = self.runs[1][0] - first
+        regular = step == 0 or (
+            first + count <= step
+            and len(self.runs) * step == self.sub_count
+            and all(
+                run == (first + i * step, count)
+                for i, run in enumerate(self.runs)
+            )
+        )
+        if regular:
+            step = step or self.sub_count
+            out.reshape(n, -1, count * sub)[...] = chunks.reshape(
+                n, -1, step * sub
+            )[:, :, first * sub : (first + count) * sub]
+            return
+        src, at = chunks.reshape(n, self.chunk_size), 0
+        for index, count in self.runs:
+            out[:, at : at + count * sub] = src[
+                :, index * sub : (index + count) * sub
+            ]
+            at += count * sub
+
+    def select(self, chunks: np.ndarray) -> np.ndarray:
+        out = np.empty(
+            (chunks.size // self.chunk_size, self.packed_chunk), np.uint8
+        )
+        self.select_into(chunks, out)
         return out

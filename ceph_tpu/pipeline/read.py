@@ -33,7 +33,7 @@ import numpy as np
 
 from ceph_tpu.utils.trace import tracer
 
-from .extents import ExtentSet
+from .extents import ExtentSet, SubchunkSelect
 from .shard_map import ShardExtentMap
 from .stripe import StripeInfo
 
@@ -52,37 +52,27 @@ class ShardReadError(Exception):
 @dataclass
 class ShardRead:
     """One shard's sub-read: extents plus optional sub-chunk selectors
-    (the ``shard_read_t`` analog, ECCommon.h:83-133)."""
+    (the ``shard_read_t`` analog, ECCommon.h:83-133).
+
+    ``subchunks`` marks a helper of a fractional repair and holds the
+    plan's runs. A helper that nobody else wants bytes from goes out
+    with ``select`` set: its extents are the whole chunks of the
+    window, the selector rides beside them as runs (never expanded to
+    a byte range a chunk), and the reply comes back packed into
+    ``packed`` (window start -> the selected bytes). A helper the
+    client also wants is read in full like any wanted shard."""
 
     shard: int
     extents: ExtentSet
     subchunks: list[tuple[int, int]] | None = None  # (index, count) runs
+    select: SubchunkSelect | None = None
+    packed: dict[int, bytes] = field(default_factory=dict)
 
-
-def subchunk_byte_extents(
-    window: ExtentSet,
-    chunk_size: int,
-    sub_chunk_count: int,
-    subchunks: list[tuple[int, int]],
-) -> ExtentSet:
-    """Restrict chunk-granular extents to selected sub-chunk byte ranges.
-
-    Each chunk_size-aligned chunk inside ``window`` contributes only the
-    (index, count) sub-chunk runs — how ECSubRead's subchunk selectors
-    shrink the wire/disk IO for CLAY repair.
-    """
-    sub = chunk_size // sub_chunk_count
-    out = ExtentSet()
-    for start, end in window:
-        c = (start // chunk_size) * chunk_size
-        while c < end:
-            for index, count in subchunks:
-                lo = max(c + index * sub, start)
-                hi = min(c + (index + count) * sub, end)
-                if lo < hi:
-                    out.insert(lo, hi - lo)
-            c += chunk_size
-    return out
+    def wire_runs(self) -> int:
+        """Extents and selector runs the sub-read carries."""
+        return len(self.extents) + len(
+            self.select.runs if self.select else ()
+        )
 
 
 def get_min_avail_to_read_shards(
@@ -145,20 +135,50 @@ def get_min_avail_to_read_shards(
         shard = sinfo.get_shard(raw)
         full = [(0, sub_count)]
         if sub_count > 1 and subchunks and list(subchunks) != full:
-            extents = subchunk_byte_extents(window, cs, sub_count, subchunks)
-            reads[shard] = ShardRead(shard, extents, list(subchunks))
+            reads[shard] = ShardRead(
+                shard, window.copy(), list(subchunks),
+                SubchunkSelect(cs, sub_count, tuple(subchunks)),
+            )
         else:
             reads[shard] = ShardRead(shard, window.copy())
     # Available wanted shards read their own extents on top of any
-    # helper role (the client still needs their bytes verbatim).
+    # helper role (the client still needs their bytes verbatim): such
+    # a helper reads the window whole, its repair planes are a view
+    # of what came back.
     for s, es in want.items():
         if s not in avail or not es:
             continue
         if s in reads:
+            reads[s].select = None
             reads[s].extents.union(es)
         else:
             reads[s] = ShardRead(s, es.copy())
     return reads, True
+
+
+def issue_shard_read(backend, oid: str, sr: ShardRead, cb) -> None:
+    """Send one sub-read; ``cb(shard, result, packed)`` when it is
+    back, ``packed`` saying whether ``result`` holds selected runs."""
+    packed = sr.select is not None
+    backend.read_shard_async(
+        sr.shard, oid, sr.extents,
+        lambda shard, result: cb(shard, result, packed),
+        select=sr.select,
+    )
+
+
+def place_shard_read(
+    result: ShardExtentMap, sr: ShardRead | None, shard: int,
+    buffers: dict, packed: bool,
+) -> None:
+    """A sub-read's reply into the op's state: plain bytes into the
+    shard map, packed repair runs onto the ShardRead that asked."""
+    if packed:
+        if sr is not None:
+            sr.packed.update(buffers)
+        return
+    for start, buf in buffers.items():
+        result.insert(shard, start, buf)
 
 
 def reconstruct_shards(
@@ -169,13 +189,15 @@ def reconstruct_shards(
     shard_reads: dict[int, ShardRead],
     object_size: int,
     error_shards: frozenset[int] | set[int] = frozenset(),
+    perf=None,
 ) -> None:
     """Fill wanted-but-unread shards of ``result`` from its survivors.
 
     Shared by the client read path and shard recovery: CLAY fractional
     repair when the plan carried sub-chunk selectors and exactly one
-    shard is lost, plain windowed decode otherwise.
-    """
+    shard is lost, plain windowed decode otherwise. ``perf``: the
+    caller's counter set, where it keeps the repair counters
+    (``ReadPipeline``)."""
     lost = set()
     for s, es in want.items():
         got = result.get_extent_set(s)
@@ -184,10 +206,10 @@ def reconstruct_shards(
     if not lost:
         return
     fractional = any(sr.subchunks is not None for sr in shard_reads.values())
-    if fractional and len(lost) == 1 and hasattr(codec, "repair"):
+    if fractional and len(lost) == 1 and hasattr(codec, "repair_window"):
         _repair_fractional(
             sinfo, codec, result, want, shard_reads, object_size,
-            error_shards, lost,
+            error_shards, lost, perf,
         )
         return
     result.decode(codec, lost, object_size)
@@ -202,43 +224,52 @@ def _repair_fractional(
     object_size: int,
     error_shards,
     lost: set[int],
+    perf=None,
 ) -> None:
-    """CLAY fractional repair: per chunk in the window, feed each
-    helper's concatenated repair sub-chunks to ``codec.repair``."""
+    """CLAY fractional repair of the one lost shard over the window's
+    chunks at once: every helper's repair sub-chunks as one row of a
+    ``[helpers, chunks, packed bytes]`` stack (a packed sub-read is
+    that row already, a helper read in full gives it as one strided
+    copy), then ``codec.repair_window``: one program on the device."""
     cs = sinfo.chunk_size
-    want_raw = {sinfo.get_raw_shard(s) for s in lost}
+    (lost_shard,) = lost
     helpers = {
-        s: sr for s, sr in shard_reads.items()
+        sinfo.get_raw_shard(s): sr for s, sr in shard_reads.items()
         if s not in error_shards and s not in lost
         and sr.subchunks is not None
     }
     # Window = chunk hull of the wanted extents.
     lo, hi = sinfo.chunk_aligned_hull(want.values())
     n_chunks = (hi - lo) // cs
-    import jax.numpy as jnp
-
-    chunks_in: dict[int, "jnp.ndarray"] = {}
-    for shard, sr in helpers.items():
-        rows = []
-        for c in range(n_chunks):
-            base = lo + c * cs
-            sel = subchunk_byte_extents(
-                ExtentSet([(base, base + cs)]),
-                cs,
-                codec.get_sub_chunk_count(),
-                sr.subchunks or [(0, codec.get_sub_chunk_count())],
-            )
-            parts = [result.get(shard, s, e - s) for s, e in sel]
-            rows.append(np.concatenate(parts))
-        chunks_in[sinfo.get_raw_shard(shard)] = jnp.asarray(np.stack(rows))
-    out = codec.repair(want_raw, chunks_in)
-    for raw in want_raw:
-        shard = sinfo.get_shard(raw)
-        buf = np.asarray(out[raw]).reshape(n_chunks * cs)
-        shard_size = sinfo.object_size_to_shard_size(object_size, shard)
-        end = min(hi, shard_size)
-        if end > lo:
-            result.insert(shard, lo, buf[: end - lo])
+    ids = sorted(helpers)
+    select = SubchunkSelect(
+        cs, codec.get_sub_chunk_count(), tuple(helpers[ids[0]].subchunks)
+    )
+    with tracer.span(
+        "clay_gather", perf=perf, key="repair_gather_seconds"
+    ):
+        stack = np.empty(
+            (len(ids), n_chunks, select.packed_chunk), np.uint8
+        )
+        for row, raw in zip(stack, ids):
+            sr = helpers[raw]
+            packed = sr.packed.get(lo)
+            if packed is not None and len(packed) == row.size:
+                row[...] = np.frombuffer(packed, np.uint8).reshape(row.shape)
+            else:
+                select.select_into(result.get(sr.shard, lo, hi - lo), row)
+    with tracer.span("clay_repair", perf=perf, key="repair_seconds"):
+        out = codec.repair_window(
+            sinfo.get_raw_shard(lost_shard), ids, stack
+        )
+    shard_size = sinfo.object_size_to_shard_size(object_size, lost_shard)
+    end = min(hi, shard_size)
+    if end > lo:
+        result.insert(lost_shard, lo, out.reshape(-1)[: end - lo])
+    if perf is not None:
+        perf.inc("repair_ops")
+        perf.inc("repair_helper_bytes", stack.size)
+        perf.inc("repair_rebuilt_bytes", out.size)
 
 
 class ClientReadOp:
@@ -321,6 +352,23 @@ class ReadPipeline:
                 "finish_seconds",
                 "ec_read.finish: assemble the byte range, complete in order",
             )
+            # the fractional (CLAY) repair inside ec_reconstruct
+            .add_u64_counter(
+                "repair_ops", "reads that rebuilt a shard by repair"
+            )
+            .add_time("repair_seconds", "clay_repair: codec.repair_window")
+            .add_time(
+                "repair_gather_seconds",
+                "clay_gather: the helpers' repair sub-chunks stacked",
+            )
+            .add_u64_counter(
+                "repair_helper_bytes", "helper bytes handed to repair"
+            )
+            .add_u64_counter("repair_rebuilt_bytes", "bytes repair rebuilt")
+            .add_u64_counter(
+                "subread_extents",
+                "extents and sub-chunk runs sent in sub-reads",
+            )
             .create_perf_counters()
         )
 
@@ -392,17 +440,20 @@ class ReadPipeline:
     def _issue(self, op: ClientReadOp, reads: dict[int, ShardRead]) -> None:
         for shard in reads:
             op.pending[shard] = op.pending.get(shard, 0) + 1
+        self.perf.inc(
+            "subread_extents", sum(sr.wire_runs() for sr in reads.values())
+        )
         for sr in list(reads.values()):
-            self.backend.read_shard_async(
-                sr.shard,
-                op.oid,
-                sr.extents,
-                lambda shard, result, _op=op: self._sub_read_done(
-                    _op, shard, result
+            issue_shard_read(
+                self.backend, op.oid, sr,
+                lambda shard, result, packed, _op=op: self._sub_read_done(
+                    _op, shard, result, packed
                 ),
             )
 
-    def _sub_read_done(self, op: ClientReadOp, shard: int, result) -> None:
+    def _sub_read_done(
+        self, op: ClientReadOp, shard: int, result, packed: bool = False
+    ) -> None:
         left = op.pending.get(shard, 0) - 1
         if left > 0:
             op.pending[shard] = left
@@ -412,8 +463,9 @@ class ReadPipeline:
             op.error_shards.add(shard)
             self._retry(op)
         else:
-            for start, buf in result.items():
-                op.result.insert(shard, start, buf)
+            place_shard_read(
+                op.result, op.shard_reads.get(shard), shard, result, packed
+            )
             if not op.pending:
                 self._complete(op)
 
@@ -440,12 +492,16 @@ class ReadPipeline:
                 continue
             already = op.result.get_extent_set(shard)
             prior = op.shard_reads.get(shard)
-            if prior is not None:
+            # a packed sub-read asked for its selector's runs only: it
+            # covers the new plan's extents only if that asks the same
+            if prior is not None and prior.select in (None, sr.select):
                 already = already.copy()
                 already.union(prior.extents)
             missing = sr.extents.difference(already)
             if missing:
-                fresh[shard] = ShardRead(shard, missing, sr.subchunks)
+                fresh[shard] = ShardRead(
+                    shard, missing, sr.subchunks, sr.select
+                )
         # Refresh the sub-chunk selectors to the CURRENT plan: a retry
         # that fell back from fractional repair to full decode must not
         # leave stale selectors steering _reconstruct into codec.repair
@@ -453,12 +509,13 @@ class ReadPipeline:
         for shard, sr in op.shard_reads.items():
             new = reads.get(shard)
             sr.subchunks = new.subchunks if new is not None else None
+            sr.select = new.select if new is not None else None
         for shard, sr in fresh.items():
             if shard in op.shard_reads:
                 op.shard_reads[shard].extents.union(sr.extents)
             else:
                 op.shard_reads[shard] = ShardRead(
-                    shard, sr.extents.copy(), sr.subchunks
+                    shard, sr.extents.copy(), sr.subchunks, sr.select
                 )
         if fresh:
             self._issue(op, fresh)
@@ -506,6 +563,7 @@ class ReadPipeline:
             op.shard_reads,
             self.size_fn(op.oid),
             op.error_shards,
+            self.perf,
         )
 
     def _finish(self, op: ClientReadOp) -> None:

@@ -37,6 +37,8 @@ from .hashinfo import SEED, HashInfo
 from .read import (
     ShardRead,
     get_min_avail_to_read_shards,
+    issue_shard_read,
+    place_shard_read,
     reconstruct_shards,
 )
 from .rmw import HINFO_KEY, OI_KEY, SI_KEY, pack_oi
@@ -230,16 +232,16 @@ class RecoveryBackend:
             return
         op.pending_reads = set(op.shard_reads)
         for sr in list(op.shard_reads.values()):
-            self.backend.read_shard_async(
-                sr.shard,
-                op.oid,
-                sr.extents,
-                lambda shard, result, _op=op: self._read_done(
-                    _op, shard, result
+            issue_shard_read(
+                self.backend, op.oid, sr,
+                lambda shard, result, packed, _op=op: self._read_done(
+                    _op, shard, result, packed
                 ),
             )
 
-    def _read_done(self, op: RecoveryOp, shard: int, result) -> None:
+    def _read_done(
+        self, op: RecoveryOp, shard: int, result, packed: bool = False
+    ) -> None:
         op.pending_reads.discard(shard)
         if isinstance(result, Exception):
             # Recovery retry policy mirrors reads: drop the shard and
@@ -256,27 +258,35 @@ class RecoveryBackend:
             except ValueError as e:
                 op.error = e
                 return
-            for s, sr in op.shard_reads.items():
-                new = reads.get(s)
-                sr.subchunks = new.subchunks if new is not None else None
+            # a helper that was read packed holds its selector's runs
+            # only: where the new plan asks anything else of it (a full
+            # decode after the repair lost a helper), read it again
             fresh = {
                 s: sr
                 for s, sr in reads.items()
-                if s not in op.shard_reads and s not in op.error_shards
+                if s not in op.error_shards and (
+                    s not in op.shard_reads
+                    or op.shard_reads[s].select not in (None, sr.select)
+                )
             }
+            for s, sr in op.shard_reads.items():
+                new = reads.get(s)
+                sr.subchunks = new.subchunks if new is not None else None
+                sr.select = new.select if new is not None else None
             op.shard_reads.update(fresh)
             op.pending_reads.update(fresh)
             for sr in list(fresh.values()):
-                self.backend.read_shard_async(
-                    sr.shard,
-                    op.oid,
-                    sr.extents,
-                    lambda s2, r2, _op=op: self._read_done(_op, s2, r2),
+                issue_shard_read(
+                    self.backend, op.oid, sr,
+                    lambda s2, r2, packed, _op=op: self._read_done(
+                        _op, s2, r2, packed
+                    ),
                 )
         else:
-            for start, buf in result.items():
-                op.result.insert(shard, start, buf)
-                op.read_bytes += len(buf)
+            place_shard_read(
+                op.result, op.shard_reads.get(shard), shard, result, packed
+            )
+            op.read_bytes += sum(len(buf) for buf in result.values())
 
     def _start_writes(self, op: RecoveryOp) -> None:
         size = self._op_size(op)

@@ -33,6 +33,8 @@ from collections import OrderedDict
 from collections.abc import Callable
 from dataclasses import dataclass, field
 
+import numpy as np
+
 from ceph_tpu.codecs.interface import Flag
 from ceph_tpu.store import Transaction
 from ceph_tpu.utils.crash_points import crash_points
@@ -41,7 +43,7 @@ from ceph_tpu.utils.trace import tracer
 
 from .dispatcher import current_tick
 from .extent_cache import CacheOp, ECExtentCache
-from .extents import ExtentSet
+from .extents import ExtentSet, SubchunkSelect
 from .hashinfo import HashInfo
 from .shard_map import ShardExtentMap
 from .stripe import StripeInfo
@@ -271,10 +273,13 @@ class ShardBackend:
         oid: str,
         extents: ExtentSet,
         cb: Callable[[int, "dict[int, bytes] | Exception"], None],
+        select: "SubchunkSelect | None" = None,
     ) -> None:
         """Sub-read fan-out seam (ECSubRead → handle_sub_read). Calls
         ``cb(shard, {offset: bytes})`` or ``cb(shard, ShardReadError)``.
-        Consults the ECInject registry the way handle_sub_read does."""
+        Consults the ECInject registry the way handle_sub_read does.
+        With ``select`` the extents are whole chunks and each comes
+        back as its selected sub-chunk runs, packed."""
         from .inject import ec_inject
         from .read import ShardReadError
 
@@ -287,7 +292,7 @@ class ShardBackend:
                 cb(shard, ShardReadError(shard, oid, kind="missing"))
             else:
                 try:
-                    cb(shard, self.read_shard(shard, oid, extents))
+                    cb(shard, self.read_shard(shard, oid, extents, select))
                 except Exception:
                     # store-level EIO (e.g. a BlockStore csum failure)
                     # answers as a shard error — the reference's
@@ -310,7 +315,10 @@ class ShardBackend:
         for _, run in pending:
             run()
 
-    def read_shard(self, shard: int, oid: str, extents: ExtentSet) -> dict[int, bytes]:
+    def read_shard(
+        self, shard: int, oid: str, extents: ExtentSet,
+        select: "SubchunkSelect | None" = None,
+    ) -> dict[int, bytes]:
         from .inject import ec_inject
 
         store = self.stores[shard]
@@ -321,6 +329,13 @@ class ShardBackend:
             except FileNotFoundError:
                 buf = b""
             buf = buf + b"\0" * (end - start - len(buf))  # zero-pad EOF
+            if select is not None:
+                # one read of the whole chunks, then the plan's runs of
+                # each as one strided copy: the wire and the primary
+                # see a q-th of the window, not a read a run
+                buf = select.select(
+                    np.frombuffer(buf, np.uint8)
+                ).tobytes()
             out[start] = buf
         if ec_inject.test_read_error2(oid, shard):
             # ECInject read type 2: the payload leaves here silently

@@ -13,11 +13,10 @@ import numpy as np
 import pytest
 
 from ceph_tpu.codecs import registry
-from ceph_tpu.pipeline.extents import ExtentSet
+from ceph_tpu.pipeline.extents import ExtentSet, SubchunkSelect
 from ceph_tpu.pipeline.read import (
     ReadPipeline,
     get_min_avail_to_read_shards,
-    subchunk_byte_extents,
 )
 from ceph_tpu.pipeline.rmw import RMWPipeline, ShardBackend
 from ceph_tpu.pipeline.stripe import PAGE_SIZE, StripeInfo
@@ -257,8 +256,8 @@ class TestOrdering:
 
 class TestSubchunkExtents:
     def test_restrict(self):
-        es = subchunk_byte_extents(
-            ExtentSet([(0, 8192)]), 4096, 8, [(0, 2), (4, 2)]
+        es = SubchunkSelect(4096, 8, ((0, 2), (4, 2))).byte_extents(
+            ExtentSet([(0, 8192)])
         )
         # Per 4K chunk with 512B sub-chunks: [0,1024) and [2048,3072).
         assert list(es) == [
@@ -324,7 +323,9 @@ class TestClayFractionalRepair:
         assert all(sr.subchunks is not None for sr in helper_reads.values())
         for s in (4, 5):
             assert (
-                helper_reads[s].extents.size()
+                helper_reads[s].select.byte_extents(
+                    helper_reads[s].extents
+                ).size()
                 == n_stripes * chunk * (Z // q) // Z
             )
 
