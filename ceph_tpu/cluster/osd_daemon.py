@@ -229,6 +229,31 @@ def make_opq_perf(name: str):
     )
 
 
+def make_stats_perf(name: str):
+    """The stats plane's counter set (``perf dump`` section
+    ``osd.<id>.stats``): reports cut, what they held of the tick's wall
+    and of its thread's CPU (``time.thread_time``, as
+    ``osd.<id>.opq:service_cpu_seconds`` is), and how much of the store
+    their census re-read."""
+    from ceph_tpu.utils import PerfCountersBuilder, perf_collection
+
+    return (
+        PerfCountersBuilder(perf_collection, name)
+        .add_u64_counter("reports", "PG-stats reports cut")
+        .add_time("report_seconds", "seconds inside report_pg_stats")
+        .add_time(
+            "report_cpu_seconds",
+            "CPU seconds of the reporting thread over the same intervals",
+        )
+        .add_u64_counter("census_keys", "store keys the reports re-read")
+        .add_u64_counter(
+            "census_walks",
+            "reports that re-read every key (the note could not answer)",
+        )
+        .create_perf_counters()
+    )
+
+
 def make_rmw_crash_perf(name: str):
     """The per-daemon ``rmw_crash`` counter set (``perf dump`` section
     ``osd.<id>.rmw_crash``): how replay converged state after a
@@ -333,6 +358,128 @@ def first_live(acting: "list[int]") -> int:
     OSDMap.pg_primary; one definition, used everywhere the daemon
     derives primacy from an acting list it already holds)."""
     return next((o for o in acting if o != SHARD_NONE), SHARD_NONE)
+
+
+class _StatsCensus:
+    """What a PG-stats report needs of the store, kept from one report
+    to the next: used bytes, key count and, for every PG the store
+    holds keys of (led by this OSD or not, so a change of leadership
+    finds its numbers here), ``{loc: logical size}`` from the OI attr.
+
+    ``refresh`` re-reads only the keys the store's note says
+    transactions touched since the last refresh. It re-reads every
+    key, which is the same code over ``list_objects()`` and is counted
+    as a walk, where the note cannot answer: the first refresh of a
+    daemon (boot, revive), a note that overflowed or that another
+    reader took, a pool of my keys whose ``pg_num`` is not the one they
+    were placed by, a store that keeps no note. A key's PG is hashed
+    once, when the key is first seen."""
+
+    def __init__(self, store, perf) -> None:
+        self.store = store
+        self.perf = perf
+        #: the tick and a forced report may cut at once
+        self.lock = DebugLock("osd.stats")
+        self._cursor: "int | None" = None
+        #: key -> (stored bytes, loc, (pool_id, pgid)); loc and PG are
+        #: None for a key that is no shard of a pool the map has
+        self._keys: dict[str, tuple] = {}
+        self._used = 0
+        #: pool_id -> the pg_num its keys were placed by (None: the
+        #: pool was not in the map)
+        self._pg_num: "dict[int, int | None]" = {}
+        #: loc -> {key: OI size}: an OSD can hold two shards of one
+        #: object while backfill runs, and the smallest key speaks
+        self._shards: dict[str, dict[str, int]] = {}
+        #: (pool_id, pgid) -> {loc: logical size}
+        self._pgs: dict[tuple[int, int], dict[str, int]] = {}
+
+    def refresh(self, osdmap: OSDMap) -> tuple[int, int]:
+        """Bring the census up to the store as it is now; (used
+        bytes, key count). Call under ``lock``."""
+        pg_nums = {
+            spec.pool_id: spec.pg_num for spec in osdmap.pools.values()
+        }
+        touched = None
+        since = getattr(self.store, "touched_since", None)
+        if since is not None:
+            touched, self._cursor = since(self._cursor)
+        if touched is not None and any(
+            pg_nums.get(pool_id) != n for pool_id, n in self._pg_num.items()
+        ):
+            touched = None
+        if touched is None:
+            self.perf.inc("census_walks")
+            self._keys.clear()
+            self._pg_num.clear()
+            self._shards.clear()
+            self._pgs.clear()
+            self._used = 0
+            touched = self.store.list_objects()
+        self.perf.inc("census_keys", len(touched))
+        for key in touched:
+            self._reread(key, pg_nums)
+        return self._used, len(self._keys)
+
+    def sized(self, pool_id: int, pgid: int) -> dict[str, int]:
+        return dict(self._pgs.get((pool_id, pgid), ()))
+
+    def _reread(self, key: str, pg_nums: dict) -> None:
+        old = self._keys.pop(key, None)
+        if old is not None:
+            self._used -= old[0]
+        try:
+            stored = self.store.stat(key)
+        except FileNotFoundError:
+            if old is not None and old[2] is not None:
+                self._place(key, old[1], old[2], None)
+            return
+        except OSError:
+            stored = 0
+        if old is not None:
+            _stored, loc, pg = old
+        else:
+            loc = pg = None
+            try:
+                loc, _si = split_shard_key(key)
+                pool_id, oid = split_loc(loc)
+            except ValueError:
+                pass
+            else:
+                pg_num = self._pg_num[pool_id] = pg_nums.get(pool_id)
+                if pg_num is not None:
+                    from ceph_tpu.placement import stable_hash
+
+                    pg = (pool_id, stable_hash(
+                        str(pool_id), head_of_loc(oid)
+                    ) % pg_num)
+        self._keys[key] = (stored, loc, pg)
+        self._used += stored
+        if pg is None:
+            return
+        try:
+            size, _ev = parse_oi(self.store.getattr(key, OI_KEY))
+        except (FileNotFoundError, KeyError, ValueError):
+            size = 0
+        self._place(key, loc, pg, size)
+
+    def _place(self, key: str, loc: str, pg: tuple, size) -> None:
+        """Record ``key``'s OI size under its loc (None: the key is
+        gone) and set the loc's logical size in its PG."""
+        shards = self._shards.setdefault(loc, {})
+        if size is None:
+            shards.pop(key, None)
+        else:
+            shards[key] = size
+        if shards:
+            self._pgs.setdefault(pg, {})[loc] = shards[min(shards)]
+        else:
+            del self._shards[loc]
+            sized = self._pgs.get(pg)
+            if sized is not None:
+                sized.pop(loc, None)
+                if not sized:
+                    del self._pgs[pg]
 
 
 class _AnyShardStores(dict):
@@ -793,10 +940,12 @@ class OSDDaemon:
         # every osd_stats_report_interval seconds (0 = off)
         self._last_stats_report = 0.0
         self._stats_seq = 0
+        self.stats_pc = make_stats_perf(f"osd.{osd_id}.stats")
+        self._census = _StatsCensus(self.store, self.stats_pc)
         #: (map epoch, {(pool, pgid) I lead per CRUSH}) — the primary
         #: sweep is O(pools x pg_num x CRUSH), so it recomputes only
         #: when the epoch moves, never per report
-        self._led_cache: tuple[int, set] = (-1, set())
+        self._led_cache: tuple[int, dict] = (-1, {})
         # -- watch/notify soft state (osd/Watch.cc role)
         self._watch_lock = DebugLock("osd.watch")
         #: (pool, loc) -> {cookie: Connection}
@@ -4083,12 +4232,32 @@ class OSDDaemon:
         self._last_stats_report = now
         if self._stopped:
             return 0
+        t0, cpu0 = time.perf_counter(), time.thread_time()
+        try:
+            return self._cut_pg_stats()
+        finally:
+            self.stats_pc.inc("reports")
+            self.stats_pc.tinc("report_seconds", time.perf_counter() - t0)
+            self.stats_pc.tinc(
+                "report_cpu_seconds", time.thread_time() - cpu0
+            )
+
+    def _cut_pg_stats(self) -> int:
+        from ceph_tpu.utils import config as _cfg
+
         osdmap = self.osdmap
-        self._stats_seq += 1
         led_keys = self._map_led_pgs(osdmap)
-        # ONE store pass serves every PG's census AND the osd_stat —
-        # per-PG scans would be O(keys x pgs) per report
-        census, used, n_keys = self._stats_census(osdmap, led_keys)
+        # the kept census serves every PG's numbers AND the osd_stat;
+        # it reflects every transaction applied before this line
+        self._stats_seq += 1
+        with self._census.lock:
+            used, n_keys = self._census.refresh(osdmap)
+            census = {
+                (pool, pgid): self._census.sized(
+                    osdmap.pools[pool].pool_id, pgid
+                )
+                for pool, pgid in led_keys if pool in osdmap.pools
+            }
         stats = []
         with self._pg_lock:
             led = [
@@ -4105,7 +4274,7 @@ class OSDDaemon:
             try:
                 stats.append(self._collect_pg_stats(
                     pool, pgid, pg, spec, osdmap,
-                    census.get((pool, pgid), {}),
+                    census.get((pool, pgid), {}), led_keys[pool, pgid],
                 ))
                 covered.add((pool, pgid))
             except Exception:
@@ -4126,7 +4295,7 @@ class OSDDaemon:
             try:
                 stats.append(self._collect_idle_pg_stats(
                     pool, pgid, spec, osdmap,
-                    census.get((pool, pgid), {}),
+                    census.get((pool, pgid), {}), led_keys[pool, pgid],
                 ))
             except Exception:
                 pass
@@ -4146,14 +4315,15 @@ class OSDDaemon:
         except Exception:
             return 0  # a mon hiccup must not kill the tick loop
 
-    def _map_led_pgs(self, osdmap: OSDMap) -> set:
-        """{(pool, pgid) whose CRUSH primary I am}, cached per map
-        epoch — the primary sweep must not run per report."""
+    def _map_led_pgs(self, osdmap: OSDMap) -> dict:
+        """{(pool, pgid) whose CRUSH primary I am: its ``up`` set},
+        cached per map epoch — neither the primary sweep nor a led
+        PG's CRUSH draw must run per report."""
         epoch, cached = self._led_cache
         if epoch == osdmap.epoch:
             return cached
         led = {
-            (pool, pgid)
+            (pool, pgid): tuple(osdmap.pg_to_raw(pool, pgid))
             for pool, spec in osdmap.pools.items()
             for pgid in range(spec.pg_num)
             if osdmap.pg_primary(pool, pgid) == self.osd_id
@@ -4161,55 +4331,9 @@ class OSDDaemon:
         self._led_cache = (osdmap.epoch, led)
         return led
 
-    def _stats_census(
-        self, osdmap: OSDMap, led_keys: set
-    ) -> tuple[dict, int, int]:
-        """One pass over my store: ({(pool, pgid) -> {loc: logical
-        size}} for the PGs in ``led_keys``, used bytes, key count).
-        Logical sizes come from the OI attr (the object_info_t size),
-        shard bytes from stat; keys of PGs led elsewhere only feed
-        the used-bytes total."""
-        from ceph_tpu.placement import stable_hash
-
-        by_id = {
-            spec.pool_id: (pool, spec)
-            for pool, spec in osdmap.pools.items()
-        }
-        census: dict[tuple[str, int], dict[str, int]] = {}
-        used = 0
-        keys = self.store.list_objects()
-        for key in keys:
-            try:
-                used += self.store.stat(key)
-            except (FileNotFoundError, OSError):
-                pass
-            try:
-                loc, _si = split_shard_key(key)
-                pool_id, oid = split_loc(loc)
-            except ValueError:
-                continue
-            entry = by_id.get(pool_id)
-            if entry is None:
-                continue
-            pool, spec = entry
-            pgid = stable_hash(
-                str(pool_id), head_of_loc(oid)
-            ) % spec.pg_num
-            if (pool, pgid) not in led_keys:
-                continue
-            sized = census.setdefault((pool, pgid), {})
-            if loc in sized:
-                continue
-            try:
-                size, _ev = parse_oi(self.store.getattr(key, OI_KEY))
-            except (FileNotFoundError, KeyError, ValueError):
-                size = 0
-            sized[loc] = size
-        return census, used, len(keys)
-
     def _collect_pg_stats(
         self, pool: str, pgid: int, pg: _PG, spec, osdmap: OSDMap,
-        sized: "dict[str, int]",
+        sized: "dict[str, int]", up: tuple,
     ):
         """One pg_stats_t record from live primary state + the shared
         store census (``sized``: loc -> logical size for this PG):
@@ -4267,7 +4391,7 @@ class OSDDaemon:
             pool_id=spec.pool_id,
             pgid=pgid,
             state=tuple(sorted(states)),
-            up=tuple(osdmap.pg_to_raw(pool, pgid)),
+            up=up,
             acting=acting,
             num_objects=n_obj,
             num_bytes=n_bytes,
@@ -4287,7 +4411,7 @@ class OSDDaemon:
 
     def _collect_idle_pg_stats(
         self, pool: str, pgid: int, spec, osdmap: OSDMap,
-        sized: "dict[str, int]",
+        sized: "dict[str, int]", up: tuple,
     ):
         """A pg_stats record for a PG I lead per the map but hold no
         live instance for (no client IO this interval): state from
@@ -4308,7 +4432,7 @@ class OSDDaemon:
             pool_id=spec.pool_id,
             pgid=pgid,
             state=tuple(sorted(states)),
-            up=tuple(osdmap.pg_to_raw(pool, pgid)),
+            up=up,
             acting=acting,
             num_objects=len(sized),
             num_bytes=sum(sized.values()),

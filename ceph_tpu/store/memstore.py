@@ -36,6 +36,11 @@ def make_store_perf(name: str):
     )
 
 
+#: keys the touched-note holds before it gives up: past this a reader
+#: re-reads about as much as a walk of the store would
+NOTE_MAX_KEYS = 4096
+
+
 class _Object:
     __slots__ = ("data", "attrs")
 
@@ -61,6 +66,12 @@ class MemStore:
         #: the owning daemon attaches ``make_store_perf(...)``; a bare
         #: store (tests, tools) counts nothing
         self.perf = None
+        #: the touched-note: every key a transaction has staged since
+        #: the note was last read (``touched_since``); None once it
+        #: outgrew ``NOTE_MAX_KEYS``, so a store nobody reads stops
+        #: noting
+        self._touched: set[str] | None = set()
+        self._touched_cursor = 0
 
     # -- write path ----------------------------------------------------
     def queue_transactions(self, txns: list[Transaction] | Transaction) -> int:
@@ -99,6 +110,10 @@ class MemStore:
                     self._objects.pop(oid, None)
                 else:
                     self._objects[oid] = obj
+            if self._touched is not None:
+                self._touched.update(staged)
+                if len(self._touched) > NOTE_MAX_KEYS:
+                    self._touched = None
             self.committed_seq += 1
             return self.committed_seq
 
@@ -198,6 +213,20 @@ class MemStore:
     def list_objects(self) -> list[str]:
         with self._lock:
             return sorted(self._objects)
+
+    def touched_since(self, cursor: "int | None") -> "tuple[set[str] | None, int]":
+        """(keys that transactions wrote, removed or set an attr of
+        since the call that returned ``cursor``, the next cursor). The
+        keys are None where the note cannot say: ``cursor`` is not the
+        newest one handed out (a first call, or another reader took
+        the note meanwhile), or the note overflowed. A key in the set
+        may be gone again; one that is not in it has its size and
+        attrs unchanged."""
+        with self._lock:
+            keys = self._touched if cursor == self._touched_cursor else None
+            self._touched = set()
+            self._touched_cursor += 1
+            return keys, self._touched_cursor
 
     def __repr__(self) -> str:
         return f"MemStore({self.name!r}, objects={len(self._objects)})"

@@ -557,3 +557,404 @@ class TestLiveStatsPlane:
         assert st["objects"] >= 1
         dump = json.loads((bundle / "pg_dump.json").read_text())
         assert dump["pg_stats"]
+
+
+# -- the kept census (PR 36): a report re-reads what changed ------------
+
+def _walk_census(store, osdmap):
+    """The census as every report walked it until PR 36, kept as the
+    reference: one pass over the whole store, the first key (sorted)
+    of a loc speaking for its logical size. ({(pool_id, pgid): {loc:
+    size}} for every PG, used bytes, key count)."""
+    from ceph_tpu.cluster.osd_daemon import (
+        head_of_loc,
+        split_loc,
+        split_shard_key,
+    )
+    from ceph_tpu.pipeline.rmw import OI_KEY, parse_oi
+    from ceph_tpu.placement import stable_hash
+
+    pg_nums = {s.pool_id: s.pg_num for s in osdmap.pools.values()}
+    census, used = {}, 0
+    keys = store.list_objects()
+    for key in keys:
+        used += store.stat(key)
+        try:
+            loc, _si = split_shard_key(key)
+            pool_id, oid = split_loc(loc)
+        except ValueError:
+            continue
+        if pool_id not in pg_nums:
+            continue
+        pgid = stable_hash(
+            str(pool_id), head_of_loc(oid)
+        ) % pg_nums[pool_id]
+        sized = census.setdefault((pool_id, pgid), {})
+        if loc in sized:
+            continue
+        try:
+            size, _ev = parse_oi(store.getattr(key, OI_KEY))
+        except (FileNotFoundError, KeyError, ValueError):
+            size = 0
+        sized[loc] = size
+    return census, used, len(keys)
+
+
+def _stats_moved(daemon, before=None):
+    now = dict(daemon.stats_pc.dump())
+    if before is None:
+        return now
+    return {k: now[k] - before[k] for k in ("census_keys", "census_walks")}
+
+
+def _assert_reports_equal_walk(cluster):
+    """Every live daemon cuts a report; what the PGMap then holds for
+    each PG and OSD equals the reference walk of that daemon's store,
+    field for field."""
+    osdmap = cluster.mon.osdmap
+    live = [cluster.daemons[i] for i in cluster.live_osds()]
+    for d in live:
+        deadline = time.monotonic() + 10.0
+        while d.osdmap.epoch < osdmap.epoch:
+            assert time.monotonic() < deadline, "map never reached the OSD"
+            time.sleep(0.02)
+        d.report_pg_stats(force=True)
+    pm = cluster.pgmap
+    checked = 0
+    for d in live:
+        census, used, n_keys = _walk_census(d.store, osdmap)
+        assert d._census._pgs == census
+        stat = pm.osd[d.osd_id]
+        assert (stat.used_bytes, stat.num_objects) == (used, n_keys)
+        for pool, spec in osdmap.pools.items():
+            for pgid in range(spec.pg_num):
+                if osdmap.pg_primary(pool, pgid) != d.osd_id:
+                    continue
+                sized = census.get((spec.pool_id, pgid), {})
+                got = pm.pg[(spec.pool_id, pgid)]
+                assert got.primary == d.osd_id
+                assert got.num_objects == len(sized), (pool, pgid)
+                assert got.num_bytes == sum(sized.values()), (pool, pgid)
+                checked += 1
+    return checked
+
+
+def _fake_map(**pg_nums):
+    """What ``_StatsCensus.refresh`` reads of an OSDMap."""
+    from types import SimpleNamespace
+
+    return SimpleNamespace(pools={
+        name: SimpleNamespace(pool_id=int(name[1:]), pg_num=n)
+        for name, n in pg_nums.items()
+    })
+
+
+def _bare_census(n_keys, pool_id=1):
+    """A MemStore holding ``n_keys`` shard keys with OI attrs, and a
+    census over it with a counter set of its own."""
+    from ceph_tpu.cluster.osd_daemon import (
+        _StatsCensus,
+        make_loc,
+        make_stats_perf,
+        shard_key,
+    )
+    from ceph_tpu.pipeline.rmw import OI_KEY, pack_oi
+    from ceph_tpu.store import MemStore, Transaction
+
+    store = MemStore()
+
+    def put(i, size=4096):
+        key = shard_key(make_loc(pool_id, f"obj-{i:06d}"), i % 3)
+        store.queue_transactions(
+            Transaction().write(key, 0, b"x" * (size // 4))
+            .setattr(key, OI_KEY, pack_oi(size))
+        )
+        return key
+
+    for i in range(n_keys):
+        put(i)
+    census = _StatsCensus(store, make_stats_perf("test.census.stats"))
+    return store, census, put
+
+
+class TestKeptCensus:
+    @pytest.mark.parametrize("backend", ["mem", "file"])
+    def test_report_equals_a_full_walk(self, backend, tmp_path):
+        """Random writefull / append / overwrite / truncate / remove /
+        snapshot clone / recovery push over two pools: after every
+        batch each OSD's report equals the walk. MemStore keeps the
+        note; FileStore keeps none and walks every time."""
+        import random
+
+        from ceph_tpu.cluster.osd_daemon import SNAP_SEP
+        from ceph_tpu.loadgen import LoadCluster
+        from ceph_tpu.store import FileStore
+
+        factory = None
+        if backend == "file":
+            def factory(i):
+                return FileStore(str(tmp_path / f"osd{i}"))
+        rng = random.Random(0x36 if backend == "mem" else 0x37)
+        with config.override(osd_stats_report_interval=0.0):
+            cluster = LoadCluster(
+                n_osds=5, k=2, m=1, pg_num=4, chunk_size=1024,
+                store_factory=factory,
+            )
+            try:
+                cluster.mon.osd_pool_create("second", 3, "loadprof")
+                ios = [cluster.io, cluster.client.open_ioctx("second")]
+                live: list[tuple[int, str]] = []
+
+                def one_op():
+                    kind = rng.choice([
+                        "writefull", "writefull", "append", "overwrite",
+                        "truncate", "remove",
+                    ])
+                    if kind == "writefull" or not live:
+                        p = rng.randrange(2)
+                        name = f"o{rng.randrange(24)}"
+                        ios[p].write_full(
+                            name, rng.randbytes(rng.randrange(1, 9000))
+                        )
+                        if (p, name) not in live:
+                            live.append((p, name))
+                        return
+                    p, name = rng.choice(live)
+                    if kind == "append":
+                        ios[p].append(name, rng.randbytes(2048))
+                    elif kind == "overwrite":
+                        ios[p].write(name, rng.randbytes(700), offset=100)
+                    elif kind == "truncate":
+                        ios[p].truncate(name, rng.randrange(0, 3000))
+                    else:
+                        ios[p].remove(name)
+                        live.remove((p, name))
+
+                for batch in range(6):
+                    if batch == 2:
+                        ios[0].snap_create("s1")
+                        ios[1].snap_create("s1")
+                    victim = None
+                    if batch == 4:
+                        # writes a dead OSD misses come back to it as
+                        # recovery pushes, through its store's apply
+                        victim = cluster.least_primary_osd()
+                        cluster.kill(victim)
+                    for _ in range(12):
+                        one_op()
+                    if victim is not None:
+                        cluster.revive(victim)
+                        cluster.daemons[victim].report_pg_stats(force=True)
+                        assert cluster.wait_recovered(timeout=60.0)
+                    assert _assert_reports_equal_walk(cluster) == 7
+                pgs_with_keys = {
+                    pg for d in cluster.daemons.values()
+                    for pg in d._census._pgs
+                }
+                assert len(pgs_with_keys) >= 3
+                assert len({pool_id for pool_id, _ in pgs_with_keys}) == 2
+                assert any(
+                    SNAP_SEP in loc for d in cluster.daemons.values()
+                    for sized in d._census._pgs.values() for loc in sized
+                ), "no write after the snapshot made a clone"
+                for i, d in cluster.daemons.items():
+                    s = _stats_moved(d)
+                    if backend == "file":
+                        assert s["census_walks"] == s["reports"]
+                    else:
+                        assert s["census_walks"] == 1, (i, s)
+            finally:
+                cluster.shutdown()
+
+    @pytest.mark.parametrize("touched", [0, 1, 37])
+    def test_a_report_rereads_the_touched_keys_only(self, touched):
+        store, census, put = _bare_census(2000)
+        osdmap = _fake_map(p1=8)
+        assert census.refresh(osdmap) == (2000 * 1024, 2000)
+        assert census.perf.get("census_keys") == 2000
+        assert census.perf.get("census_walks") == 1
+        for i in range(touched):
+            put(i * 50, size=8192)
+        used, n_keys = census.refresh(osdmap)
+        assert census.perf.get("census_keys") == 2000 + touched
+        assert census.perf.get("census_walks") == 1
+        assert (used, n_keys) == (2000 * 1024 + touched * 1024, 2000)
+        assert (census._pgs, used, n_keys) == _walk_census(store, osdmap)
+
+    def test_a_pg_num_change_is_one_walk(self):
+        store, census, put = _bare_census(300)
+        census.refresh(_fake_map(p1=8))
+        put(5, size=100)
+        wider = _fake_map(p1=16)
+        used, n_keys = census.refresh(wider)
+        assert census.perf.get("census_walks") == 2
+        assert (census._pgs, used, n_keys) == _walk_census(store, wider)
+        assert len(census._pgs) > 8
+        put(6, size=100)
+        census.refresh(wider)
+        assert census.perf.get("census_walks") == 2
+        assert census.perf.get("census_keys") == 300 + 300 + 1
+        # a pool the census holds no key of changes nothing
+        census.refresh(_fake_map(p1=16, p2=4))
+        assert census.perf.get("census_walks") == 2
+
+    def test_keys_of_a_pool_the_map_lacks_are_placed_when_it_comes(self):
+        store, census, put = _bare_census(40, pool_id=2)
+        census.refresh(_fake_map(p1=8))
+        assert census._pgs == {} and len(census._keys) == 40
+        both = _fake_map(p1=8, p2=4)
+        census.refresh(both)
+        assert census.perf.get("census_walks") == 2
+        assert (census._pgs, 40 * 1024, 40) == _walk_census(store, both)
+
+    def test_an_overflowed_note_is_one_walk(self, monkeypatch):
+        from ceph_tpu.store import memstore
+
+        monkeypatch.setattr(memstore, "NOTE_MAX_KEYS", 16)
+        store, census, put = _bare_census(10)
+        osdmap = _fake_map(p1=8)
+        census.refresh(osdmap)
+        for i in range(10, 40):
+            put(i)
+        assert store._touched is None
+        census.refresh(osdmap)
+        assert census.perf.get("census_walks") == 2
+        assert (census._pgs, 40 * 1024, 40) == _walk_census(store, osdmap)
+        put(41)
+        census.refresh(osdmap)
+        assert census.perf.get("census_walks") == 2
+        assert census.perf.get("census_keys") == 10 + 40 + 1
+
+    def test_a_second_reader_of_the_note_costs_each_a_walk(self):
+        """The cursor: a reader whose cursor is not the newest handed
+        out missed keys the other took, and is told so."""
+        store, census, put = _bare_census(20)
+        osdmap = _fake_map(p1=8)
+        census.refresh(osdmap)
+        put(3, size=64)
+        keys, _cursor = store.touched_since(None)
+        assert keys is None
+        used, n_keys = census.refresh(osdmap)
+        assert census.perf.get("census_walks") == 2
+        assert (census._pgs, used, n_keys) == _walk_census(store, osdmap)
+
+    def test_two_shards_of_one_object_and_removal(self):
+        """An OSD holds shards 0 and 2 of one loc while backfill runs:
+        the smallest key's OI speaks, as in the walk; the object goes
+        when its last key does."""
+        from ceph_tpu.cluster.osd_daemon import make_loc, shard_key
+        from ceph_tpu.pipeline.rmw import OI_KEY, pack_oi
+        from ceph_tpu.store import Transaction
+
+        store, census, put = _bare_census(0)
+        osdmap = _fake_map(p1=4)
+        loc = make_loc(1, "twice")
+        k0, k2 = shard_key(loc, 0), shard_key(loc, 2)
+        census.refresh(osdmap)
+        for key, size in ((k2, 500), (k0, 900)):
+            store.queue_transactions(
+                Transaction().write(key, 0, b"y" * 10)
+                .setattr(key, OI_KEY, pack_oi(size))
+            )
+            census.refresh(osdmap)
+            assert (census._pgs, *census.refresh(osdmap)) == (
+                _walk_census(store, osdmap)
+            )
+        assert list(census._pgs.values()) == [{loc: 900}]
+        store.queue_transactions(Transaction().remove(k0))
+        census.refresh(osdmap)
+        assert list(census._pgs.values()) == [{loc: 500}]
+        store.queue_transactions(Transaction().remove(k2))
+        assert census.refresh(osdmap) == (0, 0)
+        assert census._pgs == {} and census._shards == {}
+        assert census.perf.get("census_walks") == 1
+
+    def test_a_new_leader_reports_right_with_no_walk(self):
+        from ceph_tpu.loadgen import LoadCluster
+
+        with config.override(osd_stats_report_interval=0.0):
+            cluster = LoadCluster(
+                n_osds=5, k=2, m=1, pg_num=4, chunk_size=1024,
+            )
+            try:
+                for i in range(16):
+                    cluster.io.write_full(f"lead-{i}", b"L" * (512 + 64 * i))
+                assert _assert_reports_equal_walk(cluster) == 4
+                before_map = cluster.mon.osdmap
+                victim = cluster.most_primary_osd()
+                before = {
+                    i: _stats_moved(d) for i, d in cluster.daemons.items()
+                }
+                cluster.kill(victim)
+                osdmap = cluster.mon.osdmap
+                took_over = {
+                    osdmap.pg_primary(cluster.pool, pgid)
+                    for pgid in range(4)
+                    if before_map.pg_primary(cluster.pool, pgid) == victim
+                }
+                assert took_over and victim not in took_over
+                assert _assert_reports_equal_walk(cluster) == 4
+                for i in cluster.live_osds():
+                    moved = _stats_moved(cluster.daemons[i], before[i])
+                    assert moved["census_walks"] == 0, (i, moved)
+                for pgid in range(4):
+                    got = cluster.pgmap.pg[(osdmap.pools[cluster.pool].pool_id, pgid)]
+                    assert got.primary in cluster.live_osds()
+            finally:
+                cluster.shutdown()
+
+    def test_a_revived_osd_walks_once(self):
+        from ceph_tpu.loadgen import LoadCluster
+
+        with config.override(osd_stats_report_interval=0.0):
+            cluster = LoadCluster(
+                n_osds=5, k=2, m=1, pg_num=4, chunk_size=1024,
+            )
+            try:
+                for i in range(8):
+                    cluster.io.write_full(f"rv-{i}", b"R" * 3000)
+                victim = cluster.most_primary_osd()
+                cluster.daemons[victim].report_pg_stats(force=True)
+                cluster.kill(victim)
+                cluster.io.write_full("rv-while-down", b"D" * 3000)
+                cluster.revive(victim)
+                d = cluster.daemons[victim]
+                assert _stats_moved(d)["census_walks"] == 0
+                d.report_pg_stats(force=True)
+                assert _stats_moved(d)["census_walks"] == 1
+                assert cluster.wait_recovered(timeout=60.0)
+                cluster.io.write_full("rv-after", b"A" * 3000)
+                assert _assert_reports_equal_walk(cluster) == 4
+                d.report_pg_stats(force=True)
+                s = _stats_moved(d)
+                assert s["census_walks"] == 1 and s["reports"] >= 3, s
+            finally:
+                cluster.shutdown()
+
+    def test_the_tick_counts_its_reports(self):
+        """The five counters of ``osd.N.stats`` move on the tick's own
+        reports, and an idle store's report re-reads nothing."""
+        from ceph_tpu.loadgen import LoadCluster
+
+        cluster = LoadCluster(n_osds=4, k=2, m=1, pg_num=4, chunk_size=1024)
+        try:
+            cluster.io.write_full("ticked", b"t" * 2048)
+            d = cluster.daemons[0]
+            deadline = time.monotonic() + 10.0
+            while d.stats_pc.get("reports") < 3:
+                assert time.monotonic() < deadline
+                time.sleep(0.1)
+            settled = _stats_moved(d)
+            assert settled["census_walks"] == 1
+            assert 0 < settled["report_cpu_seconds"]
+            assert settled["report_cpu_seconds"] <= (
+                settled["report_seconds"] * 1.5 + 0.01
+            )
+            while d.stats_pc.get("reports") < settled["reports"] + 2:
+                assert time.monotonic() < deadline
+                time.sleep(0.1)
+            assert _stats_moved(d, settled) == {
+                "census_keys": 0, "census_walks": 0,
+            }
+        finally:
+            cluster.shutdown()
