@@ -49,6 +49,11 @@ from ceph_tpu.utils.optracker import NULL_OP, op_tracker
 
 from .osdmap import SHARD_NONE
 from ceph_tpu.utils.lockdep import DebugLock
+from ceph_tpu.utils.perf_counters import register_thread_roles
+
+# the objecter names its messenger "client": its readers are the
+# client's CPU, not the OSDs' messengers'
+register_thread_roles({"objecter-*": "client", "msgr-client-*": "client"})
 
 
 class NoPrimary(Exception):

@@ -91,10 +91,27 @@ from ceph_tpu.store.memstore import make_store_perf
 from ceph_tpu.utils import tracer
 from ceph_tpu.utils.lockdep import DebugLock
 from ceph_tpu.utils.mclock import MClockScheduler
+from ceph_tpu.utils.perf_counters import register_thread_roles
 
 from . import qos as _qos
 from .osdmap import OSDMap, SHARD_NONE
 from .peering import PgPeeringFsm, crash_points, make_peering_perf
+
+# the daemon's threads by what they serve: a client's op (the worker,
+# its shards, a notify), or the daemon's own upkeep
+register_thread_roles({
+    "osd.*-worker": "op_worker",
+    "osd.*-shard*": "op_worker",
+    "osd.*-notify": "op_worker",
+    "osd.*-tick": "tick",
+    "osd.*-coal": "tick",
+    "osd.*-gc": "tick",
+    "osd.*-scrub-*": "tick",
+    "osd.*-catchup": "tick",
+    "osd.*-backfill-*": "tick",
+    "osd.*-req-poll": "tick",
+    "osd.*-stop": "tick",
+})
 
 #: ops whose re-application a lost-reply resend must not repeat
 _MUTATING_OPS = frozenset(
@@ -595,7 +612,8 @@ class _PGBackend:
 
             if ec_inject.test_write_error3(loc):
                 threading.Thread(
-                    target=self.daemon.stop, daemon=True
+                    target=self.daemon.stop, daemon=True,
+                    name=f"osd.{self.daemon.osd_id}-stop",
                 ).start()
                 return
             with tracer.span(
@@ -704,7 +722,7 @@ class _PG:
         # ack path's locks.
         self.rmw.on_osd_down_inject = lambda: threading.Thread(
             target=lambda: daemon.monitor.osd_down(daemon.osd_id),
-            daemon=True,
+            daemon=True, name=f"osd.{daemon.osd_id}-stop",
         ).start()
         self.reads = ReadPipeline(
             self.sinfo, self.codec, self.backend,
@@ -964,10 +982,14 @@ class OSDDaemon:
         if self.tick_period > 0:
             self._tick_stop = threading.Event()
             self._tick_thread = threading.Thread(
-                target=self._tick_loop, daemon=True
+                target=self._tick_loop, daemon=True,
+                name=f"osd.{self.osd_id}-tick",
             )
             self._tick_thread.start()
-        self._worker = threading.Thread(target=self._worker_loop, daemon=True)
+        self._worker = threading.Thread(
+            target=self._worker_loop, daemon=True,
+            name=f"osd.{self.osd_id}-worker",
+        )
         self._worker.start()
         if self._op_nshards > 1:
             for i in range(self._op_nshards):
@@ -1606,7 +1628,10 @@ class OSDDaemon:
 
     def _maybe_gc_pools(self) -> None:
         if self._doomed_pool_ids and self._gc_clean_streak < 2:
-            threading.Thread(target=self._gc_pools, daemon=True).start()
+            threading.Thread(
+                target=self._gc_pools, daemon=True,
+                name=f"osd.{self.osd_id}-gc",
+            ).start()
 
     def _gc_pools(self) -> None:
         """A deleted pool's shard data is garbage (its id is never
@@ -1686,7 +1711,9 @@ class OSDDaemon:
                 with self._pg_lock:
                     pg._catchup_inflight.discard(shard)
 
-        threading.Thread(target=run, daemon=True).start()
+        threading.Thread(
+            target=run, daemon=True, name=f"osd.{self.osd_id}-catchup"
+        ).start()
 
     def _catch_up_shard(self, pg: _PG, shard: int) -> None:
         """Replay the op log onto a returned member until it is clean
@@ -2298,7 +2325,10 @@ class OSDDaemon:
                 # never applied, the ack never sent; heartbeats and the
                 # mon take it from here. Stop on a side thread — stop()
                 # joins the worker/messenger threads this may run on.
-                threading.Thread(target=self.stop, daemon=True).start()
+                threading.Thread(
+                    target=self.stop, daemon=True,
+                    name=f"osd.{self.osd_id}-stop",
+                ).start()
                 return
             def _applied_ack() -> None:
                 # crash point: the txn is durable in this member's
@@ -2374,7 +2404,10 @@ class OSDDaemon:
                 # abort the daemon mid-batch (ECBackend.cc:922-926):
                 # nothing later applies, no reply — every un-acked
                 # item parks at the sender
-                threading.Thread(target=self.stop, daemon=True).start()
+                threading.Thread(
+                    target=self.stop, daemon=True,
+                    name=f"osd.{self.osd_id}-stop",
+                ).start()
                 return
             acked: list[bool] = []
             with tracer.span(
@@ -2454,7 +2487,7 @@ class OSDDaemon:
             # thread.
             threading.Thread(
                 target=self._run_client_op, args=(conn, msg),
-                name="notify", daemon=True,
+                name=f"osd.{self.osd_id}-notify", daemon=True,
             ).start()
             return
         from ceph_tpu.utils import config as _cfg
@@ -4124,7 +4157,8 @@ class OSDDaemon:
             if key in self._backfills and self._backfills[key].is_alive():
                 return
             t = threading.Thread(
-                target=self._backfill_pg, args=(pool, pgid, pg), daemon=True
+                target=self._backfill_pg, args=(pool, pgid, pg), daemon=True,
+                name=f"osd.{self.osd_id}-backfill-{pool}.{pgid}",
             )
             self._backfills[key] = t
         pg.backfilling = True
@@ -4505,7 +4539,7 @@ class OSDDaemon:
             threading.Thread(
                 target=self._run_scheduled_scrub,
                 args=(pool, pgid, kind),
-                name=f"scrub-{pool}-{pgid}",
+                name=f"osd.{self.osd_id}-scrub-{pool}-{pgid}",
                 daemon=True,
             ).start()
 

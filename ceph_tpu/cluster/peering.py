@@ -116,8 +116,11 @@ import time
 from collections import deque
 
 from ceph_tpu.utils.lockdep import DebugLock
+from ceph_tpu.utils.perf_counters import register_thread_roles
 
 from .osdmap import SHARD_NONE
+
+register_thread_roles({"peering-*": "tick"})
 
 # -- states --------------------------------------------------------------
 RESET = "reset"

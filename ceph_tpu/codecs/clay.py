@@ -998,7 +998,7 @@ class ClayCodec(ErasureCodeBase):
             )
 
         try:
-            with ThreadPoolExecutor(len(chunks)) as pool:
+            with ThreadPoolExecutor(len(chunks), "ec-warm") as pool:
                 list(pool.map(compile_one, sorted(chunks)))
         finally:
             done.set()

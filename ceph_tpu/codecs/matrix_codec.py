@@ -13,7 +13,6 @@ precedent (isa/ErasureCodeIsaTableCache.cc; SURVEY.md section 7
 
 from __future__ import annotations
 
-import functools
 from collections import OrderedDict
 
 import jax
@@ -27,9 +26,13 @@ from ceph_tpu.gf import (
 from ceph_tpu.ops import xor_schedule
 from ceph_tpu.ops.bitplane import gf_encode_bitplane, xor_bytes
 from ceph_tpu.utils import platform
+from ceph_tpu.utils.perf_counters import built_once, register_thread_roles
 
 from .base import ErasureCodeBase
 from .interface import Flag
+
+# the pools that compile a geometry's programs side by side, once
+register_thread_roles({"ec-warm*": "other_python"})
 
 
 @jax.jit
@@ -37,7 +40,7 @@ def _apply_bitmatrix(bmat: jax.Array, shards: jax.Array) -> jax.Array:
     return gf_encode_bitplane(bmat, shards)
 
 
-@functools.lru_cache(maxsize=1)
+@built_once
 def _dispatch_counters():
     """Kernel-path visibility: which engine served each bit-matrix
     application (Pallas MXU kernel / XLA einsum / host GF tables) and
@@ -855,7 +858,7 @@ class MatrixErasureCodec(BitplaneDispatchMixin, ErasureCodeBase):
                 "fused", csum_block, 0,
             )
 
-        with ThreadPoolExecutor(len(batch_sizes())) as pool:
+        with ThreadPoolExecutor(len(batch_sizes()), "ec-warm") as pool:
             list(pool.map(compile_one, batch_sizes()))
 
     def _run_fused(

@@ -48,6 +48,12 @@ from ceph_tpu.cluster.osdmap import SHARD_NONE
 from .faults import FaultSchedule
 from .recorder import DeviceClock, RunRecorder
 from ceph_tpu.utils.lockdep import DebugLock
+from ceph_tpu.utils.perf_counters import register_thread_roles
+
+# ``bench-*``: the benchmark's own generator and harness
+# (``benchmark/traffic/generator.py``: ``bench-issue``, ``bench-reap``),
+# the load generator of a measured run
+register_thread_roles({"loadgen-*": "client", "bench-*": "client"})
 
 from .spec import (
     Popularity,

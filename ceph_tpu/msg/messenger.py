@@ -35,6 +35,7 @@ import itertools
 import socket
 import threading
 import time
+import weakref
 import zlib
 from collections.abc import Callable
 
@@ -45,6 +46,12 @@ from . import wire
 from .wire import BadFrame, decode_frame, encode_frame
 from ceph_tpu.utils import lockdep
 from ceph_tpu.utils.lockdep import DebugLock
+from ceph_tpu.utils.perf_counters import register_thread_roles
+
+# a messenger's threads carry its name (``msgr-<name>-rd`` a link's
+# reader, ``-acc`` the accepter, ``-hs`` an accept's handshake), so
+# that whoever names a messenger can claim its threads for another role
+register_thread_roles({"msgr-*": "msgr", "net-fault-timer": "msgr"})
 
 
 #: listening addr -> messenger name, registered at bind() — how a
@@ -417,10 +424,23 @@ def make_net_perf(name: str):
     daemon's links, and what the dedup tiers absorbed — the
     observability half of the chaos contract (injected faults MUST
     show up here, absorbed duplicates MUST show up there, and the
-    ledger still balances exactly-once)."""
+    ledger still balances exactly-once). Last the interpreter lock:
+    what the native frame calls on this set's messengers' links kept
+    of their hand-overs (:meth:`Messenger.hand_overs`), read when the
+    set is dumped; a link on the Python frame path keeps none and
+    reads 0."""
     from ceph_tpu.utils import PerfCountersBuilder, perf_collection
 
-    return (
+    def lock_sums() -> dict:
+        with _messengers_lock:
+            mine = [m for m in _messengers if m.net_pc is pc]
+        return dict(zip(
+            ("lock_waits", "lock_waits_slow", "call_seconds",
+             "lock_wait_seconds"),
+            _sum4(m.hand_overs() for m in mine),
+        ))
+
+    pc = (
         PerfCountersBuilder(perf_collection, name)
         .add_u64_counter("frames_sent", "frames written to a socket")
         .add_u64_counter("bytes_sent", "framed bytes written")
@@ -457,8 +477,29 @@ def make_net_perf(name: str):
             "dedup_hits",
             "resent client mutations replayed from the reqid cache",
         )
+        .add_sampled_group(lock_sums, {
+            "lock_waits":
+                "native frame calls that gave the interpreter lock up "
+                "and took it back (io_calls of the native frame path)",
+            "lock_waits_slow":
+                "of them, waits of one switch interval "
+                "(sys.getswitchinterval) or more",
+            "call_seconds":
+                "inside those calls with the lock given up: crc, copy, "
+                "socket (a receive counts from its header in hand)",
+            "lock_wait_seconds":
+                "waiting to hold the interpreter lock again at the end "
+                "of those calls (inside PyEval_RestoreThread)",
+        })
         .create_perf_counters()
     )
+    return pc
+
+
+def _sum4(rows) -> list:
+    """Column sums of ``(calls, slow, call seconds, wait seconds)``
+    rows (``native.HandOvers.read``)."""
+    return [sum(col) for col in zip((0, 0, 0.0, 0.0), *rows)]
 
 
 # In-the-clear handshake frame type for secure-mode nonce exchange
@@ -523,8 +564,25 @@ class Connection:
             and not messenger.compress
             and isinstance(sock, socket.socket)
         )
-        self._reader = threading.Thread(target=self._read_loop, daemon=True)
+        #: where the native frame calls keep their hand-overs of the
+        #: interpreter lock (``native.HandOvers``: the send side's,
+        #: written under the send lock, and the reader's); None on a
+        #: link the codec never takes, which then reports 0
+        self._tx_ho = self._rx_ho = None
+        self._ho_folded = False  # into the messenger's total, at close
+        if self._kernel_clear:
+            self._tx_ho, self._rx_ho = wire.hand_overs(), wire.hand_overs()
+        self._reader = threading.Thread(
+            target=self._read_loop, daemon=True,
+            name=f"msgr-{messenger.name}-rd",
+        )
         self._reader.start()
+
+    def hand_overs(self) -> list:
+        """Both sides' hand-overs of the interpreter lock, summed."""
+        return _sum4(
+            ho.read() for ho in (self._tx_ho, self._rx_ho) if ho is not None
+        )
 
     def _handshake(self, is_client: bool) -> None:
         # Bounded: a peer that connects and goes silent must not wedge
@@ -632,7 +690,8 @@ class Connection:
                     fd = self._fd_enter()
                     try:
                         nbytes = wire.send_frame(
-                            io, fd, message_type(msg), self._seq, segments
+                            io, fd, message_type(msg), self._seq, segments,
+                            self._tx_ho,
                         )
                     finally:
                         self._fd_exit()
@@ -700,7 +759,7 @@ class Connection:
         result as :meth:`_recv_py`."""
         rx = self._rx_frames
         if rx is None:
-            rx = self._rx_frames = io.FrameReceiver()
+            rx = self._rx_frames = io.FrameReceiver(self._rx_ho)
         fd = self._fd_enter()
         try:
             msg_type, _seq, segments = wire.recv_frame(io, fd, rx)
@@ -760,6 +819,12 @@ class Connection:
         self._close_sock()
 
 
+#: every messenger of the process (a ``net`` set finds the ones it is
+#: attached to when it is dumped)
+_messengers: "weakref.WeakSet[Messenger]" = weakref.WeakSet()
+_messengers_lock = DebugLock("msgr.registry")
+
+
 class Messenger:
     """Bind/connect endpoint + dispatcher registry."""
 
@@ -789,7 +854,11 @@ class Messenger:
         self._stopping = False
         self._conns: set[Connection] = set()
         self._lock = DebugLock("msgr.conns")
+        #: hand-overs of the interpreter lock on links that have closed
+        self._ho_closed = [0, 0, 0.0, 0.0]
         self.addr: tuple[str, int] | None = None
+        with _messengers_lock:
+            _messengers.add(self)
 
     def set_dispatcher(self, fn: Callable[[Connection, object], None]) -> None:
         self.dispatcher = fn
@@ -818,7 +887,8 @@ class Messenger:
         # gate decides at connect() time whether anyone upgrades)
         shm_ring.register(self.addr, self)
         self._accept_thread = threading.Thread(
-            target=self._accept_loop, daemon=True
+            target=self._accept_loop, daemon=True,
+            name=f"msgr-{self.name}-acc",
         )
         self._accept_thread.start()
         return self.addr
@@ -837,7 +907,8 @@ class Messenger:
             # handshake blocks up to its 5 s timeout, and one silent
             # connector must not starve other peers' accepts.
             threading.Thread(
-                target=self._finish_accept, args=(sock,), daemon=True
+                target=self._finish_accept, args=(sock,), daemon=True,
+                name=f"msgr-{self.name}-hs",
             ).start()
         try:
             self._listener.close()
@@ -870,6 +941,7 @@ class Messenger:
                 target=target._finish_accept,
                 args=(server_sock,),
                 daemon=True,
+                name=f"msgr-{target.name}-hs",
             ).start()
             conn = Connection(
                 client_sock, self, is_client=True, peer_name=target.name
@@ -895,6 +967,24 @@ class Messenger:
     def _conn_closed(self, conn: Connection) -> None:
         with self._lock:
             self._conns.discard(conn)
+            self._fold(conn)
+
+    def _fold(self, conn: Connection) -> None:
+        """Keep a closing link's hand-overs (once; under ``_lock``)."""
+        if conn._ho_folded:
+            return
+        conn._ho_folded = True
+        self._ho_closed = _sum4([self._ho_closed, conn.hand_overs()])
+
+    def hand_overs(self) -> list:
+        """``[calls, slow, call seconds, wait seconds]``: what the
+        native frame calls kept of the interpreter lock's hand-overs
+        on this messenger's links, the live ones' structs plus the
+        closed ones' totals."""
+        with self._lock:
+            return _sum4(
+                [self._ho_closed, *(c.hand_overs() for c in self._conns)]
+            )
 
     def shutdown(self) -> None:
         self._stopping = True
@@ -907,6 +997,8 @@ class Messenger:
             self._accept_thread.join(timeout=1.0)
         with self._lock:
             conns = list(self._conns)
+            for conn in conns:
+                self._fold(conn)
             self._conns.clear()
         for conn in conns:
             conn.close()

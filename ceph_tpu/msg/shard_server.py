@@ -56,6 +56,9 @@ from .messages import (
 from .messenger import Connection, Messenger
 from ceph_tpu.utils import lockdep
 from ceph_tpu.utils.lockdep import DebugLock, DebugRLock
+from ceph_tpu.utils.perf_counters import register_thread_roles
+
+register_thread_roles({"*-hb": "tick"})
 
 
 class ShardServer:
@@ -810,7 +813,9 @@ class NetShardBackend:
                     if age > grace:
                         self._mark_down(shard, "ping silence")
 
-        self._hb_thread = threading.Thread(target=loop, daemon=True)
+        self._hb_thread = threading.Thread(
+            target=loop, daemon=True, name=f"{self.messenger.name}-hb"
+        )
         self._hb_thread.start()
 
     def stop_heartbeat(self) -> None:

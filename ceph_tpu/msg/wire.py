@@ -250,13 +250,25 @@ def frame_io():
     return _codec()
 
 
-def send_frame(io, fd: int, msg_type: int, seq: int, segments) -> int:
+def hand_overs():
+    """A struct for one side of a link whose frames the native codec
+    may write or read (``native.hand_overs``), or None with no native
+    tier or where its calls cannot hand the lock over themselves."""
+    mod = _native()
+    return mod.hand_overs() if mod is not None else None
+
+
+def send_frame(
+    io, fd: int, msg_type: int, seq: int, segments, ho=None
+) -> int:
     """Frame ``segments`` and write them to ``fd`` in one native call
     (the bytes are ``encode_frame``'s). Returns the frame's length;
-    raises ``OSError`` as ``sendall`` would."""
+    raises ``OSError`` as ``sendall`` would. ``ho`` is the sender's
+    ``io.hand_overs()``: the call keeps its hand-over of the
+    interpreter lock there."""
     if not 0 < len(segments) <= MAX_SEGMENTS:
         raise ValueError(f"1..{MAX_SEGMENTS} segments, got {len(segments)}")
-    n = io.frame_send(fd, msg_type, 0, seq, segments)
+    n = io.frame_send(fd, msg_type, 0, seq, segments, ho)
     if n < 0:
         raise OSError(-n, os.strerror(-n))
     return n

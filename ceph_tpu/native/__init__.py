@@ -18,6 +18,7 @@ import hashlib
 import os
 import platform
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -32,6 +33,11 @@ _CXXFLAGS = (
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+#: the handle the three frame socket calls are bound through: a
+#: ``ctypes.PyDLL`` of the same library where the interpreter lock's
+#: two functions could be handed over (the calls then give the lock up
+#: and take it back themselves, and time the take-back), else ``_lib``
+_frame_lib: ctypes.CDLL | None = None
 
 
 def _host_cpu_flags() -> str:
@@ -148,27 +154,69 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.ctpu_frame_verify.argtypes = [
         ctypes.c_char_p, ctypes.c_uint32, ctypes.c_char_p, ctypes.c_uint64,
     ]
-    # frame socket I/O: the codec takes the descriptor (one call a frame)
+    lib.ctpu_task_cpu.restype = ctypes.c_int
+    lib.ctpu_task_cpu.argtypes = [
+        ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_double),
+        ctypes.c_int,
+    ]
+    lib.ctpu_lock_hooks.restype = ctypes.c_int
+    lib.ctpu_lock_hooks.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_double,
+    ]
+
+
+def _bind_frame_io(lib: ctypes.CDLL) -> None:
+    """Frame socket I/O: the codec takes the descriptor (one call a
+    frame). The last argument of each is the caller's
+    :class:`HandOvers`, or None."""
+    ho = ctypes.POINTER(HandOvers)
     lib.ctpu_frame_send.restype = ctypes.c_int64
     lib.ctpu_frame_send.argtypes = [
         ctypes.c_int, ctypes.c_uint32, ctypes.c_uint32, ctypes.c_uint64,
         ctypes.c_uint32,
         ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+        ho,
     ]
     lib.ctpu_frame_recv.restype = ctypes.c_int
     lib.ctpu_frame_recv.argtypes = [
         ctypes.c_int, ctypes.c_void_p, ctypes.c_uint64,
-        ctypes.POINTER(FrameInfo),
+        ctypes.POINTER(FrameInfo), ho,
     ]
     lib.ctpu_frame_recv_body.restype = ctypes.c_int
     lib.ctpu_frame_recv_body.argtypes = [
         ctypes.c_int, ctypes.POINTER(FrameInfo),
-        ctypes.POINTER(ctypes.c_void_p),
+        ctypes.POINTER(ctypes.c_void_p), ho,
     ]
 
 
+def _lock_api() -> "tuple[int, int] | None":
+    """Addresses of ``PyEval_SaveThread`` / ``PyEval_RestoreThread``,
+    or None where this interpreter does not export them."""
+    try:
+        api = ctypes.pythonapi
+        return tuple(
+            ctypes.cast(getattr(api, name), ctypes.c_void_p).value
+            for name in ("PyEval_SaveThread", "PyEval_RestoreThread")
+        )
+    except (AttributeError, OSError):
+        return None
+
+
+def _frame_handle(lib: ctypes.CDLL, lib_path: str) -> ctypes.CDLL:
+    """The handle of the frame socket calls. With the interpreter
+    lock's two functions in hand they go through ``ctypes.PyDLL`` (the
+    call is entered holding the lock and hands it over itself), else
+    through ``lib`` as every other function, and no :class:`HandOvers`
+    is ever passed."""
+    api = _lock_api()
+    if api is None or not lib.ctpu_lock_hooks(*api, sys.getswitchinterval()):
+        lib.ctpu_lock_hooks(None, None, 0.0)
+        return lib
+    return ctypes.PyDLL(lib_path)
+
+
 def _load() -> ctypes.CDLL | None:
-    global _lib, _tried
+    global _lib, _frame_lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
@@ -181,9 +229,11 @@ def _load() -> ctypes.CDLL | None:
         try:
             lib = ctypes.CDLL(lib_path)
             _bind(lib)
+            frame_lib = _frame_handle(lib, lib_path)
+            _bind_frame_io(frame_lib)
         except OSError:
             return None
-        _lib = lib
+        _lib, _frame_lib = lib, frame_lib
         return _lib
 
 
@@ -289,6 +339,35 @@ class FrameInfo(ctypes.Structure):
     ]
 
 
+class HandOvers(ctypes.Structure):
+    """``struct ctpu_hand_overs``: what the frame socket calls that
+    were handed this struct kept of their hand-overs of the interpreter
+    lock. One owner (a connection's send side, under its send lock; its
+    reader thread); the call writes it holding the interpreter lock, so
+    any thread reads whole values."""
+
+    _fields_ = [
+        ("calls", ctypes.c_uint64),
+        ("slow", ctypes.c_uint64),
+        ("call_seconds", ctypes.c_double),
+        ("wait_seconds", ctypes.c_double),
+    ]
+
+    def read(self) -> tuple[int, int, float, float]:
+        """(calls, waits of a switch interval or more, seconds inside
+        the calls, seconds waiting to hold the lock again)."""
+        return self.calls, self.slow, self.call_seconds, self.wait_seconds
+
+
+def hand_overs() -> "HandOvers | None":
+    """A zeroed :class:`HandOvers` for one side of a connection, or
+    None where the frame calls do not hand the lock over themselves
+    (no native tier, or an interpreter that does not export the two
+    functions): such a link reports 0, not a guess."""
+    _load()
+    return HandOvers() if isinstance(_frame_lib, ctypes.PyDLL) else None
+
+
 #: ctpu_frame_recv / ctpu_frame_recv_body results (negative values
 #: above -1000 are -errno)
 FRAME_EOF, FRAME_DONE, FRAME_BODY = 0, 1, 2
@@ -312,29 +391,35 @@ _new_bytes.restype = ctypes.py_object
 _new_bytes.argtypes = [ctypes.c_char_p, ctypes.c_ssize_t]
 
 
-def frame_send(fd: int, msg_type: int, flags: int, seq: int, segments) -> int:
+def frame_send(
+    fd: int, msg_type: int, flags: int, seq: int, segments, ho=None
+) -> int:
     """Frame ``segments`` and write the frame to descriptor ``fd`` in
     one native call: crc32c a segment, header and table on the stack,
     a gather write of the segments from where they lie. The bytes on
     the wire are :func:`frame_encode`'s. Returns the frame's length or
-    ``-errno``."""
-    lib = _load()
-    if lib is None:
+    ``-errno``. ``ho`` (from :func:`hand_overs`) is where the call
+    keeps its hand-over of the interpreter lock."""
+    if _load() is None:
         raise RuntimeError("native runtime unavailable")
     nseg, ptrs, lens = _seg_arrays(segments)
-    return lib.ctpu_frame_send(fd, msg_type, flags, seq, nseg, ptrs, lens)
+    return _frame_lib.ctpu_frame_send(
+        fd, msg_type, flags, seq, nseg, ptrs, lens, ho
+    )
 
 
 class FrameReceiver:
     """One connection's receive state for the native read: the scratch
     buffer small payloads land in and the :class:`FrameInfo` the call
-    fills. Used by one thread (the connection's reader)."""
+    fills. Used by one thread (the connection's reader). ``ho`` (from
+    :func:`hand_overs`) is where its calls keep their hand-overs of the
+    interpreter lock."""
 
-    def __init__(self) -> None:
-        lib = _load()
-        if lib is None:
+    def __init__(self, ho=None) -> None:
+        if _load() is None:
             raise RuntimeError("native runtime unavailable")
-        self._lib = lib
+        self._lib = _frame_lib
+        self._ho = ho
         self._scratch = bytearray(FRAME_SCRATCH_BYTES)
         self._view = memoryview(self._scratch)
         self._addr = ctypes.addressof(
@@ -359,7 +444,7 @@ class FrameReceiver:
         info = self.info
         self.calls = 1
         rc = self._lib.ctpu_frame_recv(
-            fd, self._addr, FRAME_SCRATCH_BYTES, info
+            fd, self._addr, FRAME_SCRATCH_BYTES, info, self._ho
         )
         if rc not in (FRAME_DONE, FRAME_BODY):
             return rc, None
@@ -376,7 +461,7 @@ class FrameReceiver:
                 else:
                     segs[i] = _new_bytes(None, n)
                     bufs[i] = ctypes.cast(segs[i], ctypes.c_void_p)
-            rc = self._lib.ctpu_frame_recv_body(fd, info, bufs)
+            rc = self._lib.ctpu_frame_recv_body(fd, info, bufs, self._ho)
             if rc != FRAME_DONE:
                 return rc, None
         pos = 0
@@ -385,6 +470,26 @@ class FrameReceiver:
                 segs[i] = bytes(self._view[pos : pos + n])
                 pos += n
         return rc, segs
+
+
+# -- CPU seconds by task -------------------------------------------------
+def task_cpu() -> dict[int, float]:
+    """``{task id: CPU seconds}`` of every task of this process, read
+    in one native pass over ``/proc/self/task`` (``schedstat`` where
+    the kernel has it, else ``stat``'s utime + stime)."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError("native runtime unavailable")
+    cap = 1024
+    while True:
+        tids = (ctypes.c_uint64 * cap)()
+        seconds = (ctypes.c_double * cap)()
+        n = lib.ctpu_task_cpu(tids, seconds, cap)
+        if n < 0:
+            raise OSError(-n, os.strerror(-n))
+        if n <= cap:
+            return dict(zip(tids[:n], seconds[:n]))
+        cap = 2 * n
 
 
 # -- GF region ops -------------------------------------------------------

@@ -23,14 +23,19 @@
 #include <cstdint>
 #include <cstring>
 #include <condition_variable>
+#include <cstdio>
+#include <cstdlib>
 #include <ctime>
 #include <mutex>
 #include <new>
 
+#include <dirent.h>
+#include <fcntl.h>
 #include <poll.h>
 #include <sys/socket.h>
 #include <sys/types.h>
 #include <sys/uio.h>
+#include <unistd.h>
 
 #if defined(__SSE4_2__)
 #include <nmmintrin.h>
@@ -429,6 +434,89 @@ struct ctpu_frame_info {
     uint8_t magic[4];    // as received (BAD_MAGIC names it)
 };
 
+// ---- the interpreter lock, given and taken back by the call itself
+// Once ctpu_lock_hooks has been handed the interpreter's two functions
+// (PyEval_SaveThread / PyEval_RestoreThread, as plain pointers: no
+// Python header is needed to build this file) the three frame calls
+// below are entered WITH the interpreter lock held (the binding goes
+// through ctypes.PyDLL then): each gives the lock up at entry and
+// takes it back as its last instruction, and where the caller passed
+// a ctpu_hand_overs it stamps both sides of the take-back. Without
+// the hooks the caller (ctypes.CDLL) dropped the lock itself, and a
+// call touches neither the lock nor a clock nor the struct.
+struct ctpu_hand_overs {
+    uint64_t calls;        // frame calls made
+    uint64_t slow;         // of them, waits of a switch interval or more
+    double call_seconds;   // entry (recv: header in hand) to the last
+                           // instruction before the lock is asked back
+    double wait_seconds;   // inside PyEval_RestoreThread
+};
+
+static void* (*g_lock_give)(void) = nullptr;
+static void (*g_lock_take)(void*) = nullptr;
+static double g_switch_interval = 0.005;
+
+// The binding's one call, before any frame call: both pointers or
+// neither. Returns 1 where the frame calls are to be entered with the
+// lock held.
+int ctpu_lock_hooks(void* give, void* take, double switch_interval) {
+    if (give == nullptr || take == nullptr) {
+        g_lock_give = nullptr;
+        g_lock_take = nullptr;
+        return 0;
+    }
+    g_lock_give = reinterpret_cast<void* (*)(void)>(give);
+    g_lock_take = reinterpret_cast<void (*)(void*)>(take);
+    g_switch_interval = switch_interval;
+    return 1;
+}
+
+static inline double mono_now() {
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return double(ts.tv_sec) + double(ts.tv_nsec) * 1e-9;
+}
+
+// One frame call's hold on "the lock is not mine": made at entry,
+// closed by done() as the call's last instruction.
+struct lock_given {
+    void (*take)(void*);
+    ctpu_hand_overs* ho;
+    void* state;
+    double t_in;
+
+    explicit lock_given(ctpu_hand_overs* h)
+        : take(g_lock_take), ho(h), state(nullptr), t_in(0.0) {
+        if (g_lock_give == nullptr) {
+            take = nullptr;
+            return;
+        }
+        if (ho != nullptr) t_in = mono_now();
+        state = g_lock_give();
+    }
+
+    // `since`: where call_seconds starts (0: at entry; a receive
+    // passes the header's stamp, negative where no header came, so
+    // that an idle link adds nothing).
+    void done(double since = 0.0) {
+        if (take == nullptr) return;
+        if (ho == nullptr) {
+            take(state);
+            return;
+        }
+        double t_out = mono_now();
+        take(state);
+        double t_back = mono_now();
+        // written with the lock held: a dump reads whole values
+        ho->calls += 1;
+        if (since >= 0.0)
+            ho->call_seconds += t_out - (since > 0.0 ? since : t_in);
+        double waited = t_back - t_out;
+        ho->wait_seconds += waited;
+        if (waited >= g_switch_interval) ho->slow += 1;
+    }
+};
+
 static int wait_fd(int fd, short events) {
     struct pollfd p = {fd, events, 0};
     for (;;) {
@@ -459,9 +547,9 @@ static int recv_all(int fd, uint8_t* buf, size_t n) {
 // Frame `segs` and write header, table and the segments from where
 // they lie (gather write; a short write resumes where it stopped).
 // Returns the frame's length, or -errno.
-int64_t ctpu_frame_send(int fd, uint32_t msg_type, uint32_t flags,
-                        uint64_t seq, uint32_t nseg,
-                        const char* const* segs, const uint64_t* lens) {
+static int64_t frame_send(int fd, uint32_t msg_type, uint32_t flags,
+                          uint64_t seq, uint32_t nseg,
+                          const char* const* segs, const uint64_t* lens) {
     if (nseg == 0 || nseg > CTPU_MAX_SEGMENTS) return -EINVAL;
     for (uint32_t i = 0; i < nseg; i++)
         if (lens[i] > 0xFFFFFFFFull) return -EMSGSIZE;
@@ -511,6 +599,16 @@ int64_t ctpu_frame_send(int fd, uint32_t msg_type, uint32_t flags,
     return total;
 }
 
+int64_t ctpu_frame_send(int fd, uint32_t msg_type, uint32_t flags,
+                        uint64_t seq, uint32_t nseg,
+                        const char* const* segs, const uint64_t* lens,
+                        ctpu_hand_overs* ho) {
+    lock_given lock(ho);
+    int64_t rc = frame_send(fd, msg_type, flags, seq, nseg, segs, lens);
+    lock.done();
+    return rc;
+}
+
 static uint32_t le32(const uint8_t* p) {
     return uint32_t(p[0]) | uint32_t(p[1]) << 8 | uint32_t(p[2]) << 16
         | uint32_t(p[3]) << 24;
@@ -522,14 +620,13 @@ static uint32_t le32(const uint8_t* p) {
 // scratch[0]). A larger payload is left on the socket
 // (CTPU_FRAME_BODY) for ctpu_frame_recv_body, into buffers the caller
 // sizes from info->lens.
-int ctpu_frame_recv(int fd, uint8_t* scratch, uint64_t cap,
-                    ctpu_frame_info* info) {
+static int frame_recv(int fd, uint8_t* scratch, uint64_t cap,
+                      ctpu_frame_info* info) {
     uint8_t hdr[16];
+    info->t_header = -1.0;
     int rc = recv_all(fd, hdr, sizeof hdr);
     if (rc <= 0) return rc;
-    struct timespec ts;
-    clock_gettime(CLOCK_MONOTONIC, &ts);
-    info->t_header = double(ts.tv_sec) + double(ts.tv_nsec) * 1e-9;
+    info->t_header = mono_now();
     std::memcpy(info->magic, hdr, 4);
     if (std::memcmp(hdr, "CTv2", 4) != 0) return CTPU_BAD_MAGIC;
     info->msg_type = uint32_t(hdr[4]) | uint32_t(hdr[5]) << 8;
@@ -569,11 +666,20 @@ int ctpu_frame_recv(int fd, uint8_t* scratch, uint64_t cap,
     return CTPU_FRAME_DONE;
 }
 
+int ctpu_frame_recv(int fd, uint8_t* scratch, uint64_t cap,
+                    ctpu_frame_info* info, ctpu_hand_overs* ho) {
+    lock_given lock(ho);
+    int rc = frame_recv(fd, scratch, cap, info);
+    // from the header's stamp: the wait for a header is an idle link
+    lock.done(info->t_header);
+    return rc;
+}
+
 // The payload ctpu_frame_recv left on the socket: segment i straight
 // into bufs[i] (info->lens[i] bytes), every crc32c checked before the
 // call returns CTPU_FRAME_DONE.
-int ctpu_frame_recv_body(int fd, ctpu_frame_info* info,
-                         uint8_t* const* bufs) {
+static int frame_recv_body(int fd, ctpu_frame_info* info,
+                           uint8_t* const* bufs) {
     for (uint32_t i = 0; i < info->nseg; i++) {
         int rc = recv_all(fd, bufs[i], info->lens[i]);
         if (rc <= 0) return rc;
@@ -586,6 +692,76 @@ int ctpu_frame_recv_body(int fd, ctpu_frame_info* info,
         }
     }
     return CTPU_FRAME_DONE;
+}
+
+int ctpu_frame_recv_body(int fd, ctpu_frame_info* info,
+                         uint8_t* const* bufs, ctpu_hand_overs* ho) {
+    lock_given lock(ho);
+    int rc = frame_recv_body(fd, info, bufs);
+    lock.done();
+    return rc;
+}
+
+// ------------------------------------------------- CPU seconds by task
+// One pass over /proc/self/task: each task's id and the CPU seconds it
+// has used, from `schedstat` (nanoseconds on the CPU) where the kernel
+// has it, else from `stat` (utime + stime, in clock ticks). Returns
+// the number of tasks found (at most `cap` are written) or -errno.
+static int read_small(const char* path, char* buf, size_t cap) {
+    int fd = open(path, O_RDONLY | O_CLOEXEC);
+    if (fd < 0) return -1;
+    ssize_t n = read(fd, buf, cap - 1);
+    close(fd);
+    if (n <= 0) return -1;
+    buf[n] = 0;
+    return static_cast<int>(n);
+}
+
+int ctpu_task_cpu(uint64_t* tids, double* seconds, int cap) {
+    DIR* d = opendir("/proc/self/task");
+    if (d == nullptr) return -errno;
+    static const double tick = 1.0 / double(sysconf(_SC_CLK_TCK));
+    int n = 0;
+    int from_sched = -1;  // decided by the first task that answers
+    char path[64], buf[1024];
+    while (struct dirent* e = readdir(d)) {
+        if (e->d_name[0] < '0' || e->d_name[0] > '9') continue;
+        double cpu = -1.0;
+        if (from_sched != 0) {
+            std::snprintf(path, sizeof path, "/proc/self/task/%s/schedstat",
+                          e->d_name);
+            if (read_small(path, buf, sizeof buf) > 0) {
+                cpu = double(std::strtoull(buf, nullptr, 10)) * 1e-9;
+                from_sched = 1;
+            } else if (from_sched < 0) {
+                from_sched = 0;
+            }
+        }
+        if (from_sched == 0) {
+            std::snprintf(path, sizeof path, "/proc/self/task/%s/stat",
+                          e->d_name);
+            if (read_small(path, buf, sizeof buf) > 0) {
+                // the name may hold spaces and brackets: fields are
+                // counted from the LAST ')'; utime and stime are the
+                // 12th and 13th after it
+                const char* p = std::strrchr(buf, ')');
+                unsigned long long ut = 0, st = 0;
+                if (p != nullptr && std::sscanf(
+                        p + 1,
+                        " %*c %*d %*d %*d %*d %*d %*u %*u %*u %*u %*u"
+                        " %llu %llu", &ut, &st) == 2)
+                    cpu = double(ut + st) * tick;
+            }
+        }
+        if (cpu < 0.0) continue;  // the task ended under the scan
+        if (n < cap) {
+            tids[n] = std::strtoull(e->d_name, nullptr, 10);
+            seconds[n] = cpu;
+        }
+        n++;
+    }
+    closedir(d);
+    return n;
 }
 
 }  // extern "C"

@@ -88,7 +88,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import threading
 import time
 from collections import defaultdict, deque
@@ -97,14 +96,17 @@ from collections.abc import Callable
 import numpy as np
 from ceph_tpu.utils import lockdep
 from ceph_tpu.utils.lockdep import DebugLock, DebugRLock
+from ceph_tpu.utils.perf_counters import built_once, register_thread_roles
 from ceph_tpu.utils.trace import tracer
+
+register_thread_roles({"ec-stream": "ec_stream"})
 
 #: an op of more bytes is no small op: it takes the per-op path
 #: (``ShardExtentMap._ring_routable``) and ``submit`` refuses it
 MAX_OP_BYTES = 256 << 10
 
 
-@functools.lru_cache(maxsize=1)
+@built_once
 def _stream_counters():
     from ceph_tpu.utils.perf_counters import (
         PerfCountersBuilder,

@@ -33,6 +33,10 @@ from __future__ import annotations
 
 import threading
 
+from .perf_counters import register_thread_roles
+
+register_thread_roles({"crash-kill-*": "other_python"})
+
 
 class CrashPointAbort(Exception):
     """Raised at an armed crash point to unwind the transition (the

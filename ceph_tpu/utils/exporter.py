@@ -31,10 +31,16 @@ from __future__ import annotations
 import http.server
 import threading
 
-from .perf_counters import CounterType, PerfCountersCollection
+from .perf_counters import (
+    CounterType,
+    PerfCountersCollection,
+    register_thread_roles,
+)
 from .perf_counters import perf_collection as _global_collection
 
 _PREFIX = "ceph_tpu"
+
+register_thread_roles({"exporter": "other_python"})
 
 
 def _sanitize(name: str) -> str:
@@ -167,7 +173,7 @@ class Exporter:
         self._server = srv
         self.addr = srv.server_address
         self._thread = threading.Thread(
-            target=srv.serve_forever, daemon=True
+            target=srv.serve_forever, daemon=True, name="exporter"
         )
         self._thread.start()
         return self.addr

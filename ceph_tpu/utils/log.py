@@ -33,6 +33,10 @@ import sys
 import threading
 import time
 
+from .perf_counters import register_thread_roles
+
+register_thread_roles({"log-flusher": "other_python"})
+
 DEFAULT_LOG_LEVEL = 1
 DEFAULT_GATHER_LEVEL = 5
 MAX_RECENT = 10000

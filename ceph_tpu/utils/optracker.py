@@ -38,6 +38,10 @@ import threading
 import time
 from contextlib import contextmanager
 
+from .perf_counters import register_thread_roles
+
+register_thread_roles({"optracker-watchdog": "other_python"})
+
 #: slow-op age histogram bounds, seconds (log2: 1 ms .. ~35 min)
 AGE_BUCKETS_S = [0.001 * (1 << i) for i in range(22)]
 

@@ -16,6 +16,10 @@ from __future__ import annotations
 
 import bisect
 import enum
+import fnmatch
+import functools
+import os
+import re
 import threading
 import time
 
@@ -27,7 +31,9 @@ class CounterType(enum.Enum):
     AVG = "avg"            # count + sum (time or value averages)
     HISTOGRAM = "histogram"
     #: a monotone reading taken when the set is dumped (a process
-    #: clock): the value is the schema's ``fn()``, nothing is stored
+    #: clock): the value is the schema's ``fn()``, nothing is stored.
+    #: Keys declared together (``add_sampled_group``) share one reading
+    #: a dump: the schema's ``group()`` answers all of them
     SAMPLED = "sampled"
 
 
@@ -120,10 +126,12 @@ class PerfCounters:
 
     def dump(self) -> dict:
         out: dict[str, object] = {}
+        sampled = []
         with self._lock:
             for key, spec in self._schema.items():
                 if spec["type"] is CounterType.SAMPLED:
-                    out[key] = spec["fn"]()
+                    out[key] = None  # keeps the schema's order
+                    sampled.append((key, spec))
                     continue
                 v = self._values[key]
                 if spec["type"] is CounterType.AVG:
@@ -136,6 +144,18 @@ class PerfCounters:
                     }
                 else:
                     out[key] = v
+        # readings are taken with no lock of ours held (one may walk a
+        # messenger's links or the process's tasks, and an ``inc`` on
+        # the hot path must not wait for that); a group is read once
+        groups: dict = {}
+        for key, spec in sampled:
+            group = spec.get("group")
+            if group is None:
+                out[key] = spec["fn"]()
+                continue
+            if group not in groups:
+                groups[group] = group()
+            out[key] = groups[group][key]
         return out
 
 
@@ -166,6 +186,17 @@ class PerfCountersBuilder:
     def add_sampled(self, key: str, fn, desc: str = ""):
         """``fn() -> number``, called at every dump."""
         return self._add(key, CounterType.SAMPLED, desc, fn=fn)
+
+    def add_sampled_group(self, group, keys: dict[str, str]):
+        """``group() -> {key: number}`` for every key of ``keys`` (key
+        -> description), called ONCE a dump however many keys it
+        serves: one walk, one consistent reading."""
+        for key, desc in keys.items():
+            self._add(
+                key, CounterType.SAMPLED, desc,
+                fn=lambda key=key: group()[key], group=group,
+            )
+        return self
 
     def add_avg(self, key: str, desc: str = ""):
         return self._add(key, CounterType.AVG, desc)
@@ -216,8 +247,12 @@ class PerfCountersCollection:
         return len(targets)
 
     def dump(self) -> dict:
+        # the sets are dumped outside the collection's lock: a sampled
+        # reading can take a while, and a set built meanwhile (a first
+        # use on a hot path) must not wait for it to register
         with self._lock:
-            return {name: pc.dump() for name, pc in sorted(self._sets.items())}
+            sets = sorted(self._sets.items())
+        return {name: pc.dump() for name, pc in sets}
 
     def snapshot(self) -> dict[str, tuple[dict, dict]]:
         """name -> (schema, dumped values), sorted — the exporter
@@ -232,6 +267,94 @@ class PerfCountersCollection:
 perf_collection = PerfCountersCollection()
 
 
+def built_once(build):
+    """A counter set built on its first use: ``build()`` runs once,
+    under a lock, however many threads make that first use together.
+    (``functools.lru_cache`` lets each of them build and register a
+    set, keeps the first and leaves the collection the last: counters
+    then move where no dump sees them.)"""
+    lock = threading.Lock()
+    made: list = []
+
+    @functools.wraps(build)
+    def get():
+        if not made:
+            with lock:
+                if not made:
+                    made.append(build())
+        return made[0]
+
+    return get
+
+
+# -- the process's CPU by thread role ------------------------------------
+#: the roles a task's CPU seconds are booked under. ``other_python`` is
+#: a Python thread whose name no pattern lists; ``runtime`` is a task no
+#: Python thread owns (XLA, libtpu, the profiler: CPU that never holds
+#: the interpreter lock); ``unlisted`` is what the process used beyond
+#: the tasks a scan saw (threads that ended before it).
+THREAD_ROLES = (
+    "op_worker", "msgr", "tick", "ec_stream", "client", "other_python",
+    "runtime", "unlisted",
+)
+_role_lock = threading.Lock()
+_role_patterns: list[tuple[str, "re.Pattern[str]", str]] = []
+
+
+def register_thread_roles(roles: dict[str, str]) -> None:
+    """Declare which role threads named like ``pattern`` (``fnmatch``)
+    work in: ``{pattern: role}``, one call a module, beside the code
+    that names the threads. Where several patterns match a name the
+    longest wins (``msgr-client-*`` over ``msgr-*``)."""
+    for role in roles.values():
+        if role not in THREAD_ROLES[:-2]:
+            raise ValueError(f"no thread role {role!r}")
+    with _role_lock:
+        known = {p for p, _, _ in _role_patterns}
+        _role_patterns.extend(
+            (p, re.compile(fnmatch.translate(p)), role)
+            for p, role in roles.items() if p not in known
+        )
+        _role_patterns.sort(key=lambda e: -len(e[0]))
+    thread_role.cache_clear()
+
+
+@functools.lru_cache(maxsize=4096)
+def thread_role(name: str) -> "str | None":
+    """The role the registered patterns give a thread's name, or None
+    where none lists it (its CPU is then booked as ``other_python``)."""
+    with _role_lock:
+        patterns = list(_role_patterns)
+    return next((r for _, rx, r in patterns if rx.match(name)), None)
+
+
+register_thread_roles({"MainThread": "other_python"})
+
+
+def thread_cpu_seconds() -> dict[str, float]:
+    """``{role + "_cpu_seconds": seconds}`` for every role: ONE native
+    pass over ``/proc/self/task`` (``native.task_cpu``), each task put
+    to the role of the Python thread that owns it
+    (``threading.enumerate()``'s ``native_id -> name``). ``unlisted``
+    is ``time.process_time()`` less the tasks seen, so the eight sum to
+    the process's CPU exactly and no record of ended threads is kept.
+    With no native tier nothing is seen and all of it is ``unlisted``."""
+    from ceph_tpu import native
+
+    tasks = native.task_cpu() if native.available() else {}
+    total = time.process_time()
+    names = {t.native_id: t.name for t in threading.enumerate()}
+    out = dict.fromkeys(THREAD_ROLES, 0.0)
+    for tid, seconds in tasks.items():
+        name = names.get(tid)
+        if name is None:
+            out["runtime"] += seconds
+        else:
+            out[thread_role(name) or "other_python"] += seconds
+    out["unlisted"] = total - sum(tasks.values())
+    return {f"{role}_cpu_seconds": v for role, v in out.items()}
+
+
 def register_process_counters(
     collection: PerfCountersCollection = perf_collection,
 ) -> PerfCounters:
@@ -239,7 +362,38 @@ def register_process_counters(
     (every thread, user + system) beside wall seconds on the tracer's
     clock. Their ratio over a window is how many cores the host side
     kept busy: near 1.0 in a one-interpreter cluster means every layer
-    is queueing for the GIL."""
+    is queueing for the GIL. Beside it ``process.threads``: the same
+    CPU seconds by thread role, read at dump time and nowhere else."""
+    clock = (
+        "schedstat, ns on the CPU"
+        if os.path.exists(f"/proc/self/task/{os.getpid()}/schedstat")
+        else "stat, utime + stime in clock ticks"
+    )
+    what = {
+        "op_worker": "OSD op workers and shards",
+        "msgr": "OSD messengers' readers, accepters and handshakes",
+        "tick": "OSD ticks, coalescer groups, heartbeat, scrub, gc, "
+                "peering, backfill",
+        "ec_stream": "the codec dispatcher's drain thread",
+        "client": "the load generator, the objecter and the client "
+                  "messenger's readers",
+        "other_python": "main and every Python thread no pattern lists",
+        "runtime": "tasks no Python thread owns (XLA, libtpu, the "
+                   "profiler: never holds the interpreter lock; and, "
+                   "until the kernel drops its task, a thread that "
+                   "has just ended)",
+        "unlisted": "process:cpu_seconds less the tasks this scan saw: "
+                    "threads that ended before it",
+    }
+    (
+        PerfCountersBuilder(collection, "process.threads")
+        .add_sampled_group(thread_cpu_seconds, {
+            f"{role}_cpu_seconds":
+                f"CPU seconds of {what[role]} (/proc/self/task/*/{clock})"
+            for role in THREAD_ROLES
+        })
+        .create_perf_counters()
+    )
     return (
         PerfCountersBuilder(collection, "process")
         .add_sampled(

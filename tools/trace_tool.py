@@ -34,7 +34,11 @@ Outputs:
   per device holding its ops;
 - with ``--xplane``: the device's idle seconds by the innermost stage
   span open at the time (``STAGE_ORDER``), and for the busiest device
-  ops the stage each call started in.
+  ops the stage each call started in;
+- with ``--live-demo``: the host's side of the run from the perf
+  counters (``host_report``): the interpreter lock's hand-overs as the
+  native frame calls kept them, the messengers' wall split into call /
+  lock / Python, and the process's CPU by thread role.
 
 The assembly core lives in ``ceph_tpu/utils/trace_assembly.py`` —
 loadgen's ``--trace-capture`` and the soak forensics bundle use the
@@ -175,6 +179,62 @@ def device_lanes(trace) -> list[dict]:
     return events
 
 
+def host_report(before: dict, after: dict) -> str:
+    """Who waited for the interpreter lock between two
+    ``benchmark.counters.snapshot`` and who was on the CPU meanwhile: the four ``*.net`` lock counters
+    over every messenger, ``send_seconds`` + ``recv_seconds`` split
+    into the native call, the wait to hold the lock again and the rest
+    (Python), and ``process.threads`` as shares of the CPU used."""
+    from benchmark import counters
+
+    moved = counters.delta(before, after)
+
+    def net(key: str) -> float:
+        return counters.total(moved, [f"*.net:{key}"])
+
+    waits, slow = net("lock_waits"), net("lock_waits_slow")
+    waited, in_call = net("lock_wait_seconds"), net("call_seconds")
+    wall = net("send_seconds") + net("recv_seconds")
+    lines = [
+        "interpreter lock, as the native frame calls kept it "
+        "(*.net, every messenger):",
+        f"  lock_waits {waits:.0f} of io_calls {net('io_calls'):.0f}  "
+        f"lock_waits_slow {slow:.0f}  "
+        f"lock_wait_seconds {waited:.6f}  call_seconds {in_call:.6f}",
+    ]
+    if waits:
+        lines.append(
+            f"  mean wait {1e6 * waited / waits:.1f} us, "
+            f"{100 * slow / waits:.1f} % of a switch interval or more"
+        )
+    if wall > 0:
+        lines.append(
+            f"messenger wall {wall:.4f} s (send_seconds + recv_seconds): "
+            f"in the call {100 * in_call / wall:.1f} %, waiting for the "
+            f"lock {100 * waited / wall:.1f} %, Python "
+            f"{100 * (wall - in_call - waited) / wall:.1f} %"
+        )
+    prefix = "process.threads:"
+    roles = {
+        k[len(prefix):-len("_cpu_seconds")]: v
+        for k, v in moved.items() if k.startswith(prefix)
+    }
+    # a thread that ended inside the window takes its earlier seconds
+    # out of its role with it: nearly all of them are tick threads
+    # (coalescer groups, peering), so the two are read as one
+    roles["tick"] = roles.get("tick", 0.0) + roles.pop("unlisted", 0.0)
+    cpu = sum(roles.values())
+    if cpu > 0:
+        lines.append(
+            f"CPU by thread role (process.threads; tick with the "
+            f"threads that ended), {cpu:.3f} s: "
+            + "  ".join(
+                f"{role} {100 * v / cpu:.1f} %" for role, v in roles.items()
+            )
+        )
+    return "\n".join(lines)
+
+
 def collect_process() -> tuple[list[dict], list[dict]]:
     """This process's spans + live ops (the in-process cluster case:
     every daemon of a LoadCluster shares the global tracer/tracker)."""
@@ -200,12 +260,14 @@ def _load_ops(path: str) -> list[dict]:
     return list(data)
 
 
-def _live_demo(args) -> tuple[list[dict], list[dict]]:
-    """Boot a LoadCluster, drive a handful of ops, return the spans.
-    With ``--xplane`` the ops run under a profiler trace written there
-    (after one untraced op has compiled what they use)."""
+def _live_demo(args) -> tuple[list[dict], list[dict], str]:
+    """Boot a LoadCluster, drive a handful of ops, return the spans
+    and the run's ``host_report``. With ``--xplane`` the ops run under
+    a profiler trace written there (after one untraced op has compiled
+    what they use)."""
     import numpy as np
 
+    from benchmark import counters
     from ceph_tpu.loadgen import LoadCluster
     from ceph_tpu.utils.trace import tracer
 
@@ -233,16 +295,18 @@ def _live_demo(args) -> tuple[list[dict], list[dict]]:
             options.host_tracer_level = 1
             jax.profiler.start_trace(args.xplane, profiler_options=options)
         tracer.clear()
+        before = counters.snapshot()
         try:
             for i in range(args.demo_ops):
                 one(f"demo-{i}")
         finally:
             if args.xplane:
                 jax.profiler.stop_trace()
+        host = host_report(before, counters.snapshot())
         spans, ops = collect_process()
     finally:
         cluster.shutdown()
-    return spans, ops
+    return spans, ops, host
 
 
 def main(argv: "list[str] | None" = None) -> int:
@@ -289,8 +353,9 @@ def main(argv: "list[str] | None" = None) -> int:
         spans.extend(_load_spans(path))
     for path in args.ops:
         ops.extend(_load_ops(path))
+    host = None
     if args.live_demo:
-        s, o = _live_demo(args)
+        s, o, host = _live_demo(args)
         spans.extend(s)
         ops.extend(o)
     if not spans and not ops:
@@ -315,6 +380,8 @@ def main(argv: "list[str] | None" = None) -> int:
     print(format_report(trees, top=args.top))
     if device is not None:
         print(device_report(device, spans))
+    if host is not None:
+        print(host)
     if args.chrome:
         chrome = chrome_trace(trees[: args.top])
         if device is not None:
