@@ -26,6 +26,7 @@ from .host import crc32c_wire
 from .crc32c import (
     crc32c_chain,
     crc32c_device,
+    crc32c_fold,
     crc32c_seed_shift,
     crc32c_stream,
 )
@@ -38,6 +39,7 @@ __all__ = [
     "crc32c_chain",
     "crc32c_host",
     "crc32c_device",
+    "crc32c_fold",
     "crc32c_ref",
     "crc32c_scalar",
     "crc32c_seed_shift",

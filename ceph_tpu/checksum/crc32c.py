@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .host import apply_columns, fold_words
 from .reference import CRC32C_POLY_REFLECTED, crc32c_ref
 
 CHUNK_BYTES = 64  # fold granularity; 512-bit MXU contraction per chunk
@@ -34,10 +35,6 @@ CHUNK_BYTES = 64  # fold granularity; 512-bit MXU contraction per chunk
 
 def _bits32(v: int) -> np.ndarray:
     return np.array([(v >> i) & 1 for i in range(32)], dtype=np.uint8)
-
-
-def _pack32(bits: np.ndarray) -> int:
-    return int(sum(int(b) << i for i, b in enumerate(bits)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -75,6 +72,26 @@ def zero_gap_matrix(nbytes: int) -> bytes:
         base = (base @ base) & 1
         n >>= 1
     return result.astype(np.uint8).tobytes()
+
+
+@functools.lru_cache(maxsize=None)
+def zero_gap_columns(nbytes: int) -> np.ndarray:
+    """``zero_gap_matrix(nbytes)`` as its 32 column words (bit i of
+    word j is ``A[i, j]``), read-only: the form the host applies
+    (``host.apply_columns``) and the native fold takes."""
+    a = _mat(zero_gap_matrix(nbytes)).astype(np.uint32)
+    cols = np.bitwise_or.reduce(
+        a << np.arange(32, dtype=np.uint32)[:, None], axis=0
+    )
+    cols.flags.writeable = False
+    return cols
+
+
+def _across_zeros(nbytes: int, reg: int) -> int:
+    """The register ``reg`` after ``nbytes`` zero bytes."""
+    return int(
+        apply_columns(zero_gap_columns(nbytes), np.uint32(reg & 0xFFFFFFFF))
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -247,8 +264,7 @@ def crc32c(init: int, data: bytes) -> int:
     if not data:
         return init & 0xFFFFFFFF
     if not any(data):
-        a = _mat(zero_gap_matrix(len(data)))
-        return _pack32((a @ _bits32(init)) & 1)
+        return _across_zeros(len(data), init)
     return crc32c_ref(init, data)
 
 
@@ -256,8 +272,7 @@ def crc32c_concat(crc_a: int, crc_b_zero_init: int, len_b: int) -> int:
     """crc(A||B) from crc(A) and crc(B with zero init) — the bufferlist
     cached-crc "range concatenation" trick (common/crc32c.h,
     buffer.cc): crc(A||B) = A_{len_b} @ crc(A) ⊕ crc_0(B)."""
-    a = _mat(zero_gap_matrix(len_b))
-    return _pack32((a @ _bits32(crc_a)) & 1) ^ crc_b_zero_init
+    return _across_zeros(len_b, crc_a) ^ crc_b_zero_init
 
 
 # -- fused-kernel csum plumbing ----------------------------------------
@@ -268,21 +283,39 @@ def crc32c_seed_shift(block_bytes: int, init: int) -> int:
     fused encode+csum kernel emits ZERO-INIT per-block csums so one
     device pass serves every consumer seed — BlueStore blob csums
     (seed -1), HashInfo chains, wire csums — via this one XOR."""
-    return _pack32(
-        (_mat(zero_gap_matrix(block_bytes)) @ _bits32(init)) & 1
-    )
+    return _across_zeros(block_bytes, init)
+
+
+def crc32c_fold(seeds, block_csums, block_bytes: int) -> np.ndarray:
+    """Fold ZERO-INIT per-block crc32c values into running registers,
+    every shard in one call: ``block_csums`` is [shards, blocks] (each
+    row the crcs of consecutive ``block_bytes`` blocks of one stream),
+    ``seeds`` [shards] the registers before them; returns [shards]
+    uint32, ``reg' = A_block @ reg ^ crc_0(B_i)`` block after block
+    (repeated range concatenation). How HashInfo seeds the cumulative
+    shard hashes from fused-kernel csums without touching the bytes
+    again. One algorithm whatever the sizes: the native fold where the
+    C++ tier loads, the numpy tree otherwise (``host.fold_words``), no
+    Python per word either way.
+
+    What a fold of 12 shards x 128 words (a 4 MiB write on k=8 m=4)
+    costs on the chip machine's host, one thread (builder's, PR 39,
+    wall, median of 200): 18 us this call, 42 us the whole of
+    ``HashInfo.append_block_csums``; 302 us through the numpy tree;
+    12,400 us as the per-block Python loop this replaced
+    (CAPABILITIES.md, "HashInfo fold")."""
+    seeds = np.ascontiguousarray(seeds, dtype=np.uint32).reshape(-1)
+    csums = np.ascontiguousarray(block_csums, dtype=np.uint32)
+    csums = csums.reshape(seeds.size, -1)
+    return fold_words(zero_gap_columns(block_bytes), seeds, csums)
 
 
 def crc32c_chain(init: int, block_csums, block_bytes: int) -> int:
-    """Fold ZERO-INIT per-block crc32c values into a running register:
-    repeated range concatenation, cum' = A_block @ cum ⊕ crc_0(B_i).
-    How HashInfo seeds cumulative shard hashes from fused-kernel csums
-    without ever touching the bytes again."""
-    a = _mat(zero_gap_matrix(block_bytes))
-    reg = _bits32(init)
-    for c0 in np.asarray(block_csums).reshape(-1):
-        reg = ((a @ reg) & 1) ^ _bits32(int(c0))
-    return _pack32(reg)
+    """``crc32c_fold`` of one stream: the register after ``init`` and
+    the blocks whose zero-init crcs are ``block_csums``."""
+    return int(
+        crc32c_fold([init & 0xFFFFFFFF], [block_csums], block_bytes)[0]
+    )
 
 
 def crc32c_stream(data, init: int = 0xFFFFFFFF) -> int:

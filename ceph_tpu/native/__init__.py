@@ -38,6 +38,8 @@ _tried = False
 #: two functions could be handed over (the calls then give the lock up
 #: and take it back themselves, and time the take-back), else ``_lib``
 _frame_lib: ctypes.CDLL | None = None
+#: the same library through ``ctypes.PyDLL``: what ``_bind_held`` binds
+_held_lib: ctypes.PyDLL | None = None
 
 
 def _host_cpu_flags() -> str:
@@ -165,6 +167,17 @@ def _bind(lib: ctypes.CDLL) -> None:
     ]
 
 
+def _bind_held(lib: ctypes.PyDLL) -> None:
+    """Calls that keep the interpreter lock: microseconds of work on a
+    few KiB, where giving the lock up would cost a wait to have it
+    back (up to a switch interval under load) many times the call."""
+    u32p = ctypes.POINTER(ctypes.c_uint32)
+    lib.ctpu_crc32c_fold.restype = None
+    lib.ctpu_crc32c_fold.argtypes = [
+        u32p, u32p, u32p, ctypes.c_size_t, ctypes.c_size_t,
+    ]
+
+
 def _bind_frame_io(lib: ctypes.CDLL) -> None:
     """Frame socket I/O: the codec takes the descriptor (one call a
     frame). The last argument of each is the caller's
@@ -216,7 +229,7 @@ def _frame_handle(lib: ctypes.CDLL, lib_path: str) -> ctypes.CDLL:
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, _frame_lib, _tried
+    global _lib, _frame_lib, _held_lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
@@ -231,9 +244,11 @@ def _load() -> ctypes.CDLL | None:
             _bind(lib)
             frame_lib = _frame_handle(lib, lib_path)
             _bind_frame_io(frame_lib)
+            held_lib = ctypes.PyDLL(lib_path)
+            _bind_held(held_lib)
         except OSError:
             return None
-        _lib, _frame_lib = lib, frame_lib
+        _lib, _frame_lib, _held_lib = lib, frame_lib, held_lib
         return _lib
 
 
@@ -267,6 +282,27 @@ def crc32c_bytes(init: int, data) -> int:
     if not isinstance(data, bytes):
         data = bytes(data)
     return lib.ctpu_crc32c_buf(init & 0xFFFFFFFF, data, len(data))
+
+
+def crc32c_fold(
+    cols: np.ndarray, seeds: np.ndarray, csums: np.ndarray
+) -> np.ndarray:
+    """Fold ``csums`` [shards, blocks] (zero-init crc32c of consecutive
+    blocks) into ``seeds`` [shards], one native call for all of them;
+    returns the new registers. ``cols`` is the zero-gap transition of
+    one block as 32 column words. All three are C-contiguous uint32
+    (``checksum.crc32c.crc32c_fold`` makes them so). The call keeps
+    the interpreter lock (``_bind_held``)."""
+    if _load() is None:
+        raise RuntimeError("native runtime unavailable")
+    u32p = ctypes.POINTER(ctypes.c_uint32)
+    out = seeds.copy()
+    shards, blocks = csums.shape
+    _held_lib.ctpu_crc32c_fold(
+        cols.ctypes.data_as(u32p), out.ctypes.data_as(u32p),
+        csums.ctypes.data_as(u32p), shards, blocks,
+    )
+    return out
 
 
 # -- frame codec ---------------------------------------------------------

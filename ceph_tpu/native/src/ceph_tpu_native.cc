@@ -4,7 +4,9 @@
 //  * crc32c: slicing-by-8 software kernel with an SSE4.2 hardware path
 //    (the ceph_crc32c dispatch analog, src/common/crc32c.cc) — raw
 //    register in/out, reflected Castagnoli, no final xor, bit-exact
-//    with the Python oracle (checksum/reference.crc32c_ref).
+//    with the Python oracle (checksum/reference.crc32c_ref); and the
+//    fold of zero-init block crcs into running registers (HashInfo's
+//    cumulative shard hashes), all shards and blocks in one call.
 //  * GF(2^8) region ops over the 0x11D field: constant-multiply /
 //    xor-accumulate regions and a full matrix encode — the
 //    jerasure/ISA-L region-op analog used for host-side staging,
@@ -101,6 +103,33 @@ uint32_t ctpu_crc32c(uint32_t crc, const uint8_t* data, size_t len) {
     while (len--) crc = crc_table[0][(crc ^ *data++) & 0xFF] ^ (crc >> 8);
     return crc;
 #endif
+}
+
+// Fold ZERO-INIT per-block crc32c values into running registers: for
+// each of ``shards`` rows, seed' = A seed ^ c over the row's ``blocks``
+// words in order (crc32c range concatenation, block after block).
+// ``cols`` is the 32x32 GF(2) transition A across one block of zero
+// bytes, as its 32 column words (bit i of cols[j] = A[i][j]); the
+// caller owns how it is made (checksum/crc32c.zero_gap_columns). A is
+// applied a byte of the register at a time from four 256-entry tables
+// built here, once a call.
+void ctpu_crc32c_fold(const uint32_t* cols, uint32_t* seeds,
+                      const uint32_t* csums, size_t shards,
+                      size_t blocks) {
+    uint32_t t[4][256];
+    for (int b = 0; b < 4; b++) {
+        t[b][0] = 0;
+        for (uint32_t v = 1; v < 256; v++)
+            t[b][v] = t[b][v & (v - 1)] ^ cols[8 * b + __builtin_ctz(v)];
+    }
+    for (size_t s = 0; s < shards; s++) {
+        uint32_t reg = seeds[s];
+        const uint32_t* row = csums + s * blocks;
+        for (size_t i = 0; i < blocks; i++)
+            reg = t[0][reg & 0xFF] ^ t[1][(reg >> 8) & 0xFF] ^
+                  t[2][(reg >> 16) & 0xFF] ^ t[3][reg >> 24] ^ row[i];
+        seeds[s] = reg;
+    }
 }
 
 // ------------------------------------------------------------- GF(2^8)

@@ -13,7 +13,8 @@ Two append paths, bit-identical by construction:
   encode+checksum kernel's ZERO-INIT per-block csums
   (ops/pallas_encode.py) via crc range concatenation — the bytes are
   hashed exactly once, on device, while they were resident for the
-  encode matmul; the host never touches them again.
+  encode matmul; the host never touches them again, and folds every
+  shard's words in one call (``checksum.crc32c_fold``).
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ import json
 
 import numpy as np
 
-from ceph_tpu.checksum import crc32c_chain, crc32c_stream
+from ceph_tpu.checksum import crc32c_fold, crc32c_stream
 
 SEED = 0xFFFFFFFF
 
@@ -76,30 +77,36 @@ class HashInfo:
         old_size: int,
         to_append: "dict[int, np.ndarray]",
         block_bytes: int,
-    ) -> None:
+    ) -> int:
         """Extend shard crcs from kernel-produced ZERO-INIT per-block
         crc32c values (the fused encode+csum output) instead of raw
         bytes: cum' = A_block @ cum ⊕ crc_0(block), repeated — bit-
         identical to ``append`` over the same bytes, with no second
-        pass over them. Same contiguity/equal-length contract."""
+        pass over them. Every shard's words go through ONE
+        ``crc32c_fold`` call. Same contiguity/equal-length contract.
+        Returns the number of csum words folded."""
         if old_size != self.total_chunk_size:
             raise ValueError(
                 f"non-contiguous append: old_size={old_size}, "
                 f"have={self.total_chunk_size}"
             )
-        blocks = {
-            shard: np.asarray(v).reshape(-1)
-            for shard, v in to_append.items()
-        }
-        sizes = {v.size for v in blocks.values()}
+        shards = list(to_append)
+        rows = [np.asarray(to_append[s]).reshape(-1) for s in shards]
+        sizes = {v.size for v in rows}
         if len(sizes) > 1:
             raise ValueError(f"unequal append sizes {sizes}")
-        for shard, csums in blocks.items():
-            self.cumulative_shard_hashes[shard] = crc32c_chain(
-                self.cumulative_shard_hashes[shard], csums, block_bytes
-            )
-        if sizes:
-            self.total_chunk_size += sizes.pop() * block_bytes
+        if not sizes:
+            return 0
+        hashes = self.cumulative_shard_hashes
+        folded = crc32c_fold(
+            [hashes[s] for s in shards], np.stack(rows), block_bytes
+        )
+        # plain ints: the hashes are persisted as JSON
+        for shard, reg in zip(shards, folded.tolist()):
+            hashes[shard] = reg
+        blocks = sizes.pop()
+        self.total_chunk_size += blocks * block_bytes
+        return blocks * len(shards)
 
     def get_chunk_hash(self, shard: int) -> int:
         return self.cumulative_shard_hashes[shard]

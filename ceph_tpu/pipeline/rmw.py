@@ -472,6 +472,15 @@ class RMWPipeline:
                 "ec_write.assemble: chunk loop and old-data merge",
             )
             .add_time("encode_seconds", "ec_write.encode: the codec call")
+            # inside the encode stage: the fused kernel's block csums
+            # folded into the object's HashInfo, one call an append
+            # (no fold where an overwrite clears the hashes, or the
+            # csums come from the separate pass)
+            .add_u64_counter("hinfo_folds", "HashInfo folds of block csums")
+            .add_u64_counter(
+                "hinfo_fold_blocks", "csum words those folds took"
+            )
+            .add_time("hinfo_fold_seconds", "wall inside those folds")
             # the old-data read of an RMW, and the parity-delta encode
             # step by step; ``rmw_read_ops`` / ``delta_ops`` are their
             # denominators
@@ -1058,6 +1067,11 @@ class RMWPipeline:
                     self.codec, hinfo, old_size=hashed,
                     csum_block=self.csum_block,
                 )
+                if new_map.hinfo_fold is not None:
+                    words, seconds = new_map.hinfo_fold
+                    self.perf.inc("hinfo_folds")
+                    self.perf.inc("hinfo_fold_blocks", words)
+                    self.perf.tinc("hinfo_fold_seconds", seconds)
             else:
                 # not a contiguous append: cumulative crcs can't be
                 # extended — invalidate (deep scrub then skips them)

@@ -18,6 +18,7 @@ pipeline); codec calls move them through jax and back.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
 
@@ -63,6 +64,10 @@ class ShardExtentMap:
         #: uint32[nblocks] ZERO-INIT per-block crc32c)}} — the blocks
         #: cover each shard's encode window contiguously
         self.csums: "dict | None" = None
+        #: ``(csum words, seconds)`` of the fold of those csums into
+        #: the HashInfo ``encode`` was given, where it took that route
+        #: (the pipeline's ``hinfo_fold*`` counters read it)
+        self.hinfo_fold: "tuple[int, float] | None" = None
         #: ``(rows, stripes)`` of the whole stripes ``insert_ro_range``
         #: last scattered from an immutable buffer: ``rows`` [k, n, chunk]
         #: holds the data shards' runs, ``stripes`` [n, k, chunk] is that
@@ -355,7 +360,7 @@ class ShardExtentMap:
         become the parity runs by ownership: the data runs are read,
         never copied or changed."""
         k, m = self.sinfo.k, self.sinfo.m
-        self.csums = None
+        self.csums = self.hinfo_fold = None
         lo0, hi0 = self._slice_window()
         if hi0 <= lo0:
             return
@@ -435,10 +440,12 @@ class ShardExtentMap:
                     and (hi0 - base) % cb == 0
                     and hi0 <= hi
                 ):
-                    # device-seeded: chain the kernel's zero-init
-                    # block csums into the cumulative shard hashes
+                    # device-seeded: fold the kernel's zero-init
+                    # block csums into the cumulative shard hashes,
+                    # all shards in one call
                     first, last = (base - lo) // cb, (hi0 - lo) // cb
-                    hashinfo.append_block_csums(
+                    t0 = time.perf_counter()
+                    words = hashinfo.append_block_csums(
                         base,
                         {
                             shard: vals[first:last]
@@ -447,6 +454,7 @@ class ShardExtentMap:
                         },
                         cb,
                     )
+                    self.hinfo_fold = (words, time.perf_counter() - t0)
                 else:
                     hashinfo.append(
                         base,

@@ -13,6 +13,8 @@ from __future__ import annotations
 import enum
 from dataclasses import dataclass, field
 
+import numpy as np
+
 
 class OpKind(enum.Enum):
     TOUCH = "touch"
@@ -60,13 +62,16 @@ class Transaction:
     ) -> "Transaction":
         """``csums``/``csum_block``: optional zero-init per-block
         crc32c of ``data`` from the fused encode+csum kernel — see
-        ``Op.csums``."""
+        ``Op.csums``. An array, list or tuple of uint32 values, taken
+        whole: one conversion, no Python per word."""
+        if csums is not None:
+            csums = tuple(np.asarray(csums, dtype=np.uint32).tolist())
+            csum_block = int(csum_block)
+        else:
+            csum_block = 0
         self.ops.append(
             Op(OpKind.WRITE, oid, offset=offset, length=len(data),
-               data=bytes(data),
-               csums=tuple(int(v) for v in csums) if csums is not None
-               else None,
-               csum_block=int(csum_block) if csums is not None else 0)
+               data=bytes(data), csums=csums, csum_block=csum_block)
         )
         return self
 
@@ -143,10 +148,9 @@ class Transaction:
             out += struct.pack("<I", len(op.data))
             out += op.data
             if ver >= 2:
-                csums = op.csums or ()
+                csums = () if op.csums is None else op.csums
                 out += struct.pack("<II", op.csum_block, len(csums))
-                for v in csums:
-                    out += struct.pack("<I", v)
+                out += np.asarray(csums, dtype="<u4").tobytes()
         return bytes(out)
 
     @classmethod

@@ -404,15 +404,17 @@ class TestTheNetSetsLockCounters:
 ROLE_KEYS = [f"{role}_cpu_seconds" for role in THREAD_ROLES]
 
 
-def spin_for(seconds, name):
-    """A thread of that name that burns ``seconds`` of wall on the CPU;
-    returns it started."""
+def spin_for(seconds, name, halfway=None):
+    """A thread of that name that burns ``seconds`` of CPU of its own
+    (``time.thread_time``, not wall: a loaded runner makes it take
+    longer, never burn less) and sets ``halfway`` once half of them are
+    burned; returns it started."""
 
     def burn():
-        end = time.perf_counter() + seconds
-        x = 0
-        while time.perf_counter() < end:
-            x += 1
+        start = time.thread_time()
+        while (burned := time.thread_time() - start) < seconds:
+            if halfway is not None and burned >= seconds / 2:
+                halfway.set()
 
     t = threading.Thread(target=burn, name=name, daemon=True)
     t.start()
@@ -458,13 +460,14 @@ class TestProcessThreads:
         # Python thread (``runtime``): let an earlier case's be reaped
         time.sleep(0.05)
         before = pc.dump()
-        t = spin_for(0.3, name)
-        time.sleep(0.2)
+        halfway = threading.Event()
+        t = spin_for(0.3, name, halfway)
+        assert halfway.wait(60)
         mid = pc.dump()  # read while it runs: it is a task of the scan
-        t.join(5)
+        t.join(60)
         moved = {k: mid[k] - before[k] for k in ROLE_KEYS}
         mine = moved.pop(f"{role}_cpu_seconds")
-        assert mine >= 0.05
+        assert mine >= 0.1
         for key, other in moved.items():
             assert other < mine / 2, key
 
@@ -472,7 +475,7 @@ class TestProcessThreads:
         pc = perf_collection._sets["process.threads"]
         time.sleep(0.05)
         before = pc.dump()
-        spin_for(0.3, "osd.92-coal").join(5)
+        spin_for(0.3, "osd.92-coal").join(60)
         time.sleep(0.05)
         after = pc.dump()
         assert after["unlisted_cpu_seconds"] - before[
