@@ -505,8 +505,11 @@ class Objecter:
 
     def _handle_reply(self, aop: _AsyncOp, reply: OSDOpReply) -> None:
         if reply.error == "eagain":
+            # the primary says why (osd.<id>.eagain's reason); an
+            # older one says nothing
+            why = bytes(reply.data).decode(errors="replace") or "eagain"
             aop.last = (
-                f"osd.{aop.osd} not primary (its epoch {reply.epoch})"
+                f"osd.{aop.osd} answered {why} (its epoch {reply.epoch})"
             )
             aop.tracked.mark_event("eagain", osd=aop.osd)
             self._retry(aop)

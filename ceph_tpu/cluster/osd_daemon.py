@@ -155,6 +155,24 @@ class _ClientOpItem:
         return self.msg.op in _COALESCE_OPS
 
 
+class _HeldOp:
+    """A mutating client op parked off the op worker until the
+    durability poll of its object is back (``_take_or_spawn_poll``):
+    the poller re-queues it and the gate then finds the verdict."""
+
+    __slots__ = ("conn", "msg", "t_parked", "parked")
+
+    def __init__(self, conn, msg) -> None:
+        self.conn = conn
+        self.msg = msg
+        self.t_parked = 0.0
+        self.parked = False
+
+
+#: what the gate answers for an op it holds: no reply goes out now
+_HELD = OSDOpReply(0, 0, error="held")
+
+
 class _CoalCtx:
     """Per-op state threaded through the coalesced batch's three
     phases (serial prelude under the op lock -> concurrent per-PG
@@ -242,8 +260,65 @@ def make_opq_perf(name: str):
             "service_cpu_seconds",
             "CPU seconds of the serving thread over the same intervals",
         )
+        # ops parked off the worker while their object's durability
+        # poll runs (``_HeldOp``); a held op passes the queue twice
+        .add_u64_counter(
+            "req_poll_holds",
+            "mutating ops held at the primary for a durability poll",
+        )
+        .add_time(
+            "req_poll_hold_seconds", "park to re-queue of those ops"
+        )
         .create_perf_counters()
     )
+
+
+#: why a client op was answered ``eagain``: one key a site that builds
+#: the reply (``OSDDaemon._eagain``), carried to the client in the
+#: reply's data so the objecter can say it
+EAGAIN_REASONS = {
+    "not_primary":
+        "this OSD's map names another primary for the object",
+    "not_primary_pgls": "the same, for a PG listing",
+    "not_primary_coalesced": "the same, found by a coalesced tick",
+    "peering_wait":
+        "the PG had not finished peering after the gate's 5 s",
+    "peering_wait_coalesced": "the same, found by a coalesced tick",
+    "resend_unverified":
+        "a resent op that an earlier interval stamped: its "
+        "durability poll is not back and the op could not be held "
+        "(cooldown after an unsettled verdict, or the hold table full)",
+    "resend_unknown":
+        "that poll came back with too few members answering to judge "
+        "the resent op durable or torn",
+    "window_unsettled":
+        "an object's seeded reqid window cannot be settled now "
+        "(verdict unknown or ambiguous, rollback failed, poll cooldown): "
+        "nothing may mutate it yet",
+    "hold_expired":
+        "an op held for its object's durability poll longer than "
+        "REQ_HOLD_MAX",
+    "transient_degraded_write":
+        "a write aborted below min_size on a transient local view "
+        "(the map still shows k live members)",
+    "transient_degraded_truncate": "the same, for a truncate",
+    "transient_degraded_read": "the same, for a read",
+    "transient_degraded_remove": "the same, for a remove",
+    "transient_degraded_attrs": "the same, for an xattr or omap update",
+    "transient_degraded_coalesced":
+        "the same, for a write of a coalesced tick",
+}
+
+
+def make_eagain_perf(name: str):
+    """``perf dump`` section ``osd.<id>.eagain``: client ops answered
+    "try again", by reason."""
+    from ceph_tpu.utils import PerfCountersBuilder, perf_collection
+
+    b = PerfCountersBuilder(perf_collection, name)
+    for reason, text in EAGAIN_REASONS.items():
+        b.add_u64_counter(reason, text)
+    return b.create_perf_counters()
 
 
 def make_stats_perf(name: str):
@@ -781,6 +856,8 @@ class OSDDaemon:
         self.peers.messenger.net_pc = self.net_pc
         #: op-queue wait and service time of queued client ops
         self.opq_pc = make_opq_perf(f"osd.{osd_id}.opq")
+        #: client ops answered "try again", by reason
+        self.eagain_pc = make_eagain_perf(f"osd.{osd_id}.eagain")
         if isinstance(self.store, MemStore):
             self.store.perf = make_store_perf(f"osd.{osd_id}.store")
         #: crash-replay observability (rollbacks/rollforwards)
@@ -923,6 +1000,11 @@ class OSDDaemon:
         self._req_polls_inflight: set[str] = set()
         self._req_poll_lock = DebugLock("osd.req_poll")
         self._req_poll_sem = threading.Semaphore(self.REQ_POLL_BUDGET)
+        #: loc -> ops held until its poll is back, and the polls that
+        #: wait for a poller (loc -> pg, in order); both guarded by
+        #: _req_poll_lock
+        self._req_held: dict[str, list[_HeldOp]] = {}
+        self._req_poll_backlog: dict[str, _PG] = {}
         #: queued reqid-cache invalidations from _kick_peering /
         #: pool deletion, applied under _op_lock by the next client
         #: op (_drain_req_flushes). _kick_peering cannot take
@@ -2551,6 +2633,8 @@ class OSDDaemon:
             self.opq_pc.tinc(
                 "service_cpu_seconds", time.thread_time() - cpu0
             )
+        if reply is _HELD:
+            return  # parked for its durability poll: re-queued later
         if msg.op in _MUTATING_OPS and not reply.error:
             # crash point: the mutation is committed cluster-wide, the
             # client reply not yet sent — a kill here forces the
@@ -2576,10 +2660,10 @@ class OSDDaemon:
             # PG-addressed, not object-addressed: offset carries pgid
             pgid = msg.offset
             if self.osdmap.pg_primary(msg.pool, pgid) != self.osd_id:
-                return OSDOpReply(msg.tid, epoch, error="eagain")
+                return self._eagain(msg, epoch, "not_primary_pgls")
             return self._op_pgls(msg, spec, pgid)
         if self.osdmap.primary(msg.pool, msg.oid) != self.osd_id:
-            return OSDOpReply(msg.tid, epoch, error="eagain")
+            return self._eagain(msg, epoch, "not_primary")
         pgid = self.osdmap.object_to_pg(msg.pool, msg.oid)
         # peering gate: a primary that has not finished this
         # interval's authoritative-log election must not serve — its
@@ -2590,7 +2674,7 @@ class OSDDaemon:
         # depends on this worker thread (no QoS admission on the
         # rewind path), so the wait cannot deadlock.
         if not self._get_pg(msg.pool, pgid).peered.wait(timeout=5.0):
-            return OSDOpReply(msg.tid, epoch, error="eagain")
+            return self._eagain(msg, epoch, "peering_wait")
         client_oid = msg.oid
         msg.oid = make_loc(spec.pool_id, msg.oid)  # pool-scoped store key
         # watch/notify live OUTSIDE the op lock: a notify waits for
@@ -2603,7 +2687,7 @@ class OSDDaemon:
             return self._op_notify(msg, client_oid)
         with self._op_shards[shard]:
             self._drain_req_flushes()
-            reply, pg = self._mutating_gate(msg, spec, pgid, epoch)
+            reply, pg = self._mutating_gate(msg, spec, pgid, epoch, conn)
             if reply is not None:
                 return reply
             if msg.op == "write":
@@ -2728,8 +2812,8 @@ class OSDDaemon:
                         msg.tid, epoch, error="enoent")))
                     continue
                 if self.osdmap.primary(msg.pool, msg.oid) != self.osd_id:
-                    to_send.append((it.conn, OSDOpReply(
-                        msg.tid, epoch, error="eagain")))
+                    to_send.append((it.conn, self._eagain(
+                        msg, epoch, "not_primary_coalesced")))
                     continue
                 pgid = self.osdmap.object_to_pg(msg.pool, msg.oid)
                 # peering gate BEFORE the lock (the serial path's
@@ -2737,8 +2821,8 @@ class OSDDaemon:
                 if not self._get_pg(msg.pool, pgid).peered.wait(
                     timeout=5.0
                 ):
-                    to_send.append((it.conn, OSDOpReply(
-                        msg.tid, epoch, error="eagain")))
+                    to_send.append((it.conn, self._eagain(
+                        msg, epoch, "peering_wait_coalesced")))
                     continue
                 msg.oid = make_loc(spec.pool_id, msg.oid)
                 pre.append(_CoalCtx(it.conn, msg, spec, pgid, epoch))
@@ -2789,7 +2873,7 @@ class OSDDaemon:
         msg = ctx.msg
         try:
             reply, pg = self._mutating_gate(
-                msg, ctx.spec, ctx.pgid, ctx.epoch
+                msg, ctx.spec, ctx.pgid, ctx.epoch, ctx.conn
             )
         except Exception as e:
             to_send.append((ctx.conn, OSDOpReply(
@@ -2797,7 +2881,8 @@ class OSDDaemon:
                 data=str(e).encode())))
             return False
         if reply is not None:
-            to_send.append((ctx.conn, reply))
+            if reply is not _HELD:
+                to_send.append((ctx.conn, reply))
             return False
         ctx.pg = pg
         try:
@@ -2979,7 +3064,9 @@ class OSDDaemon:
             )
         if kind == "eio":
             if self._transient_degraded(pg, detail or ""):
-                return OSDOpReply(msg.tid, ctx.epoch, error="eagain")
+                return self._eagain(
+                    msg, ctx.epoch, "transient_degraded_coalesced"
+                )
             return self._record_completed(
                 msg, OSDOpReply(msg.tid, ctx.epoch, error="eio",
                                 data=(detail or "").encode())
@@ -2996,13 +3083,28 @@ class OSDDaemon:
         )
 
     def _mutating_gate(
-        self, msg: OSDOp, spec, pgid: int, epoch: int
+        self, msg: OSDOp, spec, pgid: int, epoch: int, conn=None,
     ) -> "tuple[OSDOpReply | None, _PG | None]":
         """The dedup/durability gate every client op passes before its
         handler (caller holds ``_op_lock``; shared by the serial and
         the coalesced execution paths so they cannot diverge). Returns
-        ``(reply, pg)`` — a non-None reply short-circuits the op."""
+        ``(reply, pg)`` — a non-None reply short-circuits the op, and
+        ``_HELD`` says that none goes out now: the op waits, off the
+        worker, for its object's durability poll (``conn`` is where
+        its answer will go)."""
         polled = None  # durability fan-out, shared consult->resolve
+        hold = None
+        expired = msg.held_since is not None and (
+            time.monotonic() - msg.held_since >= self.REQ_HOLD_MAX
+        )
+        if conn is not None and msg.op in _MUTATING_OPS and not expired:
+            hold = _HeldOp(conn, msg)
+
+        def bounce(reason: str):
+            return self._eagain(
+                msg, epoch, "hold_expired" if expired else reason
+            ), None
+
         if msg.op in _MUTATING_OPS and msg.reqid:
             cached = self._completed_ops.get(msg.reqid)
             if cached is not None:
@@ -3028,17 +3130,18 @@ class OSDDaemon:
                 unv = self._req_unverified.get(msg.oid)
                 if unv and msg.reqid in unv:
                     # async fan-out: a cached verdict resolves
-                    # NOW; otherwise a poller thread is working
-                    # (or cooldown/budget defers one) and the op
-                    # parks in the client's retry loop — eagain,
-                    # never a multi-second wait on the op worker
+                    # NOW; otherwise a poller thread is working (or
+                    # will be) and the op is held for its verdict,
+                    # off the op worker — never a multi-second wait
+                    # on it, and an eagain only where the poll's
+                    # cooldown refuses one
                     polled = self._take_or_spawn_poll(
-                        pg0, msg.oid
+                        pg0, msg.oid, hold
                     )
                     if polled is None:
-                        return OSDOpReply(
-                            msg.tid, epoch, error="eagain"
-                        ), None
+                        if hold is not None and hold.parked:
+                            return _HELD, None
+                        return bounce("resend_unverified")
                     members = sum(
                         1 for o in pg0.acting if o != SHARD_NONE
                     )
@@ -3056,9 +3159,7 @@ class OSDDaemon:
                 if verdict == "unknown":
                     # unreachable members could still prove the
                     # op durable — back off instead of guessing
-                    return OSDOpReply(
-                        msg.tid, epoch, error="eagain"
-                    ), None
+                    return self._eagain(msg, epoch, "resend_unknown"), None
                 if verdict == "ambiguous":
                     return OSDOpReply(
                         msg.tid, epoch, error="eio",
@@ -3093,15 +3194,26 @@ class OSDDaemon:
             # and a committed op's attr stamp would launder the
             # entry to every shard (round-5 review finding)
             if not self._resolve_unverified_reqs(
-                pg, msg.oid, polled=polled
+                pg, msg.oid, polled=polled, hold=hold
             ):
-                return OSDOpReply(msg.tid, epoch, error="eagain"), None
+                if hold is not None and hold.parked:
+                    return _HELD, None
+                return bounce("window_unsettled")
             # copy-on-first-write after a pool snapshot: the head
             # must be preserved as the newest snap's clone BEFORE
             # any mutation lands (make_writeable role,
             # osd/PrimaryLogPG.cc)
             self._maybe_cow(pg, spec, msg.oid)
         return None, pg
+
+    def _eagain(self, msg: OSDOp, epoch: int, reason: str) -> OSDOpReply:
+        """"Try again", counted by reason (``osd.<id>.eagain``) and
+        carrying it to the client, whose objecter names it in the
+        error of an op that gave up."""
+        self.eagain_pc.inc(reason)
+        return OSDOpReply(
+            msg.tid, epoch, error="eagain", data=reason.encode()
+        )
 
     def _transient_degraded(self, pg: _PG, err) -> bool:
         """True when a below-min-size abort is a TRANSIENT local view
@@ -3234,66 +3346,142 @@ class OSDDaemon:
     #: on the op worker — so it cannot stall unrelated client ops)
     REQ_POLL_TIMEOUT = 2.5
     #: minimum spacing between fan-out STARTS for the SAME unsettled
-    #: object (client retries answer eagain; a finished poll's cached
-    #: verdict is consumed regardless of the cooldown)
+    #: object (an op that meets it answers eagain; a finished poll's
+    #: cached verdict is consumed regardless of the cooldown)
     REQ_POLL_COOLDOWN = 1.0
     #: daemon-wide cap on concurrent fan-out threads: an adversarial
-    #: burst of torn objects must not spawn unbounded pollers — ops
-    #: past the budget answer eagain and retry into a free slot
+    #: burst of torn objects must not spawn unbounded pollers. A poll
+    #: past the budget that an op is held for waits its turn
+    #: (``_req_poll_backlog``) and a poller takes it next
     REQ_POLL_BUDGET = 2
+    #: how long an op may be held for its poll, from its first park
+    #: (the peering gate's bound): past it the op answers eagain
+    REQ_HOLD_MAX = 5.0
+    #: cap on ops held at once, daemon-wide; past it they answer eagain
+    REQ_HELD_CAP = 1024
 
-    def _take_or_spawn_poll(self, pg: _PG, loc: str):
-        """PARK-AND-RE-ENTER for the durability fan-out (ADVICE r5
+    def _take_or_spawn_poll(
+        self, pg: _PG, loc: str, hold: "_HeldOp | None" = None
+    ):
+        """The durability fan-out, off the op worker (ADVICE r5
         osd_daemon:1912: the 2.5 s fan-out used to run under _op_lock
         ON the single op worker, so a handful of torn objects
         serialized multi-second stalls onto every client op).
 
         Returns a finished poll's ``(windows, infos)`` if one is
-        cached for this object, else starts one on a dedicated
-        thread (cooldown- and budget-gated) and returns None — the
-        caller answers eagain, the client's retry loop re-enters,
-        and a later attempt consumes the verdict synchronously. The
-        op worker never blocks. Caller holds _op_lock."""
+        cached for this object. Else it returns None and sees that a
+        poll runs: on a dedicated thread (cooldown-gated), or queued
+        for the next free poller where the budget is spent and an op
+        waits for it. With ``hold`` the op is parked for that poll
+        (``hold.parked``): the poller re-queues it when the verdict
+        is in, and the caller sends no reply. An op not parked
+        answers eagain. The op worker never blocks. Caller holds
+        _op_lock (one loc is always one shard's, so no two callers
+        race for it)."""
         with self._req_poll_lock:
             res = self._req_poll_results.pop(loc, None)
             if res is not None:
                 return res
-            if loc in self._req_polls_inflight:
-                return None  # fan-out already running: retry later
-        import time as _time
-
-        now = _time.monotonic()
+            if (
+                loc in self._req_polls_inflight
+                or loc in self._req_poll_backlog
+            ):
+                self._park_locked(loc, hold)
+                return None  # its fan-out runs or waits: no second one
+        now = time.monotonic()
         if now - self._req_poll_at.get(loc, 0.0) < self.REQ_POLL_COOLDOWN:
             return None
-        if not self._req_poll_sem.acquire(blocking=False):
-            return None  # budget exhausted: eagain, retry into a slot
+        with self._req_poll_lock:
+            spawn = self._req_poll_sem.acquire(blocking=False)
+            if spawn:
+                self._req_polls_inflight.add(loc)
+                self._park_locked(loc, hold)
+            elif self._park_locked(loc, hold):
+                self._req_poll_backlog[loc] = pg
+            else:
+                return None  # budget spent and nobody waits for it
         with self._reqcache_lock:  # possibly a new key: structural
             self._req_poll_at[loc] = now
-        with self._req_poll_lock:
-            self._req_polls_inflight.add(loc)
-
-        def run() -> None:
-            try:
-                polled = self._poll_req_state(pg, loc)
-            except Exception:
-                polled = ([], [])  # classify from nothing -> back off
-            finally:
-                self._req_poll_sem.release()
-            with self._req_poll_lock:
-                self._req_polls_inflight.discard(loc)
-                self._req_poll_results[loc] = polled
-                while len(self._req_poll_results) > 256:
-                    # an abandoned verdict (client gave up) must not
-                    # accumulate forever
-                    self._req_poll_results.pop(
-                        next(iter(self._req_poll_results))
-                    )
-
-        threading.Thread(
-            target=run, daemon=True,
-            name=f"osd.{self.osd_id}-req-poll",
-        ).start()
+        if spawn:
+            threading.Thread(
+                target=self._req_poller, args=(pg, loc), daemon=True,
+                name=f"osd.{self.osd_id}-req-poll",
+            ).start()
         return None
+
+    def _park_locked(self, loc: str, hold: "_HeldOp | None") -> bool:
+        """Park ``hold`` behind ``loc``'s poll; caller holds
+        _req_poll_lock."""
+        if hold is None or (
+            sum(map(len, self._req_held.values())) >= self.REQ_HELD_CAP
+        ):
+            return False
+        hold.t_parked = time.monotonic()
+        if hold.msg.held_since is None:
+            hold.msg.held_since = hold.t_parked
+        hold.parked = True
+        self._req_held.setdefault(loc, []).append(hold)
+        self.opq_pc.inc("req_poll_holds")
+        return True
+
+    def _req_poller(self, pg: _PG, loc: str) -> None:
+        """One poller thread: the fan-out it was started for, then
+        whatever waits in the backlog, until that is empty. Each
+        verdict is cached and the ops held for it go back to the op
+        queue, in the order they came."""
+        slot_held = True
+        try:
+            while True:
+                try:
+                    polled = self._poll_req_state(pg, loc)
+                except Exception:
+                    polled = ([], [])  # classify from nothing -> back off
+                expired: list[_HeldOp] = []
+                nxt = None
+                with self._req_poll_lock:
+                    self._req_polls_inflight.discard(loc)
+                    self._req_poll_results[loc] = polled
+                    while len(self._req_poll_results) > 256:
+                        # an abandoned verdict (client gave up) must
+                        # not accumulate forever
+                        self._req_poll_results.pop(
+                            next(iter(self._req_poll_results))
+                        )
+                    held = self._req_held.pop(loc, [])
+                    now = time.monotonic()
+                    while self._req_poll_backlog and nxt is None:
+                        nloc = next(iter(self._req_poll_backlog))
+                        npg = self._req_poll_backlog.pop(nloc)
+                        waiting = self._req_held.get(nloc, [])
+                        if all(
+                            now - h.msg.held_since >= self.REQ_HOLD_MAX
+                            for h in waiting
+                        ):
+                            # held past the bound: no poll, they bounce
+                            expired += self._req_held.pop(nloc, [])
+                            continue
+                        self._req_polls_inflight.add(nloc)
+                        nxt = (npg, nloc)
+                    if nxt is None:
+                        self._req_poll_sem.release()
+                        slot_held = False
+                self._requeue_held(held + expired)
+                if nxt is None:
+                    return
+                pg, loc = nxt
+        finally:
+            if slot_held:  # the thread is dying: the budget is not
+                self._req_poll_sem.release()
+
+    def _requeue_held(self, held: "list[_HeldOp]") -> None:
+        """Held ops back into the op queue: the gate finds the cached
+        verdict, or answers eagain to one held too long."""
+        now = time.monotonic()
+        for h in held:
+            self.opq_pc.tinc("req_poll_hold_seconds", now - h.t_parked)
+            # the op path rewrote the oid into its pool-scoped key
+            h.msg.oid = split_loc(h.msg.oid)[1]
+            self._handle_client_op(h.conn, h.msg)
 
     def _poll_req_state(self, pg: _PG, loc: str):
         """ONE async fan-out to the acting members for the object's
@@ -3409,7 +3597,7 @@ class OSDDaemon:
         return "ambiguous" if later else "reapply"
 
     def _resolve_unverified_reqs(
-        self, pg: _PG, loc: str, polled=None
+        self, pg: _PG, loc: str, polled=None, hold=None
     ) -> bool:
         """Settle every storage-seeded window entry BEFORE a new op
         stamps the window onward (round-5 review finding: stamping an
@@ -3425,7 +3613,8 @@ class OSDDaemon:
         or the rollback could not establish the committed state) —
         the caller must not mutate the object (eagain; the client's
         backoff retries once the members answer). ``polled`` reuses a
-        fan-out the caller already paid for."""
+        fan-out the caller already paid for; ``hold`` is the op to
+        park while one runs (``_take_or_spawn_poll``)."""
         win0 = self._req_window(pg, loc)  # force the storage seed
         unv = self._req_unverified.get(loc)
         if not unv:
@@ -3434,12 +3623,12 @@ class OSDDaemon:
             windows, infos = polled
         else:
             # async fan-out (cooldown + budget inside): no verdict
-            # ready yet -> eagain; the client's retry re-enters and
-            # consumes it once the poller thread finishes. The old
-            # synchronous poll held _op_lock for the full 2.5 s
-            # deadline and several torn objects serialized that stall
-            # onto every client op (ADVICE r5).
-            res = self._take_or_spawn_poll(pg, loc)
+            # ready yet -> the op is held and re-enters once the
+            # poller thread finishes (or answers eagain where it
+            # cannot be held). The old synchronous poll held _op_lock
+            # for the full 2.5 s deadline and several torn objects
+            # serialized that stall onto every client op (ADVICE r5).
+            res = self._take_or_spawn_poll(pg, loc, hold)
             if res is None:
                 return False
             windows, infos = res
@@ -3570,8 +3759,8 @@ class OSDDaemon:
             if self._transient_degraded(pg, op.error):
                 # lossy-link transient (map still healthy): the
                 # client's resend ladder retries past it
-                return OSDOpReply(
-                    msg.tid, self.osdmap.epoch, error="eagain"
+                return self._eagain(
+                    msg, self.osdmap.epoch, "transient_degraded_write"
                 )
             return OSDOpReply(
                 msg.tid, self.osdmap.epoch, error="eio",
@@ -3613,8 +3802,8 @@ class OSDDaemon:
             if self._transient_degraded(pg, op.error):
                 # lossy-link transient (map still healthy): the
                 # client's resend ladder retries past it
-                return OSDOpReply(
-                    msg.tid, self.osdmap.epoch, error="eagain"
+                return self._eagain(
+                    msg, self.osdmap.epoch, "transient_degraded_truncate"
                 )
             return OSDOpReply(
                 msg.tid, self.osdmap.epoch, error="eio",
@@ -3641,8 +3830,8 @@ class OSDDaemon:
             if self._transient_degraded(pg, op.error):
                 # lossy-link transient (map still healthy): the
                 # client's resend ladder retries past it
-                return OSDOpReply(
-                    msg.tid, self.osdmap.epoch, error="eagain"
+                return self._eagain(
+                    msg, self.osdmap.epoch, "transient_degraded_read"
                 )
             return OSDOpReply(
                 msg.tid, self.osdmap.epoch, error="eio",
@@ -3663,8 +3852,8 @@ class OSDDaemon:
             if self._transient_degraded(pg, op.error):
                 # lossy-link transient (map still healthy): the
                 # client's resend ladder retries past it
-                return OSDOpReply(
-                    msg.tid, self.osdmap.epoch, error="eagain"
+                return self._eagain(
+                    msg, self.osdmap.epoch, "transient_degraded_remove"
                 )
             return OSDOpReply(
                 msg.tid, self.osdmap.epoch, error="eio",
@@ -4010,8 +4199,8 @@ class OSDDaemon:
             if self._transient_degraded(pg, op.error):
                 # lossy-link transient (map still healthy): the
                 # client's resend ladder retries past it
-                return OSDOpReply(
-                    msg.tid, self.osdmap.epoch, error="eagain"
+                return self._eagain(
+                    msg, self.osdmap.epoch, "transient_degraded_attrs"
                 )
             return OSDOpReply(
                 msg.tid, self.osdmap.epoch, error="eio",

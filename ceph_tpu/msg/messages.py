@@ -368,6 +368,10 @@ class OSDOp:
     #: schedules the op under the dmClock class ``client.<tenant>``,
     #: falling back to ``client.<pool>`` when empty (cluster/qos.py)
     tenant: str = ""
+    #: never on the wire: when the primary first parked this op for its
+    #: object's durability poll (``OSDDaemon.REQ_HOLD_MAX`` counts
+    #: from here across re-queues), monotonic seconds
+    held_since: float | None = None
 
     def encode(self) -> list[bytes]:
         return [

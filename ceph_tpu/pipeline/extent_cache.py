@@ -183,6 +183,22 @@ class ECExtentCache:
                 if not op.done and self._missing(op)
             ]
 
+    def forget(self, oid: str, extents: dict[int, ExtentSet]) -> None:
+        """Drop cached bytes of single shards: extents a write went
+        past without producing them (a parity shard that was a hole
+        when it was planned), so what the cache holds of them is the
+        page from before the write. Called by the op that owns the
+        object's FIFO head, so nobody is waiting on these bytes."""
+        with self._lock:
+            data = self._data.get(oid)
+            present = self._present.get(oid, {})
+            for shard, es in extents.items():
+                for start, end in es:
+                    if data is not None:
+                        data.erase(shard, start, end - start)
+                    if shard in present:
+                        present[shard].erase(start, end - start)
+
     def invalidate_object(self, oid: str) -> None:
         """Drop one object's cached CONTENT (truncate invalidation):
         later ops re-read from the backend. Pins/line bookkeeping

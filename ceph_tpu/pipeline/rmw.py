@@ -93,9 +93,37 @@ class WritePlan:
     do_parity_delta: bool
     to_read: dict[int, ExtentSet] = field(default_factory=dict)
     to_write: dict[int, ExtentSet] = field(default_factory=dict)
+    #: parity shards the write touches that were not live when it was
+    #: planned, with their extents: neither read nor encoded for, only
+    #: journaled, for recovery to rebuild when the shard returns
+    holes: dict[int, ExtentSet] = field(default_factory=dict)
+    #: what the backend reads, on live shards, where ``to_read`` names
+    #: old data on a dead one (k survivors' windows, then a decode);
+    #: None: ``to_read`` itself
+    fetch: "dict[int, ExtentSet] | None" = None
 
     def read_bytes(self) -> int:
-        return sum(es.size() for es in self.to_read.values())
+        reads = self.to_read if self.fetch is None else self.fetch
+        return sum(es.size() for es in reads.values())
+
+
+def survivor_fetch(
+    sinfo: StripeInfo, need: dict[int, ExtentSet], live: set[int]
+) -> "dict[int, ExtentSet] | None":
+    """What ``_backend_read`` reads for ``need`` where some of it is on
+    a dead shard, as ``get_min_avail_to_read_shards`` plans it for an
+    MDS code: the first k live shards read the chunk-aligned hull of
+    everything wanted, the live wanted shards their own extents
+    besides. None where nothing wanted is dead."""
+    if all(s in live for s in need):
+        return None
+    hull = sinfo.chunk_aligned_hull(need.values())
+    survivors = sorted(map(sinfo.get_raw_shard, live))[: sinfo.k]
+    fetch = {sinfo.get_shard(raw): ExtentSet([hull]) for raw in survivors}
+    for s, es in need.items():
+        if s in live:
+            fetch.setdefault(s, ExtentSet()).union(es)
+    return fetch
 
 
 def plan_write(
@@ -104,8 +132,15 @@ def plan_write(
     ro_offset: int,
     length: int,
     object_size: int,
+    live: "set[int] | None" = None,
 ) -> WritePlan:
     """Choose the write strategy (ECTransaction.cc:77-79 decision).
+
+    ``live``: the PG's shards that can be read and written now (None:
+    all of them). A dead parity shard leaves both plans (``holes``). A
+    dead data shard stays in them, since its new bytes determine the
+    parity, and old data wanted from it is priced as what it costs:
+    the k survivors' windows (``fetch``).
 
     Costs, in bytes read from the backend:
     - full-stripe: the UNWRITTEN data-shard extents of every touched
@@ -164,6 +199,13 @@ def plan_write(
     full_read: dict[int, ExtentSet] = {}
     lo = min(es.range_start() for es in to_write.values())
     hi = max(es.range_end() for es in to_write.values())
+    holes: dict[int, ExtentSet] = {}
+    if live is not None:
+        holes = {
+            s: es for s, es in to_write.items()
+            if s not in live and sinfo.is_parity_shard(s)
+        }
+        to_write = {s: es for s, es in to_write.items() if s not in holes}
     for raw in range(sinfo.k):
         shard = sinfo.get_shard(raw)
         hull = ExtentSet([(lo, hi)])
@@ -179,10 +221,14 @@ def plan_write(
         if need:
             delta_read[shard] = need
 
-    full = WritePlan(False, full_read, to_write)
+    def plan(do_delta: bool, to_read: dict[int, ExtentSet]) -> WritePlan:
+        fetch = None if live is None else survivor_fetch(sinfo, to_read, live)
+        return WritePlan(do_delta, to_read, to_write, holes, fetch)
+
+    full = plan(False, full_read)
     if not (flags & Flag.PARITY_DELTA_OPTIMIZATION):
         return full
-    delta = WritePlan(True, delta_read, to_write)
+    delta = plan(True, delta_read)
     # Nothing stored yet -> both read nothing; full-stripe encode is the
     # degenerate winner (no old parity to delta against).
     if not delta_read or all(
@@ -433,6 +479,9 @@ class RMWPipeline:
         #: oid -> backend-read failure awaiting its op (degraded RMW
         #: read failed; the op aborts in _cache_ready, in order)
         self._read_errors: dict[str, Exception] = {}
+        #: oid -> (start, end) of its backend read's reconstruct,
+        #: recorded under the op's ``rmw_read_wait`` in _cache_ready
+        self._reconstructs: dict[str, tuple[float, float]] = {}
         #: ECInject write-type-2 seam: the owning daemon points this at
         #: its "mark me down" mon command (ECBackend.cc:1158-1167);
         #: standalone pipelines leave it None
@@ -492,6 +541,24 @@ class RMWPipeline:
                 "rmw_read_seconds",
                 "rmw_read_wait: the cache taking the op to its old data "
                 "being there (sub-reads, or an earlier op on the object)",
+            )
+            # inside rmw_read_seconds: what those reads sent to the
+            # shards, and the ones that had to rebuild old data of a
+            # dead shard (k survivors' windows, then a decode)
+            .add_u64_counter(
+                "rmw_subreads", "shard sub-reads the old-data reads issued"
+            )
+            .add_u64_counter(
+                "rmw_reconstruct_ops", "old-data reads that decoded"
+            )
+            .add_time(
+                "rmw_reconstruct_seconds",
+                "rmw_reconstruct: gather of the survivors and decode",
+            )
+            .add_u64_counter(
+                "hole_shard_writes",
+                "per-shard transactions not built or sent because the "
+                "shard was a hole (journaled for recovery instead)",
             )
             .add_u64_counter("delta_ops", "writes that encoded by delta")
             .add_time(
@@ -656,6 +723,7 @@ class RMWPipeline:
                     ro_offset,
                     len(data),
                     object_size,
+                    live=self.backend.avail_shards(),
                 )
                 self.perf.inc(
                     "parity_delta_ops" if op.plan.do_parity_delta
@@ -950,8 +1018,10 @@ class RMWPipeline:
         old bytes are reconstructed from a MINIMAL survivor set — the
         same planner + decode the degraded client read uses
         (get_min_avail_to_read_shards / objects_read_and_reconstruct,
-        osd/ECBackend.cc:1725). Failures never propagate: the error is
-        parked for ``_cache_ready`` to abort the op in order."""
+        osd/ECBackend.cc:1725). A live-aware plan wants nothing of a
+        dead parity shard, so only old DATA on a dead shard gets here
+        that way. Failures never propagate: the error is parked for
+        ``_cache_ready`` to abort the op in order."""
         from .read import get_min_avail_to_read_shards
 
         smap = ShardExtentMap(self.sinfo)
@@ -961,6 +1031,8 @@ class RMWPipeline:
             reads, need_decode = get_min_avail_to_read_shards(
                 self.sinfo, self.codec, want, avail
             )
+            t0 = time.perf_counter()
+            self.perf.inc("rmw_subreads", len(reads))
             for sr in reads.values():
                 for start, buf in self.backend.read_shard(
                     sr.shard, oid, sr.extents
@@ -970,6 +1042,7 @@ class RMWPipeline:
                 smap.decode(
                     self.codec, holes, self._object_sizes.get(oid, 0)
                 )
+                self._reconstructs[oid] = (t0, time.perf_counter())
         except Exception as e:
             self._read_errors[oid] = e
         self.cache.read_done(oid, smap)
@@ -992,6 +1065,7 @@ class RMWPipeline:
         Any failure in here (degraded read couldn't reconstruct, codec
         error) aborts the op in order instead of wedging the pipeline."""
         err = self._read_errors.pop(op.oid, None)
+        rebuilt = self._reconstructs.pop(op.oid, None)
         if err is not None:
             self._abort_op(op, err)
             return
@@ -999,7 +1073,7 @@ class RMWPipeline:
         if op.t_read_start is not None:
             # across threads: the cache may hand the op on from the
             # thread that ended another op's read or write
-            tracer.record(
+            wait = tracer.record(
                 "rmw_read_wait", op.t_read_start, time.perf_counter(),
                 trace_id=op.write_ctx[0], parent_id=op.write_ctx[1],
                 perf=self.perf, key="rmw_read_seconds",
@@ -1007,6 +1081,15 @@ class RMWPipeline:
             )
             self.perf.inc("rmw_read_ops")
             self.perf.inc("rmw_read_bytes", op.plan.read_bytes())
+            if rebuilt is not None:
+                tracer.record(
+                    "rmw_reconstruct", *rebuilt,
+                    trace_id=op.write_ctx[0],
+                    parent_id=wait.span_id if wait else None,
+                    perf=self.perf, key="rmw_reconstruct_seconds",
+                    oid=op.oid, tid=op.tid,
+                )
+                self.perf.inc("rmw_reconstruct_ops")
         self._in_write_ctx(op, lambda: self._cache_ready_inner(op))
 
     def _in_write_ctx(self, op: ClientOp, step: Callable[[], None]) -> None:
@@ -1184,8 +1267,6 @@ class RMWPipeline:
             "ec_write.fanout", perf=self.perf, key="fanout_seconds"
         ):
             for shard, txn in txns:
-                if shard not in live:
-                    continue  # hole: journaled above, recovered later
                 self.backend.submit_shard_txn(
                     shard, txn,
                     lambda s=shard, o=op: self._shard_ack(o, s),
@@ -1195,8 +1276,12 @@ class RMWPipeline:
     def _build_transactions(
         self, op: ClientOp, result: ShardExtentMap, new_size: int
     ) -> "tuple[set[int], list[tuple[int, Transaction]]]":
-        """(live shards, one Transaction per shard), with the pg-log
-        entry appended: everything short of the first dispatch."""
+        """(live shards, one Transaction per live shard), with the
+        pg-log entry appended: everything short of the first dispatch.
+        A hole's shard gets no transaction and no copy of its bytes:
+        its extents go to the journal, and what the encode made of it
+        (the new page of a dead data shard) to ``op.written`` as it
+        is, for the cache."""
         sinfo = self.sinfo
         hinfo_bytes = self._get_hinfo(op.oid).to_bytes()
         # Dispatch to LIVE shards only: an acting-set hole (down OSD)
@@ -1210,14 +1295,44 @@ class RMWPipeline:
             raise IOError(
                 f"only {len(live)} shards available, need {sinfo.k}"
             )
+        skipped = op.plan.holes
+        if any(s in live for s in skipped):
+            # a parity shard that was dead when the write was planned
+            # has no new page here; sending it none would leave it
+            # stale and acknowledged. The client's resend plans anew.
+            raise IOError(
+                f"interval changed - shards {sorted(skipped)} returned "
+                "since the write was planned"
+            )
         op.pending_shards = set(live)
         written = ShardExtentMap(sinfo)
         op.written = written
+        #: extents of the skipped parity shards: no bytes were made
+        #: for them, so the journal alone remembers them
+        unmade: dict[int, ExtentSet] = {}
         txns: list[tuple[int, Transaction]] = []
         for raw in range(sinfo.k + sinfo.m):
             shard = sinfo.get_shard(raw)
-            txn = Transaction().touch(op.oid)
             shard_size = sinfo.object_size_to_shard_size(new_size, shard)
+            if shard not in live:
+                self.perf.inc("hole_shard_writes")
+                for start, end in skipped.get(
+                    shard, result.get_extent_set(shard)
+                ):
+                    end = min(end, shard_size)
+                    if end <= start:
+                        continue
+                    if shard in skipped:
+                        unmade.setdefault(shard, ExtentSet()).insert(
+                            start, end - start
+                        )
+                    else:
+                        written.insert(
+                            shard, start,
+                            result.get(shard, start, end - start),
+                        )
+                continue
+            txn = Transaction().touch(op.oid)
             for start, end in result.get_extent_set(shard):
                 end = min(end, shard_size)
                 if end <= start:
@@ -1244,6 +1359,9 @@ class RMWPipeline:
                 (self.epoch, op.tid), hinfo_bytes, op.extra_attrs,
             )
             txns.append((shard, txn))
+        if unmade:
+            # what the cache holds of them is the page from before
+            self.cache.forget(op.oid, unmade)
         if self.pglog is not None:
             # OI/HINFO ride every entry so the xattr-replay's merged
             # final state never regresses them to an older op's
@@ -1252,7 +1370,11 @@ class RMWPipeline:
             self.pglog.append(
                 op.tid,
                 op.oid,
-                {s: written.get_extent_set(s) for s in written.shards()},
+                {
+                    **{s: written.get_extent_set(s)
+                       for s in written.shards()},
+                    **unmade,
+                },
                 epoch=self.epoch,
                 xattrs=self._journal_attrs(
                     new_size, (self.epoch, op.tid), hinfo_bytes,
