@@ -20,6 +20,9 @@ import json
 from dataclasses import dataclass, field
 
 from ceph_tpu.store import Transaction
+from ceph_tpu.store.transaction import pack_segments, parse_segments
+
+from .wire import MAX_SEGMENTS
 
 # Frame type ids.
 MSG_EC_SUB_WRITE = 108        # MOSDECSubOpWrite
@@ -87,7 +90,12 @@ class ECSubWrite:
             h["trace"] = [self.trace_id, self.parent_span]
         if self.epoch:
             h["e"] = [self.epoch, self.from_osd]
-        return [_header("sub_write", h), self.txn.to_bytes()]
+        # the transaction as segments: in a frame past the receiver's
+        # scratch a payload of 4 KiB or more is the sender's own buffer
+        return [
+            _header("sub_write", h),
+            *pack_segments([self.txn], MAX_SEGMENTS - 1)[0],
+        ]
 
     @classmethod
     def decode(cls, segments: list[bytes]) -> "ECSubWrite":
@@ -95,7 +103,7 @@ class ECSubWrite:
         trace = h.get("trace") or [None, None]
         e = h.get("e") or [0, -1]
         return cls(
-            h["tid"], h["shard"], Transaction.from_bytes(segments[1]),
+            h["tid"], h["shard"], parse_segments(segments[1:])[0],
             trace[0], trace[1], e[0], e[1],
         )
 
@@ -141,7 +149,11 @@ class ECSubWriteBatch:
     items: list = field(default_factory=list)
 
     def encode(self) -> list[bytes]:
-        blobs = [txn.to_bytes() for *_m, txn in self.items]
+        # one stream of all the items' transactions, cut into the
+        # frame's segments; ``lens`` says where each ends
+        segs, lens = pack_segments(
+            [txn for *_m, txn in self.items], MAX_SEGMENTS - 1
+        )
         return [
             _header(
                 "sub_write_batch",
@@ -151,20 +163,19 @@ class ECSubWriteBatch:
                     "items": [
                         list(meta) for *meta, _txn in self.items
                     ],
-                    "lens": [len(b) for b in blobs],
+                    "lens": lens,
                 },
             ),
-            b"".join(blobs),
+            *segs,
         ]
 
     @classmethod
     def decode(cls, segments: list[bytes]) -> "ECSubWriteBatch":
         h = _parse(segments[0], "sub_write_batch")
-        blob, pos, items = segments[1], 0, []
-        for meta, ln in zip(h["items"], h["lens"]):
-            txn = Transaction.from_bytes(blob[pos : pos + ln])
-            pos += ln
-            items.append(tuple(meta) + (txn,))
+        txns = parse_segments(segments[1:], h["lens"])
+        items = [
+            tuple(meta) + (txn,) for meta, txn in zip(h["items"], txns)
+        ]
         return cls(h["tid"], h["shard"], items)
 
 

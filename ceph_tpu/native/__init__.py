@@ -307,14 +307,24 @@ def crc32c_fold(
 
 # -- frame codec ---------------------------------------------------------
 def _seg_arrays(segments):
-    """(count, char* array, length array) of a frame's segments; the
-    pointers are into the ``bytes`` objects, which the array keeps."""
-    segs = [s if isinstance(s, bytes) else bytes(s) for s in segments]
-    nseg = len(segs)
+    """(count, char* array, length array) of a frame's segments: a
+    ``bytes`` goes in as it is, any other buffer (a read-only view of a
+    payload) as the address it lies at: no copy either way. The caller
+    keeps ``segments`` alive over the call."""
+    ptrs, lens = [], []
+    for s in segments:
+        if isinstance(s, bytes):
+            ptrs.append(s)
+            lens.append(len(s))
+        else:
+            flat = np.frombuffer(s, np.uint8)
+            ptrs.append(flat.ctypes.data if flat.size else None)
+            lens.append(flat.size)
+    nseg = len(ptrs)
     return (
         nseg,
-        (ctypes.c_char_p * nseg)(*segs),
-        (ctypes.c_uint64 * nseg)(*map(len, segs)),
+        (ctypes.c_char_p * nseg)(*ptrs),
+        (ctypes.c_uint64 * nseg)(*lens),
     )
 
 

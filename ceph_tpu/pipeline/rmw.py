@@ -560,6 +560,12 @@ class RMWPipeline:
                 "per-shard transactions not built or sent because the "
                 "shard was a hole (journaled for recovery instead)",
             )
+            .add_u64_counter(
+                "txn_copy_bytes",
+                "payload bytes copied building the per-shard "
+                "transactions (0 where every extent is a view of one "
+                "run of the encode's result)",
+            )
             .add_u64_counter("delta_ops", "writes that encoded by delta")
             .add_time(
                 "delta_prepare_seconds",
@@ -1311,6 +1317,8 @@ class RMWPipeline:
         #: for them, so the journal alone remembers them
         unmade: dict[int, ExtentSet] = {}
         txns: list[tuple[int, Transaction]] = []
+        #: payload bytes copied on the way into the transactions
+        copied = 0
         for raw in range(sinfo.k + sinfo.m):
             shard = sinfo.get_shard(raw)
             shard_size = sinfo.object_size_to_shard_size(new_size, shard)
@@ -1337,10 +1345,16 @@ class RMWPipeline:
                 end = min(end, shard_size)
                 if end <= start:
                     continue
-                # the one copy a shard: the store and the wire want
-                # ``bytes``, and ``written`` keeps the same immutable
-                # object (a view of the run in, no copy out)
-                buf = result.get(shard, start, end - start).tobytes()
+                # no copy: the run's read-only view changes hands, to
+                # the transaction (the wire sends it from where it
+                # lies, the store makes the one copy into its own
+                # memory) and to ``written``; the views pin the
+                # encode's arrays until the op's acks are in
+                buf = result.get(shard, start, end - start)
+                if buf.base is None:
+                    # no one run covered the range: ``get`` assembled
+                    # an array of its own
+                    copied += buf.size
                 # fused-kernel csums ride the sub-write when they
                 # describe this exact range (block-aligned within the
                 # encode window) — the store adopts them instead of
@@ -1359,6 +1373,8 @@ class RMWPipeline:
                 (self.epoch, op.tid), hinfo_bytes, op.extra_attrs,
             )
             txns.append((shard, txn))
+        if copied:
+            self.perf.inc("txn_copy_bytes", copied)
         if unmade:
             # what the cache holds of them is the page from before
             self.cache.forget(op.oid, unmade)

@@ -23,6 +23,7 @@ import time
 import numpy as np
 
 from ceph_tpu.codecs.matrix_codec import codec_stage
+from ceph_tpu.utils.buffers import is_frozen
 
 from .extents import ExtentSet
 from .hashinfo import HashInfo
@@ -79,24 +80,28 @@ class ShardExtentMap:
     @staticmethod
     def _owned(data) -> np.ndarray:
         """``data`` as a flat, read-only uint8 array the map may keep:
-        itself where nobody can write to it afterwards, else a copy."""
-        if isinstance(data, bytes):
+        itself where nobody can write to it afterwards
+        (``buffers.is_frozen``, the rule ``Transaction.write`` shares),
+        else a copy."""
+        if is_frozen(data):
+            # immutable by its maker's word
+            if isinstance(data, np.ndarray):
+                return data.reshape(-1)
             return np.frombuffer(data, dtype=np.uint8)
         arr = np.asarray(data)
         if not (
             arr.dtype == np.uint8
             and arr.flags.c_contiguous
-            and (arr.base is None or not arr.flags.writeable)
+            and arr.base is None
         ):
-            # a bytearray, a memoryview, a writable view: whoever holds
+            # a bytearray, a writable memoryview or view: whoever holds
             # the memory can still write to it
             arr = np.array(
                 np.frombuffer(data, dtype=np.uint8)
                 if isinstance(data, (bytearray, memoryview)) else arr,
                 dtype=np.uint8,
             )
-        # an array that owns its bytes changes hands here; a read-only
-        # one is immutable by its maker's word
+        # an array that owns its bytes changes hands here
         arr.flags.writeable = False
         return arr.reshape(-1)
 

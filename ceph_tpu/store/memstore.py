@@ -7,6 +7,12 @@ byte buffers plus an attr map; transactions apply atomically —
 validated first, then applied, so a failing op leaves no partial
 state (stricter than the reference's assert-on-error, deliberately:
 a functional-style store suits a replayable TPU pipeline).
+
+The store takes nothing over: a WRITE's payload is the sender's (a view
+of an encode's rows, of a received frame's segment) and stays so; the
+store copies it once into an object's own ``bytearray``, so what is
+acknowledged lies in memory the store alone owns and an overwritten
+object never changes what a sender still holds.
 """
 
 from __future__ import annotations
@@ -28,6 +34,11 @@ def make_store_perf(name: str):
         PerfCountersBuilder(perf_collection, name)
         .add_u64_counter("txns", "queue_transactions calls applied")
         .add_u64_counter("txn_bytes", "data bytes of their WRITE ops")
+        .add_u64_counter(
+            "apply_copy_bytes",
+            "bytes their WRITE ops moved or zero-filled (= txn_bytes "
+            "where every payload is copied once and no gap is filled)",
+        )
         .add_time("apply_seconds", "seconds inside queue_transactions")
         .add_u64_counter("reads", "read calls served")
         .add_u64_counter("read_bytes", "bytes they returned")
@@ -80,19 +91,20 @@ class MemStore:
         if isinstance(txns, Transaction):
             txns = [txns]
         t0 = time.perf_counter()
-        seq = self._apply_all(txns)
+        seq, written, moved = self._apply_all(txns)
         if self.perf is not None:
             self.perf.inc("txns")
-            self.perf.inc("txn_bytes", sum(
-                len(op.data) for t in txns for op in t.ops
-                if op.kind is OpKind.WRITE
-            ))
+            self.perf.inc("txn_bytes", written)
+            self.perf.inc("apply_copy_bytes", moved)
             self.perf.tinc("apply_seconds", time.perf_counter() - t0)
         return seq
 
-    def _apply_all(self, txns: list[Transaction]) -> int:
+    def _apply_all(self, txns: list[Transaction]) -> tuple[int, int, int]:
+        """(commit sequence, data bytes of the WRITE ops, bytes they
+        moved or zero-filled)."""
         with self._lock:
             staged: dict[str, _Object | None] = {}
+            written = moved = 0
 
             def get(oid: str, create: bool) -> _Object | None:
                 if oid not in staged:
@@ -104,7 +116,11 @@ class MemStore:
 
             for t in txns:
                 for op in t.ops:
-                    self._apply(op, get, staged)
+                    if op.kind is OpKind.WRITE:
+                        written += len(op.data)
+                        moved += self._write(get(op.oid, create=True), op)
+                    else:
+                        self._apply(op, get, staged)
             for oid, obj in staged.items():
                 if obj is None:
                     self._objects.pop(oid, None)
@@ -115,7 +131,29 @@ class MemStore:
                 if len(self._touched) > NOTE_MAX_KEYS:
                     self._touched = None
             self.committed_seq += 1
-            return self.committed_seq
+            return self.committed_seq, written, moved
+
+    @staticmethod
+    def _write(obj: _Object, op: Op) -> int:
+        """Copy a WRITE's payload into ``obj``, once; returns the bytes
+        moved or zero-filled. At or past the object's end (a new
+        object, an append, a write across the end) the object grows by
+        the gap's zeros and the payload itself, with no zero-fill for
+        the payload to overwrite; inside, the bytes are assigned in
+        place."""
+        buf, data, off = obj.data, op.data, op.offset
+        n, size = len(data), len(buf)
+        if off + n <= size:
+            with memoryview(buf) as inside:
+                inside[off : off + n] = data
+            return n
+        if off < size:
+            del buf[off:]  # the tail the payload covers
+        elif off > size:
+            buf += bytes(off - size)  # the gap reads as zeros
+            n += off - size
+        buf += data
+        return n
 
     @staticmethod
     def _apply(op: Op, get, staged: dict) -> None:
@@ -127,13 +165,6 @@ class MemStore:
             if obj is None:
                 raise FileNotFoundError(op.oid)
             staged[op.oid] = None
-            return
-        if op.kind is OpKind.WRITE:
-            obj = get(op.oid, create=True)
-            end = op.offset + len(op.data)
-            if len(obj.data) < end:
-                obj.data.extend(b"\0" * (end - len(obj.data)))
-            obj.data[op.offset:end] = op.data
             return
         if op.kind is OpKind.ZERO:
             obj = get(op.oid, create=True)

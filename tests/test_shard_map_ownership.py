@@ -626,8 +626,8 @@ def allocations(monkeypatch):
 def test_a_full_stripe_write_copies_its_bytes_in_once(allocations):
     """4 MiB through the served write: one array of object size in
     assemble (the scatter), none in the codec's prep, nothing of a
-    shard's size in txn_build, whose ``bytes`` are the buffers that
-    ``written`` keeps."""
+    shard's size in txn_build, whose payloads are read-only views of
+    the very runs that ``written`` keeps."""
     k, m, cs = 8, 4, 4096
     sinfo = StripeInfo(k, m, k * cs)
     backend = RecordingBackend(
@@ -655,7 +655,11 @@ def test_a_full_stripe_write_copies_its_bytes_in_once(allocations):
     for shard, ops in backend.txns:
         (write,) = [o for o in ops if o.kind is OpKind.WRITE]
         ((off, run),) = op.written._bufs[shard]
-        assert off == 0 and run.base is write.data  # one bytes a shard
+        assert off == 0 and write.data.readonly
+        payload = np.frombuffer(write.data, np.uint8)
+        assert payload.size == run.size and (
+            payload.ctypes.data == run.ctypes.data  # no copy a shard
+        )
 
 
 def test_the_codec_is_handed_the_callers_bytes(monkeypatch):
