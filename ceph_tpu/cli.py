@@ -777,7 +777,12 @@ def build_parser() -> argparse.ArgumentParser:
 
     s = sub.add_parser("profile-set")
     s.add_argument("name")
-    s.add_argument("kv", nargs="+", help="key=value pairs")
+    s.add_argument(
+        "kv", nargs="+",
+        help="key=value pairs: the profile whole, as `ceph osd "
+             "erasure-code-profile set` takes it (plugin, k, m and any "
+             "key the plugin knows: technique, d, c, l, ...)",
+    )
     s.add_argument("--force", action="store_true")
     s.set_defaults(fn=cmd_profile_set)
 

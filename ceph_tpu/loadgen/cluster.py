@@ -16,19 +16,23 @@ from ceph_tpu.cluster import Monitor, OSDDaemon, RadosClient
 from ceph_tpu.cluster.osdmap import SHARD_NONE
 
 
+#: the name the pool's erasure-code profile has at the monitor
+PROFILE_NAME = "loadprof"
+
+
 class LoadCluster:
     """mon + OSDs + EC pool + client, with thrasher controls."""
 
     def __init__(
         self,
         n_osds: int = 6,
-        k: int = 3,
-        m: int = 2,
+        k: int | None = None,
+        m: int | None = None,
         pg_num: int = 8,
         chunk_size: int = 1024,
         pool: str = "loadpool",
-        plugin: str = "jerasure",
-        technique: str = "reed_sol_van",
+        plugin: str | None = None,
+        technique: str | None = None,
         d: int | None = None,
         store_factory=None,
         tick_period: float = 0.2,
@@ -37,10 +41,60 @@ class LoadCluster:
         client_max_attempts: int = 10,
         use_mesh: bool = False,
         mesh_devices: int | None = None,
+        profile: dict[str, str] | None = None,
     ) -> None:
-        if n_osds < k + m:
-            raise ValueError(f"need >= k+m={k + m} OSDs, got {n_osds}")
-        clay_d = d  # the daemon boot loop below reuses the name ``d``
+        """``profile`` is the pool's erasure-code profile, whole, as
+        ``ceph osd erasure-code-profile set`` takes it (every key and
+        value a string, ``plugin`` among them), and is handed to the
+        monitor as it is; it is given alone, not beside the keywords
+        it replaces. Without it ``k`` (3), ``m`` (2), ``plugin``
+        (jerasure), ``technique`` and ``d`` make one; ``technique`` not
+        given is the plugin's own default. A key the plugin does not
+        know is the codec's to refuse, through the monitor's command.
+        The counts (OSDs needed, CRUSH zones, the mesh's shard axis)
+        are those of the code the monitor then holds: a code may have
+        more chunks than k+m."""
+        beside = (k, m, plugin, technique, d)
+        if profile is not None:
+            if any(given is not None for given in beside):
+                raise ValueError(
+                    "profile= is the pool's whole profile: give k, m, "
+                    "plugin, technique and d inside it, not beside it"
+                )
+        else:
+            plugin = plugin or "jerasure"
+            profile = {
+                "plugin": plugin,
+                "k": str(3 if k is None else k),
+                "m": str(2 if m is None else m),
+            }
+            if technique is None and plugin == "jerasure":
+                technique = "reed_sol_van"  # what this form always sent
+            if technique is not None:
+                profile["technique"] = technique
+            if d is not None:
+                # CLAY's d steers the MSR repair bandwidth (default
+                # k+m-1)
+                profile["d"] = str(d)
+        self.mon = Monitor()
+        self.mon.osd_erasure_code_profile_set(PROFILE_NAME, dict(profile))
+        codec = self.codec()
+        k = codec.get_data_chunk_count()
+        chunks = codec.get_chunk_count()
+        m = chunks - k
+        if n_osds < chunks:
+            raise ValueError(
+                f"need >= {chunks} OSDs (the code's chunk count), "
+                f"got {n_osds}"
+            )
+        sub = codec.get_sub_chunk_count()
+        if chunk_size % sub:
+            # a code with sub-chunks (CLAY's q^t): the fractional
+            # sub-reads take whole, lane-aligned sub-chunks of a chunk
+            raise ValueError(
+                f"chunk_size {chunk_size} must divide into the "
+                f"code's {sub} sub-chunks"
+            )
         self.pool = pool
         self.k, self.m = k, m
         self.chunk_size = chunk_size
@@ -62,7 +116,6 @@ class LoadCluster:
             self._prev_mesh = mesh_dispatch.get_mesh()
             self.mesh = make_ec_mesh(mesh_devices, k=k)
             mesh_dispatch.set_mesh(self.mesh)
-        self.mon = Monitor()
         self.daemons: dict[int, OSDDaemon] = {}
         self.stores: dict[int, object] = {}
         for i in range(n_osds):
@@ -76,27 +129,7 @@ class LoadCluster:
             d.start()
             self.daemons[i] = d
             self.stores[i] = d.store
-        profile = {
-            "plugin": plugin, "k": str(k), "m": str(m),
-        }
-        if plugin == "jerasure":
-            profile["technique"] = technique
-        if plugin == "clay":
-            # CLAY pools at the cluster tier: d steers the MSR repair
-            # bandwidth (default k+m-1); chunks must split into q^t
-            # lane-aligned sub-chunks for the fractional sub-reads
-            if clay_d is not None:
-                profile["d"] = str(clay_d)
-            from ceph_tpu.codecs import registry as _reg
-
-            sub = _reg.factory("clay", dict(profile)).get_sub_chunk_count()
-            if chunk_size % sub:
-                raise ValueError(
-                    f"chunk_size {chunk_size} must divide into the "
-                    f"pool's {sub} CLAY sub-chunks"
-                )
-        self.mon.osd_erasure_code_profile_set("loadprof", profile)
-        self.mon.osd_pool_create(pool, pg_num, "loadprof")
+        self.mon.osd_pool_create(pool, pg_num, PROFILE_NAME)
         # short op timeout: a kill can eat an in-flight op's reply
         # mid-run, and the default 30 s wait would freeze the whole
         # closed loop for the duration (the reqid dedup makes the
@@ -327,12 +360,17 @@ class LoadCluster:
         return ok
 
     def codec(self):
-        """The pool's codec instance (device-clock probe input)."""
+        """The pool's codec instance: the code of the profile the
+        monitor holds for the pool (which its command has validated; a
+        profile without ``plugin`` is the default plugin's)."""
         from ceph_tpu.codecs import registry
+        from ceph_tpu.utils import config
 
-        spec = self.mon.osdmap.pools[self.pool]
-        profile = dict(self.mon.osdmap.profiles[spec.profile_name])
-        return registry.factory(spec.plugin, profile)
+        profile = dict(self.mon.osdmap.profiles[PROFILE_NAME])
+        plugin = profile.get(
+            "plugin", config.get("erasure_code_default_plugin")
+        )
+        return registry.factory(plugin, profile)
 
     def shutdown(self) -> None:
         from ceph_tpu.msg.messenger import net_faults

@@ -514,6 +514,12 @@ class RMWPipeline:
                 "writes that reached the encode: what the stage "
                 "seconds below are summed over",
             )
+            .add_u64_counter(
+                "short_stripe_writes",
+                "of those, writes that leave their object ending "
+                "inside a stripe: the later data shards are stored a "
+                "chunk shorter, the encode's last stripe is part padding",
+            )
             .add_time("write_seconds", "ec_write: the whole of submit")
             .add_time("plan_seconds", "ec_write.plan: plan + cache prepare")
             .add_time(
@@ -1140,6 +1146,8 @@ class RMWPipeline:
                                 shard, s, old_map.get(shard, s, e - s)
                             )
         self.perf.inc("encode_ops")
+        if new_size % sinfo.stripe_width:
+            self.perf.inc("short_stripe_writes")
         if op.plan.do_parity_delta:
             self._encode_by_delta(op, new_map, old_map, hinfo, new_size)
             return

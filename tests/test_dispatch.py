@@ -373,11 +373,25 @@ ROUTE_TABLE = [
                 cfg={"ec_fused_csum_interpret": True}),
     _route_case("fused-switched-off", None, set(),
                 fused=True, staged="host", cfg={"ec_fused_csum": False}),
+    # -- the ISA Cauchy (10,4) pool's write (``cauchy104-1m.write``):
+    # 26 stripes of k = 10 rows. The write's rows come from the host
+    # and go stacked; 26 is no multiple of the shards form's 8-stripe
+    # block, so shards that are on the device are stacked too; a
+    # multiple of 8 stripes of them rides the shards form at k = 10
+    _route_case("fused-stacked-k10", "fused", {"fused_encode"},
+                codec="cauchy104", fused=True, staged="host", lead=(26,),
+                n=4096),
+    _route_case("fused-k10-shards-of-26-stripes-go-stacked", "fused",
+                {"fused_encode"},
+                codec="cauchy104", fused=True, lead=(26,), n=4096),
+    _route_case("fused-shards-form-k10", "fused_shards", {"fused_encode"},
+                codec="cauchy104", fused=True, lead=(8,), n=4096),
 ]
 
 _ROUTE_CODECS = {
     "dense": ("isa", {"k": "4", "m": "2"}),
     "xor": ("xor", {"k": "4"}),
+    "cauchy104": ("isa", {"technique": "cauchy", "k": "10", "m": "4"}),
     "packet": (
         "jerasure",
         {"technique": "liberation", "k": "4", "m": "2", "w": "7"},
