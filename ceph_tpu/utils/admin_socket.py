@@ -172,6 +172,36 @@ def _register_builtins(sock: AdminSocket) -> None:
         "per-subsystem log/gather level pairs",
     )
 
+    # Python under the interpreter lock by function and thread role
+    # (utils/pyprof.py). The only way in besides tools/pyprof_cell.py:
+    # nothing else in the package starts it, and the module is not
+    # even imported until one of the three is asked for.
+    def _pyprof(verb: str):
+        def run(**kwargs):
+            from ceph_tpu.utils import pyprof
+
+            return getattr(pyprof, "admin_" + verb)(**kwargs)
+
+        return run
+
+    sock.register(
+        "pyprof start", _pyprof("start"),
+        "start profiling every thread of the process [lock_lost_ms=1]: "
+        "self Python, native and lock-lost time by function and thread "
+        "role; a second start is an error, and a profiled process is "
+        "slower",
+    )
+    sock.register(
+        "pyprof stop", _pyprof("stop"),
+        "stop the profile (it stays for `pyprof dump`)",
+    )
+    sock.register(
+        "pyprof dump", _pyprof("dump"),
+        "the running or the last profile [top=15 ops=N text=false]: by "
+        "role, self Python / native / lock-lost ms (an op with ops=N), "
+        "the top functions and native callees; text=true for the table",
+    )
+
     def _inject(kind: str):
         def run(oid, type, when=0, duration=1, shard=None):
             from ceph_tpu.pipeline.inject import ANY_SHARD, ec_inject

@@ -272,3 +272,41 @@ def test_cli_list_rules():
     assert proc.returncode == 0
     for code in RULES:
         assert code in proc.stdout
+
+
+# -- the profiler has two ways in and no third (PR 47) -------------------
+
+def test_only_pyprof_and_its_admin_command_name_sys_monitoring():
+    """``utils/pyprof.py`` is the one module that touches
+    ``sys.monitoring``, and the admin socket's ``pyprof`` commands are
+    the one place in the package that reaches it: a run nobody profiles
+    holds no tool and sets no event, because nothing else can."""
+    allowed = {
+        os.path.join("ceph_tpu", "utils", "pyprof.py"),
+        os.path.join("ceph_tpu", "utils", "admin_socket.py"),
+    }
+    named = []
+    for folder, _dirs, files in os.walk(os.path.join(REPO_ROOT, "ceph_tpu")):
+        for name in files:
+            path = os.path.join(folder, name)
+            rel = os.path.relpath(path, REPO_ROOT)
+            if not name.endswith(".py") or rel in allowed:
+                continue
+            with open(path) as f:
+                tree = ast.parse(f.read(), rel)
+            for node in ast.walk(tree):
+                if (
+                    isinstance(node, ast.Attribute) and node.attr == "monitoring"
+                ) or (
+                    isinstance(node, (ast.Import, ast.ImportFrom))
+                    and any("pyprof" in a.name or a.name == "monitoring"
+                            or "pyprof" in (getattr(node, "module", "") or "")
+                            for a in node.names)
+                ):
+                    named.append(f"{rel}:{node.lineno}")
+    assert not named, named
+    # and the admin socket reaches it only inside the three commands
+    with open(os.path.join(REPO_ROOT, "ceph_tpu", "utils", "admin_socket.py")) as f:
+        source = f.read()
+    assert "sys.monitoring" not in source
+    assert source.count("import pyprof") == 1

@@ -173,6 +173,77 @@ class TestAdminSocket:
         with pytest.raises(KeyError):
             admin_socket.execute("launch missiles")
 
+    def test_pyprof_round_trip_on_a_two_osd_cluster(self):
+        """``pyprof start`` / ``dump`` / ``stop`` (PR 47): the process
+        profiles itself while a two-OSD cluster serves a write, every
+        thread lands in a registered role, and after ``stop`` no tool
+        is left behind."""
+        import sys
+
+        from ceph_tpu.cluster import Monitor, OSDDaemon, RadosClient
+
+        mon = Monitor()
+        for i in range(2):
+            mon.osd_crush_add(i, zone=f"z{i}")
+        daemons = [OSDDaemon(i, mon, chunk_size=1024) for i in range(2)]
+        for d in daemons:
+            d.start()
+        mon.osd_erasure_code_profile_set(
+            "rs11", {"plugin": "jerasure", "technique": "reed_sol_van",
+                     "k": "1", "m": "1"}
+        )
+        mon.osd_pool_create("pp", 2, "rs11")
+        client = RadosClient(mon, backoff=0.01)
+        try:
+            io = client.open_ioctx("pp")
+            io.write("warm", b"w" * 2048)
+            started = admin_socket.execute("pyprof start", lock_lost_ms="2")
+            assert started["active"] and started["lock_lost_ns"] == 2_000_000
+            with pytest.raises(RuntimeError, match="already started"):
+                admin_socket.execute("pyprof start")
+            for i in range(4):
+                io.write(f"obj{i}", bytes([i]) * 4096)
+                assert io.read(f"obj{i}") == bytes([i]) * 4096
+            live = admin_socket.execute("pyprof dump", top="5", ops="8")
+            assert live["active"] and live["per"] == "op"
+            stopped = admin_socket.execute("pyprof stop")
+            assert not stopped["active"]
+        finally:
+            if sys.monitoring.get_tool(sys.monitoring.PROFILER_ID):
+                admin_socket.execute("pyprof stop")
+            client.shutdown()
+            for d in daemons:
+                d.stop()
+        assert sys.monitoring.get_tool(sys.monitoring.PROFILER_ID) is None
+        done = admin_socket.execute("pyprof dump", top="5", ops="8")
+        assert not done["active"] and done["ops"] == 8
+        assert {"op_worker", "msgr", "client"} <= set(done["roles"]), (
+            done["thread_names"]
+        )
+        assert not [n for n in done["thread_names"] if n.startswith("Dummy")]
+        worker = done["roles"]["op_worker"]
+        assert worker["self_ms"] > 0 and len(worker["functions"]) == 5
+        text = admin_socket.execute("pyprof dump", text="true")
+        assert "role op_worker" in text and "role msgr" in text
+        with pytest.raises(RuntimeError, match="not started"):
+            admin_socket.execute("pyprof stop")
+
+    def test_pyprof_dump_with_nothing_started_says_so(self):
+        import subprocess
+        import sys
+
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "from ceph_tpu.utils.admin_socket import admin_socket as a\n"
+             "d = a.execute('pyprof dump')\n"
+             "print(d['active'], 'never started' in d['note'])"],
+            capture_output=True, text=True, timeout=120,
+        )
+        assert out.stdout.strip().split("\n")[-1] == "False True", out.stderr
+        assert {"pyprof start", "pyprof stop", "pyprof dump"} <= set(
+            admin_socket.help()
+        )
+
 
 class TestPipelineIntegration:
     def test_counters_and_spans_flow(self, rng):
