@@ -24,11 +24,11 @@ from .checksummer import (
 from .crc32c import crc32c as crc32c_host
 from .host import crc32c_wire
 from .crc32c import (
-    crc32c_chain,
     crc32c_device,
     crc32c_fold,
     crc32c_seed_shift,
     crc32c_stream,
+    crc32c_streams,
 )
 from .reference import crc32c_ref, xxh32_ref, xxh64_ref
 
@@ -36,7 +36,6 @@ __all__ = [
     "CSUM_ALGORITHMS",
     "Checksummer",
     "backends",
-    "crc32c_chain",
     "crc32c_host",
     "crc32c_device",
     "crc32c_fold",
@@ -44,6 +43,7 @@ __all__ = [
     "crc32c_scalar",
     "crc32c_seed_shift",
     "crc32c_stream",
+    "crc32c_streams",
     "crc32c_wire",
     "csum_value_size",
     "xxh32_ref",

@@ -310,41 +310,78 @@ def crc32c_fold(seeds, block_csums, block_bytes: int) -> np.ndarray:
     return fold_words(zero_gap_columns(block_bytes), seeds, csums)
 
 
-def crc32c_chain(init: int, block_csums, block_bytes: int) -> int:
-    """``crc32c_fold`` of one stream: the register after ``init`` and
-    the blocks whose zero-init crcs are ``block_csums``."""
-    return int(
-        crc32c_fold([init & 0xFFFFFFFF], [block_csums], block_bytes)[0]
-    )
-
-
-def crc32c_stream(data, init: int = 0xFFFFFFFF) -> int:
-    """Cumulative crc32c of one byte stream, backend-routed: host
-    scalar (native C when loaded) below ``csum_device_min_bytes``,
-    device-batched fold above — whole blocks ride ``crc32c_device``
-    zero-init and chain via ``crc32c_chain``; a ragged tail finishes
-    on the host. Callers chain across pieces by passing the previous
-    return as ``init`` (the deep-scrub stride loop)."""
+def as_stream(data) -> np.ndarray:
+    """One byte stream as a flat uint8 array, a view wherever the
+    buffer allows (a strided array stays strided: the one copy is the
+    caller's)."""
     if isinstance(data, (bytes, bytearray, memoryview)):
-        buf = np.frombuffer(data, dtype=np.uint8)
-    else:
-        buf = np.ascontiguousarray(data, dtype=np.uint8).reshape(-1)
+        return np.frombuffer(data, dtype=np.uint8)
+    arr = np.asarray(data)
+    if arr.dtype != np.uint8:
+        # no silent value casts: the crc is over stored bytes
+        raise TypeError(f"stream bytes must be uint8, got {arr.dtype}")
+    return arr.reshape(-1)
+
+
+def crc32c_streams(inits, streams) -> "tuple[list[int], int]":
+    """Cumulative crc32c of equal-length byte streams hashed TOGETHER:
+    ``inits[i]`` is stream i's register before, the answer its
+    register after, and the number of device checksum calls made
+    (0 or 1). Backend-routed by ONE stream's length: host scalar
+    (native C when loaded) below ``csum_device_min_bytes``; above it
+    the whole blocks of every stream ride ONE zero-init
+    ``crc32c_device`` call ([streams * blocks, block] rows
+    stream-major, padded with zero blocks to a count the Pallas fold
+    tiles, their words dropped), come back in one fetch and chain
+    into the registers through one ``crc32c_fold``; a ragged tail
+    finishes on the host. The streams' bytes are copied on the host
+    at most once (the stack the call uploads); one contiguous stream
+    whose block count tiles is uploaded as the view it is."""
+    rows = [as_stream(s) for s in streams]
+    sizes = {int(r.size) for r in rows}
+    if len(sizes) > 1:
+        raise ValueError(f"unequal stream sizes {sizes}")
+    regs = [int(i) & 0xFFFFFFFF for i in inits]
+    if len(regs) != len(rows):
+        raise ValueError(f"{len(regs)} inits for {len(rows)} streams")
+    if not rows:
+        return regs, 0
     from ceph_tpu.utils import config
 
     from . import backends
     from .host import crc32c as _host_crc
 
-    n = int(buf.size)
+    n = sizes.pop()
     limit = int(config.get("csum_device_min_bytes"))
-    if limit <= 0 or n < limit:
-        backends.record("host", n)
-        return _host_crc(init, buf.tobytes())
     cb = 65536 if n >= 4 * 65536 else 4096
     nb = n // cb
-    blocks = buf[: nb * cb].reshape(nb, cb)
-    c0 = np.asarray(crc32c_device(blocks, 0))
-    reg = crc32c_chain(init, c0, cb)
-    tail = buf[nb * cb :]
-    if tail.size:
-        reg = _host_crc(reg, tail.tobytes())
-    return reg
+    if limit <= 0 or n < limit or not nb:
+        for i, row in enumerate(rows):
+            backends.record("host", n)
+            regs[i] = _host_crc(regs[i], row.tobytes())
+        return regs, 0
+    from .pallas_crc import tile_blocks
+
+    count = len(rows) * nb
+    padded = tile_blocks(count)
+    if padded == count and len(rows) == 1 and rows[0].flags.c_contiguous:
+        blocks = rows[0][: nb * cb].reshape(nb, cb)
+    else:
+        blocks = np.empty((padded, cb), dtype=np.uint8)
+        flat = blocks.reshape(-1)
+        for i, row in enumerate(rows):
+            flat[i * nb * cb : (i + 1) * nb * cb] = row[: nb * cb]
+        blocks[count:] = 0
+    words = np.asarray(crc32c_device(blocks, 0))[:count]
+    regs = crc32c_fold(regs, words.reshape(len(rows), nb), cb).tolist()
+    if n > nb * cb:
+        for i, row in enumerate(rows):
+            regs[i] = _host_crc(regs[i], row[nb * cb :].tobytes())
+    return regs, 1
+
+
+def crc32c_stream(data, init: int = 0xFFFFFFFF) -> int:
+    """Cumulative crc32c of one byte stream: ``crc32c_streams`` of one.
+    Callers chain across pieces by passing the previous return as
+    ``init`` (the deep-scrub stride loop)."""
+    return crc32c_streams([init], [data])[0][0]

@@ -21,7 +21,7 @@ SUB*8 — a streamed MXU column carries 16 data bytes instead of 8.
 Long blocks fold across a second grid axis that revisits the
 accumulator (read-modify-write on out_ref); parity (&1), the
 init-register contribution, and the 32-bit pack are a tiny [B, 32]
-epilogue outside the kernel.
+epilogue after the kernel, inside the same jit (``_fold_tiled``).
 """
 
 from __future__ import annotations
@@ -137,7 +137,13 @@ def _make_kernel(bt: int, sub: int, interpret: bool):
 @functools.partial(
     jax.jit, static_argnames=("block_bytes", "interpret")
 )
-def _fold_tiled(kt, data, block_bytes, interpret=False):
+def _fold_tiled(kt, a_total, data, init, block_bytes, interpret=False):
+    """The whole checksum as one program a (blocks, block_bytes) shape:
+    the Pallas fold, the init register's journey across the block
+    (``a_total``), the mod 2 and the bit pack. ``init`` is an argument,
+    so another init compiles nothing."""
+    from .crc32c import acc_to_crc32, init_bits32
+
     nblocks = data.shape[0]
     nsub = kt.shape[0]
     sub = block_bytes // nsub
@@ -153,14 +159,40 @@ def _fold_tiled(kt, data, block_bytes, interpret=False):
         out_shape=jax.ShapeDtypeStruct((nblocks, 32), jnp.int32),
         interpret=interpret,
     )(kt, data)
-    return acc
+    acc = acc + (
+        a_total.astype(jnp.int32) @ init_bits32(init).astype(jnp.int32)
+    )
+    return acc_to_crc32(acc)
 
 
 @functools.lru_cache(maxsize=16)
-def _kt_cached(block_bytes: int, c: int):
-    from .crc32c import fold_tensor
+def _host_consts(block_bytes: int, c: int):
+    from .crc32c import fold_tensor, mat32, zero_gap_matrix
 
-    return jnp.asarray(_plane_major_kt(fold_tensor(block_bytes, c), c))
+    return (
+        _plane_major_kt(fold_tensor(block_bytes, c), c),
+        mat32(zero_gap_matrix(block_bytes)).astype(np.int8),
+    )
+
+
+_device_cache: dict = {}
+
+
+def _device_consts(block_bytes: int, c: int):
+    """(K^T plane-major, A_total) on the device, uploaded once a block
+    size. Under an active trace (``crc32c_device`` inside a jit or a
+    shard_map) an upload would be a tracer, which must NOT be cached:
+    the host arrays go in as they are and become compile-time
+    constants (``crc32c._device_fold``'s rule)."""
+    from ceph_tpu.utils.platform import trace_state_clean
+
+    if not trace_state_clean():
+        return _host_consts(block_bytes, c)
+    key = (block_bytes, c)
+    if key not in _device_cache:
+        kt, a_total = _host_consts(block_bytes, c)
+        _device_cache[key] = (jnp.asarray(kt), jnp.asarray(a_total))
+    return _device_cache[key]
 
 
 def supported(nblocks: int, block_bytes: int) -> bool:
@@ -177,34 +209,35 @@ def supported(nblocks: int, block_bytes: int) -> bool:
     )
 
 
+def tile_blocks(nblocks: int) -> int:
+    """The least block count >= ``nblocks`` that ``supported`` takes:
+    what a caller that owns its stack pads to (zero blocks, their
+    words dropped) instead of leaving the kernel for a count."""
+    if nblocks > BLOCK_TILE:
+        return -(-nblocks // BLOCK_TILE) * BLOCK_TILE
+    return max(8, -(-nblocks // 4) * 4)
+
+
 def crc32c_fold_pallas(
     data: jax.Array,  # [B, block_bytes] uint8
     init,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Per-block CRC32C accumulator path on the MXU; same contract as
-    the einsum kernel in checksum/crc32c."""
-    from .crc32c import _pick_chunk, zero_gap_matrix
+    the einsum kernel in checksum/crc32c: ONE compiled program a call
+    (``_fold_tiled``), no eager op before or after it."""
+    from .crc32c import _pick_chunk
 
     if interpret is None:
         from ceph_tpu.utils import platform
 
         interpret = platform.pallas_interpret()
-    nblocks, block_bytes = data.shape
-    c = _pick_chunk(block_bytes)
-    kt = _kt_cached(block_bytes, c)
-    acc = _fold_tiled(kt, data, block_bytes, interpret=interpret)
-    a_total = jnp.asarray(
-        np.frombuffer(
-            zero_gap_matrix(block_bytes), dtype=np.uint8
-        ).reshape(32, 32),
-        jnp.int32,
+    block_bytes = data.shape[1]
+    kt, a_total = _device_consts(block_bytes, _pick_chunk(block_bytes))
+    if isinstance(init, (int, np.integer)):
+        # a host scalar rides the call's own argument upload; a weak
+        # Python int would overflow int32 and key a second program
+        init = np.uint32(int(init) & 0xFFFFFFFF)
+    return _fold_tiled(
+        kt, a_total, data, init, block_bytes, interpret=interpret
     )
-    init_bits = (
-        (jnp.asarray(init, jnp.uint32) >> jnp.arange(32, dtype=jnp.uint32))
-        & 1
-    ).astype(jnp.int32)
-    acc = acc + (a_total @ init_bits)
-    crc_bits = (acc & 1).astype(jnp.uint32)
-    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(crc_bits * weights, axis=-1, dtype=jnp.uint32)

@@ -6,9 +6,10 @@ to the object and checked by deep scrub (ECBackend.cc:1829-1869).
 
 Two append paths, bit-identical by construction:
 
-- ``append``: raw bytes, routed through ``checksum.crc32c_stream``
-  (host native below the device threshold, device-batched fold
-  above) — the fallback tier.
+- ``append``: raw bytes, all shards together through
+  ``checksum.crc32c_streams`` (host native below the device threshold;
+  above it ONE device checksum call and one fetch an append, whatever
+  k+m is) — the tier of every write whose csums do not come fused.
 - ``append_block_csums``: seeds the cumulative hashes from the fused
   encode+checksum kernel's ZERO-INIT per-block csums
   (ops/pallas_encode.py) via crc range concatenation — the bytes are
@@ -23,7 +24,8 @@ import json
 
 import numpy as np
 
-from ceph_tpu.checksum import crc32c_fold, crc32c_stream
+from ceph_tpu.checksum import crc32c_fold, crc32c_streams
+from ceph_tpu.checksum.crc32c import as_stream
 
 SEED = 0xFFFFFFFF
 
@@ -37,40 +39,33 @@ class HashInfo:
         self,
         old_size: int,
         to_append: "dict[int, np.ndarray | bytes | bytearray | memoryview]",
-    ) -> None:
+    ) -> int:
         """Extend shard crcs with bytes written at ``old_size``.
 
         Values are raw shard bytes: bytes-like taken as-is, ndarrays
         must already be uint8 (no silent value casts — the crc is over
-        stored bytes, so a lossy cast would hide corruption).
+        stored bytes, so a lossy cast would hide corruption). They are
+        read where they lie, never copied into ``bytes``.
 
         The reference asserts appends are contiguous and equal-length
         across shards (HashInfo::append, ECUtil.cc); same contract here.
-        """
+        Returns the number of device checksum calls the append made
+        (``crc32c_streams``: one for all shards, or none where the
+        host served it)."""
         if old_size != self.total_chunk_size:
             raise ValueError(
                 f"non-contiguous append: old_size={old_size}, "
                 f"have={self.total_chunk_size}"
             )
-
-        def as_bytes(b) -> bytes:
-            if isinstance(b, (bytes, bytearray, memoryview)):
-                return bytes(b)
-            arr = np.asarray(b)
-            if arr.dtype != np.uint8:
-                raise TypeError(f"shard bytes must be uint8, got {arr.dtype}")
-            return arr.tobytes()
-
-        bufs = {shard: as_bytes(b) for shard, b in to_append.items()}
-        sizes = {len(b) for b in bufs.values()}
-        if len(sizes) > 1:
-            raise ValueError(f"unequal append sizes {sizes}")
-        for shard, data in bufs.items():
-            self.cumulative_shard_hashes[shard] = crc32c_stream(
-                data, self.cumulative_shard_hashes[shard]
-            )
-        if sizes:
-            self.total_chunk_size += sizes.pop()
+        shards = list(to_append)
+        rows = [as_stream(b) for b in to_append.values()]
+        hashes = self.cumulative_shard_hashes
+        regs, calls = crc32c_streams([hashes[s] for s in shards], rows)
+        for shard, reg in zip(shards, regs):
+            hashes[shard] = reg
+        if rows:
+            self.total_chunk_size += int(rows[0].size)
+        return calls
 
     def append_block_csums(
         self,

@@ -536,6 +536,19 @@ class RMWPipeline:
                 "hinfo_fold_blocks", "csum words those folds took"
             )
             .add_time("hinfo_fold_seconds", "wall inside those folds")
+            # the other way a write's HashInfo grows: no kernel csums,
+            # so the append hashes the shards' raw bytes, all k+m
+            # shards together (HashInfo.append)
+            .add_u64_counter(
+                "hinfo_streams", "HashInfo appends that hashed raw bytes"
+            )
+            .add_u64_counter(
+                "hinfo_stream_calls",
+                "device checksum calls those appends made (one an "
+                "append; none where the host served it)",
+            )
+            .add_u64_counter("hinfo_stream_bytes", "bytes they hashed")
+            .add_time("hinfo_stream_seconds", "wall inside those appends")
             # the old-data read of an RMW, and the parity-delta encode
             # step by step; ``rmw_read_ops`` / ``delta_ops`` are their
             # denominators
@@ -1169,6 +1182,12 @@ class RMWPipeline:
                     self.perf.inc("hinfo_folds")
                     self.perf.inc("hinfo_fold_blocks", words)
                     self.perf.tinc("hinfo_fold_seconds", seconds)
+                if new_map.hinfo_stream is not None:
+                    calls, nbytes, seconds = new_map.hinfo_stream
+                    self.perf.inc("hinfo_streams")
+                    self.perf.inc("hinfo_stream_calls", calls)
+                    self.perf.inc("hinfo_stream_bytes", nbytes)
+                    self.perf.tinc("hinfo_stream_seconds", seconds)
             else:
                 # not a contiguous append: cumulative crcs can't be
                 # extended — invalidate (deep scrub then skips them)

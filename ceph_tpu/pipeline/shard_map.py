@@ -69,6 +69,10 @@ class ShardExtentMap:
         #: the HashInfo ``encode`` was given, where it took that route
         #: (the pipeline's ``hinfo_fold*`` counters read it)
         self.hinfo_fold: "tuple[int, float] | None" = None
+        #: ``(device checksum calls, bytes, seconds)`` of the raw-bytes
+        #: HashInfo append, where ``encode`` took that route instead
+        #: (the pipeline's ``hinfo_stream*`` counters read it)
+        self.hinfo_stream: "tuple[int, int, float] | None" = None
         #: ``(rows, stripes)`` of the whole stripes ``insert_ro_range``
         #: last scattered from an immutable buffer: ``rows`` [k, n, chunk]
         #: holds the data shards' runs, ``stripes`` [n, k, chunk] is that
@@ -365,7 +369,7 @@ class ShardExtentMap:
         become the parity runs by ownership: the data runs are read,
         never copied or changed."""
         k, m = self.sinfo.k, self.sinfo.m
-        self.csums = self.hinfo_fold = None
+        self.csums = self.hinfo_fold = self.hinfo_stream = None
         lo0, hi0 = self._slice_window()
         if hi0 <= lo0:
             return
@@ -461,7 +465,11 @@ class ShardExtentMap:
                     )
                     self.hinfo_fold = (words, time.perf_counter() - t0)
                 else:
-                    hashinfo.append(
+                    # no kernel csums (a mesh, a codec without the
+                    # fused pass, a window off the csum grid): the
+                    # shards' bytes, every shard in one hash
+                    t0 = time.perf_counter()
+                    calls = hashinfo.append(
                         base,
                         {
                             self.sinfo.get_shard(raw): self.get(
@@ -470,6 +478,10 @@ class ShardExtentMap:
                             )
                             for raw in range(k + m)
                         },
+                    )
+                    self.hinfo_stream = (
+                        calls, (k + m) * (hi0 - base),
+                        time.perf_counter() - t0,
                     )
 
     @staticmethod
